@@ -358,18 +358,10 @@ def cmd_demo(out: str | None) -> int:
     and show the live registry — the zero-to-snapshot smoke path."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from triton_dist_tpu.runtime import telemetry
-    from triton_dist_tpu.runtime.platform import (
-        use_cpu_devices,
-        cpu_mesh,
-        tpu_interpret_available,
-    )
+    from triton_dist_tpu.runtime.platform import cpu_mesh, use_cpu_devices
     from triton_dist_tpu.runtime.mesh import initialize_distributed
 
     use_cpu_devices(8)
-    if not tpu_interpret_available():
-        # Old jax: no TPU interpret classes — let the demo's single-device
-        # kernels (flash-attn) run under the generic HLO interpreter.
-        os.environ.setdefault("TDT_INTERPRET_FALLBACK", "1")
     import jax
     import jax.numpy as jnp
 

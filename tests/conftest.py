@@ -4,6 +4,11 @@ This replaces the reference's torchrun launcher + ``TRITON_INTERPRET=1``
 emulation (SURVEY §4): kernels run unmodified, with simulated HBM/VMEM,
 local + remote DMAs and semaphores (``pltpu.InterpretParams``).
 
+``use_cpu_devices`` also sizes XLA's CPU thread pools well above the device
+count (``NPROC``): in the simulation threads wait on each other, and a pool
+with no thread to spare wedges the mesh (the hazards below are two faces of
+that; they were written when the pool had one thread a core).
+
 IMPORTANT (sim substrate limitation): on this single-core host, interpret-mode
 collective kernels deadlock when any single kernel buffer allocation is
 ≳128 KB — the blocking semaphore-wait callbacks starve the CPU client's
@@ -56,11 +61,6 @@ def pytest_configure(config):
     config.addinivalue_line("markers", "timeout(seconds): per-test hang watchdog limit")
     config.addinivalue_line(
         "markers",
-        "tpu: runs compiled (non-interpret) kernels on the real chip; "
-        "auto-skips when no TPU is reachable (see tests/test_on_tpu.py)",
-    )
-    config.addinivalue_line(
-        "markers",
         "chaos: fault-injection tests driving collective kernels under a "
         "FaultPlan in interpret mode (see tests/test_resilience.py)",
     )
@@ -106,15 +106,25 @@ def _hang_watchdog(request):
         return
     fired = threading.Event()
 
+    # pytest captures fd 2 while a test runs, and what is captured dies with
+    # the process: report through the copy of the real stderr that pytest's
+    # own faulthandler plugin keeps, so the run's log names the test.
+    from _pytest.faulthandler import fault_handler_stderr_fd_key
+
+    real_fd = request.config.stash.get(fault_handler_stderr_fd_key, None)
+
     def _abort():
         if fired.is_set():
             return
-        sys.stderr.write(
+        err = sys.stderr if real_fd is None else os.fdopen(
+            os.dup(real_fd), "w")
+        err.write(
             f"\n*** HANG WATCHDOG: {request.node.nodeid} exceeded {limit}s — "
             "dumping stacks and aborting ***\n"
         )
-        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
-        sys.stderr.flush()
+        err.flush()
+        faulthandler.dump_traceback(file=err, all_threads=True)
+        err.flush()
         os._exit(98)  # hard kill: a stuck XLA rendezvous is not interruptible
 
     timer = threading.Timer(limit, _abort)

@@ -41,6 +41,46 @@ def test_flash_attention_continuation(rng):
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("mode", ["causal", "full", "lse", "chunk"])
+def test_flash_attention_pads_illegal_lengths(rng, mode):
+    """A length with no Mosaic-legal divisor block (44 under 16-row blocks:
+    the largest divisor is 11, no multiple of 8) is padded to the lane width
+    and sliced back: padded keys are masked in-kernel, positions keep the
+    original lengths. Covers the prefill, the LSE output (whose block must
+    be lane-aligned), and a prefill CHUNK against a longer context buffer
+    with a dynamic offset — the server's arbitrary prompt lengths."""
+    from triton_dist_tpu.kernels.flash_attn import _legal_len
+
+    assert _legal_len(44, 16, 8) == 128 and _legal_len(1500, 1024, 8) == 1536
+    assert _legal_len(64, 16, 8) == 64 and _legal_len(61, 1024, 8) == 61
+    hq, hkv, s, d = 4, 2, 44, 32
+    k = jnp.asarray(rng.standard_normal((1, hkv, s, d)), jnp.float32) * 0.5
+    v = jnp.asarray(rng.standard_normal((1, hkv, s, d)), jnp.float32) * 0.5
+    if mode == "chunk":
+        c, off = 20, 12
+        q = jnp.asarray(rng.standard_normal((1, hq, c, d)), jnp.float32) * 0.5
+        o = flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=16,
+            q_offset=jnp.int32(off), kv_offset=jnp.int32(0),
+        )
+        kx, vx = jnp.repeat(k, hq // hkv, 1), jnp.repeat(v, hq // hkv, 1)
+        sc = jnp.einsum("bhqd,bhkd->bhqk", q, kx) * d ** -0.5
+        mask = (off + jnp.arange(c))[:, None] >= jnp.arange(s)[None]
+        ref = jnp.einsum(
+            "bhqk,bhkd->bhqd", jax.nn.softmax(jnp.where(mask, sc, -1e30), -1), vx)
+    else:
+        q = jnp.asarray(rng.standard_normal((1, hq, s, d)), jnp.float32) * 0.5
+        causal = mode != "full"
+        o = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
+                            return_lse=mode == "lse")
+        if mode == "lse":
+            o, lse = o
+            assert lse.shape == (1, hq, s) and np.isfinite(np.asarray(lse)).all()
+        ref = attention_reference(q, k, v, causal=causal)
+    assert o.shape == ref.shape
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
 def test_flash_decode_matches_reference(rng):
     b, hq, hkv, s, d = 2, 8, 2, 256, 64
     q = jnp.asarray(rng.standard_normal((b, hq, d)), jnp.float32)

@@ -37,14 +37,12 @@ from triton_dist_tpu.disagg.kv_transfer import (
 from triton_dist_tpu.disagg.pool import ROLE_DECODE, ROLE_PREFILL, default_roles
 from triton_dist_tpu.fleet import Router
 from triton_dist_tpu.runtime import introspect, resilience, telemetry
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
 from triton_dist_tpu.serving import InferenceServer
 
 MAX_LEN = 32
 
 REPLICA_ENV = {
     "JAX_PLATFORMS": "cpu",
-    "TDT_INTERPRET_FALLBACK": "1",
     "TDT_SERVE_SLOTS": "2",
     "TDT_SERVE_CHUNK": "2",
 }
@@ -55,22 +53,6 @@ REQUESTS = [
     ([17, 3, 17, 3, 17], 7),
     ([9, 8, 7, 6], 5),
 ]
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _single_device_kernels():
-    if tpu_interpret_available():
-        yield
-        return
-    prev = os.environ.get("TDT_INTERPRET_FALLBACK")
-    os.environ["TDT_INTERPRET_FALLBACK"] = "1"
-    jax.clear_caches()
-    yield
-    if prev is None:
-        os.environ.pop("TDT_INTERPRET_FALLBACK", None)
-    else:
-        os.environ["TDT_INTERPRET_FALLBACK"] = prev
-    jax.clear_caches()
 
 
 @pytest.fixture(autouse=True)

@@ -33,7 +33,6 @@ import pytest
 
 from triton_dist_tpu.fleet import FleetRequest, ReplicaService, Router
 from triton_dist_tpu.runtime import introspect, resilience, telemetry
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
 from triton_dist_tpu.serving import (
     InferenceServer,
     RequestJournal,
@@ -43,30 +42,13 @@ from triton_dist_tpu.serving import (
 MAX_LEN = 32
 BLOCK = 16  # TDT_KV_BLOCK_SIZE default — one full block indexes at 16 tokens
 
-#: Env for replica subprocesses: CPU devices, interpreter fallback for
-#: single-device Pallas, small serving shape for fast boot/serve.
+#: Env for replica subprocesses: CPU devices, small serving shape for fast
+#: boot/serve.
 REPLICA_ENV = {
     "JAX_PLATFORMS": "cpu",
-    "TDT_INTERPRET_FALLBACK": "1",
     "TDT_SERVE_SLOTS": "2",
     "TDT_SERVE_CHUNK": "2",
 }
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _single_device_kernels():
-    if tpu_interpret_available():
-        yield
-        return
-    prev = os.environ.get("TDT_INTERPRET_FALLBACK")
-    os.environ["TDT_INTERPRET_FALLBACK"] = "1"
-    jax.clear_caches()
-    yield
-    if prev is None:
-        os.environ.pop("TDT_INTERPRET_FALLBACK", None)
-    else:
-        os.environ["TDT_INTERPRET_FALLBACK"] = prev
-    jax.clear_caches()
 
 
 @pytest.fixture(autouse=True)

@@ -13,28 +13,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _single_device_kernels():
-    """On jax builds without the TPU interpret classes, run the
-    single-device Pallas kernels (group_gemm_swiglu) under the generic HLO
-    interpreter — same escape hatch as the serving tests. The collective
-    ``dist_pallas_call`` kernels still need real TPU interpret machinery;
-    their ``use_pallas=True`` variants are unaffected by this flag."""
-    if tpu_interpret_available():
-        yield
-        return
-    prev = os.environ.get("TDT_INTERPRET_FALLBACK")
-    os.environ["TDT_INTERPRET_FALLBACK"] = "1"
-    jax.clear_caches()
-    yield
-    if prev is None:
-        os.environ.pop("TDT_INTERPRET_FALLBACK", None)
-    else:
-        os.environ["TDT_INTERPRET_FALLBACK"] = prev
-    jax.clear_caches()
 
 from triton_dist_tpu.kernels.moe_utils import (
     capacity_for,

@@ -146,7 +146,8 @@ def test_gemm_ar_matches_dot_psum(request, rng, ctx_name, world, shape, method):
     the same order the psum reference uses — so it must be EXACT. The
     fused ring starts each chunk's accumulation at a rotated rank
     (chunk c sums c+1, c+2, ..., c), so its fp32 sum can differ from the
-    reference in the last ulp — last-ulp tolerance, nothing looser."""
+    reference by a few ulps of the LARGEST partial sum (|partials| reach ~30
+    here, ulp 1.9e-6, while the result may cancel to ~1) — nothing looser."""
     ctx = request.getfixturevalue(ctx_name)
     m, n = (32, 32) if shape == "square" else (8, 64)
     k = world * 16
@@ -171,7 +172,7 @@ def test_gemm_ar_matches_dot_psum(request, rng, ctx_name, world, shape, method):
         if method is GemmARMethod.LL_ONE_SHOT:
             np.testing.assert_array_equal(out[r], ref[r], err_msg=f"rank {r}")
         else:
-            np.testing.assert_allclose(out[r], ref[r], rtol=2e-7, atol=1e-6,
+            np.testing.assert_allclose(out[r], ref[r], rtol=1e-6, atol=1e-5,
                                        err_msg=f"rank {r}")
 
 
@@ -233,8 +234,8 @@ def test_gemm_ar_fused_tiled(ctx8, rng):
 
 def test_gemm_ar_auto_routing():
     """AUTO's M/world crossover (pure trace-time routing, no devices):
-    decode-sized and ragged M take the low-latency one-shot kernel, large
-    divisible M takes the fused RS+AG ring. Uses the static default
+    decode-sized M takes the low-latency one-shot kernel, large M the
+    fused RS+AG ring when its row chunks are sublane-aligned, else XLA. Uses the static default
     crossover (cold tune cache)."""
     from triton_dist_tpu.kernels.gemm_allreduce import (
         DEFAULT_GEMM_AR_CROSSOVER_M,
@@ -248,8 +249,13 @@ def test_gemm_ar_auto_routing():
                 is GemmARMethod.LL_ONE_SHOT)
         # Prefill-sized M above the crossover: the fused ring.
         assert get_auto_gemm_ar_method(4096, world) is GemmARMethod.PALLAS_FUSED
-        # Ragged M can't chunk over ranks — ll regardless of size.
-        assert get_auto_gemm_ar_method(4096 + 1, world) is GemmARMethod.LL_ONE_SHOT
+        # Large ragged M (a prompt of arbitrary length): the ring can't
+        # chunk it into sublane-aligned row blocks and the one-shot kernel
+        # would hold all of it in VMEM — dot + psum.
+        assert get_auto_gemm_ar_method(4096 + 1, world) is GemmARMethod.XLA
+        assert get_auto_gemm_ar_method(1500, world) is GemmARMethod.XLA
+        # Ragged decode M stays on the one-shot kernel.
+        assert get_auto_gemm_ar_method(6, world) is GemmARMethod.LL_ONE_SHOT
 
 
 def test_ag_gemm_pallas_tiled(ctx8, rng):
@@ -401,18 +407,10 @@ def test_gemm_rs_2d_reorder_to_outer_major(ctx24, rng):
 
 # ==================================================== prefill overlap v2
 #
-# The fused double-buffered AG-GEMM (+SwiGLU epilogue) and fused GEMM-RS
-# execute only on the TPU interpret substrate — parity tests for those
-# paths are gated; the XLA references they are compared against, the tuned
-# AUTO routing, and the ragged/tiny-M coverage run everywhere.
+# The fused double-buffered AG-GEMM (+SwiGLU epilogue) and fused GEMM-RS vs
+# the XLA references, the tuned AUTO routing, and the ragged/tiny-M coverage.
 
 from triton_dist_tpu.kernels.allgather_gemm import ag_gemm_swiglu_shard
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
-
-fused_substrate = pytest.mark.skipif(
-    not tpu_interpret_available(),
-    reason="fused collective kernels need the TPU interpret substrate",
-)
 
 
 def _swiglu_ref(a, wg, wu):
@@ -425,7 +423,7 @@ def _swiglu_ref(a, wg, wu):
 @pytest.mark.parametrize(
     "method",
     [AGGemmMethod.XLA_RING, AGGemmMethod.XLA_AG_THEN_GEMM,
-     pytest.param(AGGemmMethod.PALLAS_FUSED, marks=fused_substrate)],
+     AGGemmMethod.PALLAS_FUSED],
 )
 def test_ag_gemm_swiglu_parity(request, rng, ctx_name, world, method):
     """``silu(AG(x) @ w_gate) * (AG(x) @ w_up)`` across all three routes at
@@ -449,7 +447,6 @@ def test_ag_gemm_swiglu_parity(request, rng, ctx_name, world, method):
     )
 
 
-@fused_substrate
 def test_ag_gemm_swiglu_fused_tiled(ctx8, rng):
     """Multi-tile SwiGLU epilogue (Mt=2, Nt=2, Kt=2): both weight operands
     stream through the same double-buffered ring pass, the gate/up fp32
@@ -499,7 +496,6 @@ def test_ag_gemm_auto_tiny_ragged_m(request, rng, ctx_name, world, m_shard):
     )
 
 
-@fused_substrate
 @pytest.mark.parametrize("ctx_name,world", [("ctx8", 8), ("ctx4", 4)])
 def test_ag_gemm_fused_parity_worlds(request, rng, ctx_name, world):
     """The double-buffered fused kernel vs the plain dot reference at both
@@ -523,7 +519,6 @@ def test_ag_gemm_fused_parity_worlds(request, rng, ctx_name, world):
     )
 
 
-@fused_substrate
 @pytest.mark.parametrize("ctx_name,world", [("ctx8", 8), ("ctx4", 4)])
 def test_gemm_rs_fused_parity_worlds(request, rng, ctx_name, world):
     """Fused tile-streaming GEMM-RS vs the dot + psum_scatter reference

@@ -24,29 +24,10 @@ import pytest
 
 from triton_dist_tpu.models.kv_cache import NULL_BLOCK, BlockAllocator
 from triton_dist_tpu.runtime import resilience, telemetry
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
 from triton_dist_tpu.serving import InferenceServer, RequestState, Scheduler
 from triton_dist_tpu.serving.scheduler import KVLedger, Request
 
 MAX_LEN = 32
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _single_device_kernels():
-    """Single-device Pallas kernels run under the generic HLO interpreter
-    on jax builds without the TPU interpret classes (trace-time flag)."""
-    if tpu_interpret_available():
-        yield
-        return
-    prev = os.environ.get("TDT_INTERPRET_FALLBACK")
-    os.environ["TDT_INTERPRET_FALLBACK"] = "1"
-    jax.clear_caches()
-    yield
-    if prev is None:
-        os.environ.pop("TDT_INTERPRET_FALLBACK", None)
-    else:
-        os.environ["TDT_INTERPRET_FALLBACK"] = prev
-    jax.clear_caches()
 
 
 @pytest.fixture(autouse=True)

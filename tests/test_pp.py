@@ -28,30 +28,11 @@ from triton_dist_tpu.layers.pp import PPCommLayer
 from triton_dist_tpu.layers.pp_schedule import gpipe_forward, gpipe_stage_params
 from triton_dist_tpu.runtime import telemetry
 from triton_dist_tpu.runtime.mesh import initialize_distributed
-from triton_dist_tpu.runtime.platform import cpu_mesh, tpu_interpret_available
+from triton_dist_tpu.runtime.platform import cpu_mesh
 
 L = 4       # toy layers (one per stage on the 4-stage mesh)
 D = 8       # toy feature width
 MB = 2      # rows per microbatch
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _single_device_kernels():
-    """Engine prefill runs single-device Pallas attention; fall back to
-    the generic HLO interpreter on jax builds without the TPU interpret
-    classes (same arrangement as tests/test_paged_kv.py)."""
-    if tpu_interpret_available():
-        yield
-        return
-    prev = os.environ.get("TDT_INTERPRET_FALLBACK")
-    os.environ["TDT_INTERPRET_FALLBACK"] = "1"
-    jax.clear_caches()
-    yield
-    if prev is None:
-        os.environ.pop("TDT_INTERPRET_FALLBACK", None)
-    else:
-        os.environ["TDT_INTERPRET_FALLBACK"] = prev
-    jax.clear_caches()
 
 
 @pytest.fixture(scope="module")

@@ -54,36 +54,8 @@ from triton_dist_tpu.models.quant import (
     wire_itemsize,
 )
 from triton_dist_tpu.runtime import resilience, telemetry
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
 
 WIRES = ("int8", "fp8")
-
-fused_substrate = pytest.mark.skipif(
-    not tpu_interpret_available(),
-    reason="fused collective kernels need the TPU interpret substrate",
-)
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _single_device_kernels():
-    """Single-device Pallas kernels (paged decode, serving prefill) run
-    under the generic HLO interpreter on jax builds without the TPU
-    interpret classes — same discipline as tests/test_paged_kv.py. The
-    collective-tier tests here only exercise XLA routes on that substrate
-    (fused routes are gated), so the flag never reaches a multi-device
-    kernel."""
-    if tpu_interpret_available():
-        yield
-        return
-    prev = os.environ.get("TDT_INTERPRET_FALLBACK")
-    os.environ["TDT_INTERPRET_FALLBACK"] = "1"
-    jax.clear_caches()
-    yield
-    if prev is None:
-        os.environ.pop("TDT_INTERPRET_FALLBACK", None)
-    else:
-        os.environ["TDT_INTERPRET_FALLBACK"] = prev
-    jax.clear_caches()
 
 
 @pytest.fixture(autouse=True)
@@ -176,7 +148,7 @@ def _shard(ctx, fn, in_specs, out_specs):
 AG_METHODS = [
     AGGemmMethod.XLA_RING,
     AGGemmMethod.XLA_AG_THEN_GEMM,
-    pytest.param(AGGemmMethod.PALLAS_FUSED, marks=fused_substrate),
+    AGGemmMethod.PALLAS_FUSED,
 ]
 
 
@@ -207,7 +179,7 @@ def test_ag_gemm_quant_parity(request, ctx_name, world, method, wire, rng):
 @pytest.mark.parametrize(
     "method",
     [AGGemmMethod.XLA_RING,
-     pytest.param(AGGemmMethod.PALLAS_FUSED, marks=fused_substrate)],
+     AGGemmMethod.PALLAS_FUSED],
 )
 def test_ag_gemm_swiglu_quant_parity(ctx8, method, wire, rng):
     """Quantized AG-GEMM + SwiGLU epilogue: both weight mats consume the
@@ -239,7 +211,7 @@ def test_ag_gemm_swiglu_quant_parity(ctx8, method, wire, rng):
 RS_METHODS = [
     GemmRSMethod.XLA,
     GemmRSMethod.XLA_RING,
-    pytest.param(GemmRSMethod.PALLAS_FUSED, marks=fused_substrate),
+    GemmRSMethod.PALLAS_FUSED,
 ]
 
 
@@ -274,8 +246,8 @@ def test_gemm_rs_quant_parity(request, ctx_name, world, method, wire, rng):
 
 AR_METHODS = [
     GemmARMethod.XLA,
-    pytest.param(GemmARMethod.PALLAS_FUSED, marks=fused_substrate),
-    pytest.param(GemmARMethod.LL_ONE_SHOT, marks=fused_substrate),
+    GemmARMethod.PALLAS_FUSED,
+    GemmARMethod.LL_ONE_SHOT,
 ]
 
 

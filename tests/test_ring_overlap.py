@@ -12,9 +12,9 @@ itself: **no ring hop ever consumes a value produced (even transitively) by
 a flash kernel call**. These tests walk the jaxpr and enforce that; a
 negative control proves the walker actually catches a serialized ring.
 
-On a live chip, the scheduled-module form of the same claim (async pairs
-bracketing the flash custom-call) needs a multi-chip compile and lives with
-the other on-chip evidence (``tests/test_on_tpu.py``).
+The scheduled-module form of the same claim (async pairs bracketing the
+flash custom-call) needs a multi-chip compile and lives with the other
+deviceless compiles (``tests/test_tpu_lowering.py``).
 """
 
 import itertools

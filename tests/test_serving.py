@@ -24,7 +24,6 @@ import pytest
 
 from triton_dist_tpu.models.kv_cache import KVCache
 from triton_dist_tpu.runtime import resilience, telemetry
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
 from triton_dist_tpu.serving import (
     InferenceServer,
     RequestState,
@@ -33,26 +32,6 @@ from triton_dist_tpu.serving import (
 )
 
 MAX_LEN = 32
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _single_device_kernels():
-    """On jax builds without the TPU interpret classes, run the
-    single-device Pallas kernels under the generic HLO interpreter.
-    Trace-time flag: clear caches around both flips (module-scoped so the
-    engine fixtures below compile once under a consistent setting)."""
-    if tpu_interpret_available():
-        yield
-        return
-    prev = os.environ.get("TDT_INTERPRET_FALLBACK")
-    os.environ["TDT_INTERPRET_FALLBACK"] = "1"
-    jax.clear_caches()
-    yield
-    if prev is None:
-        os.environ.pop("TDT_INTERPRET_FALLBACK", None)
-    else:
-        os.environ["TDT_INTERPRET_FALLBACK"] = prev
-    jax.clear_caches()
 
 
 @pytest.fixture(autouse=True)

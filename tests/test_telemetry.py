@@ -19,16 +19,8 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.runtime import resilience, telemetry
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
 
 LINT = "scripts/check_metric_names.py"
-
-# Collective kernels need the TPU interpret machinery (semaphore + remote-DMA
-# simulation); on jax builds without it they cannot run on CPU at all.
-needs_tpu_interpret = pytest.mark.skipif(
-    not tpu_interpret_available(),
-    reason="jax build lacks pltpu (TPU)InterpretParams — no collective simulation",
-)
 
 
 @pytest.fixture(autouse=True)
@@ -315,20 +307,7 @@ def dense_model(request):
     return DenseLLM(cfg, ctx, key=jax.random.PRNGKey(1))
 
 
-@pytest.fixture
-def single_device_kernels(monkeypatch):
-    """On jax builds without the TPU interpret classes, single-device Pallas
-    kernels (the xla serve path's flash-attn) can still run under the generic
-    HLO interpreter. Trace-time flag: clear caches around the flip."""
-    if not tpu_interpret_available():
-        monkeypatch.setenv("TDT_INTERPRET_FALLBACK", "1")
-        jax.clear_caches()
-    yield
-    if not tpu_interpret_available():
-        jax.clear_caches()
-
-
-def test_serve_latency_histograms(dense_model, single_device_kernels):
+def test_serve_latency_histograms(dense_model):
     from triton_dist_tpu.models import Engine
 
     eng = Engine(dense_model, backend="xla", max_len=32)
@@ -355,7 +334,6 @@ W4 = 4
 
 
 @pytest.mark.chaos
-@needs_tpu_interpret
 def test_chaos_abort_counter_labeled(ctx4, rng):
     """The acceptance scenario: after a dropped-peer abort, the snapshot
     shows ``tdt_resilience_aborts_total`` labeled with the stalled phase and
@@ -398,7 +376,6 @@ def kernel_trace_env(monkeypatch):
     jax.clear_caches()
 
 
-@needs_tpu_interpret
 def test_kernel_trace_roundtrip_allgather(ctx4, rng, kernel_trace_env, tmp_path):
     from triton_dist_tpu.kernels import AllGatherMethod, all_gather_shard
     from triton_dist_tpu.tools import profiler
@@ -437,7 +414,6 @@ def test_kernel_trace_roundtrip_allgather(ctx4, rng, kernel_trace_env, tmp_path)
     assert pids == set(range(W4))  # one chrome row per rank
 
 
-@needs_tpu_interpret
 def test_kernel_trace_off_means_no_buffers(ctx4, rng):
     """Flag unset: maybe_kernel_trace returns None and kernels keep their
     exact pre-trace signature — nothing is collected."""

@@ -18,26 +18,9 @@ import numpy as np
 import pytest
 
 from triton_dist_tpu.runtime import introspect, resilience, telemetry, tracing
-from triton_dist_tpu.runtime.platform import tpu_interpret_available
 from triton_dist_tpu.serving import InferenceServer
 
 MAX_LEN = 32
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _single_device_kernels():
-    if tpu_interpret_available():
-        yield
-        return
-    prev = os.environ.get("TDT_INTERPRET_FALLBACK")
-    os.environ["TDT_INTERPRET_FALLBACK"] = "1"
-    jax.clear_caches()
-    yield
-    if prev is None:
-        os.environ.pop("TDT_INTERPRET_FALLBACK", None)
-    else:
-        os.environ["TDT_INTERPRET_FALLBACK"] = prev
-    jax.clear_caches()
 
 
 @pytest.fixture(autouse=True)

@@ -27,58 +27,6 @@ riding ICI, Pallas kernels feeding the MXU, static shapes, functional APIs.
 
 from triton_dist_tpu.version import __version__
 
-# ---------------------------------------------------------------------------
-# jax API compat: the codebase targets the stable `jax.shard_map` entry point
-# (check_vma kwarg). On older jax (< 0.6) that lives at
-# jax.experimental.shard_map.shard_map with the kwarg spelled check_rep —
-# install a forwarding alias so every call site works on both.
-# ---------------------------------------------------------------------------
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=True, **kwargs):
-        kwargs.setdefault("check_rep", check_vma)
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):  # pragma: no cover - version-dependent
-    def _axis_size_compat(axis_name):
-        # psum of a Python int is evaluated statically -> concrete axis size
-        # (the long-standing idiom axis_size replaced).
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size_compat
-
-from jax.experimental.pallas import tpu as _pltpu
-
-if not hasattr(_pltpu, "CompilerParams"):  # pragma: no cover - version-dependent
-    # Renamed upstream (TPUCompilerParams -> CompilerParams); same fields.
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-
-import dataclasses as _dataclasses
-
-if "has_side_effects" not in {
-    f.name for f in _dataclasses.fields(_pltpu.CompilerParams)
-}:  # pragma: no cover - version-dependent
-    # Older jax predates CompilerParams.has_side_effects (the DCE guard for
-    # kernels whose outputs may go unused). Accept-and-drop the kwarg via a
-    # subclass so every call site works on both; the subclass keeps the
-    # dataclass fields and isinstance identity pallas lowering relies on.
-    class _CompilerParamsCompat(_pltpu.CompilerParams):
-        def __init__(self, *args, has_side_effects=None, **kwargs):
-            del has_side_effects  # not modeled on this jax version
-            super().__init__(*args, **kwargs)
-
-    _CompilerParamsCompat.__name__ = "CompilerParams"
-    _CompilerParamsCompat.__qualname__ = "CompilerParams"
-    _pltpu.CompilerParams = _CompilerParamsCompat
-
 from triton_dist_tpu.runtime.mesh import (
     DistContext,
     initialize_distributed,

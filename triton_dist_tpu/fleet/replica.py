@@ -356,9 +356,16 @@ def build_server():
 
     from triton_dist_tpu.models import PRESETS, DenseLLM, Engine
     from triton_dist_tpu.runtime.mesh import initialize_distributed
-    from triton_dist_tpu.runtime.platform import cpu_mesh, use_cpu_devices
+    from triton_dist_tpu.runtime.platform import (
+        cpu_mesh,
+        enable_compile_cache,
+        use_cpu_devices,
+    )
     from triton_dist_tpu.serving import InferenceServer
 
+    # Every replica of a fleet compiles the same programs: the persistent
+    # cache lets a respawn or a scale-up boot from what its peers compiled.
+    enable_compile_cache()
     preset = os.environ.get("TDT_REPLICA_PRESET", "test-dense")
     backend = os.environ.get("TDT_REPLICA_BACKEND", "xla")
     max_len = get_int_env("TDT_REPLICA_MAX_LEN", 32)

@@ -37,7 +37,7 @@ LOG2E = 1.4426950408889634
 
 
 # Re-exported for backward compatibility; canonical home is kernels/gemm.py.
-from triton_dist_tpu.kernels.gemm import fit_block  # noqa: E402,F401
+from triton_dist_tpu.kernels.gemm import SUBLANES, fit_block  # noqa: E402,F401
 
 
 def _flash_kernel(
@@ -58,6 +58,7 @@ def _flash_kernel(
     n_kv: int,
     kv_len: int,
     sq: int,
+    pad_k: bool = False,
 ):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -90,6 +91,8 @@ def _flash_kernel(
         )  # (bq, bk)
         s *= scale * LOG2E
 
+        if masked or pad_k:
+            k_ids = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         if masked:
             # End-aligned (KV-cache) convention: query row i sits at absolute
             # position q_off + iq*bq + i (q_off = kv_len - sq statically, or
@@ -98,15 +101,18 @@ def _flash_kernel(
             q_ids = q_off + iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
             )
-            k_ids = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+        if pad_k:
+            # The wrapper padded K/V past kv_len to reach a Mosaic-legal
+            # block; those columns are no keys in any coordinate system.
+            s = jnp.where(k_ids < kv_len, s, NEG_INF)
 
         m_prev = m_scr[...]  # (bq, LANES)
         m_cur = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
         alpha = jnp.exp2(m_prev - m_new)  # (bq, LANES)
         p = jnp.exp2(s - m_new[:, :1])  # (bq, bk)
-        if masked:
+        if masked or pad_k:
             # A row with NO valid key yet has m_new == NEG_INF and would get
             # p = exp2(0) = 1 everywhere (→ mean(v) instead of 0). Re-mask
             # such rows, same guard as the varlen kernel. Reachable through
@@ -204,6 +210,16 @@ def flash_bwd_config_for(q_sds, k_sds, v_sds, causal: bool) -> tuple[int, int]:
     return flash_config_for(q_sds, k_sds, v_sds, causal)
 
 
+def _legal_len(n: int, want: int, mult: int) -> int:
+    """``n`` itself when ``fit_block(n, want)`` is a block Mosaic accepts on
+    that dim (the whole dim, or a multiple of ``mult``); else ``n`` rounded
+    up to the lane width, every divisor-block of which is legal."""
+    blk = fit_block(n, want)
+    if blk == n or blk % mult == 0:
+        return n
+    return -(-n // LANES) * LANES
+
+
 def flash_attention(
     q: jax.Array,  # (B, Hq, Sq, D)
     k: jax.Array,  # (B, Hkv, Sk, D)
@@ -241,22 +257,33 @@ def flash_attention(
         )
         block_q = tuned_q if block_q is None else block_q
         block_k = tuned_k if block_k is None else block_k
-    block_q = fit_block(sq, block_q)
-    block_k = fit_block(sk, block_k)
-    n_kv = sk // block_k
+    # Arbitrary lengths (a server's prompts): when no divisor of the length
+    # is a legal Mosaic block, pad the sequence dim and slice the output.
+    # Positions keep the ORIGINAL lengths (q_off = sk - sq); padded K columns
+    # are masked in-kernel, padded Q rows are computed and dropped.
+    sq_p = _legal_len(sq, block_q, LANES if return_lse else SUBLANES)
+    sk_p = _legal_len(sk, block_k, SUBLANES)
+    if sq_p != sq:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
+    if sk_p != sk:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
+    block_q = fit_block(sq_p, block_q)
+    block_k = fit_block(sk_p, block_k)
+    n_kv = sk_p // block_k
 
-    qr = q.reshape(b * hq, sq, d)
-    kr = k.reshape(b * hkv, sk, d)
-    vr = v.reshape(b * hkv, sk, d)
+    qr = q.reshape(b * hq, sq_p, d)
+    kr = k.reshape(b * hkv, sk_p, d)
+    vr = v.reshape(b * hkv, sk_p, d)
 
     def kv_index(bh, iq_, ik_, *_):
         # q head bh = bi*hq + h → kv row bi*hkv + h // group
         return (bh // hq) * hkv + (bh % hq) // group, ik_, 0
 
-    out_shape = [jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, d), lambda bh, iq, ik, *_: (bh, iq, 0))]
     if return_lse:
-        out_shape.append(jax.ShapeDtypeStruct((b * hq, 1, sq), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((b * hq, 1, sq_p), jnp.float32))
         out_specs.append(pl.BlockSpec((1, 1, block_q), lambda bh, iq, ik, *_: (bh, 0, iq)))
 
     dynamic = q_offset is not None or kv_offset is not None
@@ -269,6 +296,7 @@ def flash_attention(
         n_kv=n_kv,
         kv_len=sk,
         sq=sq,
+        pad_k=sk_p != sk,
     )
     if dynamic:
         if return_lse:
@@ -287,7 +315,7 @@ def flash_attention(
                 None, q_, k_, v_, o_, None, acc, m, l
             )
 
-    grid = (b * hq, sq // block_q, n_kv)
+    grid = (b * hq, sq_p // block_q, n_kv)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, iq, ik, *_: (bh, iq, 0)),
         pl.BlockSpec((1, block_k, d), kv_index),
@@ -326,8 +354,9 @@ def flash_attention(
 
     if return_lse:
         o, lse = res
-        return o.reshape(b, hq, sq, d), lse.reshape(b, hq, sq)
-    return res.reshape(b, hq, sq, d)
+        return (o.reshape(b, hq, sq_p, d)[:, :, :sq],
+                lse.reshape(b, hq, sq_p)[:, :, :sq])
+    return res.reshape(b, hq, sq_p, d)[:, :, :sq]
 
 
 def _flash_varlen_kernel(

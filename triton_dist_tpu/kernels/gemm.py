@@ -24,6 +24,11 @@ from jax.experimental.pallas import tpu as pltpu
 from triton_dist_tpu.runtime.platform import interpret_mode_default
 
 
+#: Rows of one Mosaic sublane tile: a block's second-to-last dim must be a
+#: multiple of this, or the whole array dim.
+SUBLANES = 8
+
+
 def fit_block(n: int, want: int) -> int:
     """Largest divisor of ``n`` that is ≤ ``want``, preferring lane-aligned
     (multiple-of-128) divisors. ALWAYS a divisor ≤ want (degenerate 1 for

@@ -24,7 +24,8 @@ decode-path wins (``e2e_dense.md:34-38``). TPU redesign:
   unfused composition baseline for tiny M.
 * **xla** — ``dot + psum`` baseline.
 
-AUTO picks ``ll_one_shot`` for ragged or small M (latency-bound decode) and
+AUTO picks ``ll_one_shot`` for small M (latency-bound decode, ragged or not),
+``dot + psum`` for large ragged M, and
 ``pallas_fused`` above the crossover; the crossover row count is a tune-cache
 entry (``gemm_ar_crossover|world=N``) read through
 ``tools.tune.agreed_cfg_value`` — cross-rank agreement from day one, since a
@@ -55,7 +56,7 @@ from triton_dist_tpu.kernels.allgather_gemm import (
     _is_quant,
     note_quant_dispatch,
 )
-from triton_dist_tpu.kernels.gemm import GemmConfig, fit_block
+from triton_dist_tpu.kernels.gemm import SUBLANES, GemmConfig, fit_block
 from triton_dist_tpu.kernels.gemm_reduce_scatter import _gemm_rs_xla_ring
 from triton_dist_tpu.shmem import kernel as sk
 from triton_dist_tpu.shmem.kernel import collective_id_for, dist_pallas_call
@@ -100,9 +101,12 @@ def gemm_ar_crossover_m(world: int, wire: str | None = None) -> int:
 def get_auto_gemm_ar_method(
     m: int, world: int, wire: str | None = None
 ) -> GemmARMethod:
-    """Reference ``get_auto_method`` analog for GEMM-AR: ragged M (the fused
-    ring chunks rows over ranks) or decode-sized M → the low-latency one-shot
-    kernel; larger M → the tile-granular fused ring.
+    """Reference ``get_auto_method`` analog for GEMM-AR: decode-sized M
+    (ragged or not) → the low-latency one-shot kernel, which carries all of
+    M in VMEM; larger M → the tile-granular fused ring, when every rank's
+    row chunk is whole sublane tiles (the only row blocks Mosaic takes);
+    larger ragged M — a server's prompt lengths are arbitrary — fits
+    neither kernel and takes ``dot + psum``.
 
     Degradation check FIRST — before the crossover lookup, which is itself
     a collective (``agreed_cfg_value``) that must not be dispatched once
@@ -113,8 +117,10 @@ def get_auto_gemm_ar_method(
             "gemm_ar.auto", "routing AUTO gemm+allreduce to XLA dot+psum"
         )
         method = GemmARMethod.XLA
-    elif m % world != 0 or m <= gemm_ar_crossover_m(world, wire):
+    elif m <= gemm_ar_crossover_m(world, wire):
         method = GemmARMethod.LL_ONE_SHOT
+    elif m % (world * SUBLANES) != 0:
+        method = GemmARMethod.XLA
     else:
         method = GemmARMethod.PALLAS_FUSED
     telemetry.inc(
@@ -196,8 +202,8 @@ def _gemm_ar_fused_kernel(
     right = tpl.ring_neighbor(axis, +1, mesh_axes=mesh_axes)
     left = tpl.ring_neighbor(axis, -1, mesh_axes=mesh_axes)
     # Peer attribution is by rank index along `axis` (not logical device id):
-    # this kernel has NO entry barrier, so the first wait that a dead left
-    # neighbour starves (rs_recv) names the exact peer in the abort record.
+    # a left neighbour that dies after the entry barrier starves rs_recv,
+    # which names the exact peer in the abort record.
     left_rank = jax.lax.rem(me - 1 + world, world)
     right_rank = jax.lax.rem(me + 1, world)
     bm, bn = acc.shape
@@ -210,6 +216,15 @@ def _gemm_ar_fused_kernel(
         @pl.when(s == 0)
         def _():
             sk.init_status(status_ref, axis=axis)
+            # Nobody pushes before everybody is IN this kernel. A remote
+            # DMA signals a scratch semaphore by its address on the peer,
+            # and until the peer enters this kernel that address belongs to
+            # whatever kernel it is still running (found on four chips: a
+            # fast rank's step-0 push landed in a neighbour's attention
+            # kernel between two layers, and the ring stalled in rs_recv).
+            sk.bounded_barrier_all(
+                status_ref, axis, mesh_axes=mesh_axes, phase="barrier"
+            )
 
         @pl.when(s > 0)
         def _():

@@ -47,7 +47,7 @@ from triton_dist_tpu.kernels.allgather_gemm import (
     _is_quant,
     note_quant_dispatch,
 )
-from triton_dist_tpu.kernels.gemm import gemm, GemmConfig
+from triton_dist_tpu.kernels.gemm import SUBLANES, gemm, GemmConfig
 from triton_dist_tpu.kernels.reduce_scatter import reduce_scatter_shard
 from triton_dist_tpu.shmem import kernel as sk
 from triton_dist_tpu.shmem.kernel import collective_id_for, dist_pallas_call
@@ -111,7 +111,8 @@ def get_auto_gemm_rs_method(
     m: int, world: int, wire: str | None = None
 ) -> GemmRSMethod:
     """Reference ``get_auto_method`` analog for GEMM-RS: ragged M (the fused
-    ring chunks rows over ranks) or small M → the XLA ring's
+    ring chunks rows over ranks, in whole sublane tiles) or small M → the
+    XLA ring's
     compiler-scheduled overlap; prefill-sized M above the tuned crossover →
     the tile-granular fused ring.
 
@@ -124,7 +125,7 @@ def get_auto_gemm_rs_method(
             "gemm_rs.auto", "routing AUTO gemm+reduce_scatter to XLA dot+psum_scatter"
         )
         method = GemmRSMethod.XLA
-    elif m % world != 0 or m <= gemm_rs_crossover_m(world, wire):
+    elif m % (world * SUBLANES) != 0 or m <= gemm_rs_crossover_m(world, wire):
         method = GemmRSMethod.XLA_RING
     else:
         method = GemmRSMethod.PALLAS_FUSED
@@ -224,8 +225,8 @@ def _gemm_rs_fused_kernel(
     right = tpl.ring_neighbor(axis, +1, mesh_axes=mesh_axes)
     left = tpl.ring_neighbor(axis, -1, mesh_axes=mesh_axes)
     # Peer attribution is by rank index along `axis` (not logical device id):
-    # this kernel has NO entry barrier, so the first wait that a dead left
-    # neighbour starves (rs_recv) names the exact peer in the abort record.
+    # a left neighbour that dies after the entry barrier starves rs_recv,
+    # which names the exact peer in the abort record.
     left_rank = jax.lax.rem(me - 1 + world, world)
     right_rank = jax.lax.rem(me + 1, world)
     bm, bn = acc.shape
@@ -239,6 +240,13 @@ def _gemm_rs_fused_kernel(
             sk.init_status(status_ref, axis=axis)
             if trace is not None:
                 trace.init(ev_ref, rank=me)
+            # Nobody pushes before everybody is IN this kernel: a remote DMA
+            # signals a scratch semaphore by its address on the peer, which
+            # belongs to another kernel until the peer gets here (see
+            # ``_gemm_ar_fused_kernel``).
+            sk.bounded_barrier_all(
+                status_ref, axis, mesh_axes=mesh_axes, phase="barrier"
+            )
 
         if trace is not None:
             trace.mark(ev_ref, s, profiler.TAG_COMPUTE, 0)

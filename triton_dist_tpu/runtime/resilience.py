@@ -48,10 +48,8 @@ production counterpart, spanning four layers:
 * **CollectiveWatchdog** — host-side wall-time bound on collective dispatch
   with retry/backoff (``TDT_COLL_TIMEOUT_MS``, ``TDT_COLL_RETRIES``); on
   final timeout it marks the feature degraded and either runs the caller's
-  fallback or raises :class:`CollectiveTimeoutError`. This complements the
-  PR 1 *bench* watchdog (``TDT_BENCH_WATCHDOG_S``), which hard-kills the
-  process: the collective watchdog is the serving-path version that keeps
-  the process alive on the XLA fallback.
+  fallback or raises :class:`CollectiveTimeoutError`: it keeps the serving
+  process alive on the XLA fallback.
 
 Env flags::
 
@@ -91,11 +89,13 @@ STATUS_OK = 0
 STATUS_ABORT = 1
 
 #: Device-side wait poll caps when ``TDT_WAIT_BOUND_ITERS`` is unset. Each
-#: poll is a ``semaphore_read`` + compare: nanoseconds compiled on hardware,
-#: a host callback (~µs) in interpret mode — hence the split defaults. Both
-#: sit far above any legitimate wait so production traffic never trips them.
+#: poll is a ``semaphore_read`` + compare: nanoseconds compiled through
+#: Mosaic, far above any legitimate wait so production traffic never trips
+#: it. The Pallas TPU interpreter has no rule for ``semaphore_read``, so the
+#: simulation default is 0 (plain blocking wait); a test that wants the
+#: timeout itself asks for a bound explicitly (FaultPlan / env / argument).
 DEFAULT_WAIT_BOUND_HW = 100_000_000
-DEFAULT_WAIT_BOUND_SIM = 1_000_000
+DEFAULT_WAIT_BOUND_SIM = 0
 
 # Phase names are registered at trace time; SPMD tracing is identical on
 # every process, so ids agree across ranks without any exchange.
@@ -138,9 +138,11 @@ def wait_bound(explicit: int | None = None) -> int:
     env = get_int_env("TDT_WAIT_BOUND_ITERS", -1)
     if env >= 0:
         return env
-    from triton_dist_tpu.runtime.platform import is_cpu_platform
+    from triton_dist_tpu.runtime.platform import interpret_mode_default
 
-    return DEFAULT_WAIT_BOUND_SIM if is_cpu_platform() else DEFAULT_WAIT_BOUND_HW
+    # Follows the launch mode, not the platform name, so a deviceless Mosaic
+    # compile under platform.force_mosaic() gets the hardware polls.
+    return DEFAULT_WAIT_BOUND_SIM if interpret_mode_default() else DEFAULT_WAIT_BOUND_HW
 
 
 # ------------------------------------------------------------------ exceptions

@@ -819,14 +819,17 @@ class InferenceServer:
         for slot in self.scheduler.occupied_slots():
             chain = slot.request.kv_blocks
             tables[slot.idx, : len(chain)] = chain
-        # Snapshot the mirror: jnp.asarray on CPU may zero-copy ALIAS an
-        # aligned numpy buffer, so pushing self._lengths directly would let
-        # later host-side `+=` mutations leak into (or race with) device
-        # reads depending on buffer alignment — a run-to-run coin flip.
+        # Placed on the mesh here, once per push: left on the default device
+        # they would be copied to every chip again by each dispatch that
+        # takes them. The mirror is snapshotted — a zero-copy alias of an
+        # aligned numpy buffer would let later host-side `+=` mutations
+        # leak into (or race with) device reads, a run-to-run coin flip.
+        tables, lengths = jax.device_put(
+            (tables, self._lengths.astype(np.int32)),
+            self.engine.model.ctx.replicated(),
+        )
         self.cache = dataclasses.replace(
-            self.cache,
-            tables=jnp.asarray(tables),
-            lengths=jnp.asarray(self._lengths.copy(), dtype=jnp.int32),
+            self.cache, tables=tables, lengths=lengths
         )
 
     def _publish_kv_gauges(self) -> None:

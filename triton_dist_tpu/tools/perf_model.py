@@ -31,17 +31,20 @@ class ChipSpec:
     ici_links: int  # links per chip (torus degree)
 
 
-# Public-spec approximations. Keyed by jax device_kind (lowercased prefix).
+# Published peaks (Google Cloud documentation of each generation). Keyed by
+# jax device_kind (lowercased prefix). A device that is not here is an error,
+# not a default: a roofline share against assumed peaks is no measurement.
 CHIPS = {
     "tpu v5 lite": ChipSpec("tpu v5 lite", 197.0, 819.0, 45.0, 4),
     "tpu v5": ChipSpec("tpu v5", 459.0, 2765.0, 90.0, 6),  # v5p
     "tpu v4": ChipSpec("tpu v4", 275.0, 1228.0, 45.0, 6),
-    "cpu": ChipSpec("cpu", 0.1, 10.0, 1.0, 1),  # sim substrate: arbitrary
 }
 
 
 def chip_spec(device_kind: str | None = None) -> ChipSpec:
-    """Spec for the current (or named) device kind; falls back to v5e."""
+    """Spec for the current (or named) device kind. Raises ``KeyError`` for a
+    kind the table does not hold (the CPU among them): callers there pass a
+    ``ChipSpec`` of their own or skip the model."""
     if device_kind is None:
         import jax
 
@@ -50,7 +53,10 @@ def chip_spec(device_kind: str | None = None) -> ChipSpec:
     for prefix, spec in sorted(CHIPS.items(), key=lambda kv: -len(kv[0])):
         if kind.startswith(prefix):
             return spec
-    return CHIPS["tpu v5 lite"]
+    raise KeyError(
+        f"no published peaks for device kind {device_kind!r}; "
+        f"known: {sorted(CHIPS)}"
+    )
 
 
 # ------------------------------------------------------------------ rooflines

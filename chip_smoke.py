@@ -96,19 +96,13 @@ ZERO_COUNTERS = (
 )
 
 
-class Lowerings:
-    """Counts jit cache misses (each one traces and lowers a program, whether
-    or not the persistent cache then spares the backend compile)."""
+def lowerings() -> float:
+    """Jit cache misses so far, as the program counts them from its first
+    engine's build (each one traces and lowers a program, whether or not the
+    persistent cache then spares the backend compile)."""
+    from triton_dist_tpu.runtime import telemetry
 
-    EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-
-    def __init__(self):
-        self.n = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **kwargs):
-        if event == self.EVENT:
-            self.n += 1
+    return telemetry.counter_total("tdt_jit_lowerings_total")
 
 
 def emit(phase: str, **fields) -> None:
@@ -154,7 +148,7 @@ def make_prompts(sizes: Sizes, vocab: int, seed: int) -> list[list[int]]:
     return [rng.integers(0, vocab, size=n).tolist() for n, _ in sizes.requests]
 
 
-def serve_once(engine, sizes: Sizes, prompts, lowerings: Lowerings):
+def serve_once(engine, sizes: Sizes, prompts):
     """One server's whole life on ``engine``: submit every request, drive
     ``run()`` to completion, shut down. Checks that every request finished
     as asked; returns (token streams, seconds in run(), programs lowered
@@ -168,11 +162,11 @@ def serve_once(engine, sizes: Sizes, prompts, lowerings: Lowerings):
         server.submit(p, max_new)
         for p, (_, max_new) in zip(prompts, sizes.requests)
     ]
-    before = lowerings.n
+    before = lowerings()
     t0 = time.perf_counter()
     server.run()
     seconds = time.perf_counter() - t0
-    lowered = lowerings.n - before
+    lowered = int(lowerings() - before)
     server.shutdown()
     for req, (n_prompt, max_new) in zip(reqs, sizes.requests):
         if (req.state is not RequestState.DONE or req.finish_reason != "ok"
@@ -207,7 +201,7 @@ def check_healthy(engine) -> dict[str, float]:
     return counts
 
 
-def serve_phase(model, backend: str, sizes: Sizes, lowerings: Lowerings):
+def serve_phase(model, backend: str, sizes: Sizes):
     """Build the engine and serve the requests twice: a warm-up pass that
     compiles every shape the server declares (one prefill program a prompt
     length, one decode program a chunk size), then the same requests on a
@@ -219,9 +213,17 @@ def serve_phase(model, backend: str, sizes: Sizes, lowerings: Lowerings):
     prompts = make_prompts(sizes, vocab, SEED + 1)
     t0 = time.perf_counter()
     engine = Engine(model, backend=backend, max_len=sizes.max_len)
-    warm, _, lowered_warm = serve_once(engine, sizes, prompts, lowerings)
+    warm, _, lowered_warm = serve_once(engine, sizes, prompts)
     build_s = time.perf_counter() - t0
-    streams, serve_s, lowered = serve_once(engine, sizes, prompts, lowerings)
+    if not lowered_warm:
+        # A new engine's programs always lower once. None counted means the
+        # program's counter is not live (TDT_TELEMETRY=0), and the check
+        # below would pass blind.
+        raise AssertionError(
+            f"no program lowered in the warm-up on {backend}: "
+            "tdt_jit_lowerings_total is not counting (is telemetry off?)"
+        )
+    streams, serve_s, lowered = serve_once(engine, sizes, prompts)
     if lowered:
         raise AssertionError(
             f"{lowered} program(s) lowered after warm-up on {backend}"
@@ -325,30 +327,28 @@ def compare_phase(engine, model, sizes: Sizes, *, oneshot: bool = False):
 
 
 def one_chip(devices, sizes: Sizes) -> None:
-    lowerings = Lowerings()
     model, init_s = build_model(sizes, sizes.depth, devices)
     emit("init", depth=sizes.depth, seconds=round(init_s, 2),
          bytes_in_use=memory_stat(devices, "bytes_in_use"))
-    engine = serve_phase(model, "dist", sizes, lowerings)
+    engine = serve_phase(model, "dist", sizes)
     compare_phase(engine, model, sizes)
     # The mega backend holds the layer weights twice: make room first.
     del engine, model
     model, init_s = build_model(sizes, sizes.mega_depth, devices)
     emit("init", depth=sizes.mega_depth, seconds=round(init_s, 2),
          bytes_in_use=memory_stat(devices, "bytes_in_use"))
-    engine = serve_phase(model, "mega", sizes, lowerings)
+    engine = serve_phase(model, "mega", sizes)
     compare_phase(engine, model, sizes)
 
 
 def four_chips(devices, sizes: Sizes) -> None:
-    lowerings = Lowerings()
     model, init_s = build_model(sizes, sizes.depth, devices)
     placed = memory_stat(devices, "bytes_in_use")
     emit("init", depth=sizes.depth, tp=len(devices), seconds=round(init_s, 2),
          bytes_in_use=placed)
     if None not in placed and max(placed) > 1.25 * min(placed):
         raise AssertionError(f"weights not spread evenly: {placed}")
-    engine = serve_phase(model, "dist", sizes, lowerings)
+    engine = serve_phase(model, "dist", sizes)
     compare_phase(engine, model, sizes, oneshot=True)
 
 

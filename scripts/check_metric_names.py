@@ -16,8 +16,8 @@ the convention (see ``docs/observability.md``) machine-enforced:
 * ``telemetry.emit`` kinds must be literal snake-case strings (the event
   ring is grep'd by kind; a dynamic kind is un-greppable);
 * SPAN names (``runtime.tracing``) follow the exact same registry
-  discipline: ``tracing.start_trace`` / ``root_span`` / ``point_current``
-  and ``<anything>trace<anything>.span`` / ``.record`` / ``.point`` (the
+  discipline: ``tracing.start_trace`` / ``root_span`` / ``point_current`` /
+  ``span_current`` and ``<anything>trace<anything>.span`` / ``.record`` / ``.point`` (the
   ``req.trace.span(...)`` call shape) must pass a literal
   ``tdt_<subsystem>_<name>`` — a trace timeline is queried by name just
   like a metric, so span names must not drift from metric names.
@@ -55,7 +55,7 @@ EVENT_FNS = {"emit", "events"}
 #: receivers whose name mentions trace/tracing (``tracing.start_trace``,
 #: ``req.trace.span``, ``self._trace.record``).
 TRACING_FNS = {"span", "record", "point", "start_trace", "root_span",
-               "point_current", "start_remote_trace"}
+               "point_current", "span_current", "start_remote_trace"}
 
 METRIC_NAME = re.compile(r"^tdt_[a-z0-9]+_[a-z0-9_]+$")
 EVENT_KIND = re.compile(r"^[a-z][a-z0-9_]*$")
@@ -190,7 +190,24 @@ REQUIRED_NAMES = {
     "tdt_disagg_handoff_bytes_total",
     "tdt_disagg_handoff_seconds",
     "tdt_disagg_pool_fallbacks_total",
+    # the serving loop on the profiler's clock: self-time digest, the
+    # counts its ratios divide by, jit cache misses (runtime/tracing.py,
+    # serving/server.py) — read by benchmark/layer_metrics/loop_*.py and
+    # join_self_ms.py; see docs/observability.md "The loop's spans"
+    "tdt_span_self_seconds",
+    "tdt_serving_joins_total",
+    "tdt_serving_decode_chunks_total",
+    "tdt_jit_lowerings_total",
     # span names
+    "tdt_serving_step",
+    "tdt_serving_join",
+    "tdt_serving_prefill_arm",
+    "tdt_serving_prefill_complete",
+    "tdt_scheduler_join_free_slots",
+    "tdt_engine_decode_steps_paged",
+    "tdt_engine_prefill_chunk",
+    "tdt_engine_complete_paged_prefill",
+    "tdt_engine_cache_scatter",
     "tdt_serving_probe",
     "tdt_serving_restore",
     "tdt_serving_recovery",

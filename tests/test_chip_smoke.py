@@ -71,3 +71,13 @@ def test_exits_nonzero_without_tpu():
     assert r.returncode != 0
     assert r.stdout == ""
     assert "needs 1 TPU device" in r.stderr
+
+
+def test_serve_phase_refuses_a_dead_lowering_counter(monkeypatch):
+    """With ``TDT_TELEMETRY=0`` the program's lowering counter stands still,
+    and "none lowered after the warm-up" would pass blind: a warm-up that
+    counted none fails the phase."""
+    model, _ = chip_smoke.build_model(TOY, TOY.depth, jax.devices()[:1])
+    monkeypatch.setattr(chip_smoke, "serve_once", lambda *a: ([[1]], 0.0, 0))
+    with pytest.raises(AssertionError, match="not counting"):
+        chip_smoke.serve_phase(model, "xla", TOY)

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 WORLD = 8
 GIB = 1 << 30
@@ -591,3 +591,69 @@ def test_smoke_tp4_programs(topo_2x2):
         assert held < HBM_BYTES / 2, held
         jaxpr = str(eng._decode_chunk.trace(*decode_args).jaxpr)
     assert "semaphore_read" in jaxpr
+
+
+# --------------------------------------------------- kernels under their names
+#
+# A device trace keeps an operation's HLO instruction name and little else,
+# so every Pallas kernel the served path reaches is given its own
+# (``pl.pallas_call(name=...)``; ``dist_pallas_call`` names every collective
+# kernel after its function). XLA names the custom call after it.
+
+
+def _flash_decode(sds):
+    from triton_dist_tpu.kernels.flash_decode import flash_decode
+
+    return flash_decode, (sds((4, 32, 128)), sds((4, 8, 2048, 128)),
+                          sds((4, 8, 2048, 128)), sds((4,), jnp.int32))
+
+
+def _paged_flash_decode(sds):
+    from triton_dist_tpu.kernels.flash_decode import paged_flash_decode
+
+    return paged_flash_decode, (sds((4, 32, 128)), sds((513, 8, 16, 128)),
+                                sds((513, 8, 16, 128)), sds((4, 128), jnp.int32),
+                                sds((4,), jnp.int32))
+
+
+def _flash_attention(sds):
+    from triton_dist_tpu.kernels.flash_attn import flash_attention
+
+    return flash_attention, (sds((1, 32, 1024, 128)), sds((1, 8, 1024, 128)),
+                             sds((1, 8, 1024, 128)))
+
+
+@pytest.mark.parametrize("case", [_flash_decode, _paged_flash_decode, _flash_attention],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_named_kernel_compiles_under_its_name(topo_2x2, case):
+    """At Qwen3-8B head shapes, for one v5e chip: the kernel compiles and
+    its custom call is the instruction ``%<name>``, not ``closed_call.N``."""
+    from triton_dist_tpu.runtime.platform import force_mosaic
+
+    one = SingleDeviceSharding(topo_2x2.devices[0])
+    fn, args = case(lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one))
+    with force_mosaic():
+        lowered = jax.jit(fn).lower(*args)
+        assert "tpu_custom_call" in lowered.as_text()
+        compiled = lowered.compile()
+    name = case.__name__.lstrip("_")
+    calls = [l for l in compiled.as_text().splitlines() if "tpu_custom_call" in l]
+    assert calls and all(l.strip().startswith(f"%{name}") for l in calls), calls
+
+
+def test_collective_kernel_is_named_after_its_function(tpu_mesh):
+    """``dist_pallas_call`` passes the kernel's own name on: the fused
+    AG-GEMM's custom call is ``%_ag_gemm_fused_kernel``."""
+    from triton_dist_tpu.kernels import AGGemmMethod, ag_gemm_shard
+
+    a = jax.ShapeDtypeStruct((WORLD * 256, 512), jnp.bfloat16)
+    b = jax.ShapeDtypeStruct((512, WORLD * 256), jnp.bfloat16)
+    compiled = compile_sharded(
+        tpu_mesh,
+        lambda a_s, b_s: ag_gemm_shard(
+            a_s, b_s, axis="tp", method=AGGemmMethod.PALLAS_FUSED),
+        (a, b), (P("tp"), P(None, "tp")), P(None, "tp"),
+    )
+    calls = [l for l in compiled.as_text().splitlines() if "tpu_custom_call" in l]
+    assert calls and all("%_ag_gemm_fused_kernel" in l.split("=")[0] for l in calls), calls

@@ -532,3 +532,170 @@ def test_merge_chrome_builds_one_timeline_across_pids():
         [{"label": "router", "pid": 0, "spans": router_spans}], trace_id=42
     )
     assert empty["traceEvents"] == []
+
+
+# =========================================== two sinks, self time, jit misses
+
+
+def _host_events(log_dir) -> dict[str, list]:
+    """{name: [(start_ns, end_ns)]} of the ``tdt_*`` events the profiler
+    wrote on host lines, read back with nothing but jax."""
+    import glob
+
+    (path,) = glob.glob(os.path.join(
+        str(log_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    found: dict[str, list] = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("tdt_"):
+                    found.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return found
+
+
+def _profile(log_dir, body):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(log_dir)
+
+
+def _self_seconds() -> dict[str, tuple[float, int]]:
+    return {
+        e["labels"]["phase"]: (e["sum"], e["n"])
+        for e in telemetry.snapshot()["digests"].get("tdt_span_self_seconds", [])
+    }
+
+
+def test_spans_reach_the_profiler_nested_as_in_the_ring(tmp_path):
+    """One span API, two sinks: a span opened while a profiler session runs
+    is an event of its own name on a host line of the profiler's trace,
+    inside its parent's — ring span, ring-less span and a lower layer's
+    ``span_current`` alike. A retroactive ``record`` has no block and stays
+    in the ring only."""
+    t = tracing.start_trace("tdt_test_trace")
+
+    def body():
+        with t.span("tdt_test_outer"):
+            with t.span("tdt_test_phase", ring=False):
+                with tracing.span_current("tdt_test_lower"):
+                    jax.block_until_ready(jax.numpy.ones((8,)) + 1)
+            t.record("tdt_test_retro", tracing.now_s() - 0.001, tracing.now_s())
+
+    ev = _profile(tmp_path, body)
+    assert {"tdt_test_outer", "tdt_test_phase", "tdt_test_lower"} <= set(ev)
+    assert "tdt_test_retro" not in ev
+    (outer,), (phase,), (lower,) = (
+        ev["tdt_test_outer"], ev["tdt_test_phase"], ev["tdt_test_lower"])
+    assert outer[0] <= phase[0] <= lower[0] <= lower[1] <= phase[1] <= outer[1]
+    # The ring holds what it held before, the ring span and the record;
+    # the two ring-less spans are in neither table (the root is still open).
+    names = {s["name"]: s for s in tracing.spans(t.trace_id, include_open=True)}
+    names.pop("tdt_jit_lowering", None)  # where an engine was built before
+    assert set(names) == {"tdt_test_trace", "tdt_test_outer", "tdt_test_retro"}
+    assert names["tdt_test_outer"]["self_s"] <= (outer[1] - outer[0]) / 1e9
+
+
+def test_self_time_on_a_nest_of_three():
+    import time
+
+    t = tracing.start_trace("tdt_test_trace")
+    with t.span("tdt_test_a") as a:
+        time.sleep(0.02)
+        with t.span("tdt_test_b", ring=False) as b:
+            time.sleep(0.03)
+            with tracing.span_current("tdt_test_c") as c:
+                time.sleep(0.04)
+            with tracing.span_current("tdt_test_c"):
+                time.sleep(0.01)
+        t.record("tdt_test_retro", a["start_s"], tracing.now_s())  # covers nothing
+    dur = {s["name"]: s["end_s"] - s["start_s"] for s in (a, b, c)}
+    assert c["self_s"] == pytest.approx(dur["tdt_test_c"]) and 0.04 <= c["self_s"] < 0.06
+    assert 0.03 <= b["self_s"] < 0.05
+    assert b["self_s"] == pytest.approx(dur["tdt_test_b"] - dur["tdt_test_c"] - 0.01, abs=5e-3)
+    assert 0.02 <= a["self_s"] < 0.04
+    assert a["self_s"] == pytest.approx(dur["tdt_test_a"] - dur["tdt_test_b"], abs=1e-6)
+    # The lower layer's spans hang off the nearest span the ring holds.
+    assert c["parent_id"] == b["parent_id"] == a["span_id"]
+    # One digest, a phase label a span name: a window reads as a difference.
+    got = _self_seconds()
+    assert got["tdt_test_a"] == (pytest.approx(a["self_s"]), 1)
+    assert got["tdt_test_b"] == (pytest.approx(b["self_s"]), 1)
+    assert got["tdt_test_c"][1] == 2
+    assert sum(s for s, _ in got.values()) == pytest.approx(dur["tdt_test_a"], abs=1e-6)
+
+
+@pytest.mark.parametrize("off", ["telemetry_off", "unsampled"])
+def test_off_is_off_in_both_sinks(off, tmp_path, monkeypatch):
+    """``TDT_TELEMETRY=0`` (the cached switch) and an unsampled trace open
+    neither sink: no annotation in the profiler's trace, no digest moves."""
+    if off == "telemetry_off":
+        telemetry.reset(enabled_override=False)
+    else:
+        monkeypatch.setenv("TDT_TRACE_SAMPLE", "0")
+    t = tracing.start_trace("tdt_test_trace")
+    assert not t.sampled
+
+    def body():
+        with t.span("tdt_test_outer") as sp:
+            assert sp is None and tracing.current_span() is None
+            with t.span("tdt_test_phase", ring=False):
+                with tracing.span_current("tdt_test_lower") as low:
+                    assert low is None
+
+    assert _profile(tmp_path, body) == {}
+    assert _self_seconds() == {} and tracing.spans() == []
+
+
+def test_request_span_keeps_its_tree_inside_the_servers_iteration():
+    """A request's span opened while a span of the SERVER's trace is ambient
+    (the loop's iteration) is parented in its own trace; the iteration
+    still holds it: its time is not the iteration's self time."""
+    import time
+
+    server = tracing.start_trace("tdt_test_server")
+    req = tracing.start_trace("tdt_test_request")
+    with server.span("tdt_test_step", ring=False) as step:
+        with req.span("tdt_test_prefill") as pf:
+            time.sleep(0.02)
+            with tracing.span_current("tdt_test_engine") as eng:
+                pass
+        pid = req.point("tdt_test_finish")
+        with server.span("tdt_test_dispatch") as dsp:
+            assert tracing.current_correlation() == (server.trace_id, dsp["span_id"])
+    assert pf["parent_id"] == req.root_id and pf["trace_id"] == req.trace_id
+    assert eng["trace_id"] == req.trace_id and eng["parent_id"] == pf["span_id"]
+    assert dsp["parent_id"] == server.root_id
+    assert {s["span_id"]: s for s in tracing.spans()}[pid]["parent_id"] == req.root_id
+    assert step["self_s"] < 0.01 < pf["self_s"]
+
+
+def test_jit_cache_misses_are_counted_and_pointed():
+    tracing.watch_lowerings()
+    tracing.watch_lowerings()  # idempotent: one listener however often asked
+    t = tracing.start_trace("tdt_test_trace")
+
+    def fresh(x):
+        return x * 3 + 1
+
+    f = jax.jit(fresh)
+    vec = jax.numpy.ones((3,))  # its own little program lowers here
+    n0 = telemetry.counter_total("tdt_jit_lowerings_total")
+    with t.span("tdt_test_step", ring=False):
+        with t.span("tdt_test_dispatch") as dsp:
+            f(1.0)
+            f(2.0)  # a cache hit lowers nothing
+    assert telemetry.counter_total("tdt_jit_lowerings_total") == n0 + 1
+    (pt,) = [s for s in tracing.spans(t.trace_id) if s["name"] == "tdt_jit_lowering"]
+    assert pt["parent_id"] == dsp["span_id"] and "fresh" in pt["attrs"]["fun_name"]
+    f(vec)  # a miss outside any span: counted, no point
+    assert telemetry.counter_total("tdt_jit_lowerings_total") == n0 + 2
+    assert len([s for s in tracing.spans() if s["name"] == "tdt_jit_lowering"]) == 1

@@ -350,6 +350,7 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret_mode_default(),
+        name="flash_attention",
     )(*operands)
 
     if return_lse:
@@ -512,6 +513,7 @@ def flash_attention_varlen(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret_mode_default(),
+        name="flash_attention_varlen",
     )(*operands)
     if return_lse:
         o, lse = res

@@ -194,6 +194,7 @@ def flash_decode(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret_mode_default(),
+        name="flash_decode",
     )(lengths.astype(jnp.int32), qr, kr, vr)
 
     o = o.reshape(b, hq, d)
@@ -470,6 +471,7 @@ def paged_flash_decode(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret_mode_default(),
+        name="paged_flash_decode_quant" if quant else "paged_flash_decode",
     )(
         tables.astype(jnp.int32).reshape(b, mb),
         *operands,

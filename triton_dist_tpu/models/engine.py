@@ -95,6 +95,7 @@ class Engine:
         # round-trips (self.backend tracks what is currently built).
         self.preferred_backend = backend
         self._drafter = None
+        tracing.watch_lowerings()
         self._build(backend)
 
     def rebuild(self, backend: str) -> None:
@@ -510,14 +511,18 @@ class Engine:
 
         cfg = model.config
 
-        # ONE jitted object keyed on the prompt length: a fresh lambda per
-        # call would retrace and recompile on every join.
-        self._kbuf_zeros = jax.jit(
-            lambda p_len: jnp.zeros(
+        # ONE jitted object keyed on the prompt length: a fresh function per
+        # call would retrace and recompile on every join. Named, so that its
+        # program reads ``jit_paged_kbuf_zeros`` in a device trace.
+        def paged_kbuf_zeros(p_len):
+            return jnp.zeros(
                 (cfg.num_layers, 1, cfg.num_kv_heads, p_len, cfg.head_dim),
                 jnp.dtype(cfg.dtype),
-            ),
-            static_argnums=(0,), out_shardings=self._kv_sharding,
+            )
+
+        self._kbuf_zeros = jax.jit(
+            paged_kbuf_zeros, static_argnums=(0,),
+            out_shardings=self._kv_sharding,
         )
 
         def paged_gather(pk, pv, ks, vs, tables):
@@ -816,18 +821,22 @@ class Engine:
         """Zeroed (L, 1, Hkv, p_len, D) chunk-prefill context buffers.
         Two independent allocations — kbuf and vbuf are donated separately
         through the chunk program."""
-        return self._kbuf_zeros(int(p_len)), self._kbuf_zeros(int(p_len))
+        with tracing.span_current("tdt_engine_paged_kbuf", p_len=int(p_len)):
+            return self._kbuf_zeros(int(p_len)), self._kbuf_zeros(int(p_len))
 
     def paged_seed_kbuf(self, paged: PagedKVCache, table_row, shared_rows: int,
                         p_len: int):
         """Context buffers seeded with a reused prefix: the first
         ``shared_rows`` rows gathered from the slot's block chain, the rest
         zeros (see the in-jit docstring)."""
-        return self._paged_seed_kbuf(
-            paged.k, paged.v, paged.k_scale, paged.v_scale,
-            jnp.asarray(table_row, jnp.int32),
-            jnp.int32(shared_rows), int(p_len),
-        )
+        with tracing.span_current(
+            "tdt_engine_paged_kbuf", p_len=int(p_len), shared_rows=int(shared_rows)
+        ):
+            return self._paged_seed_kbuf(
+                paged.k, paged.v, paged.k_scale, paged.v_scale,
+                jnp.asarray(table_row, jnp.int32),
+                jnp.int32(shared_rows), int(p_len),
+            )
 
     def prefill_chunk(self, kbuf, vbuf, chunk_ids: jax.Array, off: int,
                       last_idx: int):
@@ -839,14 +848,18 @@ class Engine:
         Returns (logits (1, V), kbuf', vbuf')."""
         timed = telemetry.enabled()
         t = time.perf_counter() if timed else 0.0
-        logits, kb, vb = self._prefill_chunk_prog(
-            self.model.params, chunk_ids, kbuf, vbuf,
-            jnp.int32(off), jnp.int32(last_idx),
-        )
-        if timed:
-            # Admission (paged): each prefill chunk's compute — the chunked
-            # analog of prefill_into_slot's join cost.
-            self._phase("admission", t, logits)
+        # The engine's side of the boundary. The call is one phase
+        # (admission), so one span: spans say where the host was, the
+        # ``_phase`` stamp below stays what ``tdt_engine_phase_seconds`` reads.
+        with tracing.span_current("tdt_engine_prefill_chunk"):
+            logits, kb, vb = self._prefill_chunk_prog(
+                self.model.params, chunk_ids, kbuf, vbuf,
+                jnp.int32(off), jnp.int32(last_idx),
+            )
+            if timed:
+                # Admission (paged): each prefill chunk's compute — the
+                # chunked analog of prefill_into_slot's join cost.
+                self._phase("admission", t, logits)
         return logits, kb, vb
 
     def complete_paged_prefill(self, paged: PagedKVCache, kbuf, vbuf, table_row,
@@ -857,13 +870,15 @@ class Engine:
         update (they travel as data with the next dispatch)."""
         timed = telemetry.enabled()
         t = time.perf_counter() if timed else 0.0
-        pk, pv, ks, vs = self._paged_scatter_prefill(
-            paged.k, paged.v, paged.k_scale, paged.v_scale, kbuf, vbuf,
-            jnp.asarray(table_row, jnp.int32), jnp.int32(start_block),
-            paged.quant,
-        )
-        if timed:
-            self._phase("cache_scatter", t, pk)
+        # One phase (cache_scatter), so one span, as in ``prefill_chunk``.
+        with tracing.span_current("tdt_engine_complete_paged_prefill"):
+            pk, pv, ks, vs = self._paged_scatter_prefill(
+                paged.k, paged.v, paged.k_scale, paged.v_scale, kbuf, vbuf,
+                jnp.asarray(table_row, jnp.int32), jnp.int32(start_block),
+                paged.quant,
+            )
+            if timed:
+                self._phase("cache_scatter", t, pk)
         return dataclasses.replace(paged, k=pk, v=pv, k_scale=ks, v_scale=vs)
 
     def decode_steps_paged(self, paged: PagedKVCache, tokens: jax.Array,
@@ -880,46 +895,59 @@ class Engine:
         Returns ``(out, last_tokens, paged', remaining')``."""
         if key is None:
             key = jax.random.PRNGKey(0)
-        timed = telemetry.enabled()
-        t = time.perf_counter() if timed else 0.0
-        if self.backend == "mega":
-            pk_in, pv_in = self._pool_pair(paged)
-            out, tok, pk, pv, lengths, rem = self._decode_chunk_paged(
-                self.model.params, self._decode_extra, tokens, pk_in,
-                pv_in, paged.tables, paged.lengths, remaining, int(chunk),
-                key,
-            )
-            telemetry.set_gauge(
-                "tdt_mega_steps_per_launch", float(chunk), path="paged"
-            )
-            if timed:
-                # dispatch = host wall to ISSUE the chunk program (async);
-                # host_sync = the wait for the device to finish it. The
-                # mega path scatters in place — no cache_scatter phase.
-                t = self._phase("dispatch", t)
-                self._phase("host_sync", t, tok)
-            return out, tok, self._pool_update(paged, pk, pv, lengths), rem
-        kc, vc = self._paged_gather(
-            paged.k, paged.v, paged.k_scale, paged.v_scale, paged.tables
-        )
-        out, tok, k2, v2, lengths, rem = self._decode_chunk(
-            self.model.params, self._decode_extra, tokens, kc, vc,
-            paged.lengths, remaining, int(chunk), key,
-        )
-        if timed:
-            t = self._phase("dispatch", t)
-            t = self._phase("host_sync", t, tok)
-        pk, pv, ks, vs = self._paged_scatter_decode(
-            paged.k, paged.v, paged.k_scale, paged.v_scale, k2, v2,
-            paged.tables, paged.lengths, remaining, int(chunk), paged.quant,
-        )
-        if timed:
-            # The gather/scatter bounce around the contiguous chunk program
-            # — exactly the cost the mega in-place path deletes.
-            self._phase("cache_scatter", t, pk)
-        return out, tok, dataclasses.replace(
-            paged, k=pk, v=pv, k_scale=ks, v_scale=vs, lengths=lengths
-        ), rem
+        with tracing.span_current(
+            "tdt_engine_decode_steps_paged", chunk=int(chunk), backend=self.backend
+        ):
+            # Each phase is also a span (``tdt_engine_<phase>``) round the very
+            # statements the ``_phase`` stamp times, fence included.
+            timed = telemetry.enabled()
+            t = time.perf_counter() if timed else 0.0
+            if self.backend == "mega":
+                with tracing.span_current("tdt_engine_dispatch"):
+                    pk_in, pv_in = self._pool_pair(paged)
+                    out, tok, pk, pv, lengths, rem = self._decode_chunk_paged(
+                        self.model.params, self._decode_extra, tokens, pk_in,
+                        pv_in, paged.tables, paged.lengths, remaining, int(chunk),
+                        key,
+                    )
+                    telemetry.set_gauge(
+                        "tdt_mega_steps_per_launch", float(chunk), path="paged"
+                    )
+                    if timed:
+                        # dispatch = host wall to ISSUE the chunk program
+                        # (async); host_sync = the wait for the device to finish
+                        # it. The mega path scatters in place — no cache_scatter
+                        # phase.
+                        t = self._phase("dispatch", t)
+                with tracing.span_current("tdt_engine_host_sync"):
+                    if timed:
+                        self._phase("host_sync", t, tok)
+                return out, tok, self._pool_update(paged, pk, pv, lengths), rem
+            with tracing.span_current("tdt_engine_dispatch"):
+                kc, vc = self._paged_gather(
+                    paged.k, paged.v, paged.k_scale, paged.v_scale, paged.tables
+                )
+                out, tok, k2, v2, lengths, rem = self._decode_chunk(
+                    self.model.params, self._decode_extra, tokens, kc, vc,
+                    paged.lengths, remaining, int(chunk), key,
+                )
+                if timed:
+                    t = self._phase("dispatch", t)
+            with tracing.span_current("tdt_engine_host_sync"):
+                if timed:
+                    t = self._phase("host_sync", t, tok)
+            with tracing.span_current("tdt_engine_cache_scatter"):
+                pk, pv, ks, vs = self._paged_scatter_decode(
+                    paged.k, paged.v, paged.k_scale, paged.v_scale, k2, v2,
+                    paged.tables, paged.lengths, remaining, int(chunk), paged.quant,
+                )
+                if timed:
+                    # The gather/scatter bounce around the contiguous chunk
+                    # program — exactly the cost the mega in-place path deletes.
+                    self._phase("cache_scatter", t, pk)
+            return out, tok, dataclasses.replace(
+                paged, k=pk, v=pv, k_scale=ks, v_scale=vs, lengths=lengths
+            ), rem
 
     def decode_logits_paged(self, paged: PagedKVCache, tokens: jax.Array):
         """(B, V) float32 logits of ONE decode step over the paged cache,
@@ -947,9 +975,10 @@ class Engine:
         """Sample with the engine's configured method — the chunked-prefill
         token-0 sample must go through the exact same path as
         ``prefill_into_slot``'s for byte parity."""
-        return sample_token(
-            logits, key, self.sample_method, self.temperature, self.top_p
-        )
+        with tracing.span_current("tdt_engine_sample_logits"):
+            return sample_token(
+                logits, key, self.sample_method, self.temperature, self.top_p
+            )
 
     # ------------------------------------------------- speculative decoding
     def attach_drafter(self, drafter) -> None:

@@ -38,6 +38,23 @@ Orca's iteration timeline). This module is that answer:
   collected from SEVERAL processes as one timeline, one pid per process —
   the fleet router's ``/fleet/trace/<id>`` merge (``docs/fleet.md``).
 
+* **Two sinks** — a live :meth:`Trace.span` (and :func:`span_current`)
+  block is also a ``jax.profiler.TraceAnnotation`` of the same name, so
+  while a profiler session runs (``jax.profiler.start_trace``) the
+  program's spans lie in the profiler's trace, nested as they nest here, on
+  the clock the device's operations are stamped on: an idle gap on the
+  device can be laid against the span the host was in. Outside a session
+  the annotation costs well under a microsecond. Retroactive
+  :meth:`Trace.record` intervals have no block to annotate and stay
+  ring-only.
+* **Self time** — as a live span closes, its duration less what the live
+  spans opened inside its block covered is stored as ``self_s`` and observed
+  into the ``tdt_span_self_seconds`` digest under ``phase=<span name>``, so
+  a window reads as the difference of two snapshots. Spans opened with
+  ``ring=False`` (the serving loop's per-iteration phases) go to the
+  profiler and the digest only: they would otherwise halve the seconds of
+  serving the ring holds.
+
 Clocks: spans stamp raw ``time.monotonic()`` seconds. Callers whose
 bookkeeping lives in another monotonic-derived clock (the serving loop's
 server-relative ``_now()``) convert with a constant offset before calling
@@ -73,6 +90,8 @@ import re
 import threading
 import time
 from typing import Any, Mapping, NamedTuple
+
+import jax
 
 from triton_dist_tpu.runtime import telemetry
 from triton_dist_tpu.runtime.utils import get_float_env, get_int_env
@@ -152,30 +171,23 @@ class Trace:
         self._name = name
 
     # -- span creation ------------------------------------------------------
-    @contextlib.contextmanager
-    def span(self, name: str, /, parent_id: int | None = None, **attrs):
+    def span(self, name: str, /, parent_id: int | None = None,
+             ring: bool = True, **attrs):
         """Context manager: one live span, timed around the block. Sets the
         ambient current span (contextvar) so nested spans and the
         resilience abort hook parent correctly. Yields the span dict —
         mutate ``["attrs"]`` inside the block to attach results.
 
+        ``ring=False`` keeps the span out of the finished-span ring, the
+        open-span table and the flight recorder: it still nests, reaches the
+        profiler and feeds the self-time digest (the module doc's two sinks).
+
         ``name`` is positional-only (here and on every span entry point)
         so ``name=...`` stays available as an attribute key — the watchdog
         labels its timeout points with the collective's name."""
         if not self.sampled:
-            yield None
-            return
-        sp = _start_span(
-            self.trace_id, name,
-            parent_id if parent_id is not None else _ambient_parent(self.root_id),
-            attrs,
-        )
-        tok = _CURRENT.set(sp)
-        try:
-            yield sp
-        finally:
-            _CURRENT.reset(tok)
-            _finish_span(sp)
+            return contextlib.nullcontext()
+        return _live_span(self.trace_id, name, parent_id, self.root_id, ring, attrs)
 
     def record(self, name: str, start_s: float, end_s: float, /,
                parent_id: int | None = None, **attrs) -> int | None:
@@ -197,7 +209,8 @@ class Trace:
         t = now_s()
         return self.record(
             name, t, t,
-            parent_id=parent_id if parent_id is not None else _ambient_parent(self.root_id),
+            parent_id=(parent_id if parent_id is not None
+                       else _ambient_parent(self.trace_id, self.root_id)),
             **attrs,
         )
 
@@ -361,13 +374,67 @@ def root_span(name: str, /, **attrs):
         t.finish()
 
 
-def _ambient_parent(default: int) -> int:
+def _ambient_parent(trace_id: int, default: int) -> int:
+    """The ambient span as a parent for a span of ``trace_id``: its nearest
+    ancestor the ring holds, and ``default`` where the ambient span belongs
+    to another trace (a request's span opened inside the server's loop
+    iteration stays in the request's own tree)."""
     cur = _CURRENT.get()
-    return cur["span_id"] if cur is not None else default
+    if cur is None or cur["trace_id"] != trace_id:
+        return default
+    return _anchor(cur)
+
+
+def _anchor(sp: dict) -> int:
+    """The id children, points and correlations of live span ``sp`` hang on:
+    its own where the ring holds it, else its nearest ancestor's that does."""
+    return sp.get("anchor_id", sp["span_id"])
+
+
+@contextlib.contextmanager
+def _live_span(trace_id: int, name: str, parent_id: int | None, root_id: int,
+               ring: bool, attrs: Mapping[str, Any]):
+    """The one place a live span opens and closes: ring and profiler."""
+    if parent_id is None:
+        parent_id = _ambient_parent(trace_id, root_id)
+    sp = _start_span(trace_id, name, parent_id, attrs, ring=ring)
+    if not ring:
+        sp["anchor_id"] = parent_id
+    sp["child_s"] = 0.0
+    outer = _CURRENT.get()
+    tok = _CURRENT.set(sp)
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield sp
+    finally:
+        _CURRENT.reset(tok)
+        end = now_s()
+        dur = end - sp["start_s"]
+        sp["self_s"] = max(dur - sp.pop("child_s"), 0.0)
+        if outer is not None and "child_s" in outer:
+            outer["child_s"] += dur
+        if ring:
+            _finish_span(sp, end_s=end)
+        else:
+            sp["end_s"] = end
+        telemetry.observe_digest("tdt_span_self_seconds", sp["self_s"], phase=name)
+
+
+def span_current(name: str, /, **attrs):
+    """A live child span of the ambient span, in the ambient span's trace
+    (a no-op context where none is live): how a layer below the one that
+    owns the trace — the engine under the server's loop — puts a span on
+    its own side of the boundary. Never in the ring (see
+    :meth:`Trace.span`)."""
+    cur = _CURRENT.get()
+    if cur is None:
+        return contextlib.nullcontext()
+    return _live_span(cur["trace_id"], name, _anchor(cur), _anchor(cur), False, attrs)
 
 
 def _start_span(trace_id: int, name: str, parent_id: int | None,
-                attrs: Mapping[str, Any], start_s: float | None = None) -> dict:
+                attrs: Mapping[str, Any], start_s: float | None = None,
+                ring: bool = True) -> dict:
     sp = {
         "trace_id": trace_id,
         "span_id": next(_IDS),
@@ -377,9 +444,10 @@ def _start_span(trace_id: int, name: str, parent_id: int | None,
         "end_s": None,
         "attrs": _clean_attrs(attrs),
     }
-    with _LOCK:
-        _OPEN[sp["span_id"]] = sp
-    _flight_span("span_start", sp)
+    if ring:
+        with _LOCK:
+            _OPEN[sp["span_id"]] = sp
+        _flight_span("span_start", sp)
     return sp
 
 
@@ -420,7 +488,7 @@ def current_correlation() -> tuple[int, int] | None:
     cur = _CURRENT.get()
     if cur is None:
         return None
-    return cur["trace_id"], cur["span_id"]
+    return cur["trace_id"], _anchor(cur)
 
 
 def point_current(name: str, /, **attrs) -> None:
@@ -431,8 +499,38 @@ def point_current(name: str, /, **attrs) -> None:
     if cur is None:
         return
     t = now_s()
-    sp = _start_span(cur["trace_id"], name, cur["span_id"], attrs, start_s=t)
+    sp = _start_span(cur["trace_id"], name, _anchor(cur), attrs, start_s=t)
     _finish_span(sp, end_s=t)
+
+
+# ------------------------------------------------------------- jit cache misses
+
+#: The event jax records once for each jit cache miss: the program is traced
+#: and lowered, whether or not the persistent cache then spares the compile.
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_WATCHING_LOWERINGS = False
+
+
+def watch_lowerings() -> None:
+    """Count jit cache misses in ``tdt_jit_lowerings_total`` and drop a
+    ``tdt_jit_lowering`` point into the ambient span, so that "which step
+    recompiled" has an answer inside the program. Idempotent; called when an
+    engine is built (jax keeps a listener for the life of the process)."""
+    global _WATCHING_LOWERINGS
+    if _WATCHING_LOWERINGS:
+        return
+    _WATCHING_LOWERINGS = True
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _on_jax_duration(event: str, duration: float, **kwargs) -> None:
+    if event != LOWERING_EVENT:
+        return
+    telemetry.inc("tdt_jit_lowerings_total")
+    point_current(
+        "tdt_jit_lowering", seconds=round(duration, 6),
+        fun_name=kwargs.get("fun_name"),
+    )
 
 
 # -------------------------------------------------------------------- queries

@@ -882,67 +882,69 @@ class Scheduler:
         ``shed_deadline``) and requests cancelled while queued are dropped
         — both run even when no slot is free, so a hopeless request never
         waits for capacity it can no longer use."""
-        joined: list[Slot] = []
-        expired: list[Request] = []
-        free = [s for s in self.slots if s.state is SlotState.FREE]
-        with self._lock:
-            deferred: collections.deque[Request] = collections.deque()
-            # Stable sort: ties (same tag — impossible within a tenant,
-            # rare across) keep submission order.
-            queue = collections.deque(
-                sorted(self._pending, key=lambda r: r.wfq_tag)
-            )
-            while queue:
-                req = queue.popleft()
-                if req.state is RequestState.CANCELLED:
-                    continue  # finalized by cancel() racing this sweep
-                if self._queue_expired(req, now_s):
-                    expired.append(req)
-                    continue
-                if req.arrival_time_s > now_s or not free:
-                    deferred.append(req)  # not offered yet / no capacity —
-                    continue              # keep its order
-                if self.kv_ledger is not None and not self.kv_ledger.reserve(req):
-                    # Pool dry even after prefix-index eviction. Blocks WILL
-                    # free as running slots finish, so this is a deferral
-                    # (kv_budget_wait), not a reject; the walk keeps going —
-                    # a smaller request behind may still fit (work-conserving
-                    # at the cost of strict FCFS under block pressure).
-                    if not req.kv_wait:
-                        req.kv_wait = True
-                        telemetry.inc("tdt_serving_kv_budget_wait_total")
-                    deferred.append(req)
-                    continue
-                req.kv_wait = False
-                slot = free.pop(0)
-                req.state = RequestState.RUNNING
-                req.arrived_at = max(req.submitted_at, req.arrival_time_s)
-                slot.state = SlotState.PREFILL
-                slot.request = req
-                self._wfq_clock = max(self._wfq_clock, req.wfq_tag)
-                joined.append(slot)
-            self._pending = deferred
-            depth = len(self._pending)
-        for req in expired:
-            self._expire(req, now_s)  # telemetry + callbacks outside the lock
-        if joined or expired:
-            telemetry.set_gauge("tdt_serving_queue_depth", float(depth))
-            self._occupancy_gauge()
-            # Queue wait = effective arrival → admission. Recorded here (not
-            # in TTFT) so queueing delay and prefill latency stop conflating.
-            # The span is retroactive: anchor its END at the tracing clock's
-            # now and stretch back by the wait measured in the caller's
-            # clock (both monotonic-derived, so durations transfer).
-            t_adm = tracing.now_s()
-            for slot in joined:
-                req = slot.request
-                wait = max(0.0, now_s - req.arrived_at)
-                telemetry.observe("tdt_serving_queue_wait_seconds", wait)
-                req.trace.record(
-                    "tdt_serving_queue_wait", t_adm - wait, t_adm,
-                    slot=slot.idx,
+        # The scheduler's side of the boundary the server's join crosses.
+        with tracing.span_current("tdt_scheduler_join_free_slots"):
+            joined: list[Slot] = []
+            expired: list[Request] = []
+            free = [s for s in self.slots if s.state is SlotState.FREE]
+            with self._lock:
+                deferred: collections.deque[Request] = collections.deque()
+                # Stable sort: ties (same tag — impossible within a tenant,
+                # rare across) keep submission order.
+                queue = collections.deque(
+                    sorted(self._pending, key=lambda r: r.wfq_tag)
                 )
-        return joined
+                while queue:
+                    req = queue.popleft()
+                    if req.state is RequestState.CANCELLED:
+                        continue  # finalized by cancel() racing this sweep
+                    if self._queue_expired(req, now_s):
+                        expired.append(req)
+                        continue
+                    if req.arrival_time_s > now_s or not free:
+                        deferred.append(req)  # not offered yet / no capacity —
+                        continue              # keep its order
+                    if self.kv_ledger is not None and not self.kv_ledger.reserve(req):
+                        # Pool dry even after prefix-index eviction. Blocks WILL
+                        # free as running slots finish, so this is a deferral
+                        # (kv_budget_wait), not a reject; the walk keeps going —
+                        # a smaller request behind may still fit (work-conserving
+                        # at the cost of strict FCFS under block pressure).
+                        if not req.kv_wait:
+                            req.kv_wait = True
+                            telemetry.inc("tdt_serving_kv_budget_wait_total")
+                        deferred.append(req)
+                        continue
+                    req.kv_wait = False
+                    slot = free.pop(0)
+                    req.state = RequestState.RUNNING
+                    req.arrived_at = max(req.submitted_at, req.arrival_time_s)
+                    slot.state = SlotState.PREFILL
+                    slot.request = req
+                    self._wfq_clock = max(self._wfq_clock, req.wfq_tag)
+                    joined.append(slot)
+                self._pending = deferred
+                depth = len(self._pending)
+            for req in expired:
+                self._expire(req, now_s)  # telemetry + callbacks outside the lock
+            if joined or expired:
+                telemetry.set_gauge("tdt_serving_queue_depth", float(depth))
+                self._occupancy_gauge()
+                # Queue wait = effective arrival → admission. Recorded here (not
+                # in TTFT) so queueing delay and prefill latency stop conflating.
+                # The span is retroactive: anchor its END at the tracing clock's
+                # now and stretch back by the wait measured in the caller's
+                # clock (both monotonic-derived, so durations transfer).
+                t_adm = tracing.now_s()
+                for slot in joined:
+                    req = slot.request
+                    wait = max(0.0, now_s - req.arrived_at)
+                    telemetry.observe("tdt_serving_queue_wait_seconds", wait)
+                    req.trace.record(
+                        "tdt_serving_queue_wait", t_adm - wait, t_adm,
+                        slot=slot.idx,
+                    )
+            return joined
 
     def _queue_expired(self, req: Request, now_s: float) -> bool:
         """Queue-time deadline check: has an arrived request already waited

@@ -254,6 +254,14 @@ class InferenceServer:
         #: Host mirror of per-slot KV lengths (paged mode: the device
         #: ``lengths`` travel as data the host re-pushes with the tables).
         self._lengths = np.zeros((self.num_slots,), np.int32)
+        # Process-level trace owning the spans no single request owns (the
+        # loop's iterations and phases, shared decode dispatches, recovery).
+        # Left open for the server's lifetime — introspection shows it as
+        # in-flight. Opened before the first table push, which is a span.
+        self._trace = tracing.start_trace(
+            "tdt_serving_server", slots=self.num_slots, chunk=self.chunk,
+            backend=getattr(engine, "backend", None),
+        )
         self.cache = self._fresh_cache()
         # Host-authoritative per-slot decode state (tiny, synced per chunk).
         self._last = np.zeros((self.num_slots,), np.int32)
@@ -268,13 +276,6 @@ class InferenceServer:
             )
         )
         self._t0 = time.monotonic()
-        # Process-level trace owning the spans no single request owns
-        # (shared decode dispatches, recovery). Left open for the server's
-        # lifetime — introspection shows it as in-flight.
-        self._trace = tracing.start_trace(
-            "tdt_serving_server", slots=self.num_slots, chunk=self.chunk,
-            backend=getattr(engine, "backend", None),
-        )
         # Live introspection endpoint (no-op unless TDT_HTTP_PORT is set).
         # The health provider makes /healthz reflect shed pressure and the
         # degraded/preferred backend split regardless of who started the
@@ -707,16 +708,25 @@ class InferenceServer:
         one masked decode chunk over the slot batch. Returns True when any
         work was done. A health sweep runs first: an expired heartbeat
         lease (or a chaos ``die@<rank>``) triggers ONE proactive rebuild at
-        the new epoch instead of a timeout per collective."""
-        worked = self._health_sweep()
-        worked = self._maybe_probe() or worked
-        worked = self._join_ready() or worked
-        worked = self._advance_prefills() or worked
-        self._reap_slots()
-        if not self.scheduler.decoding_slots():
-            return worked
-        self._guarded(self._decode_once, what="decode chunk")
-        return True
+        the new epoch instead of a timeout per collective.
+
+        The iteration is one ``tdt_serving_step`` span of the server's own
+        trace and everything it does that is not free a span beneath it
+        (``docs/observability.md``, "The loop's spans"): none enters the
+        span ring; they reach the profiler, on the device trace's clock, and
+        the ``tdt_span_self_seconds`` digest."""
+        with self._trace.span("tdt_serving_step", ring=False):
+            with self._trace.span("tdt_serving_health", ring=False):
+                worked = self._health_sweep()
+                worked = self._maybe_probe() or worked
+            worked = self._join_ready() or worked
+            worked = self._advance_prefills() or worked
+            with self._trace.span("tdt_serving_reap", ring=False):
+                self._reap_slots()
+            if not self.scheduler.decoding_slots():
+                return worked
+            self._guarded(self._decode_once, what="decode chunk")
+            return True
 
     def run(self, poll_s: float = 0.05) -> None:
         """Serve until the queue is drained and every slot is free.
@@ -814,23 +824,25 @@ class InferenceServer:
         """Re-push every slot's block table + KV length to the device. The
         tables are DATA operands of the (fixed-shape) paged programs, so
         this never recompiles anything."""
-        mb = self.cache.max_blocks
-        tables = np.zeros((self.num_slots, mb), np.int32)
-        for slot in self.scheduler.occupied_slots():
-            chain = slot.request.kv_blocks
-            tables[slot.idx, : len(chain)] = chain
-        # Placed on the mesh here, once per push: left on the default device
-        # they would be copied to every chip again by each dispatch that
-        # takes them. The mirror is snapshotted — a zero-copy alias of an
-        # aligned numpy buffer would let later host-side `+=` mutations
-        # leak into (or race with) device reads, a run-to-run coin flip.
-        tables, lengths = jax.device_put(
-            (tables, self._lengths.astype(np.int32)),
-            self.engine.model.ctx.replicated(),
-        )
-        self.cache = dataclasses.replace(
-            self.cache, tables=tables, lengths=lengths
-        )
+        with self._trace.span("tdt_serving_table_push", ring=False):
+            mb = self.cache.max_blocks
+            tables = np.zeros((self.num_slots, mb), np.int32)
+            for slot in self.scheduler.occupied_slots():
+                chain = slot.request.kv_blocks
+                tables[slot.idx, : len(chain)] = chain
+            # Placed on the mesh here, once per push: left on the default
+            # device they would be copied to every chip again by each
+            # dispatch that takes them. The mirror is snapshotted — a
+            # zero-copy alias of an aligned numpy buffer would let later
+            # host-side `+=` mutations leak into (or race with) device
+            # reads, a run-to-run coin flip.
+            tables, lengths = jax.device_put(
+                (tables, self._lengths.astype(np.int32)),
+                self.engine.model.ctx.replicated(),
+            )
+            self.cache = dataclasses.replace(
+                self.cache, tables=tables, lengths=lengths
+            )
 
     def _publish_kv_gauges(self) -> None:
         s = self.kv_ledger.stats()
@@ -844,21 +856,24 @@ class InferenceServer:
 
     # ------------------------------------------------------------------ joins
     def _join_ready(self) -> bool:
-        joined = self.scheduler.join_free_slots(self._now())
-        for slot in joined:
-            # A recovery triggered by an EARLIER slot's failed prefill
-            # already re-prefilled every occupied slot, this one included
-            # (or finished+released it) — do not stream its first token
-            # twice. State is the discriminator, not token history: a
-            # journal-recovered request joins WITH tokens but still in
-            # PREFILL, and must re-prefill from them.
-            if slot.request is None or slot.state is not SlotState.PREFILL:
-                continue
-            # Paged mode only ARMS the chunked prefill here; the per-step
-            # _advance_prefills sweep advances it one chunk at a time.
-            target = self._begin_prefill if self.paged else self._prefill_slot
-            self._guarded(lambda s=slot: target(s),
-                          what=f"join of request {slot.request.req_id}")
+        with self._trace.span("tdt_serving_join", ring=False):
+            joined = self.scheduler.join_free_slots(self._now())
+            for slot in joined:
+                # A recovery triggered by an EARLIER slot's failed prefill
+                # already re-prefilled every occupied slot, this one
+                # included (or finished+released it) — do not stream its
+                # first token twice. State is the discriminator, not token
+                # history: a journal-recovered request joins WITH tokens but
+                # still in PREFILL, and must re-prefill from them.
+                if slot.request is None or slot.state is not SlotState.PREFILL:
+                    continue
+                # Paged mode only ARMS the chunked prefill here; the per-step
+                # _advance_prefills sweep advances it one chunk at a time.
+                target = self._begin_prefill if self.paged else self._prefill_slot
+                self._guarded(lambda s=slot: target(s),
+                              what=f"join of request {slot.request.req_id}")
+        if joined:
+            telemetry.inc("tdt_serving_joins_total", float(len(joined)))
         return bool(joined)
 
     def _prefill_slot(self, slot: Slot) -> None:
@@ -925,39 +940,40 @@ class InferenceServer:
         queue the slot on the prefill cursor map. The sampling key is split
         HERE, in join order, so the token stream matches the slot-mode
         server byte-for-byte."""
-        req = slot.request
-        if req.kv_import is not None:
-            # Disaggregated handoff: the prefill KV arrived over the wire.
-            # The payload is consumed up front so any failure — a malformed
-            # blob, a pool-geometry mismatch, a recovery preemption — falls
-            # back to deriving the very same KV from the token history
-            # below (the determinism fallback: stored wire bytes and a
-            # local prefill produce bitwise-identical blocks).
-            payload, req.kv_import = req.kv_import, None
-            try:
-                self._import_prefill(slot, payload)
-                return
-            except Exception as e:
-                telemetry.emit(
-                    "serving_kv_import_failed", req_id=req.req_id,
-                    error=f"{type(e).__name__}: {e}",
+        with self._trace.span("tdt_serving_prefill_arm", ring=False, slot=slot.idx):
+            req = slot.request
+            if req.kv_import is not None:
+                # Disaggregated handoff: the prefill KV arrived over the wire.
+                # The payload is consumed up front so any failure — a malformed
+                # blob, a pool-geometry mismatch, a recovery preemption — falls
+                # back to deriving the very same KV from the token history
+                # below (the determinism fallback: stored wire bytes and a
+                # local prefill produce bitwise-identical blocks).
+                payload, req.kv_import = req.kv_import, None
+                try:
+                    self._import_prefill(slot, payload)
+                    return
+                except Exception as e:
+                    telemetry.emit(
+                        "serving_kv_import_failed", req_id=req.req_id,
+                        error=f"{type(e).__name__}: {e}",
+                    )
+            ids = req.prompt + req.tokens[:-1]
+            # Scripted chaos site: same discriminator as the slot-mode prefill.
+            resilience.chaos_check("recovery" if req.tokens else "prefill")
+            self._key, sub = jax.random.split(self._key)
+            p_len = len(ids)
+            shared_rows = min(req.kv_shared * self.block_size, max(p_len - 1, 0))
+            if shared_rows > 0:
+                kbuf, vbuf = self.engine.paged_seed_kbuf(
+                    self.cache, self._table_row(req), shared_rows, p_len
                 )
-        ids = req.prompt + req.tokens[:-1]
-        # Scripted chaos site: same discriminator as the slot-mode prefill.
-        resilience.chaos_check("recovery" if req.tokens else "prefill")
-        self._key, sub = jax.random.split(self._key)
-        p_len = len(ids)
-        shared_rows = min(req.kv_shared * self.block_size, max(p_len - 1, 0))
-        if shared_rows > 0:
-            kbuf, vbuf = self.engine.paged_seed_kbuf(
-                self.cache, self._table_row(req), shared_rows, p_len
-            )
-        else:
-            kbuf, vbuf = self.engine.paged_kbuf_zeros(p_len)
-        self._prefilling[slot.idx] = {
-            "req": req, "ids": ids, "off": shared_rows,
-            "kbuf": kbuf, "vbuf": vbuf, "key": sub, "n_chunks": 0,
-        }
+            else:
+                kbuf, vbuf = self.engine.paged_kbuf_zeros(p_len)
+            self._prefilling[slot.idx] = {
+                "req": req, "ids": ids, "off": shared_rows,
+                "kbuf": kbuf, "vbuf": vbuf, "key": sub, "n_chunks": 0,
+            }
 
     def _advance_prefills(self) -> bool:
         """Advance every in-flight chunked prefill by ONE chunk (the decode
@@ -1000,7 +1016,10 @@ class InferenceServer:
         st["off"] = off + len(take)
         st["n_chunks"] += 1
         if final:
-            self._complete_prefill(slot, st, logits)
+            with self._trace.span(
+                "tdt_serving_prefill_complete", ring=False, slot=slot.idx
+            ):
+                self._complete_prefill(slot, st, logits)
 
     def _complete_prefill(self, slot: Slot, st: dict, logits) -> None:
         """Finish a chunked prefill: scatter the context buffer into the
@@ -1040,11 +1059,14 @@ class InferenceServer:
                 self._park_handoff(slot, p_len)
             return
         _, sub = jax.random.split(st["key"])
-        tok = int(self.engine.sample_logits(logits, sub)[0])
+        tok = self.engine.sample_logits(logits, sub)
+        with self._trace.span("tdt_serving_fetch", ring=False, what="token0"):
+            tok = int(tok[0])
         self._last[slot.idx] = tok
         self._remaining[slot.idx] = req.max_new - 1
         self.scheduler.start_decode(slot)
-        self._stream(req, tok)
+        with self._trace.span("tdt_serving_emit", ring=False, n_tokens=1):
+            self._stream(req, tok)
         if self._journal is not None:
             self._journal.append(
                 "prefill", req_id=req.req_id, start=0, tokens=[tok]
@@ -1123,10 +1145,11 @@ class InferenceServer:
         if self.spec_k >= 2:
             self._spec_decode_once()
             return
-        resilience.chaos_check("decode")
-        decoding = self.scheduler.decoding_slots()
-        pre = {s.idx: int(self._remaining[s.idx]) for s in decoding}
-        self._key, sub = jax.random.split(self._key)
+        with self._trace.span("tdt_serving_decode_prep", ring=False):
+            resilience.chaos_check("decode")
+            decoding = self.scheduler.decoding_slots()
+            pre = {s.idx: int(self._remaining[s.idx]) for s in decoding}
+            self._key, sub = jax.random.split(self._key)
         t0 = time.perf_counter()
         # One decode chunk is ONE shared device dispatch over the whole slot
         # batch: it gets a single span in the SERVER trace (and is the
@@ -1149,36 +1172,38 @@ class InferenceServer:
         d_end = tracing.now_s()
         dispatch_id = dsp["span_id"] if dsp is not None else None
         self.cache = cache
-        out_np = np.asarray(out)
-        self._last = np.asarray(tok, dtype=np.int32).copy()
+        with self._trace.span("tdt_serving_fetch", ring=False, what="chunk"):
+            out_np = np.asarray(out)
+            self._last = np.asarray(tok, dtype=np.int32).copy()
         wall = time.perf_counter() - t0
         telemetry.inc("tdt_serving_decode_chunks_total")
-        n_streamed = 0
-        for slot in decoding:
-            req = slot.request
-            n_valid = min(pre[slot.idx], self.chunk)
-            req.trace.record(
-                "tdt_serving_decode_chunk", d_start, d_end,
-                slot=slot.idx, n_tokens=n_valid, dispatch=dispatch_id,
-            )
-            s_start = tracing.now_s()
-            toks = [int(out_np[slot.idx, j]) for j in range(n_valid)]
-            for t in toks:
-                self._stream(req, t)
-            if n_valid:
+        with self._trace.span("tdt_serving_emit", ring=False):
+            n_streamed = 0
+            for slot in decoding:
+                req = slot.request
+                n_valid = min(pre[slot.idx], self.chunk)
                 req.trace.record(
-                    "tdt_serving_stream", s_start, tracing.now_s(),
-                    slot=slot.idx, n_tokens=n_valid,
+                    "tdt_serving_decode_chunk", d_start, d_end,
+                    slot=slot.idx, n_tokens=n_valid, dispatch=dispatch_id,
                 )
-                if self._journal is not None:
-                    self._journal.append(
-                        "chunk", req_id=req.req_id,
-                        start=len(req.tokens) - n_valid, tokens=toks,
+                s_start = tracing.now_s()
+                toks = [int(out_np[slot.idx, j]) for j in range(n_valid)]
+                for t in toks:
+                    self._stream(req, t)
+                if n_valid:
+                    req.trace.record(
+                        "tdt_serving_stream", s_start, tracing.now_s(),
+                        slot=slot.idx, n_tokens=n_valid,
                     )
-            self._remaining[slot.idx] -= n_valid
-            if self.paged:
-                self._lengths[slot.idx] += n_valid  # device updated in-chunk
-            n_streamed += n_valid
+                    if self._journal is not None:
+                        self._journal.append(
+                            "chunk", req_id=req.req_id,
+                            start=len(req.tokens) - n_valid, tokens=toks,
+                        )
+                self._remaining[slot.idx] -= n_valid
+                if self.paged:
+                    self._lengths[slot.idx] += n_valid  # device updated in-chunk
+                n_streamed += n_valid
         # Finishes run AFTER every slot's host length mirror is advanced:
         # _finish pushes the mirror over the device lengths (wiping the
         # in-chunk update), so a finisher processed before a still-active
@@ -1343,45 +1368,48 @@ class InferenceServer:
         natural completion ("ok") from a client cancel ("cancelled") and a
         total-deadline truncation ("deadline") — only "ok" counts toward
         ``tdt_serving_requests_completed_total``."""
-        req = slot.request
-        req.finish_reason = reason
-        req.state = (
-            RequestState.CANCELLED if reason == "cancelled" else RequestState.DONE
-        )
-        req.finished_at = self._now()
-        if reason == "ok":
-            tpot = req.tpot_s
-            if tpot is not None:
-                telemetry.observe("tdt_serving_tpot_seconds", tpot)
-            telemetry.inc("tdt_serving_requests_completed_total")
-        # Per-(tenant, tier) SLO ledger: digests + goodput/violation
-        # counters, classified against the request's own deadline fields.
-        slo.record_finish(req, reason)
-        self.scheduler.finish(slot)
-        self.scheduler.release(slot)
-        self._remaining[slot.idx] = 0
-        if self.paged:
-            # A cancel can land mid-prefill: drop the cursor (its context
-            # buffers die with it), return the chain, null the table row.
-            self._prefilling.pop(slot.idx, None)
-            self._lengths[slot.idx] = 0
-            self.kv_ledger.release(req)
-            self._push_tables()
-            self._publish_kv_gauges()
-        if self._journal is not None:
-            # "finish" always forces the fsync: a completed stream must be
-            # durable so recovery can skip it idempotently.
-            self._journal.append(
-                "finish", req_id=req.req_id, reason=reason,
-                n_tokens=len(req.tokens),
+        with self._trace.span(
+            "tdt_serving_finish_slot", ring=False, slot=slot.idx, reason=reason
+        ):
+            req = slot.request
+            req.finish_reason = reason
+            req.state = (
+                RequestState.CANCELLED if reason == "cancelled" else RequestState.DONE
             )
-        if req.on_finish is not None:
-            try:
-                req.on_finish(req)
-            except Exception:
-                telemetry.inc("tdt_serving_callback_errors_total", kind="finish")
-        req.trace.point("tdt_serving_finish", slot=slot.idx, reason=reason)
-        req.trace.finish(status=reason, n_tokens=len(req.tokens))
+            req.finished_at = self._now()
+            if reason == "ok":
+                tpot = req.tpot_s
+                if tpot is not None:
+                    telemetry.observe("tdt_serving_tpot_seconds", tpot)
+                telemetry.inc("tdt_serving_requests_completed_total")
+            # Per-(tenant, tier) SLO ledger: digests + goodput/violation
+            # counters, classified against the request's own deadline fields.
+            slo.record_finish(req, reason)
+            self.scheduler.finish(slot)
+            self.scheduler.release(slot)
+            self._remaining[slot.idx] = 0
+            if self.paged:
+                # A cancel can land mid-prefill: drop the cursor (its context
+                # buffers die with it), return the chain, null the table row.
+                self._prefilling.pop(slot.idx, None)
+                self._lengths[slot.idx] = 0
+                self.kv_ledger.release(req)
+                self._push_tables()
+                self._publish_kv_gauges()
+            if self._journal is not None:
+                # "finish" always forces the fsync: a completed stream must be
+                # durable so recovery can skip it idempotently.
+                self._journal.append(
+                    "finish", req_id=req.req_id, reason=reason,
+                    n_tokens=len(req.tokens),
+                )
+            if req.on_finish is not None:
+                try:
+                    req.on_finish(req)
+                except Exception:
+                    telemetry.inc("tdt_serving_callback_errors_total", kind="finish")
+            req.trace.point("tdt_serving_finish", slot=slot.idx, reason=reason)
+            req.trace.finish(status=reason, n_tokens=len(req.tokens))
 
     def _reap_slots(self) -> None:
         """Chunk-boundary lifecycle sweep: free cancelled slots and truncate

@@ -158,6 +158,9 @@ def dist_pallas_call(
         )
     if interpret is None:
         interpret = interpret_mode_default(detect_races=detect_races)
+    # The kernel's own name on the device timeline (a partial or a fault
+    # wrapper has none, and XLA would make one up).
+    kwargs.setdefault("name", kernel_base_name(kernel))
     # Fault injection is a simulation feature: apply the active FaultPlan
     # only in interpret mode, and only after the collective id was derived
     # from the ORIGINAL kernel above (a wrapper has no stable key and would

@@ -35,7 +35,6 @@ from triton_dist_tpu.tools.profiler import (
     TRACE_TAGS,
     ChromeTrace,
     KernelTrace,
-    annotate,
     decode_to_chrome,
     profile_op,
     trace,
@@ -66,7 +65,6 @@ __all__ = [
     "overlap_efficiency",
     "ChromeTrace",
     "TRACE_TAGS",
-    "annotate",
     "decode_to_chrome",
     "profile_op",
     "trace",

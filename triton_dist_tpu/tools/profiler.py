@@ -44,13 +44,6 @@ def trace(log_dir: str, **kw):
     return jax.profiler.trace(log_dir, **kw)
 
 
-def annotate(name: str):
-    """Named region on the profiler timeline (reference profiler spans)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
-
-
 class ChromeTrace:
     """Host-measured span recorder → chrome://tracing JSON.
 

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import gc
 import json
 import sys
 import time
@@ -70,9 +71,9 @@ class Sizes:
 # Memory at Qwen3-8B widths, bfloat16 (tests/test_tpu_lowering.py compiles
 # these programs for a described v5e and holds them to 16 GB): 0.36 GiB a
 # layer, 2.32 GiB embedding + head, 4 KiB of K/V a token a layer. At 24
-# layers and 4 slots of 2048 tokens: weights 10.9 GiB, pool 0.75 GiB, and the
-# op-by-op paged decode's gathered copy plus its temp 1.5 GiB more. The mega
-# backend keeps every layer's weights twice, so it runs 12.
+# layers and 4 slots of 2048 tokens: weights 10.9 GiB, pool 0.75 GiB, which the
+# paged decode reads and writes in place. The mega backend keeps every layer's
+# weights twice, so it runs 12.
 QWEN3_8B = Sizes(
     preset="qwen3-8b", depth=24, mega_depth=12, max_len=2048, num_slots=4,
     chunk=8,
@@ -332,8 +333,11 @@ def one_chip(devices, sizes: Sizes) -> None:
          bytes_in_use=memory_stat(devices, "bytes_in_use"))
     engine = serve_phase(model, "dist", sizes)
     compare_phase(engine, model, sizes)
-    # The mega backend holds the layer weights twice: make room first.
+    # The mega backend holds the layer weights twice: make room first. An
+    # engine and its jitted closures refer to each other, so the weights go
+    # only when the collector has run.
     del engine, model
+    gc.collect()
     model, init_s = build_model(sizes, sizes.mega_depth, devices)
     emit("init", depth=sizes.mega_depth, seconds=round(init_s, 2),
          bytes_in_use=memory_stat(devices, "bytes_in_use"))

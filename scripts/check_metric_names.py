@@ -198,6 +198,9 @@ REQUIRED_NAMES = {
     "tdt_serving_joins_total",
     "tdt_serving_decode_chunks_total",
     "tdt_jit_lowerings_total",
+    # which way a paged decode chunk ran: against the pool in place, or
+    # bounced through the contiguous layout (models/engine.py)
+    "tdt_engine_decode_chunks_total",
     # span names
     "tdt_serving_step",
     "tdt_serving_join",

@@ -178,55 +178,173 @@ def test_scheduler_kv_budget_hard_and_kv_wait():
 # =============================================== paged decode (kernel tier)
 
 
-def test_paged_decode_matches_contiguous():
-    """The paged read path is bitwise-identical to the contiguous kernel:
-    scatter a contiguous cache into a shuffled block pool, decode through
-    the table walk (pallas) and the gather oracle, and compare against the
-    contiguous kernel at the same ``block_k`` partition."""
+@pytest.mark.parametrize("block_k", [16, 32, 64, 128])
+def test_paged_decode_matches_contiguous(block_k):
+    """The several-pages walk is bitwise-identical to the contiguous kernel
+    at the same ``block_k``: scatter a contiguous cache into one layer of a
+    STACKED pool whose physical pages are out of order, decode through the
+    table walk (pallas, the pages of a tile DMA'd by the table) and the
+    gather oracle, and compare outputs and log-sum-exps against
+    ``flash_decode`` — lengths 0, 1, on and round a page boundary, round a
+    tile boundary, and full."""
     from triton_dist_tpu.kernels.flash_decode import (
         flash_decode,
         paged_flash_decode,
     )
 
-    bs, mb, b, hkv, hq, d = 8, 4, 3, 2, 4, 64
+    bs, mb, hkv, hq, d, layers, layer = 16, 8, 2, 4, 64, 3, 1
     s = mb * bs
+    lens = [1, 15, 16, 17, 64, 65, s, 0]
+    b = len(lens)
     rng = np.random.RandomState(0)
     kc = rng.randn(b, hkv, s, d).astype(np.float32)
     vc = rng.randn(b, hkv, s, d).astype(np.float32)
     q = rng.randn(b, hq, d).astype(np.float32)
-    lengths = np.asarray([5, 12, s], np.int32)
+    lengths = np.asarray(lens, np.int32)
 
     # Shuffled physical placement: a distinct pool block per (seq, logical)
     # position, with the chain truncated at the null block past lengths.
+    # The other layers of the stack hold noise the walk must never read.
     nb = 1 + b * mb
-    tables = rng.permutation(np.arange(1, nb))[: b * mb].reshape(b, mb)
-    tables = tables.astype(np.int32)
-    k_pool = np.zeros((nb, hkv, bs, d), np.float32)
-    v_pool = np.zeros((nb, hkv, bs, d), np.float32)
+    tables = rng.permutation(np.arange(1, nb)).reshape(b, mb).astype(np.int32)
+    k_pool = rng.randn(layers, nb, hkv, bs, d).astype(np.float32)
+    v_pool = rng.randn(layers, nb, hkv, bs, d).astype(np.float32)
+    k_pool[:, NULL_BLOCK] = v_pool[:, NULL_BLOCK] = 0.0
     for i in range(b):
-        used = -(-int(lengths[i]) // bs)
+        used = -(-lens[i] // bs)
         for j in range(mb):
             if j >= used:
                 tables[i, j] = NULL_BLOCK
                 continue
-            k_pool[tables[i, j]] = kc[i][:, j * bs:(j + 1) * bs]
-            v_pool[tables[i, j]] = vc[i][:, j * bs:(j + 1) * bs]
-    # Rows past lengths live in the null block on the paged side: zero the
-    # contiguous reference's tail too so both kernels mask the same bytes.
-    for i in range(b):
-        kc[i][:, -(-int(lengths[i]) // bs) * bs:] = 0.0
-        vc[i][:, -(-int(lengths[i]) // bs) * bs:] = 0.0
+            k_pool[layer, tables[i, j]] = kc[i][:, j * bs:(j + 1) * bs]
+            v_pool[layer, tables[i, j]] = vc[i][:, j * bs:(j + 1) * bs]
+        # Rows past lengths live in the null block on the paged side: zero
+        # the contiguous reference's tail so both kernels mask the same bytes.
+        kc[i][:, used * bs:] = 0.0
+        vc[i][:, used * bs:] = 0.0
 
-    args = (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(tables), jnp.asarray(lengths))
     ref = flash_decode(
         jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
-        jnp.asarray(lengths), block_k=bs,
+        jnp.asarray(lengths), block_k=block_k, return_lse=True,
     )
-    gathered = paged_flash_decode(*args, impl="gather")
-    paged = paged_flash_decode(*args, impl="pallas")
-    np.testing.assert_array_equal(np.asarray(gathered), np.asarray(ref))
-    np.testing.assert_array_equal(np.asarray(paged), np.asarray(ref))
+    for impl in ("gather", "pallas"):
+        got = paged_flash_decode(
+            jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+            jnp.asarray(tables), jnp.asarray(lengths), layer=jnp.int32(layer),
+            block_k=block_k, impl=impl, return_lse=True,
+        )
+        for g, r in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(r), impl)
+
+
+# ============================ a decode chunk against the pool where it lies
+
+
+def _bounce_chunk(eng, paged, tokens, remaining, chunk, key):
+    """What ``decode_steps_paged`` was before the pool was decoded in
+    place, kept as the oracle: gather the pool into the contiguous layout,
+    run the contiguous chunk program, scatter the written rows back."""
+    import dataclasses
+
+    kc, vc = eng._paged_gather(
+        paged.k, paged.v, paged.k_scale, paged.v_scale, paged.tables)
+    out, tok, k2, v2, lengths, rem = eng._decode_chunk(
+        eng.model.params, eng._decode_extra, tokens, kc, vc, paged.lengths,
+        remaining, chunk, key)
+    pk, pv, ks, vs = eng._paged_scatter_rows(
+        paged.k, paged.v, paged.k_scale, paged.v_scale, k2, v2, paged.tables,
+        paged.lengths, jnp.clip(remaining, 0, chunk), chunk, paged.quant)
+    return out, tok, dataclasses.replace(
+        paged, k=pk, v=pv, k_scale=ks, v_scale=vs, lengths=lengths), rem
+
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["bf16", "int8"])
+@pytest.mark.parametrize("backend", ["xla", "dist_ar"])
+def test_decode_chunk_in_place_matches_the_bounce(backend, quant):
+    """A decode chunk through the pool — one K/V row written a layer, K/V
+    read through the table — gives the tokens, lengths and live pool rows
+    that the gather, the contiguous chunk and the scatter-back gave. Slot 1
+    is inactive throughout; slot 3 was freed and its pages handed to slot
+    2 while its own table still names them (so only the NULL-block redirect
+    keeps it off its new tenant's rows); lengths start on, before and after
+    a page boundary, and the second chunk crosses one.
+
+    A bfloat16 pool agrees bit for bit. A quantized pool agrees in tokens
+    and lengths, and in rows to a quantization step: the bounce attended a
+    chunk's own new rows unquantized and quantized them at the scatter,
+    while in place a row is quantized once, at the append, as ``mega``
+    does it."""
+    import dataclasses
+
+    from triton_dist_tpu.models import PRESETS, DenseLLM, Engine
+    from triton_dist_tpu.models.quant import dequantize_kv
+    from triton_dist_tpu.runtime.mesh import initialize_distributed
+    from triton_dist_tpu.runtime.platform import cpu_mesh
+
+    m = cpu_mesh((1,), ("tp",))
+    ctx = initialize_distributed(
+        devices=list(m.devices.flat), axis_names=("tp",), set_default=False)
+    cfg = dataclasses.replace(PRESETS["test-dense"], dtype="bfloat16")
+    eng = Engine(DenseLLM(cfg, ctx, key=jax.random.PRNGKey(1)),
+                 backend=backend, max_len=MAX_LEN)
+    bs, chunk = 8, 3
+    paged = eng.alloc_paged(4, block_size=bs, num_blocks=17, quant=quant)
+    lens = [8, 5, 7, 9]  # on a page boundary, inactive, before one, after one
+    tables = np.zeros((4, MAX_LEN // bs), np.int32)
+    tables[0, :3] = [9, 2, 12]  # physical pages out of order
+    tables[1, :2] = [5, 6]
+    tables[2, :3] = [7, 3, 11]
+    tables[3, :2] = [7, 3]  # freed: its pages are slot 2's now
+    prompts = np.random.RandomState(3).randint(1, cfg.vocab_size, (4, 12))
+    toks = []
+    for slot, n in enumerate(lens):
+        kbuf, vbuf = eng.paged_kbuf_zeros(n)
+        logits, kbuf, vbuf = eng.prefill_chunk(
+            kbuf, vbuf, jnp.asarray(prompts[slot:slot + 1, :n], jnp.int32), 0, n - 1)
+        toks.append(int(jnp.argmax(logits[0])))
+        if slot != 3:  # the freed slot's rows are long gone
+            paged = eng.complete_paged_prefill(paged, kbuf, vbuf, tables[slot], 0)
+    paged = dataclasses.replace(
+        paged, tables=jnp.asarray(tables), lengths=jnp.asarray(lens, jnp.int32))
+    tokens = jnp.asarray(toks, jnp.int32)
+    remaining = jnp.asarray([5, 0, 6, 0], jnp.int32)
+    key = jax.random.PRNGKey(0)
+    assert eng._decode_shard_paged is not None
+
+    copy = lambda c: jax.tree.map(jnp.copy, c)
+    ref_p, got_p = copy(paged), copy(paged)
+    ref_t = got_t = tokens
+    ref_r = got_r = remaining
+    for _ in range(2):
+        ref_out, ref_t, ref_p, ref_r = _bounce_chunk(
+            eng, ref_p, ref_t, ref_r, chunk, key)
+        got_out, got_t, got_p, got_r = eng.decode_steps_paged(
+            got_p, got_t, got_r, chunk, key)
+        np.testing.assert_array_equal(np.asarray(got_out), np.asarray(ref_out))
+        np.testing.assert_array_equal(np.asarray(got_t), np.asarray(ref_t))
+        np.testing.assert_array_equal(np.asarray(got_r), np.asarray(ref_r))
+        np.testing.assert_array_equal(
+            np.asarray(got_p.lengths), np.asarray(ref_p.lengths))
+    assert list(np.asarray(got_p.lengths)) == [13, 5, 13, 9]
+    assert telemetry.counter_value(
+        "tdt_engine_decode_chunks_total", path="pool") == 2.0
+    assert telemetry.counter_value(
+        "tdt_engine_decode_chunks_total", path="bounce") == 0.0
+
+    def rows(c, pool, scale):
+        x = pool if scale is None else dequantize_kv(pool, scale)
+        return np.asarray(x.astype(jnp.float32))[:, 1:]  # NULL block: junk
+
+    for name in ("k", "v"):
+        got = rows(got_p, getattr(got_p, name), getattr(got_p, name + "_scale"))
+        ref = rows(ref_p, getattr(ref_p, name), getattr(ref_p, name + "_scale"))
+        if quant is None:
+            np.testing.assert_array_equal(got, ref)
+        else:
+            scale = np.asarray(getattr(ref_p, name + "_scale"))[:, 1:]
+            assert (np.abs(got - ref) <= 2 * scale + 1e-6).all()
+    # The freed slot's stale table named pages 7 and 3; slot 2 wrote there.
+    assert np.asarray(got_p.k.astype(jnp.float32))[:, 3].any()
 
 
 # ======================================== acceptance: server over paged KV

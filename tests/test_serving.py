@@ -373,8 +373,8 @@ def test_chaos_abort_midserving_no_token_loss(model1):
 
     # Inject: the SECOND decode chunk aborts the way a bounded-wait
     # collective does (sticky degradation + CollectiveAbortError). The
-    # recovery rebuild replaces eng._decode_chunk, removing the hook.
-    orig = eng._decode_chunk
+    # recovery rebuild replaces eng._decode_chunk_paged, removing the hook.
+    orig = eng._decode_chunk_paged
     calls = {"n": 0}
 
     def boom(*args, **kwargs):
@@ -384,7 +384,7 @@ def test_chaos_abort_midserving_no_token_loss(model1):
             raise resilience.CollectiveAbortError("injected abort (test)")
         return orig(*args, **kwargs)
 
-    eng._decode_chunk = boom
+    eng._decode_chunk_paged = boom
 
     streams: dict[int, list[int]] = {}
     handles = [
@@ -511,8 +511,8 @@ def test_loop_phases_and_engine_spans_add_up_to_each_step(traced_serve):
              "tdt_serving_prefill", "tdt_serving_prefill_complete",
              "tdt_serving_dispatch", "tdt_serving_fetch", "tdt_serving_emit",
              "tdt_engine_decode_steps_paged", "tdt_engine_prefill_chunk",
-             "tdt_engine_complete_paged_prefill", "tdt_engine_cache_scatter",
-             "tdt_scheduler_join_free_slots"}
+             "tdt_engine_complete_paged_prefill", "tdt_engine_dispatch",
+             "tdt_engine_host_sync", "tdt_scheduler_join_free_slots"}
     assert below <= {o[0] for o in own}
     events = traced_serve["events"]
     for name, a, b, glue, _ in steps:
@@ -551,6 +551,10 @@ def test_loop_counters_equal_the_joins_and_prefill_chunks(traced_serve):
     assert traced_serve["self_n"]["tdt_engine_prefill_chunk"] == len(REQUESTS)
     steps = traced_serve["self_n"]["tdt_serving_step"]
     assert 0 < c["tdt_serving_decode_chunks_total"] <= steps
+    # every chunk the server dispatched ran against the pool in place: the
+    # engine counted each, and the bounce's scatter span never opened
+    assert c["tdt_engine_decode_chunks_total"] == c["tdt_serving_decode_chunks_total"]
+    assert "tdt_engine_cache_scatter" not in traced_serve["self_n"]
     assert c.get("tdt_jit_lowerings_total", 0.0) == 0.0  # warmed: nothing recompiled
 
 

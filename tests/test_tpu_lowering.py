@@ -25,6 +25,7 @@ tests they exercise the exact tiling Mosaic must schedule on hardware.
 
 import dataclasses
 import functools
+import re
 import os
 
 import jax
@@ -480,11 +481,25 @@ def _nbytes(sds):
     return int(np.prod(shard)) * np.dtype(sds.dtype).itemsize
 
 
+def _pool_sized_copies(hlo: str, pool) -> list[str]:
+    """The ``copy`` instructions of a compiled module whose result has the
+    shape of the stacked pool ``pool`` or of one layer's slice of it."""
+    dt = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}[np.dtype(pool.dtype).name]
+    shapes = [",".join(map(str, pool.shape[i:])) for i in (0, 1)]
+    pat = re.compile(
+        r"= \(?%s\[(%s)\][^ ]* copy\(" % (dt, "|".join(map(re.escape, shapes))))
+    return [line.strip()[:160] for line in hlo.splitlines() if pat.search(line)]
+
+
 def test_smoke_one_chip_dist_programs_fit(topo_2x2):
     """The smoke's ``dist`` serving programs on one chip: chunked prefill at
     a ragged and an aligned prompt length (flash attention pads what Mosaic
-    cannot tile), and the op-by-op paged decode — gather, the contiguous
-    chunk program, scatter — with everything it keeps resident."""
+    cannot tile), and the paged decode chunk against the pool where it
+    lies. Of that program the compiled module itself is held to what the
+    in-place decode is for: the pool operands are aliased to the outputs,
+    and no ``copy`` of the pool's shape, or of one layer's slice of it, is
+    left (the scan over ``xs`` / ``ys`` made two of the first a step, the
+    ``pool[layer]`` operand of the kernel two of the second a layer)."""
     from triton_dist_tpu.models.engine import Engine
     from triton_dist_tpu.runtime.platform import force_mosaic
 
@@ -500,22 +515,26 @@ def test_smoke_one_chip_dist_programs_fit(topo_2x2):
                 sds((), jnp.int32), sds((), jnp.int32)))
             # Beside the chunk program: the serving pool.
             assert held + 2 * _nbytes(ops["pool"]) < HBM_BYTES, (p_len, held)
-        cache = kv(sizes.num_slots, sizes.max_len)
-        _, held = _compile(eng._decode_chunk.lower(
-            params, (), ops["slots_i32"], cache, cache, ops["slots_i32"],
-            ops["slots_i32"], sizes.chunk, ops["key"]))
-        # The pool stays resident beside the gathered copy the chunk runs on.
-        assert held + 2 * _nbytes(ops["pool"]) < HBM_BYTES, held
-        gather = eng._paged_gather.lower(
-            ops["pool"], ops["pool"], None, None, ops["tables"]).compile()
-        scatter = eng._paged_scatter_decode.lower(
-            ops["pool"], ops["pool"], None, None, cache, cache, ops["tables"],
-            ops["slots_i32"], ops["slots_i32"], sizes.chunk, None).compile()
-    weights = sum(_nbytes(x) for x in jax.tree.leaves(params))
-    for c in (gather, scatter):
-        m = c.memory_analysis()
-        assert (weights + m.argument_size_in_bytes + m.output_size_in_bytes
-                + m.temp_size_in_bytes - m.alias_size_in_bytes) < HBM_BYTES
+        chunk, held = _compile(eng._decode_chunk_paged.lower(
+            params, (), ops["slots_i32"], ops["pool"], ops["pool"],
+            ops["tables"], ops["slots_i32"], ops["slots_i32"], sizes.chunk,
+            ops["key"]), kernels=("paged_flash_decode",))
+    # The pool is an operand and nothing of its size is held beside it.
+    assert held < HBM_BYTES, held
+    m = chunk.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * _nbytes(ops["pool"]), m.alias_size_in_bytes
+    hlo = chunk.as_text()
+    header = hlo.split("\n", 1)[0]
+    # (params..., token, pk, pv, ...) -> (out, token, pk, pv, ...)
+    first = len(jax.tree.leaves(params)) + 1
+    for out, param in ((2, first), (3, first + 1)):
+        assert re.search(r"\{%d\}: \(%d, \{\}, may-alias\)" % (out, param), header), header
+    assert _pool_sized_copies(hlo, ops["pool"]) == []
+    # ... and the search does find the two this PR removed, by their lines.
+    layer = ",".join(map(str, ops["pool"].shape[1:]))
+    was = ("  %copy.46 = bf16[" + str(ops["pool"].shape[0]) + "," + layer + "]{4,3,2,1,0:T(8,128)(2,1)} copy(%x)\n"
+           "  %copy.10 = bf16[" + layer + "]{3,2,1,0:T(8,128)(2,1)} copy(%y)")
+    assert len(_pool_sized_copies(was, ops["pool"])) == 2
 
 
 def test_smoke_one_chip_mega_step_fits(topo_2x2):
@@ -555,7 +574,8 @@ def test_smoke_tp4_programs(topo_2x2):
     """The four-chip path: the full 36-layer preset, TP=4 on the 2x2 mesh.
     One-shot prefill through the fused AG-GEMM / GEMM-RS kernels, chunked
     prefill through the fused GEMM-AR ring (aligned) and dot+psum (ragged),
-    decode through the one-shot GEMM-AR kernel — compiled with the hardware
+    the paged decode chunk through the one-shot GEMM-AR kernel, the pool
+    sharded by head — compiled with the hardware
     wait bound, so the bounded waits' ``semaphore_read`` polls go through
     Mosaic — and every device holds a quarter of the layers."""
     from triton_dist_tpu.models.engine import Engine
@@ -582,15 +602,18 @@ def test_smoke_tp4_programs(topo_2x2):
                 params, sds((1, p_len), jnp.int32), kv(1, p_len), kv(1, p_len),
                 sds((), jnp.int32), sds((), jnp.int32)), kernels=kernels)
             assert held < HBM_BYTES / 2, (p_len, held)
-        cache = kv(sizes.num_slots, sizes.max_len)
-        decode_args = (params, (), ops["slots_i32"], cache, cache,
-                       ops["slots_i32"], ops["slots_i32"], sizes.chunk,
-                       ops["key"])
-        _, held = _compile(eng._decode_chunk.lower(*decode_args),
-                           kernels=("_gemm_ar_ll_kernel",))
+        decode_args = (params, (), ops["slots_i32"], ops["pool"], ops["pool"],
+                       ops["tables"], ops["slots_i32"], ops["slots_i32"],
+                       sizes.chunk, ops["key"])
+        chunk, held = _compile(
+            eng._decode_chunk_paged.lower(*decode_args),
+            kernels=("_gemm_ar_ll_kernel", "paged_flash_decode"))
         assert held < HBM_BYTES / 2, held
-        jaxpr = str(eng._decode_chunk.trace(*decode_args).jaxpr)
+        jaxpr = str(eng._decode_chunk_paged.trace(*decode_args).jaxpr)
     assert "semaphore_read" in jaxpr
+    # Each chip walks its own heads' pages: its quarter of the pool, in place.
+    assert _pool_sized_copies(chunk.as_text(), jax.ShapeDtypeStruct(
+        ops["pool"].sharding.shard_shape(ops["pool"].shape), jnp.bfloat16)) == []
 
 
 # --------------------------------------------------- kernels under their names
@@ -614,6 +637,23 @@ def _paged_flash_decode(sds):
     return paged_flash_decode, (sds((4, 32, 128)), sds((513, 8, 16, 128)),
                                 sds((513, 8, 16, 128)), sds((4, 128), jnp.int32),
                                 sds((4,), jnp.int32))
+
+
+def _paged_flash_decode_quant(sds):
+    """An int8 pool. Mosaic refuses a DMA of the scale pool's one real lane,
+    so on the chip the quantized pool is gathered and the contiguous kernel
+    runs (``paged_flash_decode``): it has to compile all the same."""
+    from triton_dist_tpu.kernels.flash_decode import paged_flash_decode
+
+    def fn(q, k, v, ks, vs, tables, lengths, layer):
+        return paged_flash_decode(q, k, v, tables, lengths, layer=layer,
+                                  k_scale=ks, v_scale=vs)
+
+    pool = lambda last, dt: sds((24, 513, 8, 16, last), dt)
+    return fn, (sds((4, 32, 128)), pool(128, jnp.int8), pool(128, jnp.int8),
+                pool(1, jnp.float32), pool(1, jnp.float32),
+                sds((4, 128), jnp.int32), sds((4,), jnp.int32),
+                sds((), jnp.int32))
 
 
 def _flash_attention(sds):
@@ -640,6 +680,21 @@ def test_named_kernel_compiles_under_its_name(topo_2x2, case):
     name = case.__name__.lstrip("_")
     calls = [l for l in compiled.as_text().splitlines() if "tpu_custom_call" in l]
     assert calls and all(l.strip().startswith(f"%{name}") for l in calls), calls
+
+
+def test_quantized_pool_decodes_through_the_gather_on_the_chip(topo_2x2):
+    """The int8 pool at the same shapes: no table-walk kernel, the
+    contiguous one over the gathered and dequantized layer, compiled."""
+    from triton_dist_tpu.runtime.platform import force_mosaic
+
+    one = SingleDeviceSharding(topo_2x2.devices[0])
+    fn, args = _paged_flash_decode_quant(
+        lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=one))
+    with force_mosaic():
+        compiled = jax.jit(fn).lower(*args).compile()
+    calls = [l for l in compiled.as_text().splitlines() if "tpu_custom_call" in l]
+    assert calls and all(l.strip().startswith("%flash_decode") for l in calls), calls
 
 
 def test_collective_kernel_is_named_after_its_function(tpu_mesh):

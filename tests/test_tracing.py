@@ -301,7 +301,7 @@ def test_chaos_recovery_span_parented_under_affected_traces(model1):
     eng = make_engine(model1, backend="dist_ar")
     srv = InferenceServer(eng, num_slots=2, chunk=2)
 
-    orig = eng._decode_chunk
+    orig = eng._decode_chunk_paged
     calls = {"n": 0}
 
     def boom(*args, **kwargs):
@@ -311,7 +311,7 @@ def test_chaos_recovery_span_parented_under_affected_traces(model1):
             raise resilience.CollectiveAbortError("injected abort (test)")
         return orig(*args, **kwargs)
 
-    eng._decode_chunk = boom
+    eng._decode_chunk_paged = boom
     handles = [srv.submit([3, 17, 42], 6), srv.submit([8, 1], 5)]
     srv.run()
     assert calls["n"] == 2 and eng.backend == "xla"

@@ -68,6 +68,32 @@ def flash_decode_config_for(q_sds, k_sds, v_sds) -> int:
     return DEFAULT_BLOCK_K
 
 
+def _softmax_tile(q, k, v, k0, length, m_prev, l_prev, acc_prev, scale):
+    """One KV tile of the online softmax for one kv head: ``q`` (group, d)
+    against ``k``/``v`` (tile, d) whose first row is position ``k0``; rows at
+    or past ``length`` are masked. ``m``/``l`` are (group, LANES) lane-
+    broadcast running max and sum, ``acc`` (group, d) f32. The contiguous and
+    the paged kernel both call this, which is what makes them agree bit for
+    bit at one tile size. Returns the updated (m, l, acc)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # (group, tile)
+    k_ids = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k_ids < length, s, NEG_INF)
+    m_cur = jnp.max(s, axis=1, keepdims=True)
+    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new[:, :1])
+    l_new = l_prev * alpha + jnp.broadcast_to(
+        jnp.sum(p, axis=1, keepdims=True), m_prev.shape
+    )
+    acc_new = acc_prev * alpha[:, :1] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, l_new, acc_new
+
+
 def _decode_kernel(
     lengths_ref,  # SMEM (B,)
     q_ref,  # (1, group, d)
@@ -96,26 +122,9 @@ def _decode_kernel(
 
     @pl.when(ik * block_k < length)  # skip blocks entirely past the cache end
     def _():
-        q = q_ref[0]  # (group, d)
-        k = k_ref[0]  # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (group, bk)
-        k_ids = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_ids < length, s, NEG_INF)
-
-        m_prev = m_scr[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        l_scr[...] = l_scr[...] * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), m_prev.shape
-        )
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        m_scr[...], l_scr[...], acc_scr[...] = _softmax_tile(
+            q_ref[0], k_ref[0], v_ref[0], ik * block_k, length,
+            m_scr[...], l_scr[...], acc_scr[...], scale,
         )
 
     @pl.when(ik == n_kv - 1)
@@ -204,148 +213,98 @@ def flash_decode(
 
 
 def _paged_decode_kernel(
+    layer_ref,  # scalar-prefetch (1,) int32 — the layer of the stacked pool
     tables_ref,  # scalar-prefetch (B, max_blocks) int32
-    lengths_ref,  # SMEM (B,)
-    q_ref,  # (1, group, d)
-    k_ref,  # (1, 1, bs, d) — one physical pool block
-    v_ref,  # (1, 1, bs, d)
-    o_ref,  # (1, group, d)
-    lse_ref,  # (1, 1, group)
-    acc_scr,  # VMEM (group, d) f32
-    m_scr,  # VMEM (group, LANES) f32
-    l_scr,  # VMEM (group, LANES) f32
-    *,
+    lengths_ref,  # scalar-prefetch (B,) int32
+    q_ref,  # VMEM (1, hkv, group, d)
+    *refs,  # pools in HBM, outputs, tile buffers, semaphores, accumulators
     scale: float,
     block_size: int,
-    n_kv: int,
+    pages: int,
     hkv: int,
+    quant: bool,
 ):
-    """Online-softmax decode walking a block TABLE instead of a contiguous
-    row. Identical math to ``_decode_kernel`` with ``block_k=block_size`` —
-    the BlockSpec index_map does the page walk (physical block id prefetched
-    from ``tables_ref``), so the compute body never changes and bitwise
-    parity with the contiguous kernel at the same block partition holds by
-    construction."""
-    bh = pl.program_id(0)
-    ik = pl.program_id(1)
-    length = lengths_ref[bh // hkv]
+    """Online-softmax decode walking a block TABLE. One grid step is one
+    slot: the pools stay in HBM and the slot's live tiles of ``pages``
+    pages are DMA'd by the table into a double-buffered VMEM tile, all kv
+    heads of a page in one copy; tiles past the slot's length are neither
+    fetched nor computed. A tile's math is ``_decode_kernel``'s at
+    ``block_k = pages * block_size`` — the same dots, masks and update
+    order per kv head — so bitwise parity with the contiguous kernel at
+    that block partition holds by construction.
 
-    @pl.when(ik == 0)
+    A QUANTIZED pool walks its scale pool through the same table entries
+    and dequantizes each tile to f32 right after the VMEM read
+    (``q·scale`` is exact in f32, power-of-two scales), which keeps it
+    bitwise-comparable to the gather→dequant→contiguous oracle."""
+    n_pools = 4 if quant else 2
+    pools = refs[:n_pools]  # (L, num_blocks, hkv, bs, d | 1) in HBM
+    o_ref, lse_ref = refs[n_pools:n_pools + 2]
+    bufs = refs[n_pools + 2:2 * n_pools + 2]  # (2, hkv, tile, d | 1)
+    sems, acc_scr, m_scr, l_scr = refs[2 * n_pools + 2:]
+
+    b = pl.program_id(0)
+    li = layer_ref[0]
+    length = lengths_ref[b]
+    tile = pages * block_size
+    n_tiles = (length + tile - 1) // tile
+
+    def copies(t, slot):
+        # One descriptor a page and pool; payload and scales resolve the
+        # same table entry. A page past the length is still a mapped (or
+        # the NULL) block: finite bytes that the mask zeroes.
+        out = []
+        for p in range(pages):
+            phys = tables_ref[b, t * pages + p]
+            for i in range(n_pools):
+                out.append(pltpu.make_async_copy(
+                    pools[i].at[li, phys],
+                    bufs[i].at[slot, :, pl.ds(p * block_size, block_size), :],
+                    sems.at[slot, i],
+                ))
+        return out
+
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+
+    @pl.when(n_tiles > 0)
     def _():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        for c in copies(0, 0):
+            c.start()
 
-    @pl.when(ik * block_size < length)  # logical blocks past the cache end skip
-    def _():
-        q = q_ref[0]  # (group, d)
-        k = k_ref[0, 0]  # (bs, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (group, bs)
-        k_ids = ik * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_ids < length, s, NEG_INF)
+    def tile_step(t, carry):
+        slot = jax.lax.rem(t, 2)
 
-        m_prev = m_scr[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        l_scr[...] = l_scr[...] * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), m_prev.shape
-        )
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            for c in copies(t + 1, 1 - slot):
+                c.start()
 
-    @pl.when(ik == n_kv - 1)
-    def _():
-        l = l_scr[:, :1]
+        for c in copies(t, slot):
+            c.wait()
+
+        for h in range(hkv):
+            k = bufs[0][slot, h]  # (tile, d)
+            v = bufs[1][slot, h]
+            if quant:
+                k = k.astype(jnp.float32) * bufs[2][slot, h]
+                v = v.astype(jnp.float32) * bufs[3][slot, h]
+            m_scr[h], l_scr[h], acc_scr[h] = _softmax_tile(
+                q_ref[0, h], k, v, t * tile, length,
+                m_scr[h], l_scr[h], acc_scr[h], scale,
+            )
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, tile_step, 0)
+
+    for h in range(hkv):
+        l = l_scr[h][:, :1]  # (group, 1)
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        lse = jnp.where(
-            l_scr[:, 0] == 0.0,
-            NEG_INF,
-            m_scr[:, 0] + jnp.log(jnp.maximum(l_scr[:, 0], 1e-30)),
+        o_ref[0, h] = (acc_scr[h] / l_safe).astype(o_ref.dtype)
+        lse_ref[0, h] = jnp.where(
+            l == 0.0, NEG_INF, m_scr[h][:, :1] + jnp.log(jnp.maximum(l, 1e-30))
         )
-        lse_ref[0, 0] = lse.astype(lse_ref.dtype)
-
-
-def _paged_decode_quant_kernel(
-    tables_ref,  # scalar-prefetch (B, max_blocks) int32
-    lengths_ref,  # SMEM (B,)
-    q_ref,  # (1, group, d)
-    k_ref,  # (1, 1, bs, d) — one physical pool block, wire dtype
-    v_ref,  # (1, 1, bs, d)
-    ks_ref,  # (1, 1, bs, 1) f32 — the block's per-row scales
-    vs_ref,  # (1, 1, bs, 1) f32
-    o_ref,  # (1, group, d)
-    lse_ref,  # (1, 1, group)
-    acc_scr,  # VMEM (group, d) f32
-    m_scr,  # VMEM (group, LANES) f32
-    l_scr,  # VMEM (group, LANES) f32
-    *,
-    scale: float,
-    block_size: int,
-    n_kv: int,
-    hkv: int,
-):
-    """``_paged_decode_kernel`` over a QUANTIZED pool: the scale pool walks
-    the same table through the same index map (a whole (bs, 1) block read —
-    legal where a sublane-slice of a lane-padded memref is not, see
-    ``models/quant.py``), each block dequantizes to f32 in VMEM right after
-    the walk, and everything downstream is the identical online-softmax.
-    Dequantization ``q·scale`` is exact in f32 (power-of-two scales), so
-    this path is bitwise-comparable to the gather→dequant→contiguous oracle
-    at the same block partition."""
-    bh = pl.program_id(0)
-    ik = pl.program_id(1)
-    length = lengths_ref[bh // hkv]
-
-    @pl.when(ik == 0)
-    def _():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    @pl.when(ik * block_size < length)  # logical blocks past the cache end skip
-    def _():
-        q = q_ref[0]  # (group, d)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]  # (bs, d) f32
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (group, bs)
-        k_ids = ik * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_ids < length, s, NEG_INF)
-
-        m_prev = m_scr[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
-        l_scr[...] = l_scr[...] * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), m_prev.shape
-        )
-        m_scr[...] = m_new
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]  # (bs, d) f32
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(ik == n_kv - 1)
-    def _():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        lse = jnp.where(
-            l_scr[:, 0] == 0.0,
-            NEG_INF,
-            m_scr[:, 0] + jnp.log(jnp.maximum(l_scr[:, 0], 1e-30)),
-        )
-        lse_ref[0, 0] = lse.astype(lse_ref.dtype)
 
 
 def gather_paged_kv(k_pool: jax.Array, tables: jax.Array) -> jax.Array:
@@ -362,36 +321,85 @@ def gather_paged_kv(k_pool: jax.Array, tables: jax.Array) -> jax.Array:
     return gathered.reshape(b, hkv, mb * bs, d)
 
 
+def paged_kv_append(pk, pv, layer, k_new, v_new, tables, lengths, active):
+    """Write one decode step's new K/V rows (B, Hkv, D) of layer ``layer``
+    through the block table into the stacked pools (L, num_blocks, Hkv, bs,
+    D), in place when the pools are a donated loop carry: slot ``b``'s row
+    lands at ``pool[layer, tables[b, lengths[b] // bs], :, lengths[b] % bs]``.
+    An inactive slot's row redirects to the reserved NULL block 0 — a freed
+    slot's old blocks may already belong to another tenant. ``QuantPool``
+    pairs quantize the row ONCE, here (payload and per-row scale land
+    together); no stored row is ever re-quantized. Returns ``(pk', pv')``."""
+    from triton_dist_tpu.models.quant import QuantPool, quantize_kv_rows
+
+    quant = isinstance(pk, QuantPool)
+    bs = (pk.q if quant else pk).shape[3]
+    blk = jnp.take_along_axis(tables, (lengths // bs)[:, None], axis=1)[:, 0]
+    phys = jnp.where(active, blk, 0)
+    sub = lengths % bs
+
+    def put(pool, rows):
+        # One dynamic_update_slice a slot, not one scatter: XLA gives a
+        # scatter's operand a layout of its own choosing, and between that
+        # and the layout the kernel reads lie two copies of the whole pool
+        # a layer. A slice update takes the pool as it lies.
+        for i in range(rows.shape[0]):
+            pool = jax.lax.dynamic_update_slice(
+                pool, rows[i][None, None, :, None, :].astype(pool.dtype),
+                (layer, phys[i], 0, sub[i], 0),
+            )
+        return pool
+
+    if not quant:
+        return put(pk, k_new), put(pv, v_new)
+    kq, ks = quantize_kv_rows(k_new, pk.wire)  # (B, Hkv, D), (B, Hkv, 1)
+    vq, vs = quantize_kv_rows(v_new, pv.wire)
+    return (
+        QuantPool(put(pk.q, kq), put(pk.scale, ks), pk.wire),
+        QuantPool(put(pv.q, vq), put(pv.scale, vs), pv.wire),
+    )
+
+
 def paged_flash_decode(
     q: jax.Array,  # (B, Hq, D) — single decode step
-    k_pool: jax.Array,  # (num_blocks, Hkv, bs, D) — global block pool
-    v_pool: jax.Array,
+    k_pool: jax.Array,  # (num_blocks, Hkv, bs, D) block pool, or stacked
+    v_pool: jax.Array,  # (L, num_blocks, Hkv, bs, D) with ``layer``
     tables: jax.Array,  # (B, max_blocks) int32 physical block ids
     lengths: jax.Array,  # (B,) int32 valid cache length per sequence
     *,
+    layer=None,  # int or int32 scalar: the stacked pools' layer to read
     scale: float | None = None,
+    block_k: int | None = None,
     impl: str = "pallas",
     return_lse: bool = False,
-    k_scale: jax.Array | None = None,  # (num_blocks, Hkv, bs, 1) f32
+    k_scale: jax.Array | None = None,  # the pools' shape with D → 1, f32
     v_scale: jax.Array | None = None,
 ):
     """One-token GQA decode against a PAGED cache.
 
-    ``impl="pallas"`` walks the block table inside the kernel grid: the
-    physical block id for grid step ``(bh, ik)`` is scalar-prefetched from
-    ``tables`` and becomes the BlockSpec index — logical position is grid
-    position, physical position is table data, shapes stay fixed.
+    ``impl="pallas"`` walks the block table inside the kernel: the pools
+    stay in HBM, and for each slot the kernel DMAs the live tiles of
+    ``block_k // bs`` pages by their table entries into VMEM — logical
+    position is the table's column, physical position is table data,
+    shapes stay fixed. With ``layer`` the pools are the STACKED serving
+    pools and the layer's index is one more scalar operand, so no
+    ``pool[layer]`` slice is ever materialized for the custom call.
     ``impl="gather"`` is the oracle: gather the pool into a contiguous view
-    and run the proven contiguous kernel at ``block_k=block_size`` (the
-    same KV partition → bitwise-identical accumulation order).
+    and run the proven contiguous kernel at the same tile (the same KV
+    partition → bitwise-identical accumulation order).
+
+    ``block_k=None`` resolves like ``flash_decode``'s (pin, tune cache, 256)
+    over the equivalent contiguous cache; the tile is the largest whole
+    number of pages that divides the table and is no longer than that.
 
     With ``k_scale``/``v_scale`` (or ``QuantPool`` operands) the pool is
     quantized (``models/quant.py``): the kernel walks the parallel scale
-    pool through the same table and dequantizes each block to f32 right
+    pool through the same table and dequantizes each tile to f32 right
     after the VMEM read — no gather bounce, no fp32 pool ever materializes.
     The gather oracle dequantizes host-side and feeds the contiguous kernel
     f32 KV, which is the bitwise-identical computation (power-of-two scales
     make dequantization exact in f32)."""
+    from triton_dist_tpu.kernels.gemm import fit_block
     from triton_dist_tpu.models.quant import QuantPool, dequantize_kv
 
     if isinstance(k_pool, QuantPool):
@@ -400,81 +408,80 @@ def paged_flash_decode(
         v_pool, v_scale = v_pool.q, v_pool.scale
     quant = k_scale is not None
     assert (k_scale is None) == (v_scale is None)
+    pools = [k_pool, v_pool] + ([k_scale, v_scale] if quant else [])
+    if layer is None:
+        assert k_pool.ndim == 4, k_pool.shape
+        pools, layer = [x[None] for x in pools], 0  # a bitcast, not a slice
 
     b, hq, d = q.shape
-    nb, hkv, bs, _ = k_pool.shape
+    _, _, hkv, bs, _ = pools[0].shape
     assert hq % hkv == 0
     group = hq // hkv
     mb = tables.shape[1]
     scale = scale if scale is not None else d ** -0.5
+    if block_k is None:
+        cache_sds = jax.ShapeDtypeStruct((b, hkv, mb * bs, d), pools[0].dtype)
+        block_k = flash_decode_config_for(
+            jax.ShapeDtypeStruct(q.shape, q.dtype), cache_sds, cache_sds
+        )
+    pages = fit_block(mb, max(block_k // bs, 1))
+    tile = pages * bs
+    interpret = interpret_mode_default()
+    if quant and impl == "pallas" and not interpret:
+        # Mosaic pads the scale pool's last dimension of 1 to the 128 lanes
+        # of a tile and refuses a DMA of the one real lane, so on the chip a
+        # quantized pool is read through the gather until its scales lie
+        # lane-dense (PERF.md section 7); the interpreter walks them.
+        impl = "gather"
 
     if impl == "gather":
-        kc = gather_paged_kv(k_pool, tables)
-        vc = gather_paged_kv(v_pool, tables)
+        kc, vc, *scales = (gather_paged_kv(x[layer], tables) for x in pools)
         if quant:
-            kc = dequantize_kv(kc, gather_paged_kv(k_scale, tables))
-            vc = dequantize_kv(vc, gather_paged_kv(v_scale, tables))
+            kc = dequantize_kv(kc, scales[0])
+            vc = dequantize_kv(vc, scales[1])
         return flash_decode(
-            q, kc, vc, lengths, scale=scale, block_k=bs, return_lse=return_lse
+            q, kc, vc, lengths, scale=scale, block_k=tile, return_lse=return_lse
         )
     if impl != "pallas":
         raise ValueError(f"unknown paged decode impl {impl!r}")
 
-    qr = q.reshape(b, hkv, group, d).reshape(b * hkv, group, d)
-
-    def walk(width):
-        # Payload and scale pools walk the SAME table entry — one physical
-        # block id resolves both the bytes and their per-row scales.
-        return pl.BlockSpec(
-            (1, 1, bs, width),
-            lambda bh, ik, tab: (tab[bh // hkv, ik], bh % hkv, 0, 0),
-        )
-
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, group, d), lambda bh, ik, tab: (bh, 0, 0)),
-        walk(d),
-        walk(d),
-    ]
-    operands = [lengths.astype(jnp.int32), qr, k_pool, v_pool]
-    if quant:
-        in_specs += [walk(1), walk(1)]
-        operands += [k_scale, v_scale]
-        kernel = _paged_decode_quant_kernel
-    else:
-        kernel = _paged_decode_kernel
-
+    head_spec = lambda last: pl.BlockSpec(
+        (1, hkv, group, last), lambda bi, *_: (bi, 0, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # tables ride ahead of the grid for index maps
-        grid=(b * hkv, mb),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, group, d), lambda bh, ik, tab: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, group), lambda bh, ik, tab: (bh, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-        ],
+        num_scalar_prefetch=3,  # layer, tables, lengths: DMA addresses and bounds
+        grid=(b,),
+        in_specs=[head_spec(d)] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=[head_spec(d), head_spec(1)],
+        scratch_shapes=(
+            [pltpu.VMEM((2, hkv, tile, x.shape[-1]), x.dtype) for x in pools]
+            + [
+                pltpu.SemaphoreType.DMA((2, len(pools))),
+                pltpu.VMEM((hkv, group, d), jnp.float32),
+                pltpu.VMEM((hkv, group, LANES), jnp.float32),
+                pltpu.VMEM((hkv, group, LANES), jnp.float32),
+            ]
+        ),
     )
     o, lse = pl.pallas_call(
         functools.partial(
-            kernel, scale=scale, block_size=bs, n_kv=mb, hkv=hkv
+            _paged_decode_kernel, scale=scale, block_size=bs, pages=pages,
+            hkv=hkv, quant=quant,
         ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, group, d), q.dtype),
-            jax.ShapeDtypeStruct((b * hkv, 1, group), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret_mode_default(),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
         name="paged_flash_decode_quant" if quant else "paged_flash_decode",
     )(
-        tables.astype(jnp.int32).reshape(b, mb),
-        *operands,
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        tables.astype(jnp.int32),
+        lengths.astype(jnp.int32),
+        q.reshape(b, hkv, group, d),
+        *pools,
     )
 
     o = o.reshape(b, hq, d)

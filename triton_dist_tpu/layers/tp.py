@@ -31,7 +31,11 @@ from triton_dist_tpu.kernels.allgather_gemm import (
 from triton_dist_tpu.kernels.gemm_reduce_scatter import gemm_rs_shard, GemmRSMethod
 from triton_dist_tpu.kernels.gemm_allreduce import gemm_ar_shard, GemmARMethod
 from triton_dist_tpu.kernels.flash_attn import flash_attention
-from triton_dist_tpu.kernels.flash_decode import flash_decode
+from triton_dist_tpu.kernels.flash_decode import (
+    flash_decode,
+    paged_flash_decode,
+    paged_kv_append,
+)
 from triton_dist_tpu.kernels.moe_utils import (
     capacity_for,
     make_routing_plan,
@@ -252,30 +256,56 @@ class TP_Attn:
         CUDA-graph-safe ``KV_Cache.inc_offset``) and returns
         (out (bsz, d) replicated, (k_cache, v_cache) updated)."""
         mode = _tp_mode(mode)
-        bsz = x.shape[0]
-        qkv = jnp.dot(x, self.wqkv, preferred_element_type=jnp.float32).astype(x.dtype)
-        q, k, v = self._split_qkv(qkv, bsz, 1)
-        q = apply_rope(q, pos[:, None], self.rope_theta)
-        k = apply_rope(k, pos[:, None], self.rope_theta)
-        batch_ids = jnp.arange(bsz)
-        k_cache = k_cache.at[batch_ids, :, lengths].set(k[:, :, 0])
-        v_cache = v_cache.at[batch_ids, :, lengths].set(v[:, :, 0])
+        q, k, v = self._decode_qkv(x, pos)
+        batch_ids = jnp.arange(x.shape[0])
+        k_cache = k_cache.at[batch_ids, :, lengths].set(k)
+        v_cache = v_cache.at[batch_ids, :, lengths].set(v)
         o = flash_decode(
-            q[:, :, 0], k_cache, v_cache, lengths + 1,
+            q, k_cache, v_cache, lengths + 1,
             block_k=min(256, k_cache.shape[2]),
         )
-        o = o.reshape(bsz, -1)
+        return self._decode_out(o, mode, x.dtype), (k_cache, v_cache)
+
+    def decode_paged(self, x, pos, pk, pv, layer, tables, lengths, active,
+                     mode: str = "dist_ar"):
+        """``decode`` against the block pool where it lies. ``pk``/``pv``
+        are the STACKED pools (L, num_blocks, Hkv_l, bs, D), or ``QuantPool``
+        pairs, and ``layer`` this layer's index as data: the one new K/V row
+        of each slot is written through ``tables`` (an inactive slot's to
+        the NULL block) and attention reads K/V through the table inside the
+        kernel, at ``decode``'s tile, so the two agree bit for bit. Nothing
+        of the pool's size, nor of one layer's slice of it, is copied.
+        Returns (out (bsz, d) replicated, (pk, pv) updated)."""
+        mode = _tp_mode(mode)
+        q, k, v = self._decode_qkv(x, pos)
+        pk, pv = paged_kv_append(pk, pv, layer, k, v, tables, lengths, active)
+        o = paged_flash_decode(
+            q, pk, pv, tables, lengths + active.astype(lengths.dtype),
+            layer=layer, block_k=256,
+        )
+        return self._decode_out(o, mode, x.dtype), (pk, pv)
+
+    def _decode_qkv(self, x, pos):
+        """One decode step's projection: roped q (B, Hq_l, D) and k, and v,
+        (B, Hkv_l, D) for the token each slot holds at ``pos``."""
+        qkv = jnp.dot(x, self.wqkv, preferred_element_type=jnp.float32).astype(x.dtype)
+        q, k, v = self._split_qkv(qkv, x.shape[0], 1)
+        q = apply_rope(q, pos[:, None], self.rope_theta)
+        k = apply_rope(k, pos[:, None], self.rope_theta)
+        return q[:, :, 0], k[:, :, 0], v[:, :, 0]
+
+    def _decode_out(self, o, mode: str, dtype):
+        """The decode step's o-projection and its reduction over tp."""
+        o = o.reshape(o.shape[0], -1)
         if mode == "dist_ar":
             # bsz rows is decode-tiny (≤ the M crossover), so AUTO lands on
             # the fused ll_one_shot GEMM-AR kernel here.
-            out = gemm_ar_shard(o, self.wo, axis=self.axis, mesh_axes=self.mesh_axes)
-        elif mode == "xla":
-            out = jax.lax.psum(
+            return gemm_ar_shard(o, self.wo, axis=self.axis, mesh_axes=self.mesh_axes)
+        if mode == "xla":
+            return jax.lax.psum(
                 jnp.dot(o, self.wo, preferred_element_type=jnp.float32), self.axis
-            ).astype(x.dtype)
-        else:
-            raise ValueError(f"decode supports xla/dist_ar, got {mode}")
-        return out, (k_cache, v_cache)
+            ).astype(dtype)
+        raise ValueError(f"decode supports xla/dist_ar, got {mode}")
 
 
 #: Shared TP-MoE routing capacity factor — governs BOTH prefill and decode

@@ -402,11 +402,12 @@ class ModelBuilder:
         mesh_axes = self.mesh_axes
         eps = c.rms_eps
 
-        from triton_dist_tpu.kernels.flash_decode import flash_decode, paged_flash_decode
+        from triton_dist_tpu.kernels.flash_decode import (
+            flash_decode, paged_flash_decode, paged_kv_append,
+        )
         from triton_dist_tpu.kernels.gemm_allreduce import gemm_ar_shard
         from triton_dist_tpu.kernels.allreduce import AllReduceMethod, all_reduce_shard
         from triton_dist_tpu.layers.tp import apply_rope
-        from triton_dist_tpu.models.quant import QuantPool, quantize_kv_rows
 
         param = lambda name: name.split(":", 1)[1]
 
@@ -694,32 +695,11 @@ class ModelBuilder:
                 active = env[t.inputs[5]]
                 tables = env[t.inputs[6]]
                 li_ = cache_li(env_li)
-                quant = isinstance(pk, QuantPool)
-                bs = (pk.q if quant else pk).shape[3]
-                blk = jnp.take_along_axis(
-                    tables, (lengths // bs)[:, None], axis=1)[:, 0]
-                # Inactive slots redirect to the NULL block: their old
-                # blocks may already belong to another tenant.
-                phys = jnp.where(active, blk, 0)
-                sub = lengths % bs
-                if quant:
-                    # Quantize-once at append: the new rows pick up their
-                    # per-row scales here and are never re-quantized.
-                    kq, ksc = quantize_kv_rows(k_new, pk.wire)
-                    vq, vsc = quantize_kv_rows(v_new, pv.wire)
-                    pk = QuantPool(
-                        pk.q.at[li_, phys, :, sub, :].set(kq),
-                        pk.scale.at[li_, phys, :, sub, :].set(ksc),
-                        pk.wire,
-                    )
-                    pv = QuantPool(
-                        pv.q.at[li_, phys, :, sub, :].set(vq),
-                        pv.scale.at[li_, phys, :, sub, :].set(vsc),
-                        pv.wire,
-                    )
-                else:
-                    pk = pk.at[li_, phys, :, sub, :].set(k_new)
-                    pv = pv.at[li_, phys, :, sub, :].set(v_new)
+                # Through the table, inactive slots to the NULL block, a
+                # quantized row quantized once: ``paged_kv_append``.
+                pk, pv = paged_kv_append(
+                    pk, pv, li_, k_new, v_new, tables, lengths, active
+                )
                 env[t.outputs[0]] = (pk, li_)
                 env[t.outputs[1]] = (pv, li_)
             return standalone_cache_update_paged
@@ -749,17 +729,11 @@ class ModelBuilder:
                 li_ = cache_li(env_li)
                 b = q.shape[0]
                 step = active.astype(lengths.dtype)
-                if isinstance(pk, QuantPool):
-                    # The cache_update task already appended (quantize-once);
-                    # the walk dequantizes in-kernel via the scale pool.
-                    out = paged_flash_decode(
-                        q, pk.q[li_], pv.q[li_], tables, lengths + step,
-                        k_scale=pk.scale[li_], v_scale=pv.scale[li_],
-                    )
-                else:
-                    out = paged_flash_decode(
-                        q, pk[li_], pv[li_], tables, lengths + step,
-                    )
+                # The cache_update task already appended (quantize-once); a
+                # quantized walk dequantizes in-kernel via the scale pool.
+                out = paged_flash_decode(
+                    q, pk, pv, tables, lengths + step, layer=li_,
+                )
                 env[t.outputs[0]] = out.reshape(b, hq * hd)
             return standalone_paged_flash_decode
 

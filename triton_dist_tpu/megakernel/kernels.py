@@ -511,17 +511,17 @@ def fused_paged_attn_back(
     """Paged attention back-leg: pool scatter → block-table walk →
     o-projection partial, the serving-shaped analog of ``fused_attn_back``.
 
-    The table walk IS the Pallas kernel here (``paged_flash_decode``'s
-    scalar-prefetched grid, the vLLM/PagedAttention layout); the one-row
-    scatter and the o-proj GEMM ride the same jit step, where XLA overlaps
-    them against the sweep. Unlike the contiguous leg there is no in-VMEM
-    splice — a paged write lands at ``tables[b, pos//bs]`` which only the
-    same step's walk reads, so scatter-then-attend IS append-then-attend
-    and the accumulation partition is the pool's block size by
-    construction. That makes this path bitwise-comparable with the
-    contiguous op-by-op decode exactly when the contiguous sweep runs at
-    ``block_k == bs`` (pin via ``TDT_FLASH_BLOCK_K`` or the tune cache —
-    the megakernel parity contract, docs/megakernel.md).
+    The table walk IS the Pallas kernel here (``paged_flash_decode``: the
+    stacked pool stays in HBM, the layer's index and the tables are scalar
+    operands, tiles of pages are DMA'd by the table); the one-row scatter
+    (``paged_kv_append``) and the o-proj GEMM ride the same jit step.
+    Unlike the contiguous leg there is no in-VMEM splice — a paged write
+    lands at ``tables[b, pos//bs]`` which only the same step's walk reads,
+    so scatter-then-attend IS append-then-attend. The walk's tile resolves
+    like the contiguous sweep's ``block_k`` (pin, tune cache, 256), so the
+    two share one accumulation partition and this path is
+    bitwise-comparable with the contiguous decode (the megakernel parity
+    contract, docs/megakernel.md).
 
     ``active`` is per-slot DATA: inactive slots redirect their write to the
     reserved NULL block 0 (a freed slot's old blocks may already belong to
@@ -536,42 +536,14 @@ def fused_paged_attn_back(
     stored row is ever re-quantized (the prefix-trie/CoW invariant), and
     the step stays one fused launch: quantize → scatter → walk all ride the
     same jit step."""
-    from triton_dist_tpu.kernels.flash_decode import paged_flash_decode
-    from triton_dist_tpu.models.quant import QuantPool, quantize_kv_rows
+    from triton_dist_tpu.kernels.flash_decode import paged_flash_decode, paged_kv_append
 
     b, hq, d = q.shape
-    quant = isinstance(pk, QuantPool)
-    bs = (pk.q if quant else pk).shape[3]
-    scale = scale if scale is not None else d ** -0.5
-
-    step = active.astype(lengths.dtype)
-    pos = lengths  # the new token's row (write position)
-    blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-    phys = jnp.where(active, blk, 0)
-    sub = pos % bs
-    if quant:
-        kq, ks = quantize_kv_rows(k_new, pk.wire)  # (B, Hkv, D), (B, Hkv, 1)
-        vq, vs = quantize_kv_rows(v_new, pv.wire)
-        pk = QuantPool(
-            pk.q.at[li, phys, :, sub, :].set(kq),
-            pk.scale.at[li, phys, :, sub, :].set(ks),
-            pk.wire,
-        )
-        pv = QuantPool(
-            pv.q.at[li, phys, :, sub, :].set(vq),
-            pv.scale.at[li, phys, :, sub, :].set(vs),
-            pv.wire,
-        )
-        o = paged_flash_decode(
-            q, pk.q[li], pv.q[li], tables, lengths + step, scale=scale,
-            k_scale=pk.scale[li], v_scale=pv.scale[li],
-        )
-    else:
-        pk = pk.at[li, phys, :, sub, :].set(k_new)
-        pv = pv.at[li, phys, :, sub, :].set(v_new)
-        o = paged_flash_decode(
-            q, pk[li], pv[li], tables, lengths + step, scale=scale
-        )
+    pk, pv = paged_kv_append(pk, pv, li, k_new, v_new, tables, lengths, active)
+    o = paged_flash_decode(
+        q, pk, pv, tables, lengths + active.astype(lengths.dtype), layer=li,
+        scale=scale,
+    )
     part = jnp.dot(
         o.reshape(b, hq * d), wo, preferred_element_type=jnp.float32
     )

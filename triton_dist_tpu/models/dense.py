@@ -386,6 +386,41 @@ class DenseLLM:
         logits = jnp.dot(x, p.lm_head, preferred_element_type=jnp.float32)
         return logits, ks, vs
 
+    def decode_shard_paged(self, p: DenseParams, token: jax.Array, pk, pv,
+                           tables, lengths, active, mode: str):
+        """``decode_shard`` against the stacked block POOLS (L, num_blocks,
+        Hkv_l, bs, D; or ``QuantPool`` pairs). The pool pair is the CARRY of
+        the loop over layers, never its ``xs`` / ``ys``: a scan hands each
+        layer a slice of its ``xs`` and stacks the ``ys`` anew, which is one
+        copy of the whole cache a step, while a carry is updated where it
+        lies. The layer's index reaches the row write and the kernel as
+        data. ``tables`` (B, max_blocks) and ``active`` (B,) are data too:
+        an inactive slot writes to the NULL block and its logits are the
+        caller's to mask. Returns (logits (B, V_local), pk, pv)."""
+        c = self.config
+        x = p.embed[token]
+        eps = c.rms_eps
+        mlp_mode = "xla" if mode == "xla" else "dist_ar"
+
+        def layer_fn(carry, layer):
+            x, pk, pv = carry
+            lp, li = layer
+            h = RMSNorm(weight=lp["ln1"], eps=eps)(x)
+            a, (pk, pv) = self._attn(lp).decode_paged(
+                h, lengths, pk, pv, li, tables, lengths, active, mode=mode
+            )
+            x = x + a
+            h = RMSNorm(weight=lp["ln2"], eps=eps)(x)
+            return (x + self._mlp(lp)(h, mode=mlp_mode), pk, pv), None
+
+        (x, pk, pv), _ = jax.lax.scan(
+            layer_fn, (x, pk, pv),
+            (self._layer_stack(p), jnp.arange(c.num_layers, dtype=jnp.int32)),
+        )
+        x = RMSNorm(weight=p.final_norm, eps=eps)(x)
+        logits = jnp.dot(x, p.lm_head, preferred_element_type=jnp.float32)
+        return logits, pk, pv
+
     # -- speculative k-wide verify -----------------------------------------
 
     def verify_shard(self, p: DenseParams, tokens, ks, vs, lengths, steps, mode: str):

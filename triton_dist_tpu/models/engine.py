@@ -273,7 +273,6 @@ class Engine:
                 check_vma=False,
             )
         else:
-            self._decode_shard_paged = None
             def decode_fn(params, token, ks, vs, lengths):
                 logits, ks, vs = model.decode_shard(params, token, ks, vs, lengths, decode_mode)
                 return jax.lax.all_gather(logits, axis, axis=1, tiled=True), ks, vs
@@ -287,6 +286,28 @@ class Engine:
             self._decode_extra = ()
             self._decode_shard = lambda p_, extra, t_, k_, v_, l_: sm(
                 p_, t_, k_, v_, l_
+            )
+
+            # The same step against the block pool where it lies: the pool
+            # pair is carried through the layers, one K/V row written a
+            # layer, K/V read through the table inside the kernel.
+            def decode_paged_fn(params, token, pk, pv, tables, lengths, active):
+                logits, pk, pv = model.decode_shard_paged(
+                    params, token, pk, pv, tables, lengths, active, decode_mode
+                )
+                return jax.lax.all_gather(logits, axis, axis=1, tiled=True), pk, pv
+
+            psm = jax.shard_map(
+                decode_paged_fn, mesh=mesh,
+                in_specs=(p_specs, tok_spec, pool_spec, pool_spec, P(dp),
+                          len_spec, len_spec),
+                out_specs=(tok_spec, pool_spec, pool_spec),
+                check_vma=False,
+            )
+            self._decode_shard_paged = (
+                lambda p_, extra, t_, pk_, pv_, tab_, l_, a_: psm(
+                    p_, t_, pk_, pv_, tab_, l_, a_
+                )
             )
 
             # Speculative k-wide verify: k sequenced sub-steps of the exact
@@ -336,6 +357,9 @@ class Engine:
                 self, p_specs=p_specs, tok_spec=tok_spec,
                 kv_spec=kv_spec, len_spec=len_spec,
             )
+            # The stage-sliced step has no paged twin: a pp mesh keeps the
+            # contiguous bounce round its decode chunks.
+            self._decode_shard_paged = None
 
         # One compiled program per gen_len: the whole decode loop on device
         # (the XLA analog of replaying a captured CUDA graph gen_len times,
@@ -427,8 +451,8 @@ class Engine:
 
         self._decode_chunk = decode_chunk
 
-        # Paged twin of decode_chunk, used when the backend decodes the
-        # block pool directly (mega): same active-mask/re-feed/freeze
+        # Paged twin of decode_chunk, the decode of every backend that has a
+        # paged step (all but a pp mesh): same active-mask/re-feed/freeze
         # semantics per step, but the carry is the POOL pair and the block
         # tables ride as data — one compiled program per chunk size, zero
         # recompiles across batch compositions.
@@ -450,7 +474,7 @@ class Engine:
                 )
                 # Inactive slots re-feed their last token and freeze their
                 # lengths (decode_chunk's rule); their KV write redirects to
-                # the NULL block inside the fused step — a freed slot's old
+                # the NULL block inside the step — a freed slot's old
                 # blocks may already belong to another tenant.
                 nxt = jnp.where(active, nxt, token)
                 out = out.at[:, i].set(jnp.where(active, nxt, jnp.int32(-1)))
@@ -482,12 +506,12 @@ class Engine:
         # ---- paged-KV serving programs (block pool + tables) --------------
         # The paged layout splits the slot cache into a global block pool;
         # everything below keeps the fixed-shape discipline: block tables
-        # are DATA (int32 operands) and pool/buffer shapes are static. On
-        # op-by-op backends the decode math still runs through
-        # self._decode_chunk — gather → proven contiguous chunk → masked
-        # scatter-back, so every decode guarantee (active masks, chaos
-        # hooks, donation) carries over unchanged; the mega backend skips
-        # the bounce and decodes the pool in place (decode_chunk_paged).
+        # are DATA (int32 operands) and pool/buffer shapes are static.
+        # Decode runs against the pool in place (decode_chunk_paged). Two
+        # paths still bounce through the contiguous layout — gather →
+        # contiguous chunk → masked scatter-back of the written rows: a pp
+        # mesh (its stage-sliced step has no paged twin) and op-by-op
+        # speculation (rejected draft rows must never reach the pool).
         chunk_mode = CHUNK_MODE[backend]
 
         def chunk_fn(params, toks, kb, vb, off, last_idx):
@@ -549,47 +573,6 @@ class Engine:
         self._paged_gather = jax.jit(
             paged_gather, out_shardings=(self._kv_sharding, self._kv_sharding)
         )
-
-        @partial(jax.jit, static_argnums=(9, 10), donate_argnums=(0, 1, 2, 3))
-        def paged_scatter_decode(pk, pv, ks, vs, kc, vc, tables, lengths0,
-                                 remaining0, chunk, wire):
-            """Write the decode chunk's freshly-written contiguous rows back
-            into the pool. Row r of slot b landed at position lengths0[b]+r
-            and is real only while r < remaining0[b] (the chunk's active
-            mask); masked rows redirect to the NULL block — a freed slot's
-            old blocks may already belong to another tenant, so the
-            contiguous mode's "harmless junk write" would be cross-slot
-            corruption here.
-
-            With ``wire`` set the pool is quantized: each NEW row quantizes
-            exactly once here (payload + per-row scale scatter together);
-            rows already in the pool are never touched, so shared prefix
-            blocks stay bitwise-stable."""
-            bs = pk.shape[3]
-            b = tables.shape[0]
-            smax = kc.shape[3]
-            nv = jnp.clip(remaining0, 0, chunk)
-            b_ids = jnp.arange(b)
-            for r in range(chunk):
-                pos = jnp.minimum(lengths0 + r, smax - 1)
-                blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
-                phys = jnp.where(r < nv, blk, 0)
-                sub = pos % bs
-                krow = kc[:, b_ids, :, pos]
-                vrow = vc[:, b_ids, :, pos]
-                if wire is not None:
-                    kq, ksc = quantize_kv_rows(krow, wire)
-                    vq, vsc = quantize_kv_rows(vrow, wire)
-                    pk = pk.at[:, phys, :, sub, :].set(kq)
-                    pv = pv.at[:, phys, :, sub, :].set(vq)
-                    ks = ks.at[:, phys, :, sub, :].set(ksc)
-                    vs = vs.at[:, phys, :, sub, :].set(vsc)
-                else:
-                    pk = pk.at[:, phys, :, sub, :].set(krow)
-                    pv = pv.at[:, phys, :, sub, :].set(vrow)
-            return pk, pv, ks, vs
-
-        self._paged_scatter_decode = paged_scatter_decode
 
         @partial(jax.jit, static_argnums=(8,), donate_argnums=(0, 1, 2, 3))
         def paged_scatter_prefill(pk, pv, ks, vs, kbuf, vbuf, table_row,
@@ -667,13 +650,20 @@ class Engine:
         @partial(jax.jit, static_argnums=(9, 10), donate_argnums=(0, 1, 2, 3))
         def paged_scatter_rows(pk, pv, ks, vs, kc, vc, tables, lengths0, nv,
                                max_rows, wire):
-            """Generalized ``paged_scatter_decode``: the per-slot valid row
-            count ``nv`` is DATA, not derived from the chunk's remaining —
-            the speculative path writes back exactly the accepted prefix
-            (``lengths' - lengths0``), so rejected draft rows in the
-            contiguous bounce buffer never reach the pool. Masked rows
-            redirect to the NULL block, as everywhere; quantized rows
-            quantize once, here."""
+            """Write a bounced chunk's freshly-written contiguous rows back
+            into the pool. Row r of slot b landed at position lengths0[b]+r
+            and is real only while r < nv[b]; the per-slot valid row count
+            ``nv`` is DATA — the speculative path writes back exactly the
+            accepted prefix (``lengths' - lengths0``), so rejected draft
+            rows in the contiguous bounce buffer never reach the pool, and
+            a pp mesh's plain chunk the rows its active mask let through.
+            Masked rows redirect to the NULL block — a freed slot's old
+            blocks may already belong to another tenant, so the contiguous
+            mode's "harmless junk write" would be cross-slot corruption
+            here. With ``wire`` set the pool is quantized: each NEW row
+            quantizes exactly once here (payload + per-row scale scatter
+            together); rows already in the pool are never touched, so
+            shared prefix blocks stay bitwise-stable."""
             bs = pk.shape[3]
             b = tables.shape[0]
             smax = kc.shape[3]
@@ -884,14 +874,16 @@ class Engine:
     def decode_steps_paged(self, paged: PagedKVCache, tokens: jax.Array,
                            remaining: jax.Array, chunk: int,
                            key: jax.Array | None = None):
-        """Paged analog of ``decode_steps``. On the mega backend the chunk
-        runs DIRECTLY against the block pool — the persistent-step program
-        takes tables + active mask as data, so there is no whole-pool
-        gather/scatter bounce per chunk. Op-by-op backends gather the pool
-        into the contiguous layout, run the SAME ``self._decode_chunk``
-        program (every contiguous-mode decode guarantee — active masks,
-        donation, the chaos suite's dispatch hook — applies verbatim), then
-        scatter the chunk's written rows back with the null-block mask.
+        """Paged analog of ``decode_steps``. The chunk runs DIRECTLY against
+        the block pool: the chunk program carries the pool pair as its loop
+        state and donates it, each layer of each step writes its one new
+        K/V row through the table (an inactive slot's to the NULL block) and
+        attention reads K/V through the table inside the kernel — no
+        contiguous cache is built, copied or scattered back. Only a pp mesh,
+        whose stage-sliced step has no paged twin, still gathers the pool
+        into the contiguous layout, runs ``self._decode_chunk`` and scatters
+        the chunk's written rows back with the null-block mask.
+        ``tdt_engine_decode_chunks_total{path}`` says which ran.
         Returns ``(out, last_tokens, paged', remaining')``."""
         if key is None:
             key = jax.random.PRNGKey(0)
@@ -902,48 +894,48 @@ class Engine:
             # statements the ``_phase`` stamp times, fence included.
             timed = telemetry.enabled()
             t = time.perf_counter() if timed else 0.0
-            if self.backend == "mega":
-                with tracing.span_current("tdt_engine_dispatch"):
+            pool = self._decode_shard_paged is not None
+            telemetry.inc(
+                "tdt_engine_decode_chunks_total", path="pool" if pool else "bounce"
+            )
+            with tracing.span_current("tdt_engine_dispatch"):
+                if pool:
                     pk_in, pv_in = self._pool_pair(paged)
                     out, tok, pk, pv, lengths, rem = self._decode_chunk_paged(
                         self.model.params, self._decode_extra, tokens, pk_in,
                         pv_in, paged.tables, paged.lengths, remaining, int(chunk),
                         key,
                     )
-                    telemetry.set_gauge(
-                        "tdt_mega_steps_per_launch", float(chunk), path="paged"
+                    if self.backend == "mega":
+                        telemetry.set_gauge(
+                            "tdt_mega_steps_per_launch", float(chunk), path="paged"
+                        )
+                else:
+                    kc, vc = self._paged_gather(
+                        paged.k, paged.v, paged.k_scale, paged.v_scale, paged.tables
                     )
-                    if timed:
-                        # dispatch = host wall to ISSUE the chunk program
-                        # (async); host_sync = the wait for the device to finish
-                        # it. The mega path scatters in place — no cache_scatter
-                        # phase.
-                        t = self._phase("dispatch", t)
-                with tracing.span_current("tdt_engine_host_sync"):
-                    if timed:
-                        self._phase("host_sync", t, tok)
-                return out, tok, self._pool_update(paged, pk, pv, lengths), rem
-            with tracing.span_current("tdt_engine_dispatch"):
-                kc, vc = self._paged_gather(
-                    paged.k, paged.v, paged.k_scale, paged.v_scale, paged.tables
-                )
-                out, tok, k2, v2, lengths, rem = self._decode_chunk(
-                    self.model.params, self._decode_extra, tokens, kc, vc,
-                    paged.lengths, remaining, int(chunk), key,
-                )
+                    out, tok, k2, v2, lengths, rem = self._decode_chunk(
+                        self.model.params, self._decode_extra, tokens, kc, vc,
+                        paged.lengths, remaining, int(chunk), key,
+                    )
                 if timed:
+                    # dispatch = host wall to ISSUE the chunk program
+                    # (async); host_sync = the wait for the device to finish
+                    # it. In place there is nothing to scatter and no
+                    # cache_scatter phase.
                     t = self._phase("dispatch", t)
             with tracing.span_current("tdt_engine_host_sync"):
                 if timed:
                     t = self._phase("host_sync", t, tok)
+            if pool:
+                return out, tok, self._pool_update(paged, pk, pv, lengths), rem
             with tracing.span_current("tdt_engine_cache_scatter"):
-                pk, pv, ks, vs = self._paged_scatter_decode(
+                pk, pv, ks, vs = self._paged_scatter_rows(
                     paged.k, paged.v, paged.k_scale, paged.v_scale, k2, v2,
-                    paged.tables, paged.lengths, remaining, int(chunk), paged.quant,
+                    paged.tables, paged.lengths, jnp.clip(remaining, 0, chunk),
+                    int(chunk), paged.quant,
                 )
                 if timed:
-                    # The gather/scatter bounce around the contiguous chunk
-                    # program — exactly the cost the mega in-place path deletes.
                     self._phase("cache_scatter", t, pk)
             return out, tok, dataclasses.replace(
                 paged, k=pk, v=pv, k_scale=ks, v_scale=vs, lengths=lengths
@@ -952,11 +944,11 @@ class Engine:
     def decode_logits_paged(self, paged: PagedKVCache, tokens: jax.Array):
         """(B, V) float32 logits of ONE decode step over the paged cache,
         through the step program ``decode_steps_paged`` iterates on this
-        backend (mega: the fused paged step; op-by-op: pool gather + the
-        contiguous step). Every slot counts as active and the cache is left
-        as it was — this is for holding two backends, or a reference
-        forward, to the same state (``chip_smoke.py``)."""
-        if self.backend == "mega":
+        backend (the paged step against the pool; on a pp mesh, pool gather
+        + the contiguous step). Every slot counts as active and the cache
+        is left as it was — this is for holding two backends, or a
+        reference forward, to the same state (``chip_smoke.py``)."""
+        if self._decode_shard_paged is not None:
             pk, pv = self._pool_pair(paged)
             return self._step_logits_paged(
                 self.model.params, self._decode_extra, tokens, pk, pv,

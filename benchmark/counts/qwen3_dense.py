@@ -1,13 +1,14 @@
-"""What the algorithm needs: operations and bytes of a prefill and of decode
-steps, from the configuration and the token counts alone.
+"""Counts for ``"architecture": "qwen3_dense"``: operations and bytes of a
+prefill and of decode steps, and of the two attention kernels the served path
+calls, from the configuration and the token counts alone.
 
 Nothing here reads the program's routing, so the same work counts the same
 whatever implements it. Bytes are counted once: weights read once a step
 (or once a prefill), K/V read only for the lengths that are live, K/V
-written once. ``per_chip`` gives one chip's share on ``tp`` chips: its
-slice of every weight matrix and of the heads (the embedding row lookup and
-the norms are left out as negligible). A share over 100 % can then only be a
-fault of the count or of the time.
+written once. The embedding row lookup and the norms are left out as
+negligible. A share over 100 % can then only be a fault of the count or of
+the time. (One chip's share on ``tp`` chips and the least time a chip could
+take are arithmetic of any model: ``benchmark/counts/__init__.py``.)
 """
 
 from __future__ import annotations
@@ -45,8 +46,7 @@ def prefill(cfg: dict, p_len: int) -> dict:
     s = _s(cfg)
     flops = 2.0 * p_len * s["L"] * layer_weight_elems(cfg)
     flops += 2.0 * s["d"] * s["V"]
-    # QK^T and PV over the causal half: 4 * hq * hd * p^2 / 2 a layer.
-    flops += s["L"] * 2.0 * s["hq"] * s["hd"] * p_len * (p_len + 1)
+    flops += flash_attention(cfg, p_len)["flops"]
     byts = matmul_weight_elems(cfg) * s["item"]
     byts += p_len * kv_bytes_per_token(cfg)  # K/V written once
     return {"flops": flops, "bytes": float(byts)}
@@ -60,20 +60,31 @@ def decode_steps(cfg: dict, steps: int, row_lengths) -> dict:
     rows = len(row_lengths)
     live = float(sum(row_lengths))
     flops = 2.0 * rows * matmul_weight_elems(cfg)
-    flops += 4.0 * s["L"] * s["hq"] * s["hd"] * live
+    flops += paged_flash_decode(cfg, row_lengths)["flops"]
     byts = steps * matmul_weight_elems(cfg) * s["item"]
     byts += live * kv_bytes_per_token(cfg)  # K/V read for the live lengths
     byts += rows * kv_bytes_per_token(cfg)  # and one position written a row
     return {"flops": flops, "bytes": float(byts)}
 
 
-def per_chip(work: dict, tp: int) -> dict:
-    return {k: v / tp for k, v in work.items()}
+def paged_flash_decode(cfg: dict, row_lengths) -> dict:
+    """The table-walk attention kernel's calls (one a layer a step) that
+    between them compute one row for every entry of ``row_lengths``: K and V
+    of the live positions read once, the row's q read and its output
+    written, and QK^T and PV over the live positions."""
+    s = _s(cfg)
+    live = float(sum(row_lengths))
+    flops = 4.0 * s["L"] * s["hq"] * s["hd"] * live
+    byts = live * kv_bytes_per_token(cfg)
+    byts += len(row_lengths) * s["L"] * 2 * s["hq"] * s["hd"] * s["item"]
+    return {"flops": flops, "bytes": float(byts)}
 
 
-def least_seconds(work: dict, peaks: dict) -> dict:
-    """The least time one chip could take for ``work``, and which bound
-    sets it."""
-    tf = work["flops"] / peaks["bf16_flops_per_s"]
-    tb = work["bytes"] / peaks["hbm_bytes_per_s"]
-    return {"seconds": max(tf, tb), "bound": "compute" if tf >= tb else "memory"}
+def flash_attention(cfg: dict, p_len: int) -> dict:
+    """The prefill attention kernel's calls (one a layer) for one prompt of
+    ``p_len`` tokens: QK^T and PV over the causal half (4 * hq * hd * p^2 / 2
+    a layer), q, K and V read once and the output written once."""
+    s = _s(cfg)
+    flops = s["L"] * 2.0 * s["hq"] * s["hd"] * p_len * (p_len + 1)
+    byts = p_len * (kv_bytes_per_token(cfg) + s["L"] * 2 * s["hq"] * s["hd"] * s["item"])
+    return {"flops": flops, "bytes": float(byts)}

@@ -1,23 +1,36 @@
 """The benchmark's harness: one cell, one process, one window.
 
 Driven by data. ``BENCHMARK.json`` names cells and metrics; everything that
-belongs to one configuration, one traffic mix, one per-layer metric, one
-reference or one cell's limits is a file of its own, found by name under the
-manifest's ``paths``:
+belongs to one configuration, one architecture, one traffic mix, one
+per-layer metric or one cell's limits is a file of its own, found by name
+under the manifest's ``paths`` (``<architecture>`` is the configuration
+file's ``architecture`` key):
 
 * ``<config.file>``                      the configuration as it is run
 * ``<path>/traffic/<traffic>.json``      the mix's parameters (``traffic.py`` reads it)
 * ``<path>/loops/<loop>.py``             the arrivals the mix's ``loop`` key names
 * ``<path>/end_to_end/<name>.py``        one reader an end-to-end metric
+* ``<path>/build/<architecture>.py``     the system under test: ``build(cfg, key,
+  devices) -> (model, engine, server)``, the weights on the devices when it
+  returns, and ``release(model, engine, server)``, which frees them
+* ``<path>/counts/<architecture>.py``    operations and bytes: ``prefill(cfg, p_len)``,
+  ``decode_steps(cfg, steps, row_lengths)`` and one function a kernel whose
+  roofline a reader reports, each ``{"flops", "bytes"}`` (``run.counts``)
 * ``<path>/reference/<architecture>.py`` the plain reference
 * ``<path>/layer_metrics/<name>.py``     one reader a per-layer metric
 * ``<path>/limits/<cell>.json``          the limits ``correct`` holds the cell to
 
-From the program the harness takes the system under test
-(``InferenceServer`` over ``Engine`` over ``DenseLLM``, built as
-``chip_smoke.py`` builds them, at the program's defaults: no ``TDT_*``
-variable is set here) and its counters. It drives ``submit`` and ``step`` in
-one thread and times tokens on its own clock in ``on_token``.
+So a new architecture is new files and entries alone. Of the configuration
+the harness itself reads what every served model has: ``architecture``,
+``vocab_size`` (the vocabulary held here: a sliced vocabulary is a smaller
+one, and traffic, logits and sampling are over the slice), ``torch_dtype``
+and ``serving.{chips, tp, mesh_axis, slots, max_len, chunk, block_size,
+backend}``; every other key is the architecture's files' to read.
+
+From the program the harness takes the system under test (an
+``InferenceServer`` over an ``Engine`` over the architecture's model, as its
+``build`` file makes them) and its counters. It drives ``submit`` and
+``step`` in one thread and times tokens on its own clock in ``on_token``.
 
 A run: set-up (import, weights on the device from the seed, engine, one
 warm-up request of every prompt length of the mix), the measured window
@@ -56,6 +69,7 @@ ZERO_COUNTERS = (
     "tdt_serving_preemptions_total",
     "tdt_serving_restores_total",
     "tdt_resilience_watchdog_timeouts_total",
+    "tdt_ep_dropped_tokens_total",
 )
 
 STEP_MARK = "server.step"
@@ -93,6 +107,8 @@ class Cell:
     paths: list
     manifest: dict
     reference: object = None  # the architecture's plain-reference module
+    build: object = None  # the architecture's build and release
+    counts: object = None  # the architecture's counts beside the common arithmetic
     loop: object = None  # the module that makes the mix's arrivals
 
 
@@ -140,7 +156,10 @@ def load_cell(manifest_path, workload: str, root=None) -> Cell:
         if _lists(m, workload) and m["moves"] in reported:
             layer.append((m, _module(_find(root, paths, f"layer_metrics/{m['name']}.py"))))
     cell = Cell(workload, int(w["chips"]), cfg, mix, limits, e2e, layer, paths, manifest)
-    cell.reference = _module(_find(root, paths, f"reference/{cfg['architecture']}.py"))
+    arch = cfg["architecture"]
+    cell.reference = _module(_find(root, paths, f"reference/{arch}.py"))
+    cell.build = _module(_find(root, paths, f"build/{arch}.py"))
+    cell.counts = counts.Counts(_module(_find(root, paths, f"counts/{arch}.py")))
     cell.loop = _module(_find(root, paths, f"loops/{mix['loop']}.py"))
     return cell
 
@@ -183,60 +202,7 @@ def seed_key(seed: int):
     return np.array([(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for the configuration file: the named
-    preset at the file's depth, refused unless every width agrees."""
-    from triton_dist_tpu.models import PRESETS
-
-    preset = PRESETS[cfg["serving"]["preset"]]
-    mc = dataclasses.replace(preset, num_layers=int(cfg["num_hidden_layers"]))
-    same = {
-        "hidden_size": mc.hidden_size, "intermediate_size": mc.intermediate_size,
-        "num_attention_heads": mc.num_q_heads, "num_key_value_heads": mc.num_kv_heads,
-        "head_dim": mc.head_dim, "vocab_size": mc.vocab_size,
-        "rope_theta": mc.rope_theta, "rms_norm_eps": mc.rms_eps,
-        "torch_dtype": mc.dtype, "tie_word_embeddings": mc.tie_word_embeddings,
-    }
-    wrong = {k: (cfg[k], v) for k, v in same.items() if cfg[k] != v}
-    if wrong or mc.is_moe:
-        raise ValueError(f"configuration file and preset disagree: {wrong}")
-    return mc
-
-
 # ------------------------------------------------------------- the program
-
-
-def build(cell: Cell, seed: int, devices):
-    """(model, engine, server) as ``chip_smoke.py`` builds them."""
-    import jax.numpy as jnp
-
-    from triton_dist_tpu.models import DenseLLM, Engine
-    from triton_dist_tpu.runtime.mesh import initialize_distributed
-    from triton_dist_tpu.serving import InferenceServer
-
-    sv = cell.cfg["serving"]
-    ctx = initialize_distributed(
-        devices=list(devices), axis_names=(sv["mesh_axis"],), set_default=False)
-    model = DenseLLM(model_config(cell.cfg), ctx, key=jnp.asarray(seed_key(seed)))
-    engine = Engine(model, backend=sv["backend"], max_len=int(sv["max_len"]))
-    server = InferenceServer(engine, num_slots=int(sv["slots"]), chunk=int(sv["chunk"]))
-    if server.block_size != int(sv["block_size"]):
-        raise ValueError(f"server block size {server.block_size}, configuration "
-                         f"states {sv['block_size']}")
-    return model, engine, server
-
-
-def release(model, engine, server) -> None:
-    """Free what the program holds on the devices."""
-    import jax
-
-    server.shutdown(drain=False)
-    held = [model.params, server.cache, getattr(engine, "_decode_extra", None)]
-    for leaf in jax.tree.leaves(held):
-        if isinstance(leaf, jax.Array) and not leaf.is_deleted():
-            leaf.delete()
-    server.cache = None
-    model.params = None
 
 
 class Telemetry:
@@ -381,17 +347,22 @@ class Run:
     traced_s: float = 0.0
     traced_first_step: int = 0  # the loop iterations the profiler saw
     traced_last_step: int = -1
+    build_s: float = 0.0  # of set-up: the build file's ``build``
+    warm_up_s: float = 0.0  # of set-up: the warm-up requests
     longest_steps_s: list = dataclasses.field(default_factory=list)
     memory_peak_bytes: int | None = None
     trace: dict | None = None
 
-    counts = counts
     stats = stats
     trace_mod = trace_mod
 
     @property
     def cfg(self) -> dict:
         return self.cell.cfg
+
+    @property
+    def counts(self):
+        return self.cell.counts
 
     @property
     def window_s(self) -> float:
@@ -551,8 +522,6 @@ def run_cell(manifest_path, workload: str, seed: int, seconds: float, trace: boo
     limits, for the calibration and the tests: the benchmark's own runs
     never do. ``tamper`` is called with (model, engine, server) after
     warm-up, for the tests that break the timed path underneath."""
-    import jax
-
     out = out or sys.stdout
     err = err or sys.stderr
     t_start = time.perf_counter() if t_start is None else t_start
@@ -570,8 +539,7 @@ def run_cell(manifest_path, workload: str, seed: int, seconds: float, trace: boo
         peak_table = None  # a rehearsal off the chip: no share of a peak is reported
     lowerings = Lowerings()
     t_enter = time.perf_counter()
-    model, engine, server = build(cell, seed, devices)
-    jax.block_until_ready(model.params)
+    model, engine, server = cell.build.build(cell.cfg, seed_key(seed), devices)
     t_built = time.perf_counter()
     warmed = warm_up(server, cell, seed)
     t_warm = time.perf_counter()
@@ -589,7 +557,7 @@ def run_cell(manifest_path, workload: str, seed: int, seconds: float, trace: boo
         run = serve_window(loop, cell, seconds, lowerings, trace_dir,
                            len(devices), peak_table, seed)
     run.memory_peak_bytes = memory_peak(devices)
-    run.setup_s = setup_s
+    run.setup_s, run.build_s, run.warm_up_s = setup_s, t_built - t_enter, t_warm - t_built
 
     from triton_dist_tpu.runtime import telemetry
 
@@ -611,7 +579,7 @@ def run_cell(manifest_path, workload: str, seed: int, seconds: float, trace: boo
              programs=trace_mod.program_totals(run.trace))
     metrics = per_layer(run) if trace else end_to_end(run)
     sample = correct_mod.choose(in_win, int(cell.mix["check_requests"]), seed)
-    release(model, engine, server)
+    cell.build.release(model, engine, server)
     del model, engine, server, loop
     gc.collect()
 

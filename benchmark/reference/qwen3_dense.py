@@ -130,8 +130,8 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _layer(s: dict, precision: str, h, wqkv, wo, gate, up, down):
-    """One decoder block over h (B, T, d) float32, causal over T."""
+def _attention(s: dict, precision: str, h, wqkv, wo):
+    """h (B, T, d) float32 plus its attention block's output, causal over T."""
     B, T, _ = h.shape
     hq, hkv, hd, r = s["hq"], s["hkv"], s["hd"], s["qkv_shards"]
     x = _rms(h, s["eps"])  # norm weights are 1 in this recipe
@@ -151,7 +151,12 @@ def _layer(s: dict, precision: str, h, wqkv, wo, gate, up, down):
     scores = jnp.where(causal[None, None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum("bkgts,bskd->btkgd", probs, v, precision=HI)
-    h = h + _linear(o.reshape(B, T, hq * hd), wo, precision)
+    return h + _linear(o.reshape(B, T, hq * hd), wo, precision)
+
+
+def _layer(s: dict, precision: str, h, wqkv, wo, gate, up, down):
+    """One decoder block over h (B, T, d) float32."""
+    h = _attention(s, precision, h, wqkv, wo)
     x = _rms(h, s["eps"])
     m = jax.nn.silu(_linear(x, gate, precision)) * _linear(x, up, precision)
     return h + _linear(m, down, precision)
@@ -163,6 +168,10 @@ def _head(s: dict, precision: str, h, rows, head):
     return _linear(_rms(x, s["eps"]), head, precision)
 
 
+#: One layer's weights, in the order ``_layer`` takes them.
+LAYER_WEIGHTS = ("wqkv", "wo", "gate", "up", "down")
+
+
 def logits_at(cfg: dict, weights: dict, tokens, rows, precision: str = "stated",
               block: int | None = None):
     """Float32 logits (S, R, V) of sequences ``tokens`` (S, T) int32 at
@@ -170,14 +179,21 @@ def logits_at(cfg: dict, weights: dict, tokens, rows, precision: str = "stated",
     at a time so that nothing larger than one layer's float32 copy and one
     block's attention scores is ever live. Padding past a sequence's end
     sits in the causal future of every row asked for."""
-    s = sizes(cfg)
+    return by_layer_and_block(sizes(cfg), _layer, LAYER_WEIGHTS, weights, tokens, rows,
+                              precision, block)
+
+
+def by_layer_and_block(s: dict, layer_fn, layer_weights, weights: dict, tokens, rows,
+                       precision: str, block: int | None):
+    """:func:`logits_at` for any decoder whose block is ``layer_fn(s,
+    precision, h, *one layer's weights)`` between this embedding and head."""
     tokens = np.asarray(tokens, np.int32)
     rows = np.asarray(rows, np.int32)
     S, T = tokens.shape
     if block is None:
         block = max(1, int(1.0e9 // (s["hq"] * T * T * 4)))
     block = min(block, S)
-    layer = jax.jit(partial(_layer, s, precision))
+    layer = jax.jit(partial(layer_fn, s, precision))
     head = jax.jit(partial(_head, s, precision))
     embed = jax.jit(lambda e, t: e[t].astype(jnp.float32))
     pad = (-S) % block
@@ -187,7 +203,7 @@ def logits_at(cfg: dict, weights: dict, tokens, rows, precision: str = "stated",
     hs = [embed(weights["embed"], jnp.asarray(tokens[i:i + block]))
           for i in range(0, S + pad, block)]
     for l in range(s["L"]):
-        lw = [weights[n][l] for n in ("wqkv", "wo", "gate", "up", "down")]
+        lw = [weights[n][l] for n in layer_weights]
         hs = [layer(h, *lw) for h in hs]
     out = [head(h, jnp.asarray(rows[i * block:(i + 1) * block]), weights["head"])
            for i, h in enumerate(hs)]

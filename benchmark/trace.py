@@ -15,6 +15,7 @@ events.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import glob
 import os
@@ -28,6 +29,8 @@ OP_LINES = ("XLA Ops", "XLA Modules")
 MODULE_LINE = "XLA Modules"
 
 _FINGERPRINT = re.compile(r"\(\d+\)$")
+
+_OP_SUFFIX = re.compile(r"\.\d+$")
 
 _LAYOUT = re.compile(r"\{[^{}]*\}")
 
@@ -92,6 +95,15 @@ def program(name: str) -> str:
     return _FINGERPRINT.sub("", name)
 
 
+def op_name(text: str) -> str:
+    """An operation's name as the program gave it (a kernel's ``name``, a
+    fusion's kind), from its event's HLO text, without the ``%`` and the
+    number that tells its instances apart:
+    ``%paged_flash_decode.9 = (bf16[4,8,4,128], ...) custom-call(...)`` ->
+    ``paged_flash_decode``."""
+    return _OP_SUFFIX.sub("", text.split(" = ", 1)[0].lstrip("%"))
+
+
 def marks(planes: dict, marker: str) -> list:
     """[start, end] of every ``marker`` annotation on the host, in order."""
     for pname, lines in planes.items():
@@ -113,6 +125,15 @@ def program_seconds(trace: dict, pattern) -> tuple[float, int]:
     the host's, which differ by a millisecond in a recorded trace."""
     hit = [dur for name, _, dur in trace["programs"] if pattern.fullmatch(name)]
     return sum(hit), len(hit)
+
+
+def op_seconds(trace: dict, pattern) -> tuple[float, int]:
+    """(own device seconds, executions) of the operations in the trace whose
+    name (:func:`op_name`) matches ``pattern`` (a compiled expression) in
+    full: every one of them, not the ten that the result line prints. A
+    kernel's roofline reads its seconds here, by the kernel's name."""
+    hit = [v for text, v in trace["ops"].items() if pattern.fullmatch(op_name(text))]
+    return sum(s for s, _ in hit), sum(n for _, n in hit)
 
 
 def program_totals(trace: dict) -> dict:
@@ -216,10 +237,11 @@ def reduce(planes: dict, marker: str = "server.step", top: int = 10,
 
     ``busy_s``: seconds in which an operation ran, averaged over the device
     planes that ran any; ``window_s``: the traced span; ``idle_pct``: of
-    the busiest device; ``device_ops``: its ``top`` operations by own time;
-    ``idle_gaps``: its idle time by what the host was doing, ``top``
-    labels by seconds; ``programs``: (name, start, seconds) of every program
-    it executed and ``steps``: [start, end] of every ``marker`` annotation,
+    the busiest device; ``ops``: ``{HLO text: [own seconds, executions]}``
+    of every operation it ran (:func:`op_seconds`) and ``device_ops``: the
+    ``top`` of them by own time, for the result line; ``idle_gaps``: its idle
+    time by what the host was doing, ``top`` labels by seconds;
+    ``programs``: (name, start, seconds) of every program it executed and ``steps``: [start, end] of every ``marker`` annotation,
     both in seconds since the trace began (:func:`program_seconds`).
     """
     t0, t1 = span(planes)
@@ -239,6 +261,7 @@ def reduce(planes: dict, marker: str = "server.step", top: int = 10,
     evs = per_device[busiest]
     own = self_times(evs)
     ops = sorted(own.items(), key=lambda kv: -kv[1])
+    ran = collections.Counter(e.name for e in evs)
     gaps: dict = {}
     edges = [[t0, t0]] + union(evs) + [[t1, t1]]
     for (_, a), (b, _) in zip(edges, edges[1:]):
@@ -250,6 +273,7 @@ def reduce(planes: dict, marker: str = "server.step", top: int = 10,
         "window_s": window / 1e9,
         "idle_pct": 100.0 * (1.0 - busy[busiest] / window),
         "devices": len(per_device),
+        "ops": {k: [v / 1e9, ran[k]] for k, v in ops},
         "device_ops": [[short(k), v / 1e9] for k, v in ops[:top]],
         "idle_gaps": [[k, v / 1e9] for k, v in
                       sorted(gaps.items(), key=lambda kv: -kv[1])[:top]],

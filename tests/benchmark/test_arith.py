@@ -13,8 +13,11 @@ import pytest
 REPO = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
-from benchmark import counts, peaks, stats, traffic  # noqa: E402
+from benchmark import harness, peaks, stats, traffic  # noqa: E402
 from benchmark import trace as tr  # noqa: E402
+from benchmark.counts import Counts, qwen3_dense  # noqa: E402
+
+counts = Counts(qwen3_dense)  # what a reader sees as ``run.counts``
 
 CFG = json.loads((REPO / "benchmark/configs/qwen3-8b-d24.json").read_text())
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
@@ -227,6 +230,47 @@ def test_weights_are_read_once_a_step_whatever_the_batch():
     assert four["flops"] == 4 * one["flops"]
 
 
+def test_the_attention_kernels_by_hand():
+    # one decode step of four rows: K/V of 2614 live positions, 4 rows' q and output
+    w = counts.paged_flash_decode(CFG, [513, 100, 1, 2000])
+    assert w["flops"] == 4 * 24 * 32 * 128 * 2614
+    assert w["bytes"] == 2614 * 98_304 + 4 * 24 * 2 * 32 * 128 * 2
+    assert w["bytes"] < counts.decode_steps(CFG, 1, [513, 100, 1, 2000])["bytes"]
+    assert counts.least_seconds(w, V5E)["bound"] == "memory"
+    # a 1024-token prompt: the causal half, q and output 32 heads, K and V 8 heads
+    w = counts.flash_attention(CFG, 1024)
+    assert w["flops"] == 24 * 2 * 32 * 128 * 1024 * 1025
+    assert w["bytes"] == 1024 * (98_304 + 24 * 2 * 32 * 128 * 2)
+    assert w["flops"] < counts.prefill(CFG, 1024)["flops"]
+    assert counts.least_seconds(w, V5E)["bound"] == "compute"
+
+
+def test_the_common_arithmetic_is_no_architectures_to_replace():
+    class Arch:
+        per_chip = least_seconds = prefill = staticmethod(lambda *a: "the architecture's")
+
+    both = Counts(Arch)
+    assert both.prefill({}, 1) == "the architecture's"
+    assert both.per_chip({"flops": 8.0}, 4) == {"flops": 2.0}
+    assert both.least_seconds({"flops": 197e12, "bytes": 0.0}, V5E)["seconds"] == 1.0
+    with pytest.raises(AttributeError):
+        both.decode_steps
+
+
+def test_the_toys_second_architecture_counts_its_own_block():
+    toy = REPO / "tests/benchmark/toy/BENCHMARK.json"
+    moe = harness.load_cell(toy, "toy-moe.toy-short", root=REPO)
+    dense = harness.load_cell(toy, "toy-dense.toy-chat", root=REPO)
+    # attention 64x512 + 256x64, router 64x8, two experts of 3 x 64x48; head 64x256
+    per_token = 2 * (32_768 + 16_384 + 512 + 2 * 9_216) + 16_384
+    assert moe.counts.token_weight_elems(moe.cfg) == per_token == 152_576
+    w = moe.counts.decode_steps(moe.cfg, 1, [10, 20])
+    assert w["flops"] == 2 * 2 * per_token + 4 * 2 * 8 * 32 * 30
+    assert w["bytes"] == 4 * per_token + 32 * 2 * 2 * 4 * 32 * 4
+    assert w != dense.counts.decode_steps(dense.cfg, 1, [10, 20])
+    assert moe.counts.per_chip(w, 2)["flops"] == w["flops"] / 2
+
+
 def test_an_unknown_device_kind_is_an_error():
     assert peaks.peaks_for("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     assert peaks.peaks_for("TPU v5 lite")["hbm_bytes"] == 16e9
@@ -295,6 +339,28 @@ def test_program_seconds_by_the_programs_whole_name():
     assert tr.program_seconds(dict(r, programs=[]), re.compile(".*")) == (0.0, 0)
 
 
+def test_op_seconds_by_the_operations_name_whatever_its_rank():
+    import re
+
+    planes = _planes()
+    dev = planes["/device:TPU:0"]["XLA Ops"]
+    kernel = "%paged_flash_decode.9 = (bf16[4,8,4,128]{3,2,1,0}, f32[4,8,4,1]{3,2,1,0}) custom-call(%p)"
+    dev += [tr.Ev(kernel, 1650, 2), tr.Ev(kernel, 1660, 3),
+            tr.Ev("%paged_flash_decode_quant.2 = bf16[4] custom-call(%p)", 1670, 1)]
+    dev += [tr.Ev(f"%fusion.{100 + i} = bf16[4] fusion(%p)", 1700 + 10 * i, 8) for i in range(12)]
+    r = tr.reduce(planes)
+    assert tr.op_name(kernel) == "paged_flash_decode" and tr.op_name("fusion.1") == "fusion"
+    assert tr.op_name("%copy-start.1 = (bf16[2]) copy-start(%x)") == "copy-start"
+    # the result line keeps ten; the readers get all, with their executions
+    assert len(r["device_ops"]) == 10 and not any("paged" in n for n, _ in r["device_ops"])
+    assert len(r["ops"]) == 4 + 2 + 12 and r["ops"]["fusion.1"] == [pytest.approx(300e-9), 2]
+    assert sum(s for s, _ in r["ops"].values()) == pytest.approx(r["busy_s"] * 2 - 200e-9)
+    assert tr.op_seconds(r, re.compile("paged_flash_decode")) == (pytest.approx(5e-9), 2)
+    assert tr.op_seconds(r, re.compile(r"paged_flash_decode\w*")) == (pytest.approx(6e-9), 3)
+    assert tr.op_seconds(r, re.compile("flash_decode")) == (0.0, 0)  # the whole name
+    assert tr.op_seconds(r, re.compile("fusion")) == (pytest.approx((600 + 96) * 1e-9), 15)
+
+
 def test_a_trace_with_no_device_operation_is_refused():
     planes = _planes()
     del planes["/device:TPU:0"], planes["/device:TPU:1"]
@@ -317,6 +383,11 @@ def test_recorded_v5e_trace():
     # a program's event spans its operations and the gaps between them
     assert n == 3 and r["busy_s"] <= spent <= 1.02 * r["busy_s"]
     assert r["device_ops"] and all(s > 0 for _, s in r["device_ops"])
+    # every operation of the trace by its name: nine fusions in three runs of the loop
+    assert tr.op_seconds(r, re.compile("convolution_tanh_fusion"))[1] == 9
+    assert tr.op_seconds(r, re.compile("copy-start"))[1] == 6
+    own, ran = tr.op_seconds(r, re.compile(".*"))
+    assert ran == 36 and own == pytest.approx(r["busy_s"], rel=1e-3)
     assert sum(s for _, s in r["device_ops"]) <= r["busy_s"] * 1.0001
     assert any("server.step" in k or "outside" in k for k, _ in r["idle_gaps"])
 

@@ -80,8 +80,15 @@ def test_toy_cell_per_layer_metrics_from_a_trace():
     # peak or of a roofline is reported off the chip (a reader that finds
     # nothing returns nothing), and the four-chip metrics are not this cell's
     assert {"queue_wait_mean_ms", "ttft_p90_ms", "tpot_p90_ms", "cache_scatter_ms_per_chunk",
-            "device_idle_pct", "requests_finished"} <= got
-    assert not got & {"decode_roofline", "prefill_roofline", "serve_mfu", "hbm_peak_pct"}
+            "device_idle_pct", "requests_finished", "setup_build_s", "setup_warm_up_s"} <= got
+    assert not got & {"decode_roofline", "prefill_roofline", "serve_mfu", "hbm_peak_pct",
+                      "paged_flash_decode_roofline", "flash_attention_roofline"}
+    # set-up's two parts are the set-up line's own, and lie inside ``setup_s``
+    build_s, warm_s = (result["metrics"][n]["value"] for n in ("setup_build_s", "setup_warm_up_s"))
+    assert (build_s, warm_s) == (phases["setup"]["build_s"], phases["setup"]["warm_up_s"])
+    assert 0 < build_s and 0 < warm_s and build_s + warm_s < phases["setup"]["setup_s"]
+    # the readers get every operation of the trace; the result line its ten largest
+    assert len(result["breakdown"]["device_ops"]) == 10
     # every loop iteration the profiler saw carries its mark in the trace
     assert phases["trace"]["steps_marked"] == phases["trace"]["steps_traced"] >= 1
     assert result["correct"] is True
@@ -112,6 +119,20 @@ def test_arrivals_added_by_a_file_drive_the_same_loop():
                                       "setup_s"}
 
 
+@pytest.mark.timeout(600)
+def test_a_second_architecture_runs_from_the_toys_files_alone():
+    """``toy-moe``: the program's expert model class, built, counted and
+    compared by ``toy/{build,counts,reference}/qwen3_moe.py``, which the
+    harness finds by the configuration's ``architecture``."""
+    result, lines, err = _run("toy-moe.toy-short", trace=False)
+    m = json.loads(TOY.read_text())
+    phases = _shape(result, lines, err, {e["name"] for e in m["end_to_end"]})
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] >= 4 and phases["check"]["first_choice_share"] == 1.0
+    assert phases["window"]["backend"] == "dist_ar"
+    assert phases["setup"]["warm_up_requests"] == 3
+
+
 def _alter_a_token(model, engine, server):
     """The decode chunk program's output, one token of one slot changed in
     every chunk."""
@@ -133,10 +154,12 @@ def _leave_the_pool_unchanged(model, engine, server):
 
 
 @pytest.mark.timeout(600)
-@pytest.mark.parametrize("tamper", [_alter_a_token, _leave_the_pool_unchanged])
-def test_the_timed_path_broken_underneath_is_not_correct(tamper):
+@pytest.mark.parametrize("cell,tamper", [
+    ("toy-dense.toy-chat", _alter_a_token), ("toy-dense.toy-chat", _leave_the_pool_unchanged),
+    ("toy-moe.toy-short", _alter_a_token)], ids=["dense-token", "dense-pool", "moe-token"])
+def test_the_timed_path_broken_underneath_is_not_correct(cell, tamper):
     """After warm-up and under the window; the rest of the run as it is."""
-    result, lines, err = _run("toy-dense.toy-chat", trace=False, tamper=tamper)
+    result, lines, err = _run(cell, trace=False, tamper=tamper)
     assert result["correct"] is False
     gap, limit = result["compared"]["logit_gap"]
     assert gap > 100 * limit

@@ -96,6 +96,9 @@ def test_cell_resolves_and_metrics_agree(path, cell):
     assert c.per_layer, "every cell reports at least one per-layer metric"
     assert c.cfg["serving"]["chips"] == c.chips
     assert hasattr(c.reference, "logits_at") and hasattr(c.reference, "make_weights")
+    assert callable(c.build.build) and callable(c.build.release)
+    for fn in ("prefill", "decode_steps", "per_chip", "least_seconds"):
+        assert callable(getattr(c.counts, fn))
     held = [v["limit"] for v in c.limits.values() if isinstance(v, dict) and "limit" in v]
     assert held and all(lim > 0 for lim in held)
     for entry, mod in c.per_layer:
@@ -120,8 +123,9 @@ def test_layers_are_spelt_alike():
 
 def test_toy_adds_by_files_and_entries_alone():
     """The toy manifest reuses the harness, the generator and the readers
-    unchanged, and adds configurations, mixes, a per-layer metric and a
-    kind of arrivals as files under its own path."""
+    unchanged, and adds configurations, mixes, a per-layer metric, a kind of
+    arrivals and an architecture (its build, counts and reference) as files
+    under its own path."""
     toy = _load(MANIFESTS[1])
     assert toy["paths"][0] == "benchmark"
     own = REPO / toy["paths"][1]
@@ -133,12 +137,25 @@ def test_toy_adds_by_files_and_entries_alone():
     assert not (REPO / "benchmark/loops/open.py").exists()
     cell = harness.load_cell(MANIFESTS[1], "toy-dense.toy-open", root=REPO)
     assert cell.loop.__file__ == str(own / "loops/open.py")
+    # the second architecture: nothing of it exists under ``benchmark/``
+    moe = harness.load_cell(MANIFESTS[1], "toy-moe.toy-short", root=REPO)
+    arch = moe.cfg["architecture"]
+    assert arch != cell.cfg["architecture"]
+    assert not list((REPO / "benchmark").rglob(f"*{arch}*"))
+    for kind, mod in (("build", moe.build), ("reference", moe.reference),
+                      ("counts", moe.counts.architecture)):
+        assert mod.__file__ == str(own / f"{kind}/{arch}.py")
+    # and the first one's are the benchmark's own
+    for kind, mod in (("build", cell.build), ("reference", cell.reference),
+                      ("counts", cell.counts.architecture)):
+        assert mod.__file__ == str(REPO / f"benchmark/{kind}/qwen3_dense.py")
 
 
 def test_every_file_of_the_benchmark_is_one_a_cell_uses():
     """Nothing under ``benchmark/`` waits for a cell that is not there: each
-    reader, mix, configuration, limits file, reference and loop is named by
-    ``BENCHMARK.json`` or by a file it names."""
+    reader, mix, configuration, limits file, loop and architecture's file
+    (reference, build, counts) is named by ``BENCHMARK.json`` or by a file it
+    names."""
     m = _load(REPO / "BENCHMARK.json")
     cells = [harness.load_cell(REPO / "BENCHMARK.json", w["name"]) for w in m["workloads"]]
     used = {
@@ -149,8 +166,19 @@ def test_every_file_of_the_benchmark_is_one_a_cell_uses():
         "configs": {c["name"] for c in m["configs"]},
         "loops": {c.mix["loop"] for c in cells},
         "reference": {c.cfg["architecture"] for c in cells},
+        "build": {c.cfg["architecture"] for c in cells},
+        "counts": {c.cfg["architecture"] for c in cells},
     }
     for folder, names in used.items():
         found = {f.name.rsplit(".", 1)[0] for f in (REPO / "benchmark" / folder).iterdir()
                  if f.is_file()}
         assert found - {"__init__"} == names - {"__init__"}, folder
+
+
+def test_the_harness_knows_no_model_class_and_no_key_of_one_block():
+    """What knows the dense block lives in the architecture's three files."""
+    words = re.compile(r"DenseLLM|PRESETS|is_moe|num_key_value_heads|intermediate_size")
+    for name in ("harness", "correct", "stats", "traffic", "trace"):
+        assert not words.search((REPO / f"benchmark/{name}.py").read_text()), name
+    for kind in ("build", "counts", "reference"):
+        assert words.search((REPO / f"benchmark/{kind}/qwen3_dense.py").read_text()), kind

@@ -18,6 +18,7 @@ REPO = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
 from benchmark import correct, harness, stats  # noqa: E402
+from benchmark.build import qwen3_dense as builder  # noqa: E402
 from benchmark.reference import qwen3_dense as ref  # noqa: E402
 
 CFG = json.loads((REPO / "tests/benchmark/toy/configs/toy-dense.json").read_text())
@@ -34,8 +35,16 @@ def program():
 
     ctx = initialize_distributed(
         devices=jax.devices()[:1], axis_names=("tp",), set_default=False)
-    model = DenseLLM(harness.model_config(CFG), ctx, key=jnp.asarray(harness.seed_key(SEED)))
+    model = DenseLLM(builder.model_config(CFG), ctx, key=jnp.asarray(harness.seed_key(SEED)))
     return model, Engine(model, backend="dist", max_len=64)
+
+
+def test_the_dense_builder_refuses_an_expert_preset_and_a_width_that_differs():
+    assert builder.model_config(CFG).num_layers == CFG["num_hidden_layers"]
+    with pytest.raises(ValueError):
+        builder.model_config(dict(CFG, serving=dict(CFG["serving"], preset="test-moe")))
+    with pytest.raises(ValueError):
+        builder.model_config(dict(CFG, head_dim=64))
 
 
 @pytest.fixture(scope="module")
@@ -103,6 +112,40 @@ def test_prefill_then_paged_decode_agree_with_the_reference(program, weights):
 
 
 TOY = REPO / "tests/benchmark/toy/BENCHMARK.json"
+
+
+@pytest.mark.timeout(600)
+def test_the_toys_second_architecture_agrees_with_its_reference():
+    """``toy/build/qwen3_moe.py`` (the program's expert model class) against
+    ``toy/reference/qwen3_moe.py``, both found by the configuration's
+    ``architecture``: the weights to the bit, then prefill and paged decode."""
+    cell = harness.load_cell(TOY, "toy-moe.toy-short", root=REPO)
+    key = harness.seed_key(SEED)
+    model, engine, server = cell.build.build(cell.cfg, key, jax.devices()[:1])
+    try:
+        assert type(model).__name__ == "EPMoELLM"
+        w = cell.reference.make_weights(cell.cfg, key, jax.devices()[:1])
+        p = model.params
+        for mine, theirs in (("embed", p.embed), ("wqkv", p.wqkv), ("router", p.router),
+                             ("gate", p.mlp_gate), ("up", p.mlp_up), ("down", p.mlp_down),
+                             ("head", p.lm_head)):
+            np.testing.assert_array_equal(np.asarray(w[mine]), np.asarray(theirs))
+        # 8 tokens: a longer prompt whose rows agree on an expert overflows its slots
+        ids = np.random.default_rng(11).integers(0, 256, size=8).tolist()
+        steps = 3
+        logits_p, logits_d, toks = _serve_paged(engine, ids, steps)
+        seq = np.asarray([ids + toks[:-1]], np.int32)
+        rows = np.asarray([[len(ids) - 1 + j for j in range(steps + 1)]], np.int32)
+        want = np.asarray(cell.reference.logits_at(cell.cfg, w, seq, rows))[0]
+        assert np.abs(want[0] - logits_p).max() < 2e-4
+        for j in range(steps):
+            assert np.abs(want[j + 1] - logits_d[j]).max() < 2e-4
+        # the dense reference over the same tokens is another model's
+        dense_w = ref.make_weights(CFG, key, jax.devices()[:1])
+        assert np.abs(np.asarray(ref.logits_at(CFG, dense_w, seq, rows))[0] - want).max() > 0.1
+    finally:
+        cell.build.release(model, engine, server)
+    assert model.params is None and server.cache is None
 
 
 def _decision(numbers, served_requests):

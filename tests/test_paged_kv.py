@@ -139,6 +139,51 @@ def test_ledger_eviction_makes_room():
     assert telemetry.counter_value("tdt_kv_evictions_total") >= 1.0
 
 
+def test_eviction_walks_once_a_chain_and_keeps_the_lru_order():
+    """Evicting a long prompt's chain takes its blocks in one walk of the
+    trie (leaf, then each ancestor the removal leaves bare under the same
+    stamp), and drops exactly the entries that one walk a block dropped."""
+    import random
+
+    from triton_dist_tpu.serving.scheduler import PrefixIndex
+
+    def build():
+        alloc = BlockAllocator(400)
+        index = PrefixIndex(alloc, 4)
+        rng = random.Random(1)
+        base = [rng.randrange(50) for _ in range(40)]
+        for i in range(12):  # chains that share prefixes of ``base``
+            prompt = base[: rng.randrange(0, 40, 4)] + [
+                rng.randrange(50) for _ in range(rng.randrange(8, 60))]
+            blocks = alloc.alloc(len(prompt) // 4)
+            index.register(prompt, blocks)
+            alloc.free(blocks)
+            if i % 3 == 0:
+                index.lookup(base[: rng.randrange(4, 40)])  # restamp a prefix
+        return alloc, index
+
+    def indexed(index):
+        out, stack = [], list(index._roots.values())
+        while stack:
+            node = stack.pop()
+            out.append(node.block)
+            stack += node.children.values()
+        return sorted(out)
+
+    a1, one_a_block = build()
+    a2, one_a_chain = build()
+    need = a1.num_free + 57
+    walks = 0
+    while a1.num_free < need and one_a_block._drop_leaf(None, "pressure"):
+        walks += 1
+    calls = []
+    real = one_a_chain._lru_leaf
+    one_a_chain._lru_leaf = lambda *a, **k: calls.append(1) or real(*a, **k)
+    assert one_a_chain.evict(need) == walks == 57
+    assert indexed(one_a_block) == indexed(one_a_chain) and a1.num_free == a2.num_free
+    assert len(calls) < walks / 2
+
+
 def test_scheduler_kv_budget_hard_and_kv_wait():
     led = KVLedger(5, 4)                     # 4 usable blocks = 16 rows
     sched = Scheduler(num_slots=2, max_len=MAX_LEN, kv_ledger=led)

@@ -5,9 +5,10 @@ loading HF checkpoints into the TP layout).
 """
 
 from triton_dist_tpu.models.config import ModelConfig, PRESETS
-from triton_dist_tpu.models.kv_cache import KVCache, PagedKVCache
+from triton_dist_tpu.models.kv_cache import CacheRow, KVCache, PagedKVCache, kv_rows
 from triton_dist_tpu.models.dense import DenseLLM, Qwen3MoE, DenseParams, init_params
 from triton_dist_tpu.models.moe import EPMoELLM, ep_specs
+from triton_dist_tpu.models.latent_sparse import LatentSparseConfig, LatentSparseLLM
 from triton_dist_tpu.models.engine import Engine
 from triton_dist_tpu.models.drafter import (
     Drafter,
@@ -21,11 +22,15 @@ from triton_dist_tpu.models import checkpoint
 __all__ = [
     "ModelConfig",
     "PRESETS",
+    "CacheRow",
     "KVCache",
     "PagedKVCache",
+    "kv_rows",
     "DenseLLM",
     "Qwen3MoE",
     "EPMoELLM",
+    "LatentSparseConfig",
+    "LatentSparseLLM",
     "ep_specs",
     "DenseParams",
     "init_params",

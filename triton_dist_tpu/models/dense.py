@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.models.config import ModelConfig
+from triton_dist_tpu.models.kv_cache import kv_rows
 from triton_dist_tpu.layers.tp import MOE_CAPACITY_FACTOR, TP_Attn, TP_MLP, TP_MoE, RMSNorm, _pytree_dataclass, static_field
 from triton_dist_tpu.runtime.mesh import DistContext
 
@@ -156,6 +157,22 @@ class DenseLLM:
             params = init_params(config, key if key is not None else jax.random.PRNGKey(0), ctx)
         self.params = params
 
+    def cache_rows(self):
+        """The two kinds of cache row a token keeps (the engine sizes the
+        prompt buffers and the paged pools from this): K rows and V rows,
+        alike, on every layer."""
+        c = self.config
+        return kv_rows(c.num_layers, c.num_kv_heads, c.head_dim)
+
+    def step_stats(self):
+        """Zeros of what the step programs return beside their result and
+        sum over a chunk on the device; this model counts nothing there."""
+        return ()
+
+    def publish_step_stats(self, stats) -> None:
+        """Host side, after a step program's fence: feed the counters from
+        its ``stats``. Nothing to feed here."""
+
     # ------------------------------------------------------------ shard-local
     def _attn(self, lp, mode_decode=False) -> TP_Attn:
         c = self.config
@@ -248,7 +265,7 @@ class DenseLLM:
         int32 absolute start of this chunk; ``last_idx`` traced int32 row
         (within the chunk) whose logits the caller wants — the prompt's
         final token on the last chunk, ignored elsewhere. Returns (logits
-        (B, V_local), updated (kbufs, vbufs)). Replicated modes only —
+        (B, V_local), updated (kbufs, vbufs), ``step_stats``). Replicated modes only —
         chunks are small, so this rides the decode-regime collectives; the
         per-row math (RoPE at absolute positions, causal attention over the
         buffer, rowwise norms/MLP) matches ``prefill_shard`` row for row,
@@ -290,7 +307,7 @@ class DenseLLM:
             (bsz, 1, x.shape[-1]),
         )[:, 0]
         logits = jnp.dot(x_last, p.lm_head, preferred_element_type=jnp.float32)
-        return logits, (kbufs, vbufs)
+        return logits, (kbufs, vbufs), self.step_stats()
 
     def split_layer_params(self) -> list[dict]:
         """Materialize per-layer parameter dicts from the stacked pytree —
@@ -341,7 +358,8 @@ class DenseLLM:
         ``tables`` (B, max_blocks) and ``active`` (B,) are DATA operands,
         so one compiled program serves every batch composition with no
         whole-pool gather/scatter per chunk. Inactive slots write to the
-        NULL block (0) and their logits are masked by the caller."""
+        NULL block (0) and their logits are masked by the caller. Returns
+        what ``decode_shard_paged`` does."""
         c = self.config
         step_fn = self._mega_builder(paged=True).build_step_fn(c.num_layers)
         x = p.embed[token]
@@ -350,7 +368,7 @@ class DenseLLM:
         from triton_dist_tpu.megakernel.kernels import fused_norm_head
 
         logits = fused_norm_head(x, p.final_norm, p.lm_head, eps=c.rms_eps)
-        return logits, pk, pv
+        return logits, pk, pv, self.step_stats()
 
     def decode_shard(self, p: DenseParams, token: jax.Array, ks, vs, lengths, mode: str):
         """Inside shard_map. token (B,) → (logits (B, V_local), updated caches).
@@ -396,7 +414,8 @@ class DenseLLM:
         lies. The layer's index reaches the row write and the kernel as
         data. ``tables`` (B, max_blocks) and ``active`` (B,) are data too:
         an inactive slot writes to the NULL block and its logits are the
-        caller's to mask. Returns (logits (B, V_local), pk, pv)."""
+        caller's to mask. Returns (logits (B, V_local), pk, pv,
+        ``step_stats``)."""
         c = self.config
         x = p.embed[token]
         eps = c.rms_eps
@@ -419,7 +438,7 @@ class DenseLLM:
         )
         x = RMSNorm(weight=p.final_norm, eps=eps)(x)
         logits = jnp.dot(x, p.lm_head, preferred_element_type=jnp.float32)
-        return logits, pk, pv
+        return logits, pk, pv, self.step_stats()
 
     # -- speculative k-wide verify -----------------------------------------
 

@@ -173,6 +173,11 @@ class Engine:
         self._kv_sharding = ctx.sharding(*kv_spec)
         pool_spec = P(None, None, "tp")  # (L, blocks, Hkv over tp, bs, D)
         self._pool_sharding = ctx.sharding(*pool_spec)
+        # What a model's step programs return beside their result (per-expert
+        # row counts, say): small replicated arrays that leave the device
+        # with the chunk's tokens, summed over the chunk's steps on the
+        # device. ``model.step_stats()`` gives their zeros.
+        stats_spec = jax.tree.map(lambda _: P(), jax.eval_shape(model.step_stats))
 
         def prefill_fn(params, tokens):
             logits, (ks, vs) = model.prefill_shard(params, tokens, prefill_mode)
@@ -228,16 +233,16 @@ class Engine:
             # in place — no whole-pool gather/scatter per chunk (the
             # contiguous-bounce path below pays ~2 pool copies per chunk).
             def decode_paged_fn(params, mega, token, pk, pv, tables, lengths, active):
-                logits, pk, pv = model.decode_shard_mega_paged(
+                logits, pk, pv, stats = model.decode_shard_mega_paged(
                     params, mega, token, pk, pv, tables, lengths, active
                 )
-                return jax.lax.all_gather(logits, axis, axis=1, tiled=True), pk, pv
+                return jax.lax.all_gather(logits, axis, axis=1, tiled=True), pk, pv, stats
 
             self._decode_shard_paged = jax.shard_map(
                 decode_paged_fn, mesh=mesh,
                 in_specs=(p_specs, mega_specs, tok_spec, pool_spec, pool_spec,
                           P(dp), len_spec, len_spec),
-                out_specs=(tok_spec, pool_spec, pool_spec),
+                out_specs=(tok_spec, pool_spec, pool_spec, stats_spec),
                 check_vma=False,
             )
 
@@ -292,16 +297,16 @@ class Engine:
             # pair is carried through the layers, one K/V row written a
             # layer, K/V read through the table inside the kernel.
             def decode_paged_fn(params, token, pk, pv, tables, lengths, active):
-                logits, pk, pv = model.decode_shard_paged(
+                logits, pk, pv, stats = model.decode_shard_paged(
                     params, token, pk, pv, tables, lengths, active, decode_mode
                 )
-                return jax.lax.all_gather(logits, axis, axis=1, tiled=True), pk, pv
+                return jax.lax.all_gather(logits, axis, axis=1, tiled=True), pk, pv, stats
 
             psm = jax.shard_map(
                 decode_paged_fn, mesh=mesh,
                 in_specs=(p_specs, tok_spec, pool_spec, pool_spec, P(dp),
                           len_spec, len_spec),
-                out_specs=(tok_spec, pool_spec, pool_spec),
+                out_specs=(tok_spec, pool_spec, pool_spec, stats_spec),
                 check_vma=False,
             )
             self._decode_shard_paged = (
@@ -463,11 +468,12 @@ class Engine:
             out0 = jnp.full((bsz, chunk), -1, jnp.int32)
 
             def body(i, carry):
-                out, token, pk, pv, lengths, remaining, key = carry
+                out, token, pk, pv, lengths, remaining, key, stats = carry
                 active = remaining > 0
-                logits, pk, pv = self._decode_shard_paged(
+                logits, pk, pv, step = self._decode_shard_paged(
                     params, extra, token, pk, pv, tables, lengths, active
                 )
+                stats = jax.tree.map(jnp.add, stats, step)
                 key, sub = jax.random.split(key)
                 nxt = sample_token(
                     logits, sub, self.sample_method, self.temperature, self.top_p
@@ -478,14 +484,14 @@ class Engine:
                 # blocks may already belong to another tenant.
                 nxt = jnp.where(active, nxt, token)
                 out = out.at[:, i].set(jnp.where(active, nxt, jnp.int32(-1)))
-                step = active.astype(lengths.dtype)
-                return (out, nxt, pk, pv, lengths + step, remaining - step, key)
+                adv = active.astype(lengths.dtype)
+                return (out, nxt, pk, pv, lengths + adv, remaining - adv, key, stats)
 
-            carry = (out0, token, pk, pv, lengths, remaining, key)
-            out, token, pk, pv, lengths, remaining, _ = jax.lax.fori_loop(
+            carry = (out0, token, pk, pv, lengths, remaining, key, model.step_stats())
+            out, token, pk, pv, lengths, remaining, _, stats = jax.lax.fori_loop(
                 0, chunk, body, carry
             )
-            return out, token, pk, pv, lengths, remaining
+            return out, token, pk, pv, lengths, remaining, stats
 
         self._decode_chunk_paged = decode_chunk_paged
 
@@ -515,10 +521,10 @@ class Engine:
         chunk_mode = CHUNK_MODE[backend]
 
         def chunk_fn(params, toks, kb, vb, off, last_idx):
-            logits, (kb, vb) = model.prefill_chunk_shard(
+            logits, (kb, vb), stats = model.prefill_chunk_shard(
                 params, toks, kb, vb, off, last_idx, chunk_mode
             )
-            return jax.lax.all_gather(logits, axis, axis=1, tiled=True), kb, vb
+            return jax.lax.all_gather(logits, axis, axis=1, tiled=True), kb, vb, stats
 
         # One jitted object; jit's shape cache keys each (chunk_len, P)
         # combination. kbuf/vbuf are donated — the running context buffer
@@ -527,25 +533,28 @@ class Engine:
             jax.shard_map(
                 chunk_fn, mesh=mesh,
                 in_specs=(p_specs, tok_spec, kv_spec, kv_spec, P(), P()),
-                out_specs=(tok_spec, kv_spec, kv_spec),
+                out_specs=(tok_spec, kv_spec, kv_spec, stats_spec),
                 check_vma=False,
             ),
             donate_argnums=(2, 3),
         )
 
         cfg = model.config
+        rows = model.cache_rows()
 
-        # ONE jitted object keyed on the prompt length: a fresh function per
-        # call would retrace and recompile on every join. Named, so that its
-        # program reads ``jit_paged_kbuf_zeros`` in a device trace.
-        def paged_kbuf_zeros(p_len):
+        # ONE jitted object keyed on the prompt length (and on which of the
+        # model's two kinds of cache row): a fresh function per call would
+        # retrace and recompile on every join. Named, so that its program
+        # reads ``jit_paged_kbuf_zeros`` in a device trace.
+        def paged_kbuf_zeros(p_len, which):
+            r = rows[which]
             return jnp.zeros(
-                (cfg.num_layers, 1, cfg.num_kv_heads, p_len, cfg.head_dim),
+                (r.layers, 1, r.heads, p_len, r.width),
                 jnp.dtype(cfg.dtype),
             )
 
         self._kbuf_zeros = jax.jit(
-            paged_kbuf_zeros, static_argnums=(0,),
+            paged_kbuf_zeros, static_argnums=(0, 1),
             out_shardings=self._kv_sharding,
         )
 
@@ -777,12 +786,17 @@ class Engine:
         block (see ``BlockAllocator``); the pool is zeroed so null reads are
         finite. ``quant`` ("int8"/"fp8") stores the pool in the wire dtype
         with a parallel per-row scale pool (``models/quant.py``)."""
-        c = self.model.config
-        return PagedKVCache.create(
-            c.num_layers, num_slots, c.num_kv_heads, c.head_dim,
-            block_size=block_size, num_blocks=num_blocks, max_len=self.max_len,
-            dtype=jnp.dtype(c.dtype), sharding=self._pool_sharding, quant=quant,
+        cache = PagedKVCache.create(
+            self.model.cache_rows(), num_slots, block_size=block_size,
+            num_blocks=num_blocks, max_len=self.max_len,
+            dtype=jnp.dtype(self.model.config.dtype),
+            sharding=self._pool_sharding, quant=quant,
         )
+        for kind, nbytes in cache.bytes_per_block_by_kind.items():
+            telemetry.set_gauge(
+                "tdt_kv_pool_bytes", float(nbytes * num_blocks), kind=kind
+            )
+        return cache
 
     @staticmethod
     def _pool_pair(paged: PagedKVCache):
@@ -808,11 +822,12 @@ class Engine:
         return dataclasses.replace(paged, k=pk, v=pv, lengths=lengths)
 
     def paged_kbuf_zeros(self, p_len: int):
-        """Zeroed (L, 1, Hkv, p_len, D) chunk-prefill context buffers.
-        Two independent allocations — kbuf and vbuf are donated separately
-        through the chunk program."""
+        """Zeroed (L, 1, Hkv, p_len, D) chunk-prefill context buffers, one
+        for each of the model's two kinds of cache row (K and V rows for
+        ``DenseLLM``). Two independent allocations — kbuf and vbuf are
+        donated separately through the chunk program."""
         with tracing.span_current("tdt_engine_paged_kbuf", p_len=int(p_len)):
-            return self._kbuf_zeros(int(p_len)), self._kbuf_zeros(int(p_len))
+            return self._kbuf_zeros(int(p_len), 0), self._kbuf_zeros(int(p_len), 1)
 
     def paged_seed_kbuf(self, paged: PagedKVCache, table_row, shared_rows: int,
                         p_len: int):
@@ -842,7 +857,7 @@ class Engine:
         # (admission), so one span: spans say where the host was, the
         # ``_phase`` stamp below stays what ``tdt_engine_phase_seconds`` reads.
         with tracing.span_current("tdt_engine_prefill_chunk"):
-            logits, kb, vb = self._prefill_chunk_prog(
+            logits, kb, vb, stats = self._prefill_chunk_prog(
                 self.model.params, chunk_ids, kbuf, vbuf,
                 jnp.int32(off), jnp.int32(last_idx),
             )
@@ -850,6 +865,7 @@ class Engine:
                 # Admission (paged): each prefill chunk's compute — the
                 # chunked analog of prefill_into_slot's join cost.
                 self._phase("admission", t, logits)
+                self.model.publish_step_stats(stats)
         return logits, kb, vb
 
     def complete_paged_prefill(self, paged: PagedKVCache, kbuf, vbuf, table_row,
@@ -901,7 +917,7 @@ class Engine:
             with tracing.span_current("tdt_engine_dispatch"):
                 if pool:
                     pk_in, pv_in = self._pool_pair(paged)
-                    out, tok, pk, pv, lengths, rem = self._decode_chunk_paged(
+                    out, tok, pk, pv, lengths, rem, stats = self._decode_chunk_paged(
                         self.model.params, self._decode_extra, tokens, pk_in,
                         pv_in, paged.tables, paged.lengths, remaining, int(chunk),
                         key,
@@ -927,6 +943,8 @@ class Engine:
             with tracing.span_current("tdt_engine_host_sync"):
                 if timed:
                     t = self._phase("host_sync", t, tok)
+                    if pool:
+                        self.model.publish_step_stats(stats)
             if pool:
                 return out, tok, self._pool_update(paged, pk, pv, lengths), rem
             with tracing.span_current("tdt_engine_cache_scatter"):

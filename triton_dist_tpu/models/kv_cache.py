@@ -56,6 +56,28 @@ jax.tree_util.register_dataclass(
 NULL_BLOCK = 0  # reserved: never allocated, masked/garbage writes land here
 
 
+@dataclasses.dataclass(frozen=True)
+class CacheRow:
+    """One kind of cache row a model keeps a token: its name, how many
+    layers keep it, and its (heads, width). A model declares a pair of them
+    (``cache_rows()``): ``DenseLLM`` its K and V rows, alike; a latent
+    model one latent row for all heads on every layer and an index key on
+    the layers that own an indexer. The pool manager holds one pool a kind
+    under ONE block table, and a block's price is the sum over the kinds."""
+
+    kind: str
+    layers: int
+    heads: int
+    width: int
+
+
+def kv_rows(num_layers: int, num_kv_heads: int, head_dim: int) -> tuple:
+    """K rows and V rows, alike, on every layer: what a model with per-head
+    keys and values declares."""
+    return (CacheRow("k", num_layers, num_kv_heads, head_dim),
+            CacheRow("v", num_layers, num_kv_heads, head_dim))
+
+
 class BlockAllocator:
     """Host-side free-list + refcount bookkeeping for a paged KV pool.
 
@@ -172,43 +194,51 @@ class PagedKVCache:
     k_scale: jax.Array | None = None  # (L, blocks, Hkv, bs, 1) f32 when quant
     v_scale: jax.Array | None = None
     quant: str | None = None  # None | "int8" | "fp8"
+    #: What the two pools hold (``CacheRow.kind``): K and V rows, or the
+    #: row kinds a model declared.
+    kinds: tuple = ("k", "v")
 
     @staticmethod
-    def create(num_layers, num_slots, num_kv_heads, head_dim, *,
-               block_size, num_blocks, max_len, dtype=jnp.bfloat16,
-               sharding=None, quant=None):
+    def create(rows, num_slots, *, block_size, num_blocks, max_len,
+               dtype=jnp.bfloat16, sharding=None, quant=None):
+        """``rows`` is a pair of :class:`CacheRow` (a model's
+        ``cache_rows()``; :func:`kv_rows` for K and V rows alike): each pool
+        gets the shape its kind of row asks for, (layers, blocks, heads, bs,
+        width)."""
         if quant is not None:
             from triton_dist_tpu.models.quant import wire_dtype
 
             dtype = wire_dtype(quant)
-        shape = (num_layers, num_blocks, num_kv_heads, block_size, head_dim)
-        if sharding is not None:
-            zeros = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)()
-        else:
-            zeros = jnp.zeros(shape, dtype)
+        shapes = [(r.layers, num_blocks, r.heads, block_size, r.width) for r in rows]
+        alike = shapes[0] == shapes[1]
+        if quant is not None and not alike:
+            raise NotImplementedError("a quantized pool holds K and V rows of one shape")
+
+        def filled(fill, shape, dt):
+            if sharding is None:
+                return fill(shape, dt)
+            return jax.jit(lambda: fill(shape, dt), out_shardings=sharding)()
+
+        k = filled(jnp.zeros, shapes[0], dtype)
+        v = jnp.copy(k) if alike else filled(jnp.zeros, shapes[1], dtype)
         k_scale = v_scale = None
         if quant is not None:
             # Scale pools start at 1.0 — quantize_rows' scale for an
             # all-zero row — so NULL-block reads dequantize to exact zeros
             # and an untouched row round-trips bitwise.
-            sshape = shape[:-1] + (1,)
-            if sharding is not None:
-                ones = jax.jit(
-                    lambda: jnp.ones(sshape, jnp.float32), out_shardings=sharding
-                )()
-            else:
-                ones = jnp.ones(sshape, jnp.float32)
-            k_scale, v_scale = ones, jnp.copy(ones)
+            k_scale = filled(jnp.ones, shapes[0][:-1] + (1,), jnp.float32)
+            v_scale = jnp.copy(k_scale)
         max_blocks = -(-max_len // block_size)
         return PagedKVCache(
-            k=zeros,
-            v=jnp.copy(zeros),
+            k=k,
+            v=v,
             tables=jnp.zeros((num_slots, max_blocks), jnp.int32),
             lengths=jnp.zeros((num_slots,), jnp.int32),
             block_size=block_size,
             k_scale=k_scale,
             v_scale=v_scale,
             quant=quant,
+            kinds=(rows[0].kind, rows[1].kind),
         )
 
     @property
@@ -229,17 +259,27 @@ class PagedKVCache:
         scale pools — the ledger's admission unit (logical block count alone
         under-charges quantized pools by the scale overhead and over-charges
         them by the dtype shrink)."""
-        nl, _, hkv, bs, hd = self.k.shape
-        per = 2 * nl * hkv * bs * hd * self.k.dtype.itemsize
+        per = sum(self.bytes_per_block_by_kind.values())
         if self.k_scale is not None:
+            nl, _, hkv, bs, _ = self.k.shape
             per += 2 * nl * hkv * bs * self.k_scale.dtype.itemsize
         return per
+
+    @property
+    def bytes_per_block_by_kind(self) -> dict:
+        """Payload bytes of one block in each pool, by the kind of row it
+        holds (the two pools may differ in layers, heads and width)."""
+        out = {}
+        for kind, pool in zip(self.kinds, (self.k, self.v)):
+            nl, _, hkv, bs, hd = pool.shape
+            out[kind] = nl * hkv * bs * hd * pool.dtype.itemsize
+        return out
 
 
 jax.tree_util.register_dataclass(
     PagedKVCache,
     data_fields=["k", "v", "tables", "lengths", "k_scale", "v_scale"],
-    meta_fields=["block_size", "quant"],
+    meta_fields=["block_size", "quant", "kinds"],
 )
 
 

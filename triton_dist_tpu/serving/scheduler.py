@@ -214,12 +214,14 @@ class _PrefixNode:
     """One radix-trie node: an edge of ``block_size`` prompt tokens mapping
     to the physical block that holds their KV rows."""
 
-    __slots__ = ("children", "block", "last_used")
+    __slots__ = ("children", "block", "last_used", "parent", "key")
 
-    def __init__(self, block: int):
+    def __init__(self, block: int, parent: "_PrefixNode | None" = None, key: tuple = ()):
         self.children: dict[tuple, "_PrefixNode"] = {}
         self.block = int(block)
         self.last_used = 0
+        self.parent = parent  # None for a tenant's root
+        self.key = key  # this node's edge in ``parent.children``
 
 
 class PrefixIndex:
@@ -339,7 +341,7 @@ class PrefixIndex:
                 ):
                     break
                 self.allocator.incref([blk])
-                child = _PrefixNode(blk)
+                child = _PrefixNode(blk, node, key)
                 node.children[key] = child
                 self.num_blocks_indexed += 1
                 self._note_blocks(tenant, +1)
@@ -368,42 +370,60 @@ class PrefixIndex:
         its block when no running slot still holds a ref — the loop keeps
         going either way. Returns the number of index entries dropped."""
         dropped = 0
+        short = lambda: self.allocator.num_free < need_free
         if tenant is not None:
-            while self.allocator.num_free < need_free:
-                if not self._drop_leaf([tenant], cause="self"):
+            while short():
+                n = self._drop_leaf([tenant], cause="self", more=short)
+                if not n:
                     break
-                dropped += 1
+                dropped += n
         if self.tenant_quota > 0:
-            while self.allocator.num_free < need_free:
+            while short():
                 over = [
                     t for t, n in self._tenant_blocks.items()
                     if n > self.tenant_quota
                 ]
-                if not over or not self._drop_leaf(over, cause="over_quota"):
+                n = self._drop_leaf(over, cause="over_quota", more=short) if over else 0
+                if not n:
                     break
-                dropped += 1
-        while self.allocator.num_free < need_free:
-            if not self._drop_leaf(None, cause="pressure"):
+                dropped += n
+        while short():
+            n = self._drop_leaf(None, cause="pressure", more=short)
+            if not n:
                 break
-            dropped += 1
+            dropped += n
         return dropped
 
     def _drop_leaf(self, tenants: list[str] | None, cause: str,
-                   exclude_tick: int | None = None) -> bool:
-        """Remove the LRU leaf among ``tenants`` (None = all). Returns
-        False when no eligible leaf exists."""
+                   exclude_tick: int | None = None, more=None) -> int:
+        """Remove the LRU leaf among ``tenants`` (None = all); 0 when no
+        eligible leaf exists. While ``more()`` holds, go on up the chain:
+        an ancestor that the removal leaves bare and that carries the same
+        stamp is the next LRU leaf (one lookup or registration stamps a
+        whole path with its tick, and an ancestor is never older than what
+        hangs below it), so the order of eviction is what one walk of the
+        trie a block gave, at one walk a chain: a long prompt's thousand
+        blocks cost a thousand walks before. Returns the number removed."""
         lru = self._lru_leaf(tenants, exclude_tick=exclude_tick)
         if lru is None:
-            return False
+            return 0
         tname, parent, key, node = lru
-        del parent.children[key]
-        self.num_blocks_indexed -= 1
-        self._note_blocks(tname, -1)
-        self.allocator.free([node.block])
-        telemetry.inc(
-            "tdt_tenant_prefix_evictions_total", tenant=tname, cause=cause
-        )
-        return True
+        stamp = node.last_used
+        removed = 0
+        while True:
+            del parent.children[key]
+            self.num_blocks_indexed -= 1
+            self._note_blocks(tname, -1)
+            self.allocator.free([node.block])
+            telemetry.inc(
+                "tdt_tenant_prefix_evictions_total", tenant=tname, cause=cause
+            )
+            removed += 1
+            node = parent
+            if (more is None or not more() or node.parent is None
+                    or node.children or node.last_used != stamp):
+                return removed
+            parent, key = node.parent, node.key
 
     def _lru_leaf(
         self, tenants: list[str] | None = None,

@@ -159,7 +159,8 @@ class InferenceServer:
                  key: jax.Array | None = None, watchdog=None,
                  shed_wait_s: float | None = None,
                  shed_priority: int | None = None,
-                 journal=None, spec_k: int | None = None, drafter=None):
+                 journal=None, spec_k: int | None = None, drafter=None,
+                 prefill_chunk: int | None = None):
         self.engine = engine
         self.num_slots = (
             get_int_env("TDT_SERVE_SLOTS", 4) if num_slots is None else int(num_slots)
@@ -193,8 +194,11 @@ class InferenceServer:
             self.num_blocks = get_int_env(
                 "TDT_KV_BLOCKS", self.num_slots * max_blocks + 1
             )
-            self.prefill_chunk = get_int_env(
-                "TDT_PREFILL_CHUNK", engine.max_len
+            #: Prefill rows a chunk dispatch: the argument, else
+            #: TDT_PREFILL_CHUNK, else the whole prompt in one.
+            self.prefill_chunk = (
+                get_int_env("TDT_PREFILL_CHUNK", engine.max_len)
+                if prefill_chunk is None else int(prefill_chunk)
             )
             assert self.prefill_chunk >= 1
             #: Quantized KV storage (TDT_QUANT_KV=int8|fp8): the pool holds

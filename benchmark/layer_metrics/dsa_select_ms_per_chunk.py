@@ -1,0 +1,26 @@
+"""What the sparse selection's k-th value costs a prefill chunk, from the
+device trace: own device seconds of the operations named ``dsa_kth_value``
+(``trace.op_seconds``: the kernel that finds each query row's k-th largest
+index score without a sort, once a selecting layer) over the executions of
+the chunk program (``jit_chunk_fn``), in milliseconds. Nothing to read
+where the trace holds no such kernel (a program that sorts, a model that
+does not select) or no chunk."""
+
+import re
+
+LAYER = "model step, prefill (models/engine.py, layers/, kernels/)"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "out_tokens_per_s"
+
+#: The kernel, by the name it gives its ``pallas_call``.
+KERNEL = re.compile(r"dsa_kth_value")
+CHUNK = re.compile(r"jit_chunk_fn")
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    spent, calls = run.trace_mod.op_seconds(run.trace, KERNEL)
+    _, chunks = run.trace_mod.program_seconds(run.trace, CHUNK)
+    return 1e3 * spent / chunks if calls and chunks else None

@@ -212,13 +212,37 @@ def test_expanded_equals_absorbed():
 def test_exact_selection_breaks_ties_like_top_k():
     scores = jnp.asarray([[1.0, 3.0, 3.0, 2.0, 3.0, 0.5, 3.0, 9.0]])
     visible = jnp.asarray([[True] * 7 + [False]])
-    mask = ls.select_mask(scores, visible, 3)
+    mask, _ = ls.select_mask(scores, visible, 3)
     idx, real = ls.select_positions(scores, visible, 3)
     assert np.asarray(mask)[0].nonzero()[0].tolist() == [1, 2, 4] == sorted(np.asarray(idx)[0])
     assert np.asarray(real).all()
     few = jnp.asarray([[True, True] + [False] * 6])
-    assert np.asarray(ls.select_mask(scores, few, 3))[0].tolist() == [True, True] + [False] * 6
+    assert np.asarray(ls.select_mask(scores, few, 3)[0])[0].tolist() == [True, True] + [False] * 6
     assert np.asarray(ls.select_positions(scores, few, 3)[1]).sum() == 2
+
+
+def test_tie_rows_counter_counts_the_rows_whose_ties_overflow(ctx, model):
+    """An indexer whose head weights are zero scores every position 0.0, so
+    each real prefill row that sees more than ``index_topk`` positions has
+    more equal scores at its boundary than places: the selection walks the
+    ties (the lowest positions win) on both selecting layers, and the rows
+    leave the device with the chunk's other statistics."""
+    p = model.params
+    for layer in CFG.index_layers:
+        p = _with_indexer(p, layer, w_iw=jnp.zeros_like(p["layers"][layer]["w_iw"]))
+    eng = Engine(LatentSparseLLM(CFG, ctx, params=p), backend="dist", max_len=T_REF)
+    telemetry.reset()
+    served = _serve(eng, 32, n_requests=2)
+    got = {e["labels"]["phase"]: e["value"]
+           for e in telemetry.snapshot()["counters"]["tdt_dsa_select_tie_rows_total"]}
+    prompts = [len(r.prompt) for r, _ in served]
+    assert got == {"prefill": len(CFG.index_layers) * sum(n - CFG.index_topk for n in prompts)}
+    # ... and the walk gave the lowest positions: what was selected is still
+    # min(visible, index_topk) a row
+    selected = sum(e["value"] for e in telemetry.snapshot()["counters"][
+        "tdt_dsa_positions_selected_total"] if e["labels"]["phase"] == "prefill")
+    assert selected == len(CFG.index_layers) * sum(
+        np.minimum(np.arange(1, n + 1), CFG.index_topk).sum() for n in prompts)
 
 
 def _expert_layer(model, layer=1, rows=40, seed=0):

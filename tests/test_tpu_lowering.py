@@ -663,8 +663,17 @@ def _flash_attention(sds):
                              sds((1, 8, 1024, 128)))
 
 
-@pytest.mark.parametrize("case", [_flash_decode, _paged_flash_decode, _flash_attention],
-                         ids=lambda f: f.__name__.lstrip("_"))
+def _dsa_kth_value(sds):
+    """A prefill chunk's index scores over the longest prompt buffer there
+    is: 2048 query rows, 16640 positions, the 2048th largest a row."""
+    from triton_dist_tpu.kernels.kth_value import kth_value
+
+    return (lambda x: kth_value(x, 2048)), (sds((2048, 16640), jnp.float32),)
+
+
+@pytest.mark.parametrize(
+    "case", [_flash_decode, _paged_flash_decode, _flash_attention, _dsa_kth_value],
+    ids=lambda f: f.__name__.lstrip("_"))
 def test_named_kernel_compiles_under_its_name(topo_2x2, case):
     """At Qwen3-8B head shapes, for one v5e chip: the kernel compiles and
     its custom call is the instruction ``%<name>``, not ``closed_call.N``."""
@@ -712,3 +721,88 @@ def test_collective_kernel_is_named_after_its_function(tpu_mesh):
     )
     calls = [l for l in compiled.as_text().splitlines() if "tpu_custom_call" in l]
     assert calls and all("%_ag_gemm_fused_kernel" in l.split("=")[0] for l in calls), calls
+
+
+# ---------------------------------------------------------------------------
+# The second configuration's prefill chunk, at its published widths
+# ---------------------------------------------------------------------------
+
+
+def _abstract_latent_sparse(devices):
+    """(model, params, configuration file) of ``glm-5.2-ep16-d5`` over one
+    described chip, the parameters as shapes."""
+    import json
+    import sys
+
+    from triton_dist_tpu.models import LatentSparseLLM
+    from triton_dist_tpu.models.latent_sparse import layer_ones, layer_tensors
+    from triton_dist_tpu.runtime.mesh import initialize_distributed
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.build.glm_moe_dsa import model_config
+
+    with open(os.path.join(root, "benchmark/configs/glm-5.2-ep16-d5.json")) as f:
+        cfg = json.load(f)
+    c = model_config(cfg)
+    ctx = initialize_distributed(devices=list(devices), axis_names=("tp",), set_default=False)
+    dt = jnp.dtype(c.dtype)
+    sds = lambda shape, dtype=dt: jax.ShapeDtypeStruct(
+        tuple(shape), dtype, sharding=ctx.replicated())
+    params = {"embed": sds((c.vocab_size, c.hidden_size)), "final_norm": sds((c.hidden_size,)),
+              "lm_head": sds((c.hidden_size, c.vocab_size)), "layers": []}
+    for layer in range(c.num_layers):
+        lp = {name: sds(shape, jnp.float32 if name.startswith("router") else dt)
+              for name, shape, _ in layer_tensors(c, layer)}
+        lp.update({name: sds((n,)) for name, n in layer_ones(c, layer)})
+        if c.index_kinds[layer] == "full":
+            lp["ik_norm_b"] = sds((c.index_head_dim,))
+        params["layers"].append(lp)
+    return LatentSparseLLM(c, ctx, params=params), params, cfg
+
+
+def _sorted_shapes(hlo: str) -> list[str]:
+    """The first operand's shape of every ``sort`` in a compiled module."""
+    types = (l.split(" = ", 1)[1].split(" sort(", 1)[0]
+             for l in hlo.splitlines() if " = " in l and " sort(" in l)
+    return [re.search(r"\w+\[[\d,]*\]", t).group() for t in types]
+
+
+@pytest.mark.parametrize("p_len", [4096, 8192, 16384])
+def test_latent_sparse_chunk_selects_without_a_sort(topo_2x2, p_len):
+    """``longdoc``'s chunk program at each of its prompt lengths, for one
+    v5e chip: it fits beside the pools, each of its two selecting layers
+    finds the k-th index score in the ``dsa_kth_value`` kernel, and no sort
+    of the chunk's ``f32[2048, P]`` scores is left (XLA's lowering of
+    ``lax.top_k``, 27.6 ms a call at P 16384 on the chip). The sorts that
+    stay are the router's top 8 of 256 and the held experts' ordering."""
+    from triton_dist_tpu.models.engine import Engine
+    from triton_dist_tpu.runtime.platform import force_mosaic
+
+    model, params, cfg = _abstract_latent_sparse(topo_2x2.devices[:1])
+    c, sv = model.config, cfg["serving"]
+    rows = int(sv["prefill_chunk"])
+    assert rows == c.index_topk == 2048
+    with force_mosaic():
+        eng = Engine(model, backend=sv["backend"], max_len=int(sv["max_len"]))
+        rep = model.ctx.replicated()
+        buf = lambda layers, width: jax.ShapeDtypeStruct(
+            (layers, 1, 1, p_len, width), jnp.dtype(c.dtype), sharding=eng._kv_sharding)
+        i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+        compiled, held = _compile(eng._prefill_chunk_prog.lower(
+            params, i32((1, rows)), buf(c.num_layers, c.latent_row),
+            buf(len(c.index_layers), c.index_head_dim), i32(()), i32(())),
+            kernels=("dsa_kth_value",))
+    pools = int(sv["slots"]) * int(sv["max_len"]) * sum(
+        r.layers * r.heads * r.width for r in model.cache_rows()) * 2
+    assert held + pools < HBM_BYTES, held
+    hlo = compiled.as_text()
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l]
+    assert len(calls) == len(c.index_layers) and all(
+        l.strip().startswith("%dsa_kth_value") for l in calls), calls
+    assert f"f32[{rows},{p_len}]" not in _sorted_shapes(hlo), _sorted_shapes(hlo)
+    # ... and the search does find the parent's, by its line in the ledger
+    was = ("  %sort.34 = (f32[2048,16384]{1,0:T(8,128)}, s32[2048,16384]{1,0:T(8,128)}) "
+           "sort(%fusion.1, %iota.2), dimensions={1}, is_stable=true")
+    assert _sorted_shapes(was) == ["f32[2048,16384]"]

@@ -1,7 +1,8 @@
 """Latent attention, a learned sparse selection, and a sigmoid-routed expert
 layer that is told which experts it holds: the shard-local math of
-``models/latent_sparse.py``, in plain XLA (no Pallas kernel yet; the cell
-that runs these shows which a later change should replace).
+``models/latent_sparse.py``, in plain XLA but for the selection's k-th
+value (``kernels/kth_value.py``; the cell that runs these shows which part
+a later change should replace next).
 
 * **Latent attention.** A token's cache row is ``[c_kv | k_r]``: the
   normalised KV latent and one roped key part shared by all heads. Prefill
@@ -10,9 +11,11 @@ that runs these shows which a later change should replace).
   (scores and the weighted sum taken in the latent space). The two give the
   same numbers (``tests/test_latent_sparse.py``).
 * **Sparse selection.** Index scores ``I[t, s] = sum_h w[t, h] relu(q[t, h]
-  . k[s])`` over the visible cache, and exactly the ``k`` largest a query
-  (``lax.top_k``; ties go to the lower position). Prefill carries the
-  selection as a mask over the prompt's buffer, decode as positions.
+  . k[s])`` over the visible cache, and exactly the ``k`` largest a query,
+  ties to the lower position: ``lax.top_k``'s set. Prefill finds a row's
+  k-th largest score by bisection over its bits, without a sort, and
+  carries the selection as a mask over the prompt's buffer; decode asks
+  ``lax.top_k`` for the positions.
 * **Experts.** Sigmoid scores over every published expert, the top ``k`` by
   score plus bias, gates from the scores alone; of the chosen, only those
   held here are computed, sorted by expert and taken a tile of rows at a
@@ -26,6 +29,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from triton_dist_tpu.kernels.kth_value import kth_value
 from triton_dist_tpu.layers.tp import RMSNorm
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -136,15 +140,27 @@ def select_mask(scores, visible, k: int):
     """The exact top-``k`` of ``scores`` (T, S) among ``visible`` (T, S), as
     a mask: everything visible where at most ``k`` positions are. Equal
     scores at the boundary go to the lower position, as ``lax.top_k``
-    orders them, so this is the set :func:`select_positions` returns."""
+    orders them, so this is the set :func:`select_positions` returns.
+
+    The k-th largest score of a row comes from ``kernels/kth_value.py``
+    (no sort), and the mask from float comparisons with it. Where the
+    scores equal to it are more than the places left, the lower positions
+    take them, which needs a running count along the row: that walk is
+    made only if some row of the call needs it. -> (mask (T, S), the rows
+    that needed the walk (T,) bool)."""
     if scores.shape[-1] <= k:
-        return visible
+        return visible, jnp.zeros(scores.shape[:1], bool)
     s = jnp.where(visible, scores, NEG)
-    kth = jax.lax.top_k(s, k)[0][:, -1:]
+    kth = kth_value(s, k)
     above = s > kth
     tied = (s == kth) & visible
     room = k - above.sum(axis=-1, keepdims=True)
-    return above | (tied & (jnp.cumsum(tied, axis=-1) <= room))
+    overflows = tied.sum(axis=-1, keepdims=True) > room
+    mask = jax.lax.cond(
+        overflows.any(),
+        lambda: above | (tied & (jnp.cumsum(tied, axis=-1) <= room)),
+        lambda: above | tied)
+    return mask, overflows[:, 0]
 
 
 def select_positions(scores, visible, k: int):

@@ -230,12 +230,16 @@ class LatentSparseLLM:
         sums over a chunk on the device: rows each published expert was
         chosen by, expert-layer calls, and on the layers that select the
         positions the queries could see and the positions selected for
-        them, [in prefill chunks, in decode steps]. Rows nobody sent (a
-        chunk's padding, an inactive slot) are in none of them."""
+        them, [in prefill chunks, in decode steps], and the prefill rows
+        whose selection had more equal scores at its boundary than places
+        left (``layers/latent_sparse.py:select_mask``'s slow path). Rows
+        nobody sent (a chunk's padding, an inactive slot) are in none of
+        them."""
         return {"expert_rows": jnp.zeros((self.config.num_experts,), jnp.int32),
                 "dispatches": jnp.zeros((), jnp.int32),
                 "visible": jnp.zeros((2,), jnp.int32),
-                "selected": jnp.zeros((2,), jnp.int32)}
+                "selected": jnp.zeros((2,), jnp.int32),
+                "tie_rows": jnp.zeros((), jnp.int32)}
 
     def publish_step_stats(self, stats) -> None:
         """Host side: feed the counters from a finished program's stats."""
@@ -249,6 +253,8 @@ class LatentSparseLLM:
                           float(stats["visible"][i]), phase=phase)
             telemetry.inc("tdt_dsa_positions_selected_total",
                           float(stats["selected"][i]), phase=phase)
+        telemetry.inc("tdt_dsa_select_tie_rows_total", float(stats["tie_rows"]),
+                      phase="prefill")
 
     @staticmethod
     def _selected(stats, phase: int, rows, n_visible, chosen):
@@ -307,8 +313,10 @@ class LatentSparseLLM:
                 q_i, k_i, w_i = ls.index_project(lp, h, c_q, pos, c)
                 vbufs = vbufs.at[fi, 0, 0, pos].set(k_i, mode="drop")
                 scores = ls.index_scores(q_i, w_i, vbufs[fi, 0, 0])
-                allowed = ls.select_mask(scores, visible, c.index_topk)
+                allowed, walked = ls.select_mask(scores, visible, c.index_topk)
                 stats = self._selected(stats, 0, sent, pos + 1, allowed)
+                stats = {**stats, "tie_rows": stats["tie_rows"]
+                         + (walked & sent).sum(dtype=jnp.int32)}
             a = ls.attend_expanded(q_nope, q_rope, kbufs[layer, 0, 0], allowed, off,
                                    lp["w_uk"], lp["w_uv"], c)
             x = x + ls.mm(a, lp["w_o"])

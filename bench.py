@@ -2020,8 +2020,8 @@ def bench_serving_spec(on_tpu):
     the default truncated drafter (first half of the target's layers)
     proposes k=3 tokens per round, the k-wide masked verify scores them in
     one launch, and the stream must stay byte-identical to non-speculative
-    greedy decode. Four configs: dense + MoE, each on the contiguous
-    slot-cache xla path and the mega paged path. Gates:
+    greedy decode. Four configs: dense + MoE, each on xla (verify on the
+    contiguous bounce) and mega (verify against the pool). Gates:
 
     * ``serving_spec*_parity_frac`` — fraction of requests whose spec
       stream equals the k=1 stream (must be 1.0, the correctness bar);
@@ -2034,7 +2034,6 @@ def bench_serving_spec(on_tpu):
 
     ``accepted_per_round`` (informational) is the mean verified window per
     spec round — > 1.0 is the whole point of speculation."""
-    import os
     import time
 
     from triton_dist_tpu.models import PRESETS, DenseLLM, EPMoELLM, Engine
@@ -2073,38 +2072,30 @@ def bench_serving_spec(on_tpu):
         return [list(h.tokens) for h in handles], round(toks / wall, 1)
 
     configs = [
-        ("", dense, "xla", 0),
-        ("mega_", dense, "mega", 1),
-        ("moe_", moe, "xla", 0),
-        ("moe_mega_", moe, "mega", 1),
+        ("", dense, "xla"),
+        ("mega_", dense, "mega"),
+        ("moe_", moe, "xla"),
+        ("moe_mega_", moe, "mega"),
     ]
-    for label, model, backend, paged in configs:
-        prev = os.environ.get("TDT_SERVING_PAGED")
-        os.environ["TDT_SERVING_PAGED"] = str(paged)
-        try:
-            eng = Engine(model, backend=backend, max_len=max_len)
-            # Warm both program families (k=1 decode chunk + spec verify
-            # chunk + every prefill shape) so the timed passes measure the
-            # serving loop, not compilation.
-            for k in (0, spec_k):
-                warm = InferenceServer(eng, num_slots=slots, chunk=chunk,
-                                       spec_k=k)
-                for plen in sorted({len(p) for p, _ in reqs}):
-                    warm.submit(list(range(plen)), 2)
-                warm.run()
-            refs, k1_tps = serve_all(eng, 0)
-            p0 = telemetry.counter_total("tdt_spec_proposed_total")
-            a0 = telemetry.counter_total("tdt_spec_accepted_total")
-            s0, n0 = _accept_len_hist()
-            streams, tps = serve_all(eng, spec_k)
-            proposed = telemetry.counter_total("tdt_spec_proposed_total") - p0
-            accepted = telemetry.counter_total("tdt_spec_accepted_total") - a0
-            s1, n1 = _accept_len_hist()
-        finally:
-            if prev is None:
-                os.environ.pop("TDT_SERVING_PAGED", None)
-            else:
-                os.environ["TDT_SERVING_PAGED"] = prev
+    for label, model, backend in configs:
+        eng = Engine(model, backend=backend, max_len=max_len)
+        # Warm both program families (k=1 decode chunk + spec verify
+        # chunk + every prefill shape) so the timed passes measure the
+        # serving loop, not compilation.
+        for k in (0, spec_k):
+            warm = InferenceServer(eng, num_slots=slots, chunk=chunk,
+                                   spec_k=k)
+            for plen in sorted({len(p) for p, _ in reqs}):
+                warm.submit(list(range(plen)), 2)
+            warm.run()
+        refs, k1_tps = serve_all(eng, 0)
+        p0 = telemetry.counter_total("tdt_spec_proposed_total")
+        a0 = telemetry.counter_total("tdt_spec_accepted_total")
+        s0, n0 = _accept_len_hist()
+        streams, tps = serve_all(eng, spec_k)
+        proposed = telemetry.counter_total("tdt_spec_proposed_total") - p0
+        accepted = telemetry.counter_total("tdt_spec_accepted_total") - a0
+        s1, n1 = _accept_len_hist()
         same = sum(a == b for a, b in zip(streams, refs))
         out[f"serving_spec_{label}parity_frac"] = round(same / len(reqs), 3)
         out[f"serving_spec_{label}accept_frac"] = round(

@@ -222,6 +222,140 @@ def test_chaos_probe_arc_restores_fused_backend(model1, monkeypatch):
     assert len(telemetry.events("serving_restore")) == 1
 
 
+def _pool_state(srv):
+    """What a probe must leave alone: the serving pool (the very arrays),
+    the ledger's books, and the counter that says which decode ran."""
+    return {
+        "cache": srv.cache,
+        "pool": (np.asarray(srv.cache.k), np.asarray(srv.cache.v)),
+        "tables": np.asarray(srv.cache.tables),
+        "ledger": srv.kv_ledger.stats(),
+        "occupied": len(srv.scheduler.occupied_slots()),
+        "pool_chunks": telemetry.counter_value(
+            "tdt_engine_decode_chunks_total", path="pool"),
+        "pool_bytes": {
+            g["labels"]["kind"]: g["value"]
+            for g in telemetry.snapshot()["gauges"]["tdt_kv_pool_bytes"]
+        },
+    }
+
+
+def _assert_pool_untouched(before, after):
+    assert after["cache"] is before["cache"]
+    for got, want in zip(after["pool"], before["pool"]):
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(after["tables"], before["tables"])
+    assert after["ledger"] == before["ledger"]
+
+
+def _degraded_server(model1, monkeypatch):
+    """A dist_ar server whose second decode chunk aborts, its requests
+    submitted: (engine, server, handles, streams, xla references)."""
+    monkeypatch.setenv("TDT_DEGRADE_PROBE_S", "0.01")
+    refs = _references(make_engine(model1, backend="xla"))
+    eng = make_engine(model1, backend="dist_ar")
+    srv = InferenceServer(eng, num_slots=2, chunk=2)
+    streams: dict[int, list[int]] = {}
+    handles = [
+        srv.submit(p, g, on_token=lambda r, t, i: streams.setdefault(
+            r.req_id, []).append(t))
+        for p, g in REQUESTS
+    ]
+    return eng, srv, handles, streams, refs
+
+
+@pytest.mark.chaos
+def test_chaos_probe_runs_the_programs_that_serve(model1, monkeypatch):
+    """The probe's sandbox is a pool of its own driven through the join's and
+    the chunk's programs: a passing probe counts one in-place decode chunk
+    (``tdt_engine_decode_chunks_total{path="pool"}``) and, up to the moment
+    the restore begins, leaves the serving pool's bytes, its tables and the
+    ledger's books as they were — with tenants in their slots."""
+    eng, srv, handles, streams, refs = _degraded_server(model1, monkeypatch)
+    before, after = {}, {}
+    dispatch, restore = srv._probe_dispatch, srv._restore_streams
+
+    def probe_dispatch():
+        before.update(_pool_state(srv))
+        dispatch()
+
+    def restore_streams():
+        after.update(_pool_state(srv))
+        restore()
+
+    monkeypatch.setattr(srv, "_probe_dispatch", probe_dispatch)
+    monkeypatch.setattr(srv, "_restore_streams", restore_streams)
+    with resilience.chaos_schedule("abort@decode:1,heal"):
+        srv.run()
+    assert eng.backend == "dist_ar" and not resilience.any_degraded()
+    assert before["occupied"] > 0, "the probe found no tenant to disturb"
+    _assert_pool_untouched(before, after)
+    assert after["pool_chunks"] == before["pool_chunks"] + 1.0
+    for h, ref in zip(handles, refs):
+        assert h.done
+        np.testing.assert_array_equal(np.asarray(h.tokens, np.int32), ref)
+        assert streams[h.req_id] == list(h.tokens)
+
+
+@pytest.mark.chaos
+def test_chaos_probe_fails_on_a_fault_in_the_paged_step(model1, monkeypatch):
+    """A fault in the decode chunk that serves, and in nothing else, fails
+    the probe: the breaker re-opens, the engine goes back to xla, and the
+    live streams end there byte for byte, their pool and its gauge as the
+    probe found them."""
+    eng, srv, handles, streams, refs = _degraded_server(model1, monkeypatch)
+    before, after = [], []
+    dispatch = srv._probe_dispatch
+
+    def boom(*_a, **_k):
+        raise RuntimeError("planted in decode_chunk_paged")
+
+    def faulty_probe_dispatch():
+        before.append(_pool_state(srv))
+        with monkeypatch.context() as m:
+            # after the probe's rebuild: a rebuild makes the programs anew
+            m.setattr(eng, "_decode_chunk_paged", boom)
+            dispatch()
+
+    monkeypatch.setattr(srv, "_probe_dispatch", faulty_probe_dispatch)
+    maybe_probe = srv._maybe_probe
+
+    def probe_then_look():
+        n = len(before)
+        worked = maybe_probe()
+        if len(before) > n:
+            after.append(_pool_state(srv))
+        return worked
+
+    monkeypatch.setattr(srv, "_maybe_probe", probe_then_look)
+    with resilience.chaos_schedule("abort@decode:1,heal"):
+        srv.run()
+    assert before and before[0]["occupied"] > 0
+    for b, a in zip(before, after):
+        _assert_pool_untouched(b, a)
+        assert a["pool_bytes"] == b["pool_bytes"]
+        assert a["pool_chunks"] == b["pool_chunks"] + 1.0  # it got that far
+    assert eng.backend == "xla" and resilience.is_degraded("collectives")
+    failed = telemetry.events("serving_probe_failed")
+    assert len(failed) == len(before)
+    assert all("planted in decode_chunk_paged" in e["error"] for e in failed)
+    assert telemetry.counter_value(
+        "tdt_resilience_probes_total", feature="collectives", outcome="failed"
+    ) == float(len(before))
+    assert not telemetry.events("serving_restore")
+    trans = [
+        (e["from_state"], e["to_state"])
+        for e in telemetry.events("breaker_transition")
+        if e["feature"] == "collectives"
+    ]
+    assert trans[:3] == [
+        ("closed", "open"), ("open", "half_open"), ("half_open", "open")]
+    for h, ref in zip(handles, refs):
+        assert h.done
+        np.testing.assert_array_equal(np.asarray(h.tokens, np.int32), ref)
+        assert streams[h.req_id] == list(h.tokens)
+
+
 @pytest.mark.chaos
 def test_chaos_double_fault_recovery_stays_degraded(model1, monkeypatch):
     """Double fault: the chunk abort's recovery re-prefill is ITSELF

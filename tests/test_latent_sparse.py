@@ -14,6 +14,7 @@ program computing in a lower precision than stated fails it.
 """
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,7 @@ import latent_sparse_ref as ref
 from triton_dist_tpu.layers import latent_sparse as ls
 from triton_dist_tpu.models import (
     PRESETS, DenseLLM, Engine, LatentSparseConfig, LatentSparseLLM, PagedKVCache, kv_rows)
-from triton_dist_tpu.runtime import telemetry
+from triton_dist_tpu.runtime import resilience, telemetry
 from triton_dist_tpu.runtime.mesh import initialize_distributed
 from triton_dist_tpu.serving import InferenceServer
 
@@ -331,3 +332,46 @@ def test_pools_follow_the_declared_rows(ctx, model):
     assert srv.cache.bytes_per_block_by_kind == {
         "latent": 5 * c.latent_row * bs * 4, "index_key": 2 * c.index_head_dim * bs * 4}
     srv.shutdown(drain=False)
+
+
+@pytest.mark.chaos
+def test_probe_restores_a_model_that_has_the_paged_programs_only(model, monkeypatch):
+    """Degraded by an abort in its second decode chunk, a server of this
+    model comes back to its preferred backend: the half-open probe runs a
+    chunked prefill and a paged step, which the model has, and no one-shot
+    prefill, which it has not. No token is lost or doubled on the way, and
+    the streams are those of the same requests served undisturbed."""
+    monkeypatch.setenv("TDT_DEGRADE_PROBE_S", "0.01")
+    telemetry.reset()
+    resilience.reset_degradation()
+    eng = Engine(model, backend="dist", max_len=64)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, CFG.vocab_size, size=20).tolist() for _ in range(2)]
+
+    def serve(schedule):
+        srv = InferenceServer(eng, num_slots=2, chunk=2)
+        streams = {}
+        reqs = [srv.submit(p, 7, on_token=lambda r, t, i: streams.setdefault(
+            r.req_id, []).append(t)) for p in prompts]
+        with resilience.chaos_schedule(schedule):
+            srv.run()
+            for _ in range(2000):  # backoffs of 10 ms, doubling while probes fail
+                if eng.backend == "dist":
+                    break
+                if not srv.step():
+                    time.sleep(0.005)
+        srv.shutdown(drain=False)
+        assert all(r.done and streams[r.req_id] == list(r.tokens) for r in reqs)
+        return [list(r.tokens) for r in reqs]
+
+    try:
+        got = serve("abort@decode:1,heal")
+        assert eng.backend == "dist", telemetry.events("serving_probe_failed")[-1:]
+        assert not resilience.any_degraded()
+        assert telemetry.counter_value("tdt_serving_recoveries_total", from_backend="dist") == 1.0
+        assert telemetry.counter_value("tdt_serving_restores_total", to_backend="dist") == 1.0
+        assert not telemetry.events("serving_probe_failed")
+        assert got == serve("heal") and all(len(t) == 7 for t in got)
+    finally:
+        resilience.reset_degradation()
+

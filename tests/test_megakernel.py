@@ -332,6 +332,19 @@ def test_builder_graph_summary():
     assert "attn_front" in s and "mlp_block" in s and "flash_decode" in s
 
 
+def test_verify_program_is_paged_only():
+    """The k-wide verify replays the PAGED step (masks and tables as data):
+    a builder made without ``paged=True`` has no such step to replay and
+    says so, where it would once have built the contiguous twin."""
+    from triton_dist_tpu.models.config import PRESETS
+
+    cfg = PRESETS["test-dense"]
+    with pytest.raises(ValueError, match="paged=True"):
+        ModelBuilder(cfg, world=1).build_verify_fn(cfg.num_layers, 3)
+    vfn = ModelBuilder(cfg, world=1, paged=True).build_verify_fn(cfg.num_layers, 3)
+    assert any("paged" in p for p in vfn.plan), vfn.plan
+
+
 def test_builder_requires_cache_update():
     """A hand-recorded graph without attention fails with a clear error,
     not a bare StopIteration (r3 advisor)."""
@@ -527,69 +540,57 @@ def model1():
     return DenseLLM(PRESETS["test-dense"], ctx, key=jax.random.PRNGKey(1))
 
 
+_RAGGED = {0: [3, 17, 42, 7, 99, 5], 2: [3, 17, 42, 7]}  # slot 1 stays free
+
+
+def _ragged_paged_chunk(eng):
+    """Joins of two lengths into a three-slot pool, then one chunk of 6
+    steps over the ragged mask (slot 0 has 5 left, slot 1 none, slot 2
+    three): (token0s of slots 0 and 2, out, remaining')."""
+    from paged_drive import alloc_chains, join
+
+    paged = alloc_chains(eng, 3)
+    t_a, paged = join(eng, paged, 0, _RAGGED[0])
+    t_b, paged = join(eng, paged, 2, _RAGGED[2])
+    out, _, paged, rem = eng.decode_steps_paged(
+        paged, jnp.asarray([t_a, 0, t_b], jnp.int32),
+        jnp.asarray([5, 0, 3], jnp.int32), 6)
+    return (t_a, t_b), np.asarray(out), np.asarray(rem)
+
+
+def _assert_ragged_chunk_is_serve(ref_eng, t0s, out):
+    """The chunk's streams are ``Engine.serve``'s on ``ref_eng``, and the
+    free slot stayed masked the whole chunk."""
+    assert (out[1] == -1).all()
+    for slot, t0, n in ((0, t0s[0], 5), (2, t0s[1], 3)):
+        ref = np.asarray(ref_eng.serve(
+            jnp.asarray([_RAGGED[slot]], jnp.int32), gen_len=n + 1))[0]
+        np.testing.assert_array_equal([t0, *out[slot, :n]], ref)
+        assert (out[slot, n:] == -1).all()
+
+
 def test_mega_masked_decode_steps_parity(model1):
     """Ragged active masks through the persistent-step program: mega
-    decode_steps (contiguous) and decode_steps_paged (direct pool walk,
-    no gather/scatter bounce) both match xla token-for-token, including
-    the inactive slots' -1 cells and frozen lengths. Also pins the
-    tdt_mega_* telemetry contract."""
-    import dataclasses
+    decode_steps_paged (direct pool walk, no gather/scatter bounce) matches
+    xla's one-shot serve token-for-token, including the inactive slots' -1
+    cells and frozen lengths. Also pins the tdt_mega_* telemetry
+    contract."""
     from triton_dist_tpu.models import Engine
     from triton_dist_tpu.runtime import telemetry
 
-    ids = jnp.asarray([[3, 17, 42, 7, 99, 5]], jnp.int32)
-    results = {}
     telemetry.reset()
-    for backend in ("xla", "mega"):
-        eng = Engine(model1, backend=backend, max_len=32)
-        # -- contiguous, ragged mask: slot 1 is free (remaining 0)
-        cache = eng.alloc_slots(3)
-        t_a, cache = eng.prefill_into_slot(cache, 0, ids)
-        t_b, cache = eng.prefill_into_slot(cache, 2, ids[:, :4])
-        toks = jnp.asarray([t_a, 0, t_b], jnp.int32)
-        rem = jnp.asarray([5, 0, 3], jnp.int32)
-        out_c, _, cache, rem_c = eng.decode_steps(cache, toks, rem, 6)
-        # -- paged, same composition, decoded against the block pool
-        paged = eng.alloc_paged(3, block_size=8, num_blocks=32)
-        tables = np.zeros((3, paged.tables.shape[1]), np.int32)
-        tables[0, :4] = np.arange(1, 5)
-        tables[2, :4] = np.arange(5, 9)
-        paged = dataclasses.replace(paged, tables=jnp.asarray(tables))
-        logits_a, ka, va = eng._prefill(model1.params, ids)
-        pk, pv, _, _ = eng._paged_scatter_prefill(
-            paged.k, paged.v, None, None, ka, va,
-            jnp.asarray(tables[0]), jnp.int32(0), None)
-        logits_b, kb, vb = eng._prefill(model1.params, ids[:, :4])
-        pad = ids.shape[1] - 4
-        kb = jnp.pad(kb, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-        vb = jnp.pad(vb, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-        pk, pv, _, _ = eng._paged_scatter_prefill(
-            pk, pv, None, None, kb, vb,
-            jnp.asarray(tables[2]), jnp.int32(0), None)
-        key = jax.random.PRNGKey(0)
-        toks_p = jnp.asarray([eng.sample_logits(logits_a, key)[0], 0,
-                              eng.sample_logits(logits_b, key)[0]], jnp.int32)
-        paged = dataclasses.replace(
-            paged, k=pk, v=pv,
-            lengths=jnp.asarray([ids.shape[1], 0, 4], jnp.int32))
-        out_p, _, paged, rem_p = eng.decode_steps_paged(
-            paged, toks_p, jnp.asarray([5, 0, 3], jnp.int32), 6)
-        results[backend] = (np.asarray(out_c), np.asarray(rem_c),
-                            np.asarray(out_p), np.asarray(rem_p))
-        if backend == "mega":
-            gauges = telemetry.snapshot()["gauges"]
-            assert "tdt_mega_ready_depth" in gauges
-            paths = {g["labels"]["path"]
-                     for g in gauges["tdt_mega_steps_per_launch"]}
-            assert paths == {"contiguous", "paged"}
-            counters = telemetry.snapshot()["counters"]
-            assert "tdt_mega_tasks_scheduled_total" in counters
-            assert "tdt_mega_fusion_hits_total" in counters
+    t0s, out, rem = _ragged_paged_chunk(Engine(model1, backend="mega", max_len=32))
+    gauges = telemetry.snapshot()["gauges"]
+    assert "tdt_mega_ready_depth" in gauges
+    paths = {g["labels"]["path"] for g in gauges["tdt_mega_steps_per_launch"]}
+    assert paths == {"paged"}
+    counters = telemetry.snapshot()["counters"]
+    assert "tdt_mega_tasks_scheduled_total" in counters
+    assert "tdt_mega_fusion_hits_total" in counters
 
-    for got, ref in zip(results["mega"], results["xla"]):
-        np.testing.assert_array_equal(got, ref)
-    # Inactive slot stayed masked the whole chunk.
-    assert (results["mega"][0][1] == -1).all()
+    _assert_ragged_chunk_is_serve(
+        Engine(model1, backend="xla", max_len=32), t0s, out)
+    np.testing.assert_array_equal(rem, [0, 0, 0])
 
 
 def test_ep_moe_serves_on_mega(model1):
@@ -705,58 +706,26 @@ def _skip_if_cpu_cant_interpret_collectives(exc: Exception):
 
 
 def test_mega_masked_paged_parity_world4(dense_model, monkeypatch):
-    """World-4 ragged-mask byte parity vs the op-by-op dist_ar path,
-    contiguous AND paged. TDT_FLASH_BLOCK_K pins the contiguous sweep's
-    block partition to the paged block size so the two table walks share
-    one online-softmax accumulation order (docs/megakernel.md parity
-    contract). On CPU the world-4 one-shot AR cannot interpret — the
-    test skips there and runs on hardware."""
-    import dataclasses
+    """World-4 ragged-mask byte parity of the paged chunk vs the op-by-op
+    dist_ar path, chunk against chunk and against dist_ar's one-shot serve.
+    TDT_FLASH_BLOCK_K pins the contiguous sweep's block partition to the
+    paged block size so the two table walks share one online-softmax
+    accumulation order (docs/megakernel.md parity contract). On CPU the
+    world-4 one-shot AR cannot interpret — the test skips there and runs
+    on hardware."""
     from triton_dist_tpu.models import Engine
 
     monkeypatch.setenv("TDT_FLASH_BLOCK_K", "8")
-    ids = jnp.asarray([[3, 17, 42, 7, 99, 5]], jnp.int32)
-    results = {}
     try:
-        for backend in ("dist_ar", "mega"):
-            eng = Engine(dense_model, backend=backend, max_len=32)
-            cache = eng.alloc_slots(3)
-            t_a, cache = eng.prefill_into_slot(cache, 0, ids)
-            t_b, cache = eng.prefill_into_slot(cache, 2, ids[:, :4])
-            toks = jnp.asarray([t_a, 0, t_b], jnp.int32)
-            out_c, _, cache, _ = eng.decode_steps(
-                cache, toks, jnp.asarray([5, 0, 3], jnp.int32), 6)
-
-            paged = eng.alloc_paged(3, block_size=8, num_blocks=32)
-            tables = np.zeros((3, paged.tables.shape[1]), np.int32)
-            tables[0, :4] = np.arange(1, 5)
-            tables[2, :4] = np.arange(5, 9)
-            paged = dataclasses.replace(paged, tables=jnp.asarray(tables))
-            logits_a, ka, va = eng._prefill(dense_model.params, ids)
-            pk, pv, _, _ = eng._paged_scatter_prefill(
-                paged.k, paged.v, None, None, ka, va,
-                jnp.asarray(tables[0]), jnp.int32(0), None)
-            logits_b, kb, vb = eng._prefill(dense_model.params, ids[:, :4])
-            pad = ids.shape[1] - 4
-            kb = jnp.pad(kb, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-            vb = jnp.pad(vb, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-            pk, pv, _, _ = eng._paged_scatter_prefill(
-                pk, pv, None, None, kb, vb,
-                jnp.asarray(tables[2]), jnp.int32(0), None)
-            key = jax.random.PRNGKey(0)
-            toks_p = jnp.asarray(
-                [eng.sample_logits(logits_a, key)[0], 0,
-                 eng.sample_logits(logits_b, key)[0]], jnp.int32)
-            paged = dataclasses.replace(
-                paged, k=pk, v=pv,
-                lengths=jnp.asarray([ids.shape[1], 0, 4], jnp.int32))
-            out_p, _, paged, _ = eng.decode_steps_paged(
-                paged, toks_p, jnp.asarray([5, 0, 3], jnp.int32), 6)
-            results[backend] = (np.asarray(out_c), np.asarray(out_p))
+        ref_eng = Engine(dense_model, backend="dist_ar", max_len=32)
+        t0s_r, out_r, _ = _ragged_paged_chunk(ref_eng)
+        t0s, out, _ = _ragged_paged_chunk(
+            Engine(dense_model, backend="mega", max_len=32))
+        _assert_ragged_chunk_is_serve(ref_eng, t0s, out)
     except NotImplementedError as e:
         _skip_if_cpu_cant_interpret_collectives(e)
-    for got, ref in zip(results["mega"], results["dist_ar"]):
-        np.testing.assert_array_equal(got, ref)
+    assert t0s == t0s_r
+    np.testing.assert_array_equal(out, out_r)
 
 
 def test_mega_decode_agrees_on_multi_axis_mesh(ctx24):

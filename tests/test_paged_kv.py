@@ -6,13 +6,11 @@ Host tier for the pure bookkeeping (``BlockAllocator``, ``PrefixIndex``,
 ``KVLedger``, scheduler admission); world=1 xla-backend serving (same
 harness as ``tests/test_serving.py``) for the end-to-end bars:
 
-* the paged DEFAULT server must produce byte-identical tokens to one-shot
+* the server must produce byte-identical tokens to one-shot
   ``Engine.serve`` — including when requests share a >=block_size prompt
   prefix (borrowed donor blocks) and when ``TDT_PREFILL_CHUNK`` splits
   prefills into several chunks (token-identical: multi-chunk GEMM
-  accumulation is not bitwise on logits, argmax is stable);
-* the ``TDT_SERVING_PAGED=0`` fallback must keep the legacy contiguous
-  behavior bit for bit.
+  accumulation is not bitwise on logits, argmax is stable).
 """
 
 import os
@@ -426,7 +424,7 @@ def test_server_prefix_reuse_hits_and_parity(engine):
     only the prefix index holds pool blocks."""
     refs = _references(engine, SHARED_REQUESTS)
     srv = InferenceServer(engine, num_slots=1, chunk=2)  # serialize joins
-    assert srv.paged and srv.kv_ledger is not None
+    assert srv.cache.block_size == srv.block_size == 16
     handles = [srv.submit(p, g) for p, g in SHARED_REQUESTS]
     srv.run()
     for h, ref in zip(handles, refs):
@@ -466,17 +464,3 @@ def test_chunked_prefill_staggered_parity(engine, monkeypatch):
     (entry,) = telemetry.snapshot()["histograms"]["tdt_serving_prefill_chunks"]
     assert entry["count"] == len(REQUESTS)
     assert entry["sum"] == float(sum(-(-len(p) // 3) for p, _ in REQUESTS))
-
-
-def test_slot_mode_fallback_matches_one_shot(engine, monkeypatch):
-    """TDT_SERVING_PAGED=0 restores the legacy contiguous slot cache —
-    byte-identical to one-shot serve, no ledger attached."""
-    monkeypatch.setenv("TDT_SERVING_PAGED", "0")
-    refs = _references(engine, REQUESTS)
-    srv = InferenceServer(engine, num_slots=3, chunk=2)
-    assert not srv.paged and srv.kv_ledger is None
-    handles = [srv.submit(p, g) for p, g in REQUESTS]
-    srv.run()
-    for h, ref in zip(handles, refs):
-        assert h.done
-        assert list(h.tokens) == ref

@@ -221,24 +221,33 @@ def test_pad_path_is_single_program(model1):
     assert float(jnp.abs(cache.v[:, :, :, 5:]).sum()) == 0.0
 
 
-def test_prefill_into_slot_and_masked_decode(model1):
+def test_paged_join_and_masked_decode(model1):
+    """Ragged masks through the programs that serve: joins of two lengths
+    into a three-slot pool, then one masked chunk whose streams are
+    ``Engine.serve``'s."""
+    from paged_drive import alloc_chains, join
+
     eng = make_engine(model1)
-    cache = eng.alloc_slots(3)
-    t0a, cache = eng.prefill_into_slot(cache, 0, jnp.asarray([[3, 17, 42, 7, 99]], jnp.int32))
-    t0c, cache = eng.prefill_into_slot(cache, 2, jnp.asarray([[8, 1, 13]], jnp.int32))
-    np.testing.assert_array_equal(np.asarray(cache.lengths), [5, 0, 3])
+    prompts = {0: [3, 17, 42, 7, 99], 2: [8, 1, 13]}
+    paged = alloc_chains(eng, 3)
+    t0a, paged = join(eng, paged, 0, prompts[0])
+    t0c, paged = join(eng, paged, 2, prompts[2])
+    np.testing.assert_array_equal(np.asarray(paged.lengths), [5, 0, 3])
     # Masked chunk: slot 1 is empty (inactive), slot 0 runs dry mid-chunk.
     remaining = jnp.asarray([2, 0, 3], jnp.int32)
-    tokens = jnp.asarray([int(t0a), 0, int(t0c)], jnp.int32)
-    out, last, cache, rem = eng.decode_steps(cache, tokens, remaining, chunk=3)
+    tokens = jnp.asarray([t0a, 0, t0c], jnp.int32)
+    out, last, paged, rem = eng.decode_steps_paged(paged, tokens, remaining, chunk=3)
     out = np.asarray(out)
     assert out.shape == (3, 3)
     # Inactive slots emit -1 sentinels; lengths freeze for them.
     assert (out[1] == -1).all()
-    assert (out[0, :2] != -1).all() and out[0, 2] == -1
-    assert (out[2] != -1).all()
-    np.testing.assert_array_equal(np.asarray(cache.lengths), [7, 0, 6])
+    assert out[0, 2] == -1
+    np.testing.assert_array_equal(np.asarray(paged.lengths), [7, 0, 6])
     np.testing.assert_array_equal(np.asarray(rem), [0, 0, 0])
+    for slot, t0, n in ((0, t0a, 2), (2, t0c, 3)):
+        ref = np.asarray(
+            eng.serve(jnp.asarray([prompts[slot]], jnp.int32), gen_len=n + 1))[0]
+        np.testing.assert_array_equal([t0, *out[slot, :n]], ref)
 
 
 # ======================================== acceptance: server vs one-shot

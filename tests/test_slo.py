@@ -309,18 +309,17 @@ def test_mid_decode_cancel_frees_slot_within_one_chunk(model1):
     assert telemetry.counter_value("tdt_serving_requests_completed_total") == 2.0
 
 
-def test_mid_decode_deadline_truncates_with_distinct_reason(model1):
+def test_mid_decode_deadline_truncates_with_distinct_reason(model1, monkeypatch):
     eng = make_engine(model1)
     srv = InferenceServer(eng, num_slots=1, chunk=1)
-    # Warm the prefill/chunk compiles first — a cold compile inside the
-    # request's budget would (correctly) expire it before decode starts.
-    warm = srv.submit([3, 17, 42], max_new=2)
-    srv.run()
-    assert warm.done
+    # The server's clock is the test's: what a loaded machine takes for a
+    # submit, a join and a chunk (compiles included) is none of the budget's.
+    clock = [0.0]
+    monkeypatch.setattr(srv, "_now", lambda: clock[0])
     r = srv.submit([3, 17, 42], max_new=20, deadline_s=0.3)
     assert srv.step()
     assert r.state is RequestState.RUNNING
-    time.sleep(0.35)  # blow the total budget mid-decode
+    clock[0] = 0.35  # blow the total budget mid-decode
     srv.step()  # reaped at the chunk boundary
     assert r.state is RequestState.DONE and r.finish_reason == "deadline"
     assert 0 < len(r.tokens) < 20  # truncated, not completed or dropped
@@ -328,10 +327,10 @@ def test_mid_decode_deadline_truncates_with_distinct_reason(model1):
     assert telemetry.counter_value(
         "tdt_serving_deadline_expiries_total", where="decode"
     ) == 1.0
-    # Only the warm-up stream counts as a completion.
-    assert telemetry.counter_value("tdt_serving_requests_completed_total") == 1.0
-    snap = telemetry.snapshot()["histograms"]
-    assert snap["tdt_serving_deadline_overrun_seconds"][0]["count"] == 1
+    # A truncated stream is no completion.
+    assert telemetry.counter_value("tdt_serving_requests_completed_total") == 0.0
+    (overrun,) = telemetry.snapshot()["histograms"]["tdt_serving_deadline_overrun_seconds"]
+    assert overrun["count"] == 1 and overrun["sum"] == pytest.approx(0.05)
 
 
 # ================================================== live SLO engine (PR 18)

@@ -1,5 +1,5 @@
 """Speculative decoding acceptance bar (``models/drafter.py`` +
-``Engine.spec_decode_steps[_paged]`` + the serving integration).
+``Engine.spec_decode_steps_paged`` + the serving integration).
 
 The contract under test (docs/speculative.md): greedy speculative decode
 is **byte-identical** to plain greedy decode — the k-wide verify step
@@ -7,10 +7,11 @@ scores every draft with the target's own decode program, emitted tokens
 are the target's argmaxes, and rejection rolls the paged pool back by a
 pure length rewind. Anchored here:
 
-* engine-level parity on the contiguous slot cache (truncated AND GDN
-  drafters — parity is drafter-independent by construction);
-* serving-loop parity across all four layout/backend configs
-  (xla/mega x paged/contiguous) with staggered joins, plus the
+* engine-level parity on the block pool (truncated AND GDN drafters —
+  parity is drafter-independent by construction);
+* serving-loop parity on both verify lowerings (xla: the contiguous bounce
+  with accepted rows scattered back; mega: the pool in place) with
+  staggered joins, plus the
   zero-recompile guarantee: one jit cache entry per (chunk, k) no matter
   how batch composition, kcap, or acceptance patterns move;
 * the rollback invariant, forced acceptance pattern by acceptance pattern
@@ -64,18 +65,26 @@ def model1():
 # =============================================== engine-level k-wide verify
 
 
-def _engine_reference(eng, prompts, gens):
-    """Plain batched ``decode_steps`` streams, one list per slot."""
-    cache = eng.alloc_slots(len(prompts))
+def _joined(eng, prompts):
+    """A pool with every prompt joined into its slot: (paged, token0s)."""
+    from paged_drive import alloc_chains, join
+
+    paged = alloc_chains(eng, len(prompts))
     toks = []
     for i, p in enumerate(prompts):
-        t0, cache = eng.prefill_into_slot(cache, i, jnp.asarray([p], jnp.int32))
-        toks.append(int(t0))
+        t0, paged = join(eng, paged, i, p)
+        toks.append(t0)
+    return paged, toks
+
+
+def _engine_reference(eng, prompts, gens):
+    """Plain batched ``decode_steps_paged`` streams, one list per slot."""
+    paged, toks = _joined(eng, prompts)
     last = jnp.asarray(toks, jnp.int32)
     remaining = jnp.asarray([g - 1 for g in gens], jnp.int32)
     ref = [[t] for t in toks]
     while int(jnp.max(remaining)) > 0:
-        out, last, cache, remaining = eng.decode_steps(cache, last, remaining, 3)
+        out, last, paged, remaining = eng.decode_steps_paged(paged, last, remaining, 3)
         o = np.asarray(out)
         for b in range(len(prompts)):
             ref[b].extend(int(x) for x in o[b] if x >= 0)
@@ -83,13 +92,13 @@ def _engine_reference(eng, prompts, gens):
 
 
 def _engine_spec_run(eng, drafter, prompts, gens, token0s, kcaps):
-    """Drive ``spec_decode_steps`` to completion; returns (streams, stats)."""
+    """Drive ``spec_decode_steps_paged`` to completion; returns (streams,
+    stats, the spec program's cache size after every chunk)."""
     B = len(prompts)
-    cache = eng.alloc_slots(B)
+    paged, toks = _joined(eng, prompts)
+    assert toks == token0s
     dstate = drafter.init_state(B)
     for i, p in enumerate(prompts):
-        t0, cache = eng.prefill_into_slot(cache, i, jnp.asarray([p], jnp.int32))
-        assert int(t0) == token0s[i]
         dstate = drafter.prefill_state(dstate, i, p)
     last = jnp.asarray(token0s, jnp.int32)
     remaining = jnp.asarray([g - 1 for g in gens], jnp.int32)
@@ -100,8 +109,8 @@ def _engine_spec_run(eng, drafter, prompts, gens, token0s, kcaps):
     while int(jnp.max(remaining)) > 0:
         # Vary the adaptive width mid-run: kcap is DATA, not a jit key.
         kcap = jnp.asarray(kcaps[min(it, len(kcaps) - 1)], jnp.int32)
-        out, last, cache, remaining, dstate, stats = eng.spec_decode_steps(
-            cache, dstate, last, remaining, kcap, 2, 3
+        out, last, paged, remaining, dstate, stats = eng.spec_decode_steps_paged(
+            paged, dstate, last, remaining, kcap, 2, 3
         )
         o = np.asarray(out)
         stats_tot += np.asarray(stats)
@@ -112,16 +121,19 @@ def _engine_spec_run(eng, drafter, prompts, gens, token0s, kcaps):
     return spec, stats_tot, sizes
 
 
-def test_spec_engine_parity_contiguous(model1):
+def test_spec_engine_parity_paged(model1):
     """Byte parity of the k-wide verify against plain greedy decode on the
-    contiguous slot cache — truncated AND GDN drafters, with kcap moving
-    mid-run and a single jit cache entry at the end (zero recompiles)."""
+    block pool — truncated AND GDN drafters, with kcap moving mid-run and a
+    single jit cache entry at the end (zero recompiles)."""
     from triton_dist_tpu.models import Engine, GDNDrafter, TruncatedDrafter
 
     prompts = [[3, 5, 7, 2], [11, 4, 9], [1, 2]]
     gens = [8, 6, 7]
     eng = Engine(model1, backend="xla", max_len=MAX_LEN)
     ref, token0s = _engine_reference(eng, prompts, gens)
+    for p, g, r in zip(prompts, gens, ref):
+        np.testing.assert_array_equal(
+            r, np.asarray(eng.serve(jnp.asarray([p], jnp.int32), gen_len=g))[0])
 
     eng2 = Engine(model1, backend="xla", max_len=MAX_LEN)
     dr = TruncatedDrafter(model1, num_layers=2, max_len=MAX_LEN, block_size=4)
@@ -169,15 +181,13 @@ def _one_shot_refs(eng):
 
 
 @pytest.mark.parametrize("backend", ["xla", "mega"])
-@pytest.mark.parametrize("paged", [1, 0])
-def test_spec_serving_parity_staggered(model1, monkeypatch, backend, paged):
+def test_spec_serving_parity_staggered(model1, backend):
     """The acceptance bar: a spec-enabled InferenceServer streams
     byte-identical tokens to one-shot non-speculative greedy serve, with
-    staggered joins, on every layout/backend config — and the whole run
-    compiles the spec chunk exactly once."""
+    staggered joins, on both verify lowerings — and the whole run compiles
+    the spec chunk exactly once."""
     from triton_dist_tpu.models import Engine
 
-    monkeypatch.setenv("TDT_SERVING_PAGED", str(paged))
     eng = Engine(model1, backend=backend, max_len=MAX_LEN)
     refs = _one_shot_refs(eng)
     telemetry.reset()
@@ -220,13 +230,12 @@ def test_spec_serving_parity_staggered(model1, monkeypatch, backend, paged):
 
     # Zero-recompile in steady state: a SECOND wave of the same requests in
     # reversed arrival order (different batch composition, different
-    # join/finish interleaving, fresh kcap/EWMA trajectories, paged-mode
-    # prefix-cache HITS this time) must not grow the spec program's cache —
+    # join/finish interleaving, fresh kcap/EWMA trajectories, prefix-cache
+    # HITS this time) must not grow the spec program's cache —
     # (chunk, k) are the only static keys. Captured AFTER wave 1 because the
     # C++ fast-path cache key-splits on argument committed-ness (same single
     # trace — see the engine-level test), and all variants appear in wave 1.
-    jfn = (eng2._spec_chunk_paged if (backend == "mega" and paged)
-           else eng2._spec_chunk)
+    jfn = eng2._spec_chunk_paged if backend == "mega" else eng2._spec_chunk
     steady = jfn._cache_size()
     wave2 = list(reversed(REQUESTS))
     handles2 = [srv.submit(p, g, on_token=on_token) for p, g in wave2]
@@ -305,7 +314,6 @@ def test_spec_rollback_pool_invariants(model1, monkeypatch, schedule):
     from triton_dist_tpu.models import Engine, ScriptedDrafter
 
     prompt, max_new = [3, 5, 7, 2], 10
-    monkeypatch.setenv("TDT_SERVING_PAGED", "1")
     # Pin kcap at spec_k: the EWMA can never fall below 0.0, so adaptive
     # backoff stays out of the way of the forced schedule.
     monkeypatch.setenv("TDT_SPEC_MIN_ACCEPT", "0.0")
@@ -382,7 +390,6 @@ def test_spec_chaos_abort_mid_verify_restores_mega(model1, monkeypatch):
     from triton_dist_tpu.models import Engine
 
     monkeypatch.setenv("TDT_DEGRADE_PROBE_S", "0.01")
-    monkeypatch.setenv("TDT_SERVING_PAGED", "1")
     telemetry.reset()
     resilience.reset_degradation()
     requests = [

@@ -79,7 +79,7 @@ class ModelBuilder:
                                 else default_schedule_policy())
         self.batch_hint = batch_hint
         self.ctx_hint = ctx_hint
-        self.paged = paged
+        self._paged = paged
         self.moe_impl = moe_impl
         self.graph = TaskGraph()
         self.plan: list[str] = []
@@ -148,13 +148,13 @@ class ModelBuilder:
           (``flash_decode_append``, in-VMEM splice of the new token) runs
           first and the HBM cache scatter is a SEPARATE task depending only
           on k/v — the scoreboard defers it behind later-ready work.
-        * ``self.paged``: the cache tasks take ``input:active`` +
+        * ``self._paged``: the cache tasks take ``input:active`` +
           ``input:tables`` data operands and scatter/walk the block pool
           (scatter must precede the walk — a paged write has no in-VMEM
           splice to hide behind, so the classic chain order stands).
         """
         g = self.graph
-        if self.paged:
+        if self._paged:
             g.add(Task(f"cache_update{tag}", "cache_update",
                        (f"v:k{tag}", f"v:v{tag}", kc_in, vc_in, "input:lengths",
                         "input:active", "input:tables"),
@@ -220,7 +220,7 @@ class ModelBuilder:
         vc_in = "input:vc" if i == 0 else f"v:vc2@{i - 1}"
         self.make_attn_front(tag=tag, x_in=x_in)
         self.make_attn_back(tag=tag, x_in=x_in, kc_in=kc_in, vc_in=vc_in,
-                            split_sweep=not self.paged)
+                            split_sweep=not self._paged)
         if getattr(self.config, "is_moe", False):
             self.make_moe_block(tag=tag)
         else:
@@ -335,7 +335,7 @@ class ModelBuilder:
         last = num_layers - 1
         final_out = f"v:x2@{last}"
         kc_out, vc_out = f"v:kc2@{last}", f"v:vc2@{last}"
-        paged = self.paged
+        paged = self._paged
 
         def step_fn(layers, x, ks, vs, lengths, active=None, tables=None):
             env = {"input:x": x, "input:pos": lengths, "input:lengths": lengths,
@@ -355,34 +355,31 @@ class ModelBuilder:
         return step_fn
 
     def build_verify_fn(self, num_layers: int, k: int):
-        """Speculative k-wide verify program: the persistent step graph of
-        ``build_step_fn`` replayed ``k`` times inside ONE launch. Sub-step
-        ``j`` scores column ``j`` of each slot's draft window at position
-        ``lengths + min(j, steps)`` — ``steps`` (B,) is the per-slot
-        participating width, flowing as DATA (like the paged path's masks
-        and tables), so one compiled program covers every acceptance
-        pattern, batch composition and adaptive-k backoff state; the jit
-        cache above is keyed on ``k`` alone. In paged mode each sub-step's
-        active mask is ``j < steps``: a non-participating slot's cache
-        write redirects to the NULL block and its attention bound stays at
-        its frozen length, exactly the non-speculative inactive-slot
-        contract. Returns ``verify_fn(layers, xs (B, k, d), ks, vs,
-        lengths, steps, tables=None) -> (x2 (B, k, d), ks, vs)``."""
+        """Speculative k-wide verify program: the persistent paged step
+        graph of ``build_step_fn`` replayed ``k`` times inside ONE launch.
+        Sub-step ``j`` scores column ``j`` of each slot's draft window at
+        position ``lengths + min(j, steps)`` — ``steps`` (B,) is the
+        per-slot participating width, flowing as DATA (like the masks and
+        tables), so one compiled program covers every acceptance pattern,
+        batch composition and adaptive-k backoff state; the jit cache above
+        is keyed on ``k`` alone. Each sub-step's active mask is
+        ``j < steps``: a non-participating slot's cache write redirects to
+        the NULL block and its attention bound stays at its frozen length,
+        exactly the non-speculative inactive-slot contract. Returns
+        ``verify_fn(layers, xs (B, k, d), pk, pv, lengths, steps, tables)
+        -> (x2 (B, k, d), pk, pv)``."""
+        if not self._paged:
+            raise ValueError("the verify program decodes the block pool: paged=True")
         step_fn = self.build_step_fn(num_layers)
-        paged = self.paged
 
-        def verify_fn(layers, xs, ks, vs, lengths, steps, tables=None):
+        def verify_fn(layers, xs, pk, pv, lengths, steps, tables):
             outs = []
             for j in range(k):
                 pos = lengths + jnp.minimum(jnp.int32(j), steps)
-                if paged:
-                    act = j < steps
-                    x, ks, vs = step_fn(layers, xs[:, j], ks, vs, pos,
-                                        active=act, tables=tables)
-                else:
-                    x, ks, vs = step_fn(layers, xs[:, j], ks, vs, pos)
+                x, pk, pv = step_fn(layers, xs[:, j], pk, pv, pos,
+                                    active=j < steps, tables=tables)
                 outs.append(x)
-            return jnp.stack(outs, axis=1), ks, vs
+            return jnp.stack(outs, axis=1), pk, pv
 
         verify_fn.plan = step_fn.plan
         return verify_fn
@@ -440,7 +437,7 @@ class ModelBuilder:
                 env[out_v] = v.reshape(b, hkv, hd)
             return fused_attn_front
 
-        if gname == "attn_back" and self.paged:
+        if gname == "attn_back" and self._paged:
             # [cache_update(k,v,pk,pv,len,active,tables), flash_decode(·),
             #  linear_allreduce(·, wo), add(x, ·)] — pool scatter + block-
             #  table walk + o-proj partial in one jit step (the walk is the
@@ -686,7 +683,7 @@ class ModelBuilder:
                 env[t.outputs[2]] = h3[:, hq + hkv :]
             return standalone_rope
 
-        if op == "cache_update" and self.paged:
+        if op == "cache_update" and self._paged:
             def standalone_cache_update_paged(env, lp, t=task):
                 k_new, v_new = env[t.inputs[0]], env[t.inputs[1]]
                 pk, env_li = env[t.inputs[2]]
@@ -718,7 +715,7 @@ class ModelBuilder:
                 env[t.outputs[1]] = (vs, li_)
             return standalone_cache_update
 
-        if op == "flash_decode" and self.paged:
+        if op == "flash_decode" and self._paged:
             def standalone_paged_flash_decode(env, lp, t=task):
                 q = env[t.inputs[0]]
                 pk, env_li = env[t.inputs[1]]

@@ -461,31 +461,15 @@ class DenseLLM:
             outs.append(logits)
         return jnp.stack(outs, axis=1), ks, vs
 
-    def verify_shard_mega(self, p: DenseParams, mega_layers: list, tokens,
-                          ks, vs, lengths, steps):
-        """Megakernel k-wide verify: the persistent step graph replayed k
-        times inside ONE launch (``build_verify_fn``), plus a single fused
-        norm+head over all B·k scored positions."""
-        c = self.config
-        k = tokens.shape[1]
-        vfn = self._mega_builder().build_verify_fn(c.num_layers, k)
-        xs = p.embed[tokens]  # (B, k, d)
-        x2, ks, vs = vfn(mega_layers, xs, ks, vs, lengths, steps)
-        from triton_dist_tpu.megakernel.kernels import fused_norm_head
-
-        b = x2.shape[0]
-        logits = fused_norm_head(
-            x2.reshape(b * k, -1), p.final_norm, p.lm_head, eps=c.rms_eps
-        )
-        return logits.reshape(b, k, -1), ks, vs
-
     def verify_shard_mega_paged(self, p: DenseParams, mega_layers: list, tokens,
                                 pk, pv, tables, lengths, steps):
-        """Paged megakernel k-wide verify: same replayed step graph over the
-        block pools — per-sub-step masks derive from ``steps`` as data, so
-        one compiled program serves every acceptance pattern and batch
-        composition (jit cache keyed on k alone). Non-participating
-        sub-steps write to the NULL block."""
+        """Megakernel k-wide verify: the persistent step graph replayed k
+        times inside ONE launch (``build_verify_fn``) over the block pools,
+        plus a single fused norm+head over all B·k scored positions.
+        Per-sub-step masks derive from ``steps`` as data, so one compiled
+        program serves every acceptance pattern and batch composition (jit
+        cache keyed on k alone). Non-participating sub-steps write to the
+        NULL block."""
         c = self.config
         k = tokens.shape[1]
         vfn = self._mega_builder(paged=True).build_verify_fn(c.num_layers, k)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from triton_dist_tpu.models.dense import DenseLLM
 from triton_dist_tpu.models.kv_cache import KVCache, PagedKVCache
 from triton_dist_tpu.models.quant import QuantPool, dequantize_kv, quantize_kv_rows
 from triton_dist_tpu.runtime import telemetry, tracing
@@ -79,9 +78,11 @@ def sample_token(
 
 
 class Engine:
-    """Reference ``Engine`` (``models/engine.py:37``)."""
+    """Reference ``Engine`` (``models/engine.py:37``). ``model`` is any
+    class that brings what docs/serving.md lists under "What a model brings
+    to be served" (``DenseLLM`` and its MoE subclasses, ``LatentSparseLLM``)."""
 
-    def __init__(self, model: DenseLLM, backend: str = "dist", max_len: int = 512,
+    def __init__(self, model, backend: str = "dist", max_len: int = 512,
                  sample: str = "greedy", temperature: float = 1.0, top_p: float = 1.0):
         assert backend in _BACKENDS, backend
         self.model = model
@@ -247,23 +248,9 @@ class Engine:
             )
 
             # Speculative k-wide verify: the persistent step graph replayed
-            # k times inside ONE shard_map launch (build_verify_fn) — the
-            # per-slot participating width rides as data, so the jit cache
-            # above keys on (chunk, k) alone.
-            def verify_fn(params, mega, tokens, ks, vs, lengths, steps):
-                logits, ks, vs = model.verify_shard_mega(
-                    params, mega, tokens, ks, vs, lengths, steps
-                )
-                return jax.lax.all_gather(logits, axis, axis=2, tiled=True), ks, vs
-
-            self._verify_shard = jax.shard_map(
-                verify_fn, mesh=mesh,
-                in_specs=(p_specs, mega_specs, tok_spec, kv_spec, kv_spec,
-                          len_spec, len_spec),
-                out_specs=(tok_spec, kv_spec, kv_spec),
-                check_vma=False,
-            )
-
+            # k times inside ONE shard_map launch (build_verify_fn) against
+            # the block pool — the per-slot participating width rides as
+            # data, so the jit cache above keys on (chunk, k) alone.
             def verify_paged_fn(params, mega, tokens, pk, pv, tables, lengths, steps):
                 logits, pk, pv = model.verify_shard_mega_paged(
                     params, mega, tokens, pk, pv, tables, lengths, steps
@@ -277,6 +264,7 @@ class Engine:
                 out_specs=(tok_spec, pool_spec, pool_spec),
                 check_vma=False,
             )
+            self._verify_shard = None
         else:
             def decode_fn(params, token, ks, vs, lengths):
                 logits, ks, vs = model.decode_shard(params, token, ks, vs, lengths, decode_mode)
@@ -389,16 +377,8 @@ class Engine:
 
         self._generate = generate
 
-        # ---- step-granular serving programs (serving/ subsystem) ----------
-        # Everything below stays FIXED-SHAPE: slot index and prompt length
-        # are traced scalars, the KV update operand is always the full
-        # padded (L, 1, Hkv, max_len, D) buffer, and the decode chunk is one
-        # compiled program per chunk size — batch composition (which slots
-        # are live, how long each prompt was) never recompiles. Defined in
-        # _build so a degraded-mode rebuild refreshes them alongside
-        # prefill/generate (fresh closures retrace with the new backend).
+        # The one-shot path's cache: prefill's K/V padded to max_len.
         max_len = self.max_len
-        len_sharding = ctx.sharding(*len_spec)
 
         def pad_to_max(k, v):
             shape = k.shape[:3] + (max_len,) + k.shape[4:]
@@ -411,18 +391,17 @@ class Engine:
             pad_to_max, out_shardings=(self._kv_sharding, self._kv_sharding)
         )
 
-        def scatter_slot(kb, vb, kn, vn, lengths, slot, seq):
-            return (
-                jax.lax.dynamic_update_slice(kb, kn, (0, slot, 0, 0, 0)),
-                jax.lax.dynamic_update_slice(vb, vn, (0, slot, 0, 0, 0)),
-                jax.lax.dynamic_update_slice(lengths, seq[None], (slot,)),
-            )
+        # ---- step-granular serving programs (serving/ subsystem) ----------
+        # Everything below stays FIXED-SHAPE: block tables, lengths and the
+        # active mask are data, pool and buffer shapes are static, and the
+        # decode chunk is one compiled program per chunk size — batch
+        # composition (which slots are live, how long each prompt was)
+        # never recompiles. Defined in _build so a degraded-mode rebuild
+        # refreshes them alongside prefill/generate (fresh closures retrace
+        # with the new backend).
 
-        self._scatter_slot = jax.jit(
-            scatter_slot, donate_argnums=(0, 1),
-            out_shardings=(self._kv_sharding, self._kv_sharding, len_sharding),
-        )
-
+        # The contiguous chunk: what a pp mesh's paged decode bounces
+        # through (see decode_steps_paged).
         @partial(jax.jit, static_argnums=(7,), donate_argnums=(3, 4))
         def decode_chunk(params, extra, token, ks, vs, lengths, remaining, chunk, key):
             bsz = token.shape[0]
@@ -440,9 +419,8 @@ class Engine:
                 # still flows through the fixed-shape batch, but the junk it
                 # produces is masked out of the output, their lengths freeze
                 # (the KVCache.inc_offset active-mask rule), and the only KV
-                # it writes lands at the frozen `lengths` position — the
-                # slot's next unwritten row, fully overwritten by the next
-                # tenant's prefill scatter.
+                # it writes lands at the frozen `lengths` position of the
+                # bounce buffer — a row the scatter-back masks to NULL.
                 nxt = jnp.where(active, nxt, token)
                 out = out.at[:, i].set(jnp.where(active, nxt, jnp.int32(-1)))
                 step = active.astype(lengths.dtype)
@@ -456,8 +434,8 @@ class Engine:
 
         self._decode_chunk = decode_chunk
 
-        # Paged twin of decode_chunk, the decode of every backend that has a
-        # paged step (all but a pp mesh): same active-mask/re-feed/freeze
+        # The serving decode of every backend that has a paged step (all
+        # but a pp mesh): decode_chunk's active-mask/re-feed/freeze
         # semantics per step, but the carry is the POOL pair and the block
         # tables ride as data — one compiled program per chunk size, zero
         # recompiles across batch compositions.
@@ -509,10 +487,7 @@ class Engine:
             )[0]
         )
 
-        # ---- paged-KV serving programs (block pool + tables) --------------
-        # The paged layout splits the slot cache into a global block pool;
-        # everything below keeps the fixed-shape discipline: block tables
-        # are DATA (int32 operands) and pool/buffer shapes are static.
+        # ---- prefill into the pool, and the two bounces ------------------
         # Decode runs against the pool in place (decode_chunk_paged). Two
         # paths still bounce through the contiguous layout — gather →
         # contiguous chunk → masked scatter-back of the written rows: a pp
@@ -667,12 +642,12 @@ class Engine:
             rows in the contiguous bounce buffer never reach the pool, and
             a pp mesh's plain chunk the rows its active mask let through.
             Masked rows redirect to the NULL block — a freed slot's old
-            blocks may already belong to another tenant, so the contiguous
-            mode's "harmless junk write" would be cross-slot corruption
-            here. With ``wire`` set the pool is quantized: each NEW row
-            quantizes exactly once here (payload + per-row scale scatter
-            together); rows already in the pool are never touched, so
-            shared prefix blocks stay bitwise-stable."""
+            blocks may already belong to another tenant, so a junk write
+            through its table would be cross-slot corruption. With ``wire``
+            set the pool is quantized: each NEW row quantizes exactly once
+            here (payload + per-row scale scatter together); rows already
+            in the pool are never touched, so shared prefix blocks stay
+            bitwise-stable."""
             bs = pk.shape[3]
             b = tables.shape[0]
             smax = kc.shape[3]
@@ -718,7 +693,7 @@ class Engine:
         lengths = jnp.full((ks.shape[1],), seq, jnp.int32)
         return KVCache(k=ks, v=vs, lengths=lengths)
 
-    # ------------------------------------------------- serving (slot-granular)
+    # ---------------------------------------------------------------- serving
     def _phase(self, name: str, t0: float, *arrays) -> float:
         """Stamp one step-phase digest (``tdt_engine_phase_seconds``) and
         return a fresh timestamp for the next phase. When ``arrays`` are
@@ -736,49 +711,6 @@ class Engine:
         )
         return now
 
-    def alloc_slots(self, num_slots: int) -> KVCache:
-        """Fresh zeroed KV for a fixed batch of ``num_slots`` serving slots
-        (each slot owns a full max_len row — the scheduler's KV budget)."""
-        c = self.model.config
-        return KVCache.create(
-            c.num_layers, num_slots, c.num_kv_heads, self.max_len, c.head_dim,
-            dtype=jnp.dtype(c.dtype), sharding=self._kv_sharding,
-        )
-
-    def prefill_into_slot(self, cache: KVCache, slot: int, input_ids: jax.Array,
-                          key: jax.Array | None = None):
-        """Prefill ONE request (bsz=1) and scatter its KV into slot ``slot``
-        of the serving cache — the join step of continuous batching.
-
-        Returns ``(token0, cache')``: token0 is the request's first
-        generated token, sampled from the prefill logits exactly as
-        ``serve`` does, and cache' has the slot's lengths set to the prompt
-        length. The scatter writes the full padded max_len row, so slot
-        reuse never sees a previous tenant's KV. The slot index is a traced
-        scalar — joining into a different slot never recompiles."""
-        bsz, seq = input_ids.shape
-        assert bsz == 1, "prefill_into_slot joins one request at a time"
-        assert seq <= self.max_len
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        timed = telemetry.enabled()
-        t = time.perf_counter() if timed else 0.0
-        logits, ks, vs = self._prefill(self.model.params, input_ids)
-        if seq < self.max_len:
-            ks, vs = self._pad_to_max(ks, vs)
-        k2, v2, lengths = self._scatter_slot(
-            cache.k, cache.v, ks, vs, cache.lengths,
-            jnp.int32(slot), jnp.int32(seq),
-        )
-        key, sub = jax.random.split(key)
-        token0 = sample_token(logits, sub, self.sample_method, self.temperature, self.top_p)
-        if timed:
-            # Admission: prefill + slot scatter + token-0 sample — the full
-            # cost of joining one request into the running batch.
-            self._phase("admission", t, token0)
-        return token0[0], KVCache(k=k2, v=v2, lengths=lengths)
-
-    # ------------------------------------------------ serving (paged blocks)
     def alloc_paged(self, num_slots: int, *, block_size: int,
                     num_blocks: int, quant: str | None = None) -> PagedKVCache:
         """Fresh paged KV: a global (num_blocks, block_size) pool + per-slot
@@ -786,17 +718,12 @@ class Engine:
         block (see ``BlockAllocator``); the pool is zeroed so null reads are
         finite. ``quant`` ("int8"/"fp8") stores the pool in the wire dtype
         with a parallel per-row scale pool (``models/quant.py``)."""
-        cache = PagedKVCache.create(
+        return PagedKVCache.create(
             self.model.cache_rows(), num_slots, block_size=block_size,
             num_blocks=num_blocks, max_len=self.max_len,
             dtype=jnp.dtype(self.model.config.dtype),
             sharding=self._pool_sharding, quant=quant,
         )
-        for kind, nbytes in cache.bytes_per_block_by_kind.items():
-            telemetry.set_gauge(
-                "tdt_kv_pool_bytes", float(nbytes * num_blocks), kind=kind
-            )
-        return cache
 
     @staticmethod
     def _pool_pair(paged: PagedKVCache):
@@ -862,8 +789,8 @@ class Engine:
                 jnp.int32(off), jnp.int32(last_idx),
             )
             if timed:
-                # Admission (paged): each prefill chunk's compute — the
-                # chunked analog of prefill_into_slot's join cost.
+                # Admission: each prefill chunk's compute, the cost of
+                # joining one request into the running batch.
                 self._phase("admission", t, logits)
                 self.model.publish_step_stats(stats)
         return logits, kb, vb
@@ -890,12 +817,16 @@ class Engine:
     def decode_steps_paged(self, paged: PagedKVCache, tokens: jax.Array,
                            remaining: jax.Array, chunk: int,
                            key: jax.Array | None = None):
-        """Paged analog of ``decode_steps``. The chunk runs DIRECTLY against
-        the block pool: the chunk program carries the pool pair as its loop
-        state and donates it, each layer of each step writes its one new
-        K/V row through the table (an inactive slot's to the NULL block) and
-        attention reads K/V through the table inside the kernel — no
-        contiguous cache is built, copied or scattered back. Only a pp mesh,
+        """Run ``chunk`` decode steps over the slot batch with a per-slot
+        active mask (``remaining > 0``): finished/free slots neither advance
+        their lengths nor contribute sampled tokens (their output cells hold
+        -1). One compiled program per chunk size. The chunk runs DIRECTLY
+        against the block pool: the chunk program carries the pool pair as
+        its loop state and donates it (callers replace their handle with
+        paged'), each layer of each step writes its one new K/V row through
+        the table (an inactive slot's to the NULL block) and attention reads
+        K/V through the table inside the kernel — no contiguous cache is
+        built, copied or scattered back. Only a pp mesh,
         whose stage-sliced step has no paged twin, still gathers the pool
         into the contiguous layout, runs ``self._decode_chunk`` and scatters
         the chunk's written rows back with the null-block mask.
@@ -982,9 +913,9 @@ class Engine:
         )
 
     def sample_logits(self, logits: jax.Array, key: jax.Array) -> jax.Array:
-        """Sample with the engine's configured method — the chunked-prefill
-        token-0 sample must go through the exact same path as
-        ``prefill_into_slot``'s for byte parity."""
+        """Sample with the engine's configured method: a join's token 0
+        goes through the very ``sample_token`` call that ``serve`` and the
+        decode chunk make, which byte parity with them rests on."""
         with tracing.span_current("tdt_engine_sample_logits"):
             return sample_token(
                 logits, key, self.sample_method, self.temperature, self.top_p
@@ -1050,32 +981,34 @@ class Engine:
             )
             return (out, token, store, lengths + adv, remaining - adv, dstate, stats)
 
-        @partial(jax.jit, static_argnums=(9, 10), donate_argnums=(4, 5))
-        def spec_chunk(params, extra, dparams, token, ks, vs, lengths,
-                       remaining, kcap, chunk, k, dstate):
-            bsz = token.shape[0]
-            out0 = jnp.full((bsz, chunk * k), -1, jnp.int32)
-            stats0 = jnp.zeros((bsz, 3), jnp.int32)
+        if self._verify_shard_paged is None:
+            # Op-by-op backends: the rounds run on the contiguous bounce of
+            # spec_decode_steps_paged.
+            @partial(jax.jit, static_argnums=(9, 10), donate_argnums=(4, 5))
+            def spec_chunk(params, extra, dparams, token, ks, vs, lengths,
+                           remaining, kcap, chunk, k, dstate):
+                bsz = token.shape[0]
+                out0 = jnp.full((bsz, chunk * k), -1, jnp.int32)
+                stats0 = jnp.zeros((bsz, 3), jnp.int32)
 
-            def verify(win, store, lengths, ec):
-                ks, vs = store
-                logits, ks, vs = self._verify_shard(
-                    params, extra, win, ks, vs, lengths, ec
+                def verify(win, store, lengths, ec):
+                    ks, vs = store
+                    logits, ks, vs = self._verify_shard(
+                        params, extra, win, ks, vs, lengths, ec
+                    )
+                    return logits, (ks, vs)
+
+                def body(r, carry):
+                    return spec_round(r, carry, dparams, kcap, k, verify)
+
+                carry = (out0, token, (ks, vs), lengths, remaining, dstate, stats0)
+                out, token, (ks, vs), lengths, remaining, dstate, stats = (
+                    jax.lax.fori_loop(0, chunk, body, carry)
                 )
-                return logits, (ks, vs)
+                return out, token, ks, vs, lengths, remaining, dstate, stats
 
-            def body(r, carry):
-                return spec_round(r, carry, dparams, kcap, k, verify)
-
-            carry = (out0, token, (ks, vs), lengths, remaining, dstate, stats0)
-            out, token, (ks, vs), lengths, remaining, dstate, stats = (
-                jax.lax.fori_loop(0, chunk, body, carry)
-            )
-            return out, token, ks, vs, lengths, remaining, dstate, stats
-
-        self._spec_chunk = spec_chunk
-
-        if self._verify_shard_paged is not None:
+            self._spec_chunk = spec_chunk
+        else:
             @partial(jax.jit, static_argnums=(10, 11), donate_argnums=(4, 5))
             def spec_chunk_paged(params, extra, dparams, token, pk, pv, tables,
                                  lengths, remaining, kcap, chunk, k, dstate):
@@ -1100,46 +1033,21 @@ class Engine:
                 return out, token, pk, pv, lengths, remaining, dstate, stats
 
             self._spec_chunk_paged = spec_chunk_paged
-        else:
-            self._spec_chunk_paged = None
-
-    def spec_decode_steps(self, cache: KVCache, dstate, tokens: jax.Array,
-                          remaining: jax.Array, kcap: jax.Array, chunk: int,
-                          k: int, key: jax.Array | None = None):
-        """Speculative twin of ``decode_steps``: ``chunk`` spec rounds, each
-        accepting 1..k tokens per active slot. Returns ``(out (B, chunk·k)
-        int32 with -1 holes, last_tokens, cache', remaining', dstate',
-        stats (B, 3) [proposed, accepted, rounds])``. ``key`` is accepted
-        for call-site symmetry and unused — spec decode is greedy-only."""
-        del key
-        assert self._drafter is not None, "attach_drafter first"
-        timed = telemetry.enabled()
-        t = time.perf_counter() if timed else 0.0
-        out, tok, k2, v2, lengths, rem, dstate, stats = self._spec_chunk(
-            self.model.params, self._decode_extra, self._drafter.params,
-            tokens, cache.k, cache.v, cache.lengths, remaining, kcap,
-            int(chunk), int(k), dstate,
-        )
-        if self.backend == "mega":
-            telemetry.set_gauge(
-                "tdt_mega_steps_per_launch", float(chunk * k), path="spec"
-            )
-        if timed:
-            # The fused propose+verify rounds; contiguous layout commits
-            # in place, so there is no spec_commit phase here.
-            self._phase("spec_propose", t, tok)
-        return out, tok, KVCache(k=k2, v=v2, lengths=lengths), rem, dstate, stats
 
     def spec_decode_steps_paged(self, paged: PagedKVCache, dstate,
                                 tokens: jax.Array, remaining: jax.Array,
                                 kcap: jax.Array, chunk: int, k: int,
                                 key: jax.Array | None = None):
-        """Speculative twin of ``decode_steps_paged``. Mega runs the spec
-        rounds directly against the block pool (tables + per-sub-step masks
-        as data); op-by-op backends bounce through the contiguous layout
-        and scatter back ONLY the accepted rows (``paged_scatter_rows`` with
+        """Speculative twin of ``decode_steps_paged``: ``chunk`` spec rounds,
+        each accepting 1..k tokens per active slot. Mega runs the rounds
+        directly against the block pool (tables + per-sub-step masks as
+        data); op-by-op backends bounce through the contiguous layout and
+        scatter back ONLY the accepted rows (``paged_scatter_rows`` with
         the data-driven count ``lengths' - lengths0``) — the pool never
-        holds a rejected draft's KV."""
+        holds a rejected draft's KV. Returns ``(out (B, chunk·k) int32 with
+        -1 holes, last_tokens, paged', remaining', dstate', stats (B, 3)
+        [proposed, accepted, rounds])``. ``key`` is accepted for call-site
+        symmetry and unused — spec decode is greedy-only."""
         del key
         assert self._drafter is not None, "attach_drafter first"
         timed = telemetry.enabled()
@@ -1180,36 +1088,6 @@ class Engine:
         return out, tok, dataclasses.replace(
             paged, k=pk, v=pv, k_scale=ks, v_scale=vs, lengths=lengths
         ), rem, dstate, stats
-
-    def decode_steps(self, cache: KVCache, tokens: jax.Array, remaining: jax.Array,
-                     chunk: int, key: jax.Array | None = None):
-        """Run ``chunk`` decode steps over the slot batch with a per-slot
-        active mask (``remaining > 0``): finished/free slots neither advance
-        their lengths nor contribute sampled tokens (their output cells hold
-        -1). One compiled program per chunk size.
-
-        Returns ``(out (B, chunk) int32, last_tokens (B,), cache',
-        remaining')``. ``cache.k``/``cache.v`` are donated — callers must
-        replace their handle with cache'."""
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        if self.backend == "mega":
-            # The whole chunk is `chunk` dispatches of ONE fused step
-            # program (the persistent-step graph) inside a single on-device
-            # fori_loop launch.
-            telemetry.set_gauge(
-                "tdt_mega_steps_per_launch", float(chunk), path="contiguous"
-            )
-        timed = telemetry.enabled()
-        t = time.perf_counter() if timed else 0.0
-        out, tok, k2, v2, lengths, rem = self._decode_chunk(
-            self.model.params, self._decode_extra, tokens, cache.k, cache.v,
-            cache.lengths, remaining, int(chunk), key,
-        )
-        if timed:
-            t = self._phase("dispatch", t)
-            self._phase("host_sync", t, tok)
-        return out, tok, KVCache(k=k2, v=v2, lengths=lengths), rem
 
     # ----------------------------------------------------------------- serve
     def serve(self, input_ids: jax.Array, gen_len: int, key: jax.Array | None = None,
@@ -1378,7 +1256,7 @@ class Engine:
         return (long_ - short_) / (iters - short_iters)
 
 
-def bench_decode_table(model: DenseLLM, backends=_BACKENDS, bsz: int = 1,
+def bench_decode_table(model, backends=_BACKENDS, bsz: int = 1,
                        prompt_len: int = 64, iters: int = 20, max_len: int = 512):
     """Per-backend decode latency comparison (the reference's e2e table,
     ``e2e_dense.md``): {backend: seconds/token}."""
@@ -1390,7 +1268,7 @@ def bench_decode_table(model: DenseLLM, backends=_BACKENDS, bsz: int = 1,
     }
 
 
-def modelspecs(model: DenseLLM):
+def modelspecs(model):
     """Parameter PartitionSpec pytree for ``model``. Models with a custom
     layout (the EP MoE model's expert-sharded slabs, ``models/moe.py``)
     override via a ``param_specs`` method; default is the dense/TP layout."""

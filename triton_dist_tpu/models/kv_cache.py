@@ -22,15 +22,6 @@ class KVCache:
     v: jax.Array
     lengths: jax.Array  # (B,) int32
 
-    @staticmethod
-    def create(num_layers, bsz, num_kv_heads, max_len, head_dim, dtype=jnp.bfloat16, sharding=None):
-        shape = (num_layers, bsz, num_kv_heads, max_len, head_dim)
-        if sharding is not None:
-            zeros = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)()
-        else:
-            zeros = jnp.zeros(shape, dtype)
-        return KVCache(k=zeros, v=jnp.copy(zeros), lengths=jnp.zeros((bsz,), jnp.int32))
-
     @property
     def max_len(self) -> int:
         return self.k.shape[3]
@@ -85,7 +76,7 @@ class BlockAllocator:
     tables the serving layer builds from the chains handed out here.
     Block 0 is the NULL block: it is never allocated, so table rows can
     point masked or out-of-range writes at it without corrupting a
-    tenant (the paged analog of the slot cache's harmless-garbage row).
+    tenant.
 
     Refcounts make prefix sharing safe: a block chain owned by the radix
     index and referenced by N running slots has refcount N+1; ``free``

@@ -3,11 +3,12 @@
 Iteration-level (Orca-style, Yu et al. OSDI'22) scheduling over a FIXED
 batch of B slots: requests join the running batch whenever a slot frees up
 instead of waiting for the whole batch to drain, and short requests stop
-consuming decode steps the moment they finish. The KV side is the TPU
-analog of vLLM's slot management (Kwon et al., SOSP'23) flattened to fixed
-shapes: every slot owns one full ``max_len`` KV row (no paging — XLA/jit
-wants static shapes), so admission is a per-request budget check rather
-than a block-allocator walk.
+consuming decode steps the moment they finish. The KV side is vLLM's
+block management (Kwon et al., SOSP'23) under fixed shapes: a global pool
+of blocks, a block table a slot that travels to the device as data, and a
+:class:`KVLedger` here on the host that owns the free list, the refcounts
+and the prefix index, so admission is a reservation of the request's whole
+chain.
 
 State machines::
 
@@ -24,11 +25,8 @@ walk degrades to exactly the old FCFS. A request whose (synthetic)
 arrival lies in the future never blocks one behind it that has already
 arrived.
 
-Admission contract (KV-budget aware). Without a :class:`KVLedger` (legacy
-slot mode), a request is admitted only when ``len(prompt) + max_new <=
-max_len`` — the whole generation must fit the slot's fixed KV row — and
-oversized requests are rejected at submit with ``reason="kv_budget"``.
-With a ledger attached (paged mode), the budget is BLOCKS:
+Admission contract (KV-budget aware). The server always attaches a
+:class:`KVLedger`, and the budget is BLOCKS:
 ``blocks_needed(prompt, max_new) = ceil((len(prompt)+max_new)/block_size)``
 must fit the pool outright (else ``kv_budget_hard`` at submit — it can
 NEVER fit), and at join time the ledger must actually reserve the chain —
@@ -37,7 +35,10 @@ in-flight frees is *parked* (``kv_wait``), not rejected, and is exempt
 from queue-time deadline expiry while parked (it is one eviction away
 from admission, not doomed). A full bounded queue still rejects with
 ``reason="queue_full"``. Either way a running request can NEVER run out
-of cache mid-decode.
+of cache mid-decode. A bare ``Scheduler`` built without a ledger, as its
+unit tests build it, checks ``len(prompt) + max_new <= max_len`` alone and
+rejects with ``reason="kv_budget"`` (making the ledger mandatory is
+ROADMAP D6's remainder).
 
 SLO guardrails (all optional, all enforced BEFORE a slot is spent):
 
@@ -59,7 +60,7 @@ SLO guardrails (all optional, all enforced BEFORE a slot is spent):
   boundary. Terminal requests are never re-finalized (no double-free).
 
 The scheduler is pure host-side bookkeeping — it never touches jax. The
-device work (prefill scatter, masked decode chunks) lives in
+device work (chunked prefill, masked decode chunks) lives in
 ``models/engine.py``; the loop that drives both is ``InferenceServer``.
 Telemetry: ``tdt_serving_queue_depth`` / ``tdt_serving_slot_occupancy``
 gauges track every transition, counters are listed in ``docs/serving.md``.
@@ -602,9 +603,9 @@ class Scheduler:
         assert num_slots >= 1 and max_len >= 2
         self.num_slots = num_slots
         self.max_len = max_len
-        #: Paged-KV block ledger (None = legacy slot-row budget). When set,
-        #: ``join_free_slots`` reserves each request's block chain
-        #: atomically with admission.
+        #: Block ledger (None only in unit tests of the bare scheduler: a
+        #: ``max_len`` budget check then). When set, ``join_free_slots``
+        #: reserves each request's block chain atomically with admission.
         self.kv_ledger = kv_ledger
         self.queue_limit = queue_limit  # 0 = unbounded
         #: Global projected-wait shed budget, seconds (0 = only per-request

@@ -1,12 +1,17 @@
 """Streaming inference server: the host loop of continuous batching.
 
 ``InferenceServer`` drives one :class:`~triton_dist_tpu.models.engine.Engine`
-with the step-granular programs it exposes (``prefill_into_slot``,
-``decode_steps``) under a :class:`~triton_dist_tpu.serving.scheduler.Scheduler`:
+with the step-granular programs it exposes (``prefill_chunk``,
+``complete_paged_prefill``, ``decode_steps_paged``) under a
+:class:`~triton_dist_tpu.serving.scheduler.Scheduler`, over ONE serving
+cache: a global block pool + per-slot block tables
+(:class:`~triton_dist_tpu.models.kv_cache.PagedKVCache`):
 
 * **join** — every loop iteration first admits arrived requests (FCFS) into
-  free slots: per-request prefill, scatter into the slot's KV row, stream
-  the first sampled token (TTFT is measured to this point);
+  free slots and arms their prefill; the prefill then advances one chunk of
+  ``TDT_PREFILL_CHUNK`` rows an iteration, and its last chunk scatters the
+  prompt's KV into the slot's block chain and streams the first sampled
+  token (TTFT is measured to this point);
 * **decode chunk** — then runs ``TDT_SERVE_CHUNK`` decode steps over the
   whole slot batch as ONE device dispatch with a per-slot active mask, and
   streams each slot's newly valid tokens to its ``on_token`` callback.
@@ -14,17 +19,18 @@ with the step-granular programs it exposes (``prefill_into_slot``,
   smaller chunks tighten join latency for requests arriving mid-decode.
 
 Everything the device sees is fixed-shape (one compile per chunk size, one
-prefill compile per distinct prompt length, one scatter program total), so
-a slot batch whose composition changes every chunk never recompiles — the
-jit analog of the reference engine's per-token CUDA-graph replay, lifted to
-iteration-level scheduling.
+prefill compile per distinct (chunk, prompt) length pair, one scatter
+program per prompt length; block tables are data), so a slot batch whose
+composition changes every chunk never recompiles — the jit analog of the
+reference engine's per-token CUDA-graph replay, lifted to iteration-level
+scheduling.
 
 **Degraded-mode recovery without dropping the queue**: a bounded-wait abort
 (``CollectiveAbortError`` via ``resilience.consume_status``) or a
 ``CollectiveWatchdog`` timeout surfacing from a join or a decode chunk
 triggers :meth:`InferenceServer._recover`: the engine rebuilds on the
 ``xla`` backend (the feature's circuit breaker OPENs, same contract as
-``Engine.serve``), a fresh slot cache is allocated (the aborted dispatch
+``Engine.serve``), a fresh pool is allocated (the aborted dispatch
 may have poisoned or consumed the donated buffers), and every in-flight
 slot re-prefills from its token history ``prompt + tokens[:-1]`` — the
 re-prefill's sampled token is discarded (it was already streamed), so
@@ -36,9 +42,9 @@ cache before surfacing.
 **Un-degrade via half-open probes**: while the engine runs degraded, every
 :meth:`step` first asks ``resilience.probe_due()`` whether a breaker's
 backoff has elapsed; if so the preferred backend is rebuilt and probed with
-ONE sandboxed dispatch (a throwaway 1-slot cache, under
-``resilience.probe_scope`` so only the probing thread sees the feature
-healthy). A successful probe CLOSEs the breaker and
+ONE sandboxed join and decode step through the programs that serve (on a
+throwaway 1-slot pool, under ``resilience.probe_scope`` so only the probing
+thread sees the feature healthy). A successful probe CLOSEs the breaker and
 :meth:`_restore_streams` re-resolves routing for live traffic — fresh
 cache, re-prefill from history, zero stream disruption (the same machinery
 as recovery, pointed back at the fused path). A failed probe re-opens the
@@ -56,7 +62,7 @@ with a journal attached (``journal=`` or ``TDT_JOURNAL_DIR``) the server
 journals every request lifecycle transition; after a process crash a fresh
 server pointed at the same journal calls :meth:`recover` — queued requests
 are re-admitted, in-flight requests re-prefill from ``prompt + journaled
-tokens`` (the recovery branch of :meth:`_prefill_slot`), and completed
+tokens`` (the recovery branch of :meth:`_complete_prefill`), and completed
 requests are skipped idempotently. **Rank death** (heartbeat lease expiry
 on the ``mesh.HealthBoard``, or a scripted chaos ``die@<rank>``) is
 discovered by the per-step health sweep or by the trace-time ``dead_peer``
@@ -68,25 +74,21 @@ no per-collective timeout storm — and resume every stream from history.
 joins with reason ``shutting_down``, drains (or journals) running slots,
 flushes the journal + dumps telemetry, and stops the introspect endpoint.
 
-**Paged KV with prefix reuse and chunked prefill** (default ON,
-``TDT_SERVING_PAGED=0`` restores the slot-row cache): the serving cache
-becomes a global block pool + per-slot block tables
-(:class:`~triton_dist_tpu.models.kv_cache.PagedKVCache`), admission becomes
-a block-budget reservation through the scheduler's
+**Paged KV with prefix reuse and chunked prefill**: admission is a
+block-budget reservation through the scheduler's
 :class:`~triton_dist_tpu.serving.scheduler.KVLedger` (prefix-index eviction,
 ``kv_wait`` parking), prompts sharing a block-aligned prefix reuse the
 donor's KV blocks via the radix index, and prefill runs as incremental
-chunks (``TDT_PREFILL_CHUNK`` rows per dispatch) interleaved with decode —
-a long prompt joining mid-decode stalls the decode stream at most ONE chunk
-boundary. Prompts no longer than the chunk knob prefill in one chunk sized
-exactly to the prompt, which is bitwise-identical to the one-shot prefill
-program; see ``docs/serving.md`` for the full parity contract.
+chunks interleaved with decode — a long prompt joining mid-decode stalls
+the decode stream at most ONE chunk boundary. Prompts no longer than the
+chunk knob prefill in one chunk sized exactly to the prompt, which is
+bitwise-identical to the one-shot prefill program; see ``docs/serving.md``
+for the full parity contract.
 
 Env knobs::
 
     TDT_SERVE_SLOTS       fixed slot-batch size B (default 4)
     TDT_SERVE_CHUNK       decode steps per device dispatch (default 8)
-    TDT_SERVING_PAGED     paged block-pool serving (default 1; 0 = slot rows)
     TDT_KV_BLOCK_SIZE     KV block size, token rows per block (default 16)
     TDT_KV_BLOCKS         pool size incl. the null block (default: every
                           slot can hold a full max_len chain, + 1)
@@ -178,37 +180,31 @@ class InferenceServer:
         self._preferred_backend = getattr(
             engine, "preferred_backend", engine.backend
         )
-        #: Paged-KV serving (block pool + prefix reuse + chunked prefill).
-        #: Default ON; TDT_SERVING_PAGED=0 restores the slot-row cache.
-        self.paged = get_int_env("TDT_SERVING_PAGED", 1) != 0
-        self.kv_ledger: KVLedger | None = None
-        if self.paged:
-            self.block_size = get_int_env("TDT_KV_BLOCK_SIZE", 16)
-            assert self.block_size >= 1
-            max_blocks = -(-engine.max_len // self.block_size)
-            # Default pool: every slot can hold a FULL max_len chain at
-            # once (+1 for the reserved null block) — zero eviction
-            # pressure, strictly more admittable than slot mode. Size it
-            # down (TDT_KV_BLOCKS) to trade capacity for memory; prefix
-            # sharing and kv_wait parking absorb the overcommit.
-            self.num_blocks = get_int_env(
-                "TDT_KV_BLOCKS", self.num_slots * max_blocks + 1
-            )
-            #: Prefill rows a chunk dispatch: the argument, else
-            #: TDT_PREFILL_CHUNK, else the whole prompt in one.
-            self.prefill_chunk = (
-                get_int_env("TDT_PREFILL_CHUNK", engine.max_len)
-                if prefill_chunk is None else int(prefill_chunk)
-            )
-            assert self.prefill_chunk >= 1
-            #: Quantized KV storage (TDT_QUANT_KV=int8|fp8): the pool holds
-            #: wire-dtype blocks + per-row scale pools; greedy streams stay
-            #: byte-identical across prefix sharing/CoW (quantize-once).
-            self.kv_quant = kv_quant_from_env()
-            self.kv_ledger = KVLedger(
-                self.num_blocks, self.block_size,
-                prefix_reuse=get_int_env("TDT_PREFIX_REUSE", 1) != 0,
-            )
+        self.block_size = get_int_env("TDT_KV_BLOCK_SIZE", 16)
+        assert self.block_size >= 1
+        max_blocks = -(-engine.max_len // self.block_size)
+        # Default pool: every slot can hold a FULL max_len chain at once
+        # (+1 for the reserved null block) — zero eviction pressure. Size
+        # it down (TDT_KV_BLOCKS) to trade capacity for memory; prefix
+        # sharing and kv_wait parking absorb the overcommit.
+        self.num_blocks = get_int_env(
+            "TDT_KV_BLOCKS", self.num_slots * max_blocks + 1
+        )
+        #: Prefill rows a chunk dispatch: the argument, else
+        #: TDT_PREFILL_CHUNK, else the whole prompt in one.
+        self.prefill_chunk = (
+            get_int_env("TDT_PREFILL_CHUNK", engine.max_len)
+            if prefill_chunk is None else int(prefill_chunk)
+        )
+        assert self.prefill_chunk >= 1
+        #: Quantized KV storage (TDT_QUANT_KV=int8|fp8): the pool holds
+        #: wire-dtype blocks + per-row scale pools; greedy streams stay
+        #: byte-identical across prefix sharing/CoW (quantize-once).
+        self.kv_quant = kv_quant_from_env()
+        self.kv_ledger = KVLedger(
+            self.num_blocks, self.block_size,
+            prefix_reuse=get_int_env("TDT_PREFIX_REUSE", 1) != 0,
+        )
         #: Disaggregated-pool role (``TDT_POOL_ROLE``, docs/disagg.md): a
         #: "prefill" replica parks finished prefills for handoff instead of
         #: decoding them; a "decode" replica receives parked KV over the
@@ -255,8 +251,8 @@ class InferenceServer:
         #: offset, context buffers, sampling key). One chunk per slot per
         #: step keeps decode within one chunk boundary of a long prompt.
         self._prefilling: dict[int, dict] = {}
-        #: Host mirror of per-slot KV lengths (paged mode: the device
-        #: ``lengths`` travel as data the host re-pushes with the tables).
+        #: Host mirror of per-slot KV lengths (the device ``lengths``
+        #: travel as data the host re-pushes with the tables).
         self._lengths = np.zeros((self.num_slots,), np.int32)
         # Process-level trace owning the spans no single request owns (the
         # loop's iterations and phases, shared decode dispatches, recovery).
@@ -271,7 +267,7 @@ class InferenceServer:
         self._last = np.zeros((self.num_slots,), np.int32)
         self._remaining = np.zeros((self.num_slots,), np.int32)
         self._key = jax.random.PRNGKey(0) if key is None else key
-        # retries=0: decode_steps donates the slot cache, so a timed-out
+        # retries=0: decode_steps_paged donates the pool, so a timed-out
         # attempt must NOT be re-dispatched on the same (now consumed)
         # buffers — recovery reallocates instead.
         self._watchdog = watchdog if watchdog is not None else (
@@ -329,7 +325,7 @@ class InferenceServer:
             self.engine.model,
             num_layers=layers if layers >= 1 else None,
             max_len=self.engine.max_len,
-            block_size=self.block_size if self.paged else 16,
+            block_size=self.block_size,
         )
 
     def _spec_prefill(self, idx: int, ids) -> None:
@@ -385,14 +381,11 @@ class InferenceServer:
                         if req.ttft_deadline_s is not None
                         and req.first_token_at is None else None
                     ),
+                    kv_blocks=len(req.kv_blocks),
+                    kv_prefix_shared=req.kv_shared,
+                    kv_len=int(self._lengths[slot.idx]),
+                    prefilling=slot.idx in self._prefilling,
                 )
-                if self.paged:
-                    entry.update(
-                        kv_blocks=len(req.kv_blocks),
-                        kv_prefix_shared=req.kv_shared,
-                        kv_len=int(self._lengths[slot.idx]),
-                        prefilling=slot.idx in self._prefilling,
-                    )
                 if req.prefill_only:
                     entry["prefill_only"] = True
                 if self.spec_k >= 2:
@@ -404,7 +397,7 @@ class InferenceServer:
                     )
             slots.append(entry)
         return {
-            **({"kv": self.kv_ledger.stats()} if self.kv_ledger else {}),
+            "kv": self.kv_ledger.stats(),
             **({"spec": {
                 "k": self.spec_k,
                 "min_accept": self.spec_min_accept,
@@ -494,13 +487,9 @@ class InferenceServer:
         ``trace_ctx`` (an extracted ``tracing.SpanContext``) makes the
         request trace continue a remote caller's trace — the fleet replica
         passes the router's propagated context through here.
-        ``prefill_only`` (paged mode only) runs prefill + the first token
-        and then parks the KV chain for a disaggregated handoff instead of
-        decoding — see docs/disagg.md."""
-        if prefill_only and not self.paged:
-            raise ValueError(
-                "prefill_only requires paged serving (TDT_SERVING_PAGED=1)"
-            )
+        ``prefill_only`` runs prefill + the first token and then parks the
+        KV chain for a disaggregated handoff instead of decoding — see
+        docs/disagg.md."""
         req = self.scheduler.submit(
             prompt, max_new, arrival_time_s=arrival_time_s,
             on_token=on_token, on_finish=on_finish, now_s=self._now(),
@@ -580,12 +569,12 @@ class InferenceServer:
         can serve it off the loop thread."""
         prompt = [int(t) for t in prompt]
         warm = 0
-        if self.kv_ledger is not None and self.kv_ledger.prefix_reuse:
+        if self.kv_ledger.prefix_reuse:
             warm = self.kv_ledger.prefix.match_blocks(prompt, tenant)
         est = self.scheduler.est_wait_s()
         return {
             "warm_blocks": warm,
-            "block_size": self.block_size if self.paged else 0,
+            "block_size": self.block_size,
             "est_wait_s": None if est is None else round(est, 6),
             "backlog_tokens": self.scheduler.backlog_tokens(),
             "queue_depth": self.scheduler.queue_depth(),
@@ -674,10 +663,6 @@ class InferenceServer:
         is consumed on first application, so a crash after admission falls
         back to re-deriving the same KV from the journaled token history —
         the stream stays byte-identical either way."""
-        if not self.paged:
-            raise ValueError(
-                "KV import requires paged serving (TDT_SERVING_PAGED=1)"
-            )
         payload = unpack_kv_blocks(kv_blob)
         toks = [int(t) for t in tokens][: int(max_new)]
         if not toks:
@@ -760,9 +745,9 @@ class InferenceServer:
 
     # --------------------------------------------------------------- paged KV
     def _fresh_cache(self):
-        """Allocate the serving KV cache — and, on the paged path, resync
-        every piece of host bookkeeping to the empty pool (recovery and
-        restore reallocate mid-flight).
+        """Allocate the serving KV cache and resync every piece of host
+        bookkeeping to the empty pool (recovery and restore reallocate
+        mid-flight).
 
         A fresh pool holds NO valid content, so the prefix index must
         forget its donor blocks and every surviving tenant must own its
@@ -775,8 +760,6 @@ class InferenceServer:
             # Speculative state is never durable: a fresh cache always
             # pairs with a drafter reset + per-slot re-prefill from history.
             self._dstate = self._drafter.init_state(self.num_slots)
-        if not self.paged:
-            return self.engine.alloc_slots(self.num_slots)
         self._prefilling.clear()
         self._lengths = np.zeros((self.num_slots,), np.int32)
         led = self.kv_ledger
@@ -814,6 +797,10 @@ class InferenceServer:
         # scale pools) — quantized pools admit more chains per byte and the
         # ledger/gauges must reflect that, not the logical block count.
         led.set_bytes_per_block(self.cache.bytes_per_block)
+        for kind, nbytes in self.cache.bytes_per_block_by_kind.items():
+            telemetry.set_gauge(
+                "tdt_kv_pool_bytes", float(nbytes * self.num_blocks), kind=kind
+            )
         self._push_tables()
         self._publish_kv_gauges()
         return self.cache
@@ -871,79 +858,31 @@ class InferenceServer:
                 # still in PREFILL, and must re-prefill from them.
                 if slot.request is None or slot.state is not SlotState.PREFILL:
                     continue
-                # Paged mode only ARMS the chunked prefill here; the per-step
+                # The join only ARMS the chunked prefill; the per-step
                 # _advance_prefills sweep advances it one chunk at a time.
-                target = self._begin_prefill if self.paged else self._prefill_slot
-                self._guarded(lambda s=slot: target(s),
+                self._guarded(lambda s=slot: self._begin_prefill(s),
                               what=f"join of request {slot.request.req_id}")
         if joined:
             telemetry.inc("tdt_serving_joins_total", float(len(joined)))
         return bool(joined)
 
-    def _prefill_slot(self, slot: Slot) -> None:
-        """Prefill ``slot``'s tenant from its token history and arm decode.
-
-        Fresh join: history is just the prompt — sample + stream token0.
-        Recovery re-prefill: history is ``prompt + tokens[:-1]`` (the last
-        streamed token's KV is pending, exactly like a resumed decode) —
-        the prefill-sampled token is discarded, nothing streams twice."""
-        if self.paged:
-            # Synchronous variant for the recovery/restore paths: run the
-            # chunked prefill to completion before the next slot's turn.
-            self._begin_prefill(slot)
-            while slot.idx in self._prefilling:
-                self._advance_prefill(slot)
-            return
-        req = slot.request
-        ids = req.prompt + req.tokens[:-1]
-        # Scripted chaos site: "recovery" when re-prefilling from history
-        # (double-fault scenarios), "prefill" on a fresh join.
-        resilience.chaos_check("recovery" if req.tokens else "prefill")
-        self._key, sub = jax.random.split(self._key)
-        # The live span makes this request the AMBIENT trace while the
-        # prefill program traces/compiles — KernelTrace records collected
-        # during that compile correlate to this span (see telemetry.
-        # consume_kernel_trace).
-        with req.trace.span(
-            "tdt_serving_prefill", slot=slot.idx, hist_len=len(ids),
-            recovery=bool(req.tokens),
-        ):
-            token0, self.cache = self.engine.prefill_into_slot(
-                self.cache, slot.idx, jnp.asarray([ids], jnp.int32), key=sub
-            )
-        self._spec_prefill(slot.idx, ids)
-        if req.tokens:
-            self._last[slot.idx] = req.tokens[-1]
-            # Host decode state must derive from the durable history, not
-            # from retained process memory: a journal-recovered request
-            # arrives in a FRESH process where _remaining is all zeros.
-            self._remaining[slot.idx] = max(req.max_new - len(req.tokens), 0)
-            if slot.state is SlotState.PREFILL:
-                self.scheduler.start_decode(slot)
-            if self._remaining[slot.idx] == 0:
-                # Fully generated before the crash, only the finish record
-                # was lost — finalize now, nothing to decode.
-                self._finish(slot)
-            return
-        tok = int(token0)
-        self._last[slot.idx] = tok
-        self._remaining[slot.idx] = req.max_new - 1
-        self.scheduler.start_decode(slot)
-        self._stream(req, tok)
-        if self._journal is not None:
-            self._journal.append(
-                "prefill", req_id=req.req_id, start=0, tokens=[tok]
-            )
-        if self._remaining[slot.idx] == 0:
-            self._finish(slot)
+    def _prefill_to_completion(self, slot: Slot) -> None:
+        """The chunked prefill of ``slot``'s tenant run to its end before
+        the next slot's turn: what recovery and restore re-prefill with.
+        History is ``prompt + tokens[:-1]`` (the last streamed token's KV is
+        pending, exactly like a resumed decode) — the prefill-sampled token
+        is discarded, nothing streams twice."""
+        self._begin_prefill(slot)
+        while slot.idx in self._prefilling:
+            self._advance_prefill(slot)
 
     # ------------------------------------------------------- chunked prefill
     def _begin_prefill(self, slot: Slot) -> None:
-        """Arm a paged (chunked) prefill: seed the context buffer — from the
-        reused prefix chain when the ledger found one, zeros otherwise — and
-        queue the slot on the prefill cursor map. The sampling key is split
-        HERE, in join order, so the token stream matches the slot-mode
-        server byte-for-byte."""
+        """Arm a chunked prefill: seed the context buffer — from the reused
+        prefix chain when the ledger found one, zeros otherwise — and queue
+        the slot on the prefill cursor map. The sampling key is split HERE,
+        in join order, so the token stream does not depend on how the
+        chunks of several prefills interleave."""
         with self._trace.span("tdt_serving_prefill_arm", ring=False, slot=slot.idx):
             req = slot.request
             if req.kv_import is not None:
@@ -963,7 +902,8 @@ class InferenceServer:
                         error=f"{type(e).__name__}: {e}",
                     )
             ids = req.prompt + req.tokens[:-1]
-            # Scripted chaos site: same discriminator as the slot-mode prefill.
+            # Scripted chaos site: "recovery" when re-prefilling from history
+            # (double-fault scenarios), "prefill" on a fresh join.
             resilience.chaos_check("recovery" if req.tokens else "prefill")
             self._key, sub = jax.random.split(self._key)
             p_len = len(ids)
@@ -1028,8 +968,9 @@ class InferenceServer:
     def _complete_prefill(self, slot: Slot, st: dict, logits) -> None:
         """Finish a chunked prefill: scatter the context buffer into the
         pool along the slot's chain (shared prefix blocks stay the donor's),
-        publish the table row, then sample/stream token0 exactly as the
-        slot-mode join does."""
+        publish the table row, then sample/stream token0. A request that
+        arrives WITH tokens (recovery, restore, journal replay, migration)
+        arms decode from its history instead."""
         req = st["req"]
         del self._prefilling[slot.idx]
         p_len = len(st["ids"])
@@ -1049,13 +990,18 @@ class InferenceServer:
         telemetry.observe("tdt_serving_prefill_chunks", float(st["n_chunks"]))
         self._spec_prefill(slot.idx, st["ids"])
         if req.tokens:
-            # Recovery re-prefill: mirror the slot-mode branch — the last
-            # streamed token's KV is pending, nothing streams twice.
+            # Recovery re-prefill: the last streamed token's KV is pending,
+            # nothing streams twice. Host decode state must derive from the
+            # durable history, not from retained process memory: a
+            # journal-recovered request arrives in a FRESH process where
+            # _remaining is all zeros.
             self._last[slot.idx] = req.tokens[-1]
             self._remaining[slot.idx] = max(req.max_new - len(req.tokens), 0)
             if slot.state is SlotState.PREFILL:
                 self.scheduler.start_decode(slot)
             if self._remaining[slot.idx] == 0:
+                # Fully generated before the crash, only the finish record
+                # was lost — finalize now, nothing to decode.
                 self._finish(slot)
             elif req.prefill_only:
                 # A prefill-pool donor recovering mid-handoff re-parks: the
@@ -1164,12 +1110,8 @@ class InferenceServer:
         with self._trace.span(
             "tdt_serving_dispatch", n_active=len(decoding), chunk=self.chunk
         ) as dsp:
-            decode = (
-                self.engine.decode_steps_paged if self.paged
-                else self.engine.decode_steps
-            )
             out, tok, cache, _ = self._watchdog.call(
-                decode, self.cache,
+                self.engine.decode_steps_paged, self.cache,
                 jnp.asarray(self._last), jnp.asarray(self._remaining),
                 self.chunk, sub,
             )
@@ -1205,8 +1147,7 @@ class InferenceServer:
                             start=len(req.tokens) - n_valid, tokens=toks,
                         )
                 self._remaining[slot.idx] -= n_valid
-                if self.paged:
-                    self._lengths[slot.idx] += n_valid  # device updated in-chunk
+                self._lengths[slot.idx] += n_valid  # device updated in-chunk
                 n_streamed += n_valid
         # Finishes run AFTER every slot's host length mirror is advanced:
         # _finish pushes the mirror over the device lengths (wiping the
@@ -1260,8 +1201,7 @@ class InferenceServer:
         resilience.chaos_check("decode")
         decoding = self.scheduler.decoding_slots()
         pre = {s.idx: int(self._remaining[s.idx]) for s in decoding}
-        if self.paged:
-            self._pin_draft_blocks(decoding)
+        self._pin_draft_blocks(decoding)
         self._key, sub = jax.random.split(self._key)
         t0 = time.perf_counter()
         d_start = tracing.now_s()
@@ -1269,12 +1209,8 @@ class InferenceServer:
             "tdt_serving_dispatch", n_active=len(decoding), chunk=self.chunk,
             spec_k=self.spec_k,
         ) as dsp:
-            spec = (
-                self.engine.spec_decode_steps_paged if self.paged
-                else self.engine.spec_decode_steps
-            )
             out, tok, cache, _, dstate, stats = self._watchdog.call(
-                spec, self.cache, self._dstate,
+                self.engine.spec_decode_steps_paged, self.cache, self._dstate,
                 jnp.asarray(self._last), jnp.asarray(self._remaining),
                 jnp.asarray(self._kcap), self.chunk, self.spec_k, sub,
             )
@@ -1318,8 +1254,7 @@ class InferenceServer:
                         start=len(req.tokens) - n_valid, tokens=toks,
                     )
             self._remaining[slot.idx] -= n_valid
-            if self.paged:
-                self._lengths[slot.idx] += n_valid
+            self._lengths[slot.idx] += n_valid
             n_streamed += n_valid
             proposed, accepted, rounds = (int(x) for x in stats_np[slot.idx])
             n_proposed += proposed
@@ -1392,14 +1327,13 @@ class InferenceServer:
             self.scheduler.finish(slot)
             self.scheduler.release(slot)
             self._remaining[slot.idx] = 0
-            if self.paged:
-                # A cancel can land mid-prefill: drop the cursor (its context
-                # buffers die with it), return the chain, null the table row.
-                self._prefilling.pop(slot.idx, None)
-                self._lengths[slot.idx] = 0
-                self.kv_ledger.release(req)
-                self._push_tables()
-                self._publish_kv_gauges()
+            # A cancel can land mid-prefill: drop the cursor (its context
+            # buffers die with it), return the chain, null the table row.
+            self._prefilling.pop(slot.idx, None)
+            self._lengths[slot.idx] = 0
+            self.kv_ledger.release(req)
+            self._push_tables()
+            self._publish_kv_gauges()
             if self._journal is not None:
                 # "finish" always forces the fsync: a completed stream must be
                 # durable so recovery can skip it idempotently.
@@ -1494,10 +1428,10 @@ class InferenceServer:
             try:
                 for slot in occupied:
                     if slot.request is None:
-                        # Preempted back to the queue by the paged pool
-                        # fixup (_fresh_cache) — nothing to re-prefill.
+                        # Preempted back to the queue by the pool fixup
+                        # (_fresh_cache) — nothing to re-prefill.
                         continue
-                    self._prefill_slot(slot)
+                    self._prefill_to_completion(slot)
                 return
             except (resilience.CollectiveAbortError,
                     resilience.CollectiveTimeoutError) as e:
@@ -1531,7 +1465,7 @@ class InferenceServer:
         r_start = tracing.now_s()
         eng._degrade_to_xla(why)
         # The aborted dispatch consumed (donated) or may have poisoned the
-        # old slot cache — rebuild it whole from each tenant's durable
+        # old pool — rebuild it whole from each tenant's durable
         # token history. Queued requests ride along untouched.
         self.cache = self._fresh_cache()
         self._reprefill_occupied(occupied)
@@ -1554,10 +1488,10 @@ class InferenceServer:
     # ------------------------------------------------------- half-open probe
     def _maybe_probe(self) -> bool:
         """When running degraded and a breaker's backoff has elapsed, probe
-        the preferred backend with one sandboxed dispatch. Success closes
-        the breaker and restores live routing; failure re-opens it with
-        doubled backoff. Either way the serving cache is untouched — the
-        probe runs on a throwaway 1-slot cache."""
+        the preferred backend with one sandboxed join and decode step.
+        Success closes the breaker and restores live routing; failure
+        re-opens it with doubled backoff. Either way the serving cache is
+        untouched — the probe runs on a throwaway 1-slot pool."""
         if self.engine.backend == self._preferred_backend:
             return False
         if resilience.dead_ranks():
@@ -1578,15 +1512,7 @@ class InferenceServer:
                 with resilience.probe_scope(due):
                     self.engine.rebuild(self._preferred_backend)
                     resilience.chaos_check("probe")
-                    sandbox = self.engine.alloc_slots(1)
-                    token0, sandbox = self.engine.prefill_into_slot(
-                        sandbox, 0, jnp.asarray([[1, 2, 3]], jnp.int32)
-                    )
-                    out = self.engine.decode_steps(
-                        sandbox, jnp.asarray([int(token0)], jnp.int32),
-                        jnp.asarray([1], jnp.int32), 1,
-                    )
-                    jax.block_until_ready(out[0])
+                    self._probe_dispatch()
             except Exception as e:  # a probe must never kill the loop
                 ok, err = False, f"{type(e).__name__}: {e}"
         resilience.end_probe(due, ok=ok)
@@ -1599,9 +1525,41 @@ class InferenceServer:
             self.engine.rebuild("xla")
         return True
 
+    def _probe_dispatch(self) -> None:
+        """What a join and a chunk dispatch, once, on a throwaway one-slot
+        pool of one chain: the probe compiles and runs the programs that
+        serve (``chunk_fn``, ``paged_scatter_prefill``,
+        ``decode_chunk_paged``) before live streams are moved onto them.
+        Its sampling key is its own — the serving key stream is not the
+        probe's to advance."""
+        eng = self.engine
+        ids = np.asarray([[1, 2, 3]], np.int32)
+        p_len = ids.shape[1]
+        chain = -(-(p_len + 1) // self.block_size)  # the prompt + one step
+        sandbox = eng.alloc_paged(
+            1, block_size=self.block_size, num_blocks=chain + 1,
+            quant=self.kv_quant,
+        )
+        row = np.zeros((sandbox.max_blocks,), np.int32)
+        row[:chain] = np.arange(1, chain + 1)
+        kbuf, vbuf = eng.paged_kbuf_zeros(p_len)
+        logits, kbuf, vbuf = eng.prefill_chunk(
+            kbuf, vbuf, jnp.asarray(ids), 0, p_len - 1
+        )
+        sandbox = eng.complete_paged_prefill(sandbox, kbuf, vbuf, row, 0)
+        sandbox = dataclasses.replace(
+            sandbox, tables=jnp.asarray(row[None]),
+            lengths=jnp.asarray([p_len], jnp.int32),
+        )
+        token0 = eng.sample_logits(logits, jax.random.PRNGKey(0))
+        out = eng.decode_steps_paged(
+            sandbox, token0, jnp.asarray([1], jnp.int32), 1
+        )
+        jax.block_until_ready(out[0])
+
     def _restore_streams(self) -> None:
         """Re-resolve routing onto the (just-probed) preferred backend for
-        LIVE traffic without dropping a stream: fresh slot cache +
+        LIVE traffic without dropping a stream: fresh pool +
         re-prefill from history — the recovery machinery pointed back at
         the fused path."""
         occupied = self.scheduler.occupied_slots()
@@ -1673,9 +1631,9 @@ class InferenceServer:
                     outcome="skipped_duplicate",
                 )
                 continue
-            if len(rr.prompt) + rr.max_new > self.engine.max_len or (
-                self.kv_ledger is not None
-                and not self.kv_ledger.can_ever_fit(len(rr.prompt), rr.max_new)
+            if (
+                len(rr.prompt) + rr.max_new > self.engine.max_len
+                or not self.kv_ledger.can_ever_fit(len(rr.prompt), rr.max_new)
             ):
                 # The journal came from a server with a bigger KV row (or
                 # block pool); resuming here would abort mid-decode. Drop

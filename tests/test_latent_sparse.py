@@ -204,8 +204,8 @@ def test_expanded_equals_absorbed():
     w_uv = jax.random.normal(k[4], (c.kv_lora_rank, c.num_heads, c.v_head_dim)) / 4
     sel = jnp.asarray(np.random.default_rng(0).permutation(S)[:K])
     allowed = jnp.zeros((1, S), bool).at[0, sel].set(True)
-    exp = ls.attend_expanded(q_nope, q_rope, rows, allowed, jnp.int32(S - 1), w_uk, w_uv, c,
-                             head_group=2, key_block=16)  # several groups and blocks
+    exp = ls.attend_expanded_xla(q_nope, q_rope, rows, allowed, jnp.int32(S - 1), w_uk, w_uv, c,
+                                 head_group=2, key_block=16)  # several groups and blocks
     ab = ls.attend_absorbed(q_nope, q_rope, rows[sel][None], jnp.ones((1, K), bool), w_uk, w_uv, c)
     np.testing.assert_allclose(np.asarray(exp), np.asarray(ab), atol=2e-5, rtol=1e-5)
 
@@ -244,6 +244,44 @@ def test_tie_rows_counter_counts_the_rows_whose_ties_overflow(ctx, model):
         "tdt_dsa_positions_selected_total"] if e["labels"]["phase"] == "prefill")
     assert selected == len(CFG.index_layers) * sum(
         np.minimum(np.arange(1, n + 1), CFG.index_topk).sum() for n in prompts)
+
+
+def test_attend_tiles_counter_counts_the_tiles_of_the_mask(model, monkeypatch):
+    """``tdt_dsa_attend_tiles_total``: a chunk of 16 rows at offset 32 over a
+    buffer of 64, in tiles of 8 queries by 8 keys. ``under_diagonal`` is
+    arithmetic: the key tiles that hold a position up to the chunk's last,
+    times the query tiles, a layer. ``visited`` is what the masks hold: the
+    tiles in which a row allows anything, which leaves out at least the
+    tile above the first query tile's diagonal."""
+    from triton_dist_tpu.kernels import latent_flash
+
+    monkeypatch.setattr(latent_flash, "QUERY_TILE", 8)
+    monkeypatch.setattr(latent_flash, "KEY_TILE", 8)
+    C, P, off, t = 16, 64, 32, 8
+    masks = []
+    inner = ls.attend_expanded
+    monkeypatch.setattr(ls, "attend_expanded", lambda *a, **k: (
+        masks.append((np.asarray(a[3]), np.asarray(k["table"]))), inner(*a, **k))[1])
+    c = model.config
+    bufs = [jnp.zeros((r.layers, 1, r.heads, P, r.width), jnp.dtype(c.dtype))
+            for r in model.cache_rows()]
+    tokens = jnp.asarray([np.arange(C) % c.vocab_size], jnp.int32)
+    _, _, stats = model.prefill_chunk_shard(  # op by op: the masks are values
+        model.params, tokens, bufs[0], bufs[1], jnp.int32(off), jnp.int32(C - 1), "dist_ar")
+    visited, under = (int(x) for x in stats["attend_tiles"])
+    assert under == c.num_layers * (C // t) * ((off + C) // t) == 5 * 2 * 6
+    assert len(masks) == c.num_layers
+    nonempty = [m.reshape(C // t, t, P // t, t).any(axis=(1, 3)) for m, _ in masks]
+    assert all((n == table).all() for n, (_, table) in zip(nonempty, masks))
+    assert visited == sum(int(n.sum()) for n in nonempty)
+    assert 0 < visited <= under - c.num_layers  # rows 32..39 see no key of 40..47
+    # the selection binds: 16 of the 33..48 positions a row sees
+    assert all((m.sum(axis=1) == c.index_topk).all() for m, _ in masks)
+    telemetry.reset()
+    model.publish_step_stats(stats)
+    got = {e["labels"]["kind"]: e["value"]
+           for e in telemetry.snapshot()["counters"]["tdt_dsa_attend_tiles_total"]}
+    assert got == {"visited": visited, "under_diagonal": under}
 
 
 def _expert_layer(model, layer=1, rows=40, seed=0):

@@ -671,8 +671,20 @@ def _dsa_kth_value(sds):
     return (lambda x: kth_value(x, 2048)), (sds((2048, 16640), jnp.float32),)
 
 
+def _dsa_flash_prefill(sds):
+    """A prefill chunk's attention under the selection at the published
+    widths: 2048 query rows of 64 heads (192 + 64 and 256 wide) over the
+    longest prompt buffer's latent rows."""
+    from triton_dist_tpu.kernels.latent_flash import dsa_flash_prefill
+
+    return (lambda *a: dsa_flash_prefill(*a, 256 ** -0.5)), (
+        sds((2048, 64, 192)), sds((2048, 64, 64)), sds((16384, 576)),
+        sds((2048, 16384), jnp.bool_), sds((512, 64, 192)), sds((512, 64, 256)))
+
+
 @pytest.mark.parametrize(
-    "case", [_flash_decode, _paged_flash_decode, _flash_attention, _dsa_kth_value],
+    "case", [_flash_decode, _paged_flash_decode, _flash_attention, _dsa_kth_value,
+             _dsa_flash_prefill],
     ids=lambda f: f.__name__.lstrip("_"))
 def test_named_kernel_compiles_under_its_name(topo_2x2, case):
     """At Qwen3-8B head shapes, for one v5e chip: the kernel compiles and
@@ -687,8 +699,9 @@ def test_named_kernel_compiles_under_its_name(topo_2x2, case):
         assert "tpu_custom_call" in lowered.as_text()
         compiled = lowered.compile()
     name = case.__name__.lstrip("_")
-    calls = [l for l in compiled.as_text().splitlines() if "tpu_custom_call" in l]
-    assert calls and all(l.strip().startswith(f"%{name}") for l in calls), calls
+    calls = [l.strip().removeprefix("ROOT ") for l in compiled.as_text().splitlines()
+             if "tpu_custom_call" in l]
+    assert calls and all(l.startswith(f"%{name}") for l in calls), calls
 
 
 def test_quantized_pool_decodes_through_the_gather_on_the_chip(topo_2x2):
@@ -769,18 +782,16 @@ def _sorted_shapes(hlo: str) -> list[str]:
     return [re.search(r"\w+\[[\d,]*\]", t).group() for t in types]
 
 
-@pytest.mark.parametrize("p_len", [4096, 8192, 16384])
-def test_latent_sparse_chunk_selects_without_a_sort(topo_2x2, p_len):
-    """``longdoc``'s chunk program at each of its prompt lengths, for one
-    v5e chip: it fits beside the pools, each of its two selecting layers
-    finds the k-th index score in the ``dsa_kth_value`` kernel, and no sort
-    of the chunk's ``f32[2048, P]`` scores is left (XLA's lowering of
-    ``lax.top_k``, 27.6 ms a call at P 16384 on the chip). The sorts that
-    stay are the router's top 8 of 256 and the held experts' ordering."""
+@functools.lru_cache(maxsize=None)
+def _longdoc_chunk_program(topo, p_len: int):
+    """``longdoc``'s chunk program over a prompt buffer of ``p_len`` rows,
+    compiled for one described v5e chip (once a length: the tests below
+    share it). -> (configuration, compiled HLO text, its ``tpu_custom_call``
+    lines, bytes the device holds while it runs beside the pools)."""
     from triton_dist_tpu.models.engine import Engine
     from triton_dist_tpu.runtime.platform import force_mosaic
 
-    model, params, cfg = _abstract_latent_sparse(topo_2x2.devices[:1])
+    model, params, cfg = _abstract_latent_sparse(topo.devices[:1])
     c, sv = model.config, cfg["serving"]
     rows = int(sv["prefill_chunk"])
     assert rows == c.index_topk == 2048
@@ -793,16 +804,45 @@ def test_latent_sparse_chunk_selects_without_a_sort(topo_2x2, p_len):
         compiled, held = _compile(eng._prefill_chunk_prog.lower(
             params, i32((1, rows)), buf(c.num_layers, c.latent_row),
             buf(len(c.index_layers), c.index_head_dim), i32(()), i32(())),
-            kernels=("dsa_kth_value",))
+            kernels=("dsa_kth_value", "dsa_flash_prefill"))
     pools = int(sv["slots"]) * int(sv["max_len"]) * sum(
         r.layers * r.heads * r.width for r in model.cache_rows()) * 2
-    assert held + pools < HBM_BYTES, held
     hlo = compiled.as_text()
-    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l]
-    assert len(calls) == len(c.index_layers) and all(
-        l.strip().startswith("%dsa_kth_value") for l in calls), calls
-    assert f"f32[{rows},{p_len}]" not in _sorted_shapes(hlo), _sorted_shapes(hlo)
+    calls = [l.strip() for l in hlo.splitlines() if "tpu_custom_call" in l]
+    return c, hlo, calls, held + pools
+
+
+@pytest.mark.parametrize("p_len", [4096, 8192, 16384])
+def test_latent_sparse_chunk_selects_without_a_sort(topo_2x2, p_len):
+    """``longdoc``'s chunk program at each of its prompt lengths, for one
+    v5e chip: it fits beside the pools, each of its two selecting layers
+    finds the k-th index score in the ``dsa_kth_value`` kernel, and no sort
+    of the chunk's ``f32[2048, P]`` scores is left (XLA's lowering of
+    ``lax.top_k``, 27.6 ms a call at P 16384 on the chip). The sorts that
+    stay are the router's top 8 of 256 and the held experts' ordering."""
+    c, hlo, calls, held = _longdoc_chunk_program(topo_2x2, p_len)
+    assert held < HBM_BYTES, held
+    assert sum(l.startswith("%dsa_kth_value") for l in calls) == len(c.index_layers), calls
+    assert f"f32[2048,{p_len}]" not in _sorted_shapes(hlo), _sorted_shapes(hlo)
     # ... and the search does find the parent's, by its line in the ledger
     was = ("  %sort.34 = (f32[2048,16384]{1,0:T(8,128)}, s32[2048,16384]{1,0:T(8,128)}) "
            "sort(%fusion.1, %iota.2), dimensions={1}, is_stable=true")
     assert _sorted_shapes(was) == ["f32[2048,16384]"]
+
+
+@pytest.mark.parametrize("p_len", [4096, 8192, 16384])
+def test_latent_sparse_chunk_attends_without_a_score_matrix(topo_2x2, p_len):
+    """The same programs: every layer's attention under the mask is one call
+    of the ``dsa_flash_prefill`` kernel, the selection's kernel is the only
+    other one, and no value of the XLA body's score matrix's shape, 16 heads
+    by 2048 queries by 2048 keys in float32 (268 MB through HBM three times
+    a key block and head group; 0.077 s a fusion in the ledger's PR 30
+    trace), is left in the program."""
+    c, hlo, calls, _ = _longdoc_chunk_program(topo_2x2, p_len)
+    flash = [l for l in calls if l.startswith("%dsa_flash_prefill")]
+    assert len(flash) == c.num_layers == 5, calls
+    assert len(calls) == c.num_layers + len(c.index_layers), calls
+    assert "f32[16,2048,2048]" not in hlo
+    # ... and the search does find the parent's, by its line in the ledger
+    was = "%fusion.431 = f32[16,2048,2048]{2,1,0:T(8,128)} fusion(%bitcast.9, %p.1), kind=kLoop"
+    assert "f32[16,2048,2048]" in was

@@ -1,13 +1,15 @@
 """Latent attention, a learned sparse selection, and a sigmoid-routed expert
 layer that is told which experts it holds: the shard-local math of
 ``models/latent_sparse.py``, in plain XLA but for the selection's k-th
-value (``kernels/kth_value.py``; the cell that runs these shows which part
-a later change should replace next).
+value (``kernels/kth_value.py``) and a prefill chunk's attention under the
+selection (``kernels/latent_flash.py``); the cell that runs these shows
+which part a later change should replace next.
 
 * **Latent attention.** A token's cache row is ``[c_kv | k_r]``: the
   normalised KV latent and one roped key part shared by all heads. Prefill
   attends in the *expanded* form (per-head K and V made from the rows, a
-  block of keys at a time, online softmax), decode in the *absorbed* form
+  block of keys at a time, online softmax: at serving shapes one flash
+  kernel, ``kernels/latent_flash.py``), decode in the *absorbed* form
   (scores and the weighted sum taken in the latent space). The two give the
   same numbers (``tests/test_latent_sparse.py``).
 * **Sparse selection.** Index scores ``I[t, s] = sum_h w[t, h] relu(q[t, h]
@@ -29,6 +31,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from triton_dist_tpu.kernels import latent_flash
 from triton_dist_tpu.kernels.kth_value import kth_value
 from triton_dist_tpu.layers.tp import RMSNorm
 
@@ -174,14 +177,46 @@ def select_positions(scores, visible, k: int):
 # -------------------------------------------------------------- attention
 
 
-def attend_expanded(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c, *,
-                    head_group: int = 16, key_block: int = 2048):
+def attend_tiles(allowed, off):
+    """What :func:`attend_expanded` computes of a chunk's attention, in tiles
+    of ``kernels/latent_flash.py``'s sizes: ``table`` (query tiles, key
+    tiles) bool, the tiles where ``allowed`` (C, P) allows anything, and
+    ``counts`` (2,) int32: how many those are, and how many tiles hold a key
+    at or before the chunk's last position ``off + C - 1`` (what a loop over
+    the key blocks under the chunk's diagonal computes)."""
+    C, P = allowed.shape
+    tq, tk = latent_flash.tile_sizes(C, P)
+    table = latent_flash.tile_table(allowed, tq, tk)
+    nq, nk = table.shape
+    under = nq * jnp.clip((off + C + tk - 1) // tk, 1, nk)
+    return table, jnp.stack([table.sum(dtype=jnp.int32), under.astype(jnp.int32)])
+
+
+def attend_expanded(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c, *, table=None):
     """Expanded-form attention of a prefill chunk. q_nope (C, H, N), q_rope
     (C, H, R); ``rows`` (P, kv_rank + R) the prompt's latent buffer;
     ``allowed`` (C, P) bool, selection and causality together; ``off`` the
-    chunk's first position. K and V of a block of keys are made from the
-    rows a group of heads at a time and folded into an online softmax; the
-    loop stops at the last block a row of this chunk can see, so a chunk
+    chunk's first position; ``table`` :func:`attend_tiles`' of ``allowed``
+    where the caller has it. Where the shapes tile, one flash kernel
+    (``kernels/latent_flash.py``: K and V made from the rows in VMEM, scores
+    and ``p`` never in HBM, tiles the table leaves empty skipped); else
+    :func:`attend_expanded_xla`, the same mathematics in plain XLA.
+    -> (C, H * V) in q's type."""
+    C, H, N = q_nope.shape
+    if latent_flash.takes(C, H, c.kv_lora_rank, N + q_rope.shape[-1], w_uv.shape[-1],
+                          q_nope.dtype.itemsize):
+        scale = float(c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+        return latent_flash.dsa_flash_prefill(
+            q_nope, q_rope, rows, allowed, w_uk, w_uv, scale, table=table)
+    return attend_expanded_xla(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c)
+
+
+def attend_expanded_xla(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c, *,
+                        head_group: int = 16, key_block: int = 2048):
+    """:func:`attend_expanded` in plain XLA: the path of shapes the kernel
+    does not take, and its oracle. K and V of a block of keys are made from
+    the rows a group of heads at a time and folded into an online softmax;
+    the loop stops at the last block a row of this chunk can see, so a chunk
     costs what lies under its diagonal. -> (C, H * V) in q's type."""
     C, H, _ = q_nope.shape
     P = rows.shape[0]

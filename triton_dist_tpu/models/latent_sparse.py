@@ -234,12 +234,16 @@ class LatentSparseLLM:
         whose selection had more equal scores at its boundary than places
         left (``layers/latent_sparse.py:select_mask``'s slow path). Rows
         nobody sent (a chunk's padding, an inactive slot) are in none of
-        them."""
+        those. ``attend_tiles``: over the layers of the prefill chunks, the
+        tiles of the attention's mask that allow anything, [which the flash
+        kernel visits, and those at or before the chunk's last position]
+        (``layers/latent_sparse.py:attend_tiles``)."""
         return {"expert_rows": jnp.zeros((self.config.num_experts,), jnp.int32),
                 "dispatches": jnp.zeros((), jnp.int32),
                 "visible": jnp.zeros((2,), jnp.int32),
                 "selected": jnp.zeros((2,), jnp.int32),
-                "tie_rows": jnp.zeros((), jnp.int32)}
+                "tie_rows": jnp.zeros((), jnp.int32),
+                "attend_tiles": jnp.zeros((2,), jnp.int32)}
 
     def publish_step_stats(self, stats) -> None:
         """Host side: feed the counters from a finished program's stats."""
@@ -255,6 +259,9 @@ class LatentSparseLLM:
                           float(stats["selected"][i]), phase=phase)
         telemetry.inc("tdt_dsa_select_tie_rows_total", float(stats["tie_rows"]),
                       phase="prefill")
+        for i, kind in enumerate(("visited", "under_diagonal")):
+            telemetry.inc("tdt_dsa_attend_tiles_total", float(stats["attend_tiles"][i]),
+                          kind=kind)
 
     @staticmethod
     def _selected(stats, phase: int, rows, n_visible, chosen):
@@ -317,8 +324,10 @@ class LatentSparseLLM:
                 stats = self._selected(stats, 0, sent, pos + 1, allowed)
                 stats = {**stats, "tie_rows": stats["tie_rows"]
                          + (walked & sent).sum(dtype=jnp.int32)}
+                table, tiles = ls.attend_tiles(allowed, off)  # a shared layer's too
             a = ls.attend_expanded(q_nope, q_rope, kbufs[layer, 0, 0], allowed, off,
-                                   lp["w_uk"], lp["w_uv"], c)
+                                   lp["w_uk"], lp["w_uv"], c, table=table)
+            stats = {**stats, "attend_tiles": stats["attend_tiles"] + tiles}
             x = x + ls.mm(a, lp["w_o"])
             h = ls.rms_norm(x, lp["ln2"], c.rms_eps)
             m, stats = self._mlp(lp, layer, h, stats, sent)

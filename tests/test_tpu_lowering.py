@@ -460,12 +460,12 @@ def _operands(engine, sizes, slots):
     }
 
 
-def _compile(lowered, *, kernels=()):
-    """Compile a lowered program; assert it reaches Mosaic (and, by name,
-    the kernels it is expected to hold). Returns (compiled, bytes one
-    device holds while it runs)."""
+def _compile(lowered, *, kernels=(), mosaic=True):
+    """Compile a lowered program; assert it reaches Mosaic (unless it is one
+    that holds no kernel) and, by name, the kernels it is expected to hold.
+    Returns (compiled, bytes one device holds while it runs)."""
     txt = lowered.as_text()
-    assert "tpu_custom_call" in txt, "no Mosaic kernel in the lowered module"
+    assert not mosaic or "tpu_custom_call" in txt, "no Mosaic kernel in the lowered module"
     for name in kernels:
         assert name in txt, f"kernel {name} not in the lowered module"
     compiled = lowered.compile()
@@ -682,9 +682,19 @@ def _dsa_flash_prefill(sds):
         sds((2048, 16384), jnp.bool_), sds((512, 64, 192)), sds((512, 64, 256)))
 
 
+def _ssm_scan(sds):
+    """One Mamba layer's scan over a prefill chunk at the published widths:
+    512 rows of 5120 channels, a state of 16 a channel."""
+    from triton_dist_tpu.kernels.ssm_scan import ssm_scan
+
+    f32 = lambda *shape: sds(shape, jnp.float32)
+    return ssm_scan, (f32(512, 5120), f32(512, 5120), f32(16, 5120), f32(512, 16),
+                      f32(512, 16), f32(5120), f32(16, 5120))
+
+
 @pytest.mark.parametrize(
     "case", [_flash_decode, _paged_flash_decode, _flash_attention, _dsa_kth_value,
-             _dsa_flash_prefill],
+             _dsa_flash_prefill, _ssm_scan],
     ids=lambda f: f.__name__.lstrip("_"))
 def test_named_kernel_compiles_under_its_name(topo_2x2, case):
     """At Qwen3-8B head shapes, for one v5e chip: the kernel compiles and
@@ -846,3 +856,88 @@ def test_latent_sparse_chunk_attends_without_a_score_matrix(topo_2x2, p_len):
     # ... and the search does find the parent's, by its line in the ledger
     was = "%fusion.431 = f32[16,2048,2048]{2,1,0:T(8,128)} fusion(%bitcast.9, %p.1), kind=kLoop"
     assert "f32[16,2048,2048]" in was
+
+
+# ---------------------------------------------------------------------------
+# The third configuration's programs, whole and at its published widths
+# ---------------------------------------------------------------------------
+
+
+def _abstract_hybrid_ssm(devices):
+    """(model, params, configuration file) of ``phi-4-mini-flash`` over one
+    described chip, the parameters as shapes."""
+    import json
+    import sys
+
+    from triton_dist_tpu.models import HybridSSMLLM
+    from triton_dist_tpu.models.hybrid_ssm import SCAN_F32, layer_fixed, layer_tensors
+    from triton_dist_tpu.runtime.mesh import initialize_distributed
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.build.phi4flash import model_config
+
+    with open(os.path.join(root, "benchmark/configs/phi-4-mini-flash.json")) as f:
+        cfg = json.load(f)
+    c = model_config(cfg)
+    ctx = initialize_distributed(devices=list(devices), axis_names=("tp",), set_default=False)
+    dt = jnp.dtype(c.dtype)
+    sds = lambda shape, dtype=dt: jax.ShapeDtypeStruct(
+        tuple(shape), dtype, sharding=ctx.replicated())
+    params = {"embed": sds((c.vocab_size, c.hidden_size)), "final_w": sds((c.hidden_size,)),
+              "final_b": sds((c.hidden_size,)), "layers": []}
+    for layer in range(c.num_layers):
+        shapes = [(n, s) for n, s, _ in layer_tensors(c, layer)] + [
+            (n, s) for n, (s, _) in layer_fixed(c, layer).items()]
+        params["layers"].append(
+            {n: sds(s, jnp.float32 if n in SCAN_F32 else dt) for n, s in shapes})
+    return HybridSSMLLM(c, ctx, params=params), params, cfg
+
+
+def test_hybrid_ssm_programs_fit_whole(topo_2x2):
+    """``reason``'s programs for one v5e chip, the model whole (32 layers,
+    7.7 GB of weights): the prefill chunk over each of the mix's prompt
+    buffers, with one slot's state carried in and out and the scan in its
+    kernel once a Mamba layer, and the decode chunk at 32 slots, which
+    carries the pool pair AND the slots' state in place (both aliased to
+    its outputs) and holds no copy of a ring's shape beside them."""
+    from triton_dist_tpu.models.engine import Engine
+    from triton_dist_tpu.runtime.platform import force_mosaic
+
+    model, params, cfg = _abstract_hybrid_ssm(topo_2x2.devices[:1])
+    c, sv = model.config, cfg["serving"]
+    slots, rows, bs = int(sv["slots"]), int(sv["prefill_chunk"]), int(sv["block_size"])
+    max_blocks = -(-int(sv["max_len"]) // bs)
+    with force_mosaic():
+        eng = Engine(model, backend=sv["backend"], max_len=int(sv["max_len"]))
+        rep = model.ctx.replicated()
+        shaped = lambda tree: jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep), tree)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+        pool = jax.ShapeDtypeStruct(
+            (1, slots * max_blocks + 1, 1, bs, c.num_kv_heads * c.head_dim), jnp.dtype(c.dtype),
+            sharding=eng._pool_sharding)
+        state = shaped(jax.eval_shape(lambda: model.slot_state(slots)))
+        resident = 2 * _nbytes(pool) + sum(_nbytes(x) for x in jax.tree.leaves(state))
+        assert resident == cfg["bytes"]["pool"] + cfg["bytes"]["slot_state"]
+        for p_len in (512, 1024, 1536):
+            buf = jax.ShapeDtypeStruct((1, 1, 1, p_len, c.num_kv_heads * c.head_dim),
+                                       jnp.dtype(c.dtype), sharding=eng._kv_sharding)
+            one = shaped(jax.eval_shape(lambda: model.slot_state(1)))
+            compiled, held = _compile(eng._prefill_chunk_prog.lower(
+                params, i32(1, rows), buf, buf, i32(), i32(), one), kernels=("ssm_scan",))
+            assert held + resident < HBM_BYTES, (p_len, held)
+            calls = [l for l in compiled.as_text().splitlines() if "tpu_custom_call" in l]
+            assert sum("%ssm_scan" in l.split("=")[0] for l in calls) == len(
+                c.layers_of("mamba")), calls
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+        chunk, held = _compile(eng._decode_chunk_paged.lower(
+            params, (), i32(slots), pool, pool, i32(slots, max_blocks), i32(slots), i32(slots),
+            int(sv["chunk"]), key, state), mosaic=False)  # decode attends in plain XLA
+    assert held < HBM_BYTES, held
+    assert chunk.memory_analysis().alias_size_in_bytes >= resident
+    ring = state["ring_k"][0]
+    assert _pool_sized_copies(chunk.as_text(), jax.ShapeDtypeStruct(
+        (1,) + ring.shape, ring.dtype)) == []
+

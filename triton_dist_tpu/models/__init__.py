@@ -4,11 +4,17 @@ Reference: ``python/triton_dist/models/__init__.py:33-60`` (``AutoLLM``
 loading HF checkpoints into the TP layout).
 """
 
-from triton_dist_tpu.models.config import ModelConfig, PRESETS
+from triton_dist_tpu.models.config import (
+    HYBRID_SSM_PRESETS,
+    HybridSSMConfig,
+    ModelConfig,
+    PRESETS,
+)
 from triton_dist_tpu.models.kv_cache import CacheRow, KVCache, PagedKVCache, kv_rows
 from triton_dist_tpu.models.dense import DenseLLM, Qwen3MoE, DenseParams, init_params
 from triton_dist_tpu.models.moe import EPMoELLM, ep_specs
 from triton_dist_tpu.models.latent_sparse import LatentSparseConfig, LatentSparseLLM
+from triton_dist_tpu.models.hybrid_ssm import HybridSSMLLM
 from triton_dist_tpu.models.engine import Engine
 from triton_dist_tpu.models.drafter import (
     Drafter,
@@ -31,6 +37,9 @@ __all__ = [
     "EPMoELLM",
     "LatentSparseConfig",
     "LatentSparseLLM",
+    "HybridSSMConfig",
+    "HYBRID_SSM_PRESETS",
+    "HybridSSMLLM",
     "ep_specs",
     "DenseParams",
     "init_params",

@@ -179,6 +179,36 @@ class Engine:
         # with the chunk's tokens, summed over the chunk's steps on the
         # device. ``model.step_stats()`` gives their zeros.
         stats_spec = jax.tree.map(lambda _: P(), jax.eval_shape(model.step_stats))
+        # What a slot keeps whatever its length (recurrent state, a window's
+        # ring): ``model.slot_state(num_slots)`` gives its zeros, the slot's
+        # axis first on every array. A model without one has the empty
+        # state ``()``, which adds no operand to any program below.
+        self.stateful = hasattr(model, "slot_state")
+        state_spec = (
+            jax.tree.map(lambda _: P(), jax.eval_shape(lambda: model.slot_state(1)))
+            if self.stateful else ()
+        )
+        if self.stateful and backend == "mega":
+            raise ValueError("a model with per-slot state has no megakernel step")
+        # One protocol below this point: the model's chunk and its paged step
+        # take the state last and return it before the stats. A model that
+        # keeps none is wrapped here, once, and handed the empty state back.
+        if self.stateful:
+            chunk_model, step_model = model.prefill_chunk_shard, model.decode_shard_paged
+            self._state_zeros = jax.jit(
+                model.slot_state, static_argnums=(0,),
+                out_shardings=jax.tree.map(lambda _: ctx.replicated(), state_spec),
+            )
+        else:
+            def chunk_model(*args):
+                logits, kv, stats = model.prefill_chunk_shard(*args[:-1])
+                return logits, kv, args[-1], stats
+
+            def step_model(*args):
+                logits, pk, pv, stats = model.decode_shard_paged(*args[:-1])
+                return logits, pk, pv, args[-1], stats
+
+            self._state_zeros = lambda num_slots: ()
 
         def prefill_fn(params, tokens):
             logits, (ks, vs) = model.prefill_shard(params, tokens, prefill_mode)
@@ -239,12 +269,17 @@ class Engine:
                 )
                 return jax.lax.all_gather(logits, axis, axis=1, tiled=True), pk, pv, stats
 
-            self._decode_shard_paged = jax.shard_map(
+            mega_psm = jax.shard_map(
                 decode_paged_fn, mesh=mesh,
                 in_specs=(p_specs, mega_specs, tok_spec, pool_spec, pool_spec,
                           P(dp), len_spec, len_spec),
                 out_specs=(tok_spec, pool_spec, pool_spec, stats_spec),
                 check_vma=False,
+            )
+            self._decode_shard_paged = (
+                lambda p_, extra, t_, pk_, pv_, tab_, l_, a_, st_: mega_psm(
+                    p_, extra, t_, pk_, pv_, tab_, l_, a_
+                ) + (st_,)
             )
 
             # Speculative k-wide verify: the persistent step graph replayed
@@ -284,22 +319,23 @@ class Engine:
             # The same step against the block pool where it lies: the pool
             # pair is carried through the layers, one K/V row written a
             # layer, K/V read through the table inside the kernel.
-            def decode_paged_fn(params, token, pk, pv, tables, lengths, active):
-                logits, pk, pv, stats = model.decode_shard_paged(
-                    params, token, pk, pv, tables, lengths, active, decode_mode
+            def decode_paged_fn(params, token, pk, pv, tables, lengths, active, state):
+                logits, pk, pv, state, stats = step_model(
+                    params, token, pk, pv, tables, lengths, active, decode_mode, state
                 )
-                return jax.lax.all_gather(logits, axis, axis=1, tiled=True), pk, pv, stats
+                logits = jax.lax.all_gather(logits, axis, axis=1, tiled=True)
+                return logits, pk, pv, stats, state
 
             psm = jax.shard_map(
                 decode_paged_fn, mesh=mesh,
                 in_specs=(p_specs, tok_spec, pool_spec, pool_spec, P(dp),
-                          len_spec, len_spec),
-                out_specs=(tok_spec, pool_spec, pool_spec, stats_spec),
+                          len_spec, len_spec, state_spec),
+                out_specs=(tok_spec, pool_spec, pool_spec, stats_spec, state_spec),
                 check_vma=False,
             )
             self._decode_shard_paged = (
-                lambda p_, extra, t_, pk_, pv_, tab_, l_, a_: psm(
-                    p_, t_, pk_, pv_, tab_, l_, a_
+                lambda p_, extra, t_, pk_, pv_, tab_, l_, a_, st_: psm(
+                    p_, t_, pk_, pv_, tab_, l_, a_, st_
                 )
             )
 
@@ -439,17 +475,17 @@ class Engine:
         # semantics per step, but the carry is the POOL pair and the block
         # tables ride as data — one compiled program per chunk size, zero
         # recompiles across batch compositions.
-        @partial(jax.jit, static_argnums=(8,), donate_argnums=(3, 4))
+        @partial(jax.jit, static_argnums=(8,), donate_argnums=(3, 4, 10))
         def decode_chunk_paged(params, extra, token, pk, pv, tables, lengths,
-                               remaining, chunk, key):
+                               remaining, chunk, key, state=()):
             bsz = token.shape[0]
             out0 = jnp.full((bsz, chunk), -1, jnp.int32)
 
             def body(i, carry):
-                out, token, pk, pv, lengths, remaining, key, stats = carry
+                out, token, pk, pv, lengths, remaining, key, stats, state = carry
                 active = remaining > 0
-                logits, pk, pv, step = self._decode_shard_paged(
-                    params, extra, token, pk, pv, tables, lengths, active
+                logits, pk, pv, step, state = self._decode_shard_paged(
+                    params, extra, token, pk, pv, tables, lengths, active, state
                 )
                 stats = jax.tree.map(jnp.add, stats, step)
                 key, sub = jax.random.split(key)
@@ -463,13 +499,15 @@ class Engine:
                 nxt = jnp.where(active, nxt, token)
                 out = out.at[:, i].set(jnp.where(active, nxt, jnp.int32(-1)))
                 adv = active.astype(lengths.dtype)
-                return (out, nxt, pk, pv, lengths + adv, remaining - adv, key, stats)
+                return (out, nxt, pk, pv, lengths + adv, remaining - adv, key, stats,
+                        state)
 
-            carry = (out0, token, pk, pv, lengths, remaining, key, model.step_stats())
-            out, token, pk, pv, lengths, remaining, _, stats = jax.lax.fori_loop(
+            carry = (out0, token, pk, pv, lengths, remaining, key, model.step_stats(),
+                     state)
+            out, token, pk, pv, lengths, remaining, _, stats, state = jax.lax.fori_loop(
                 0, chunk, body, carry
             )
-            return out, token, pk, pv, lengths, remaining, stats
+            return out, token, pk, pv, lengths, remaining, stats, state
 
         self._decode_chunk_paged = decode_chunk_paged
 
@@ -481,9 +519,9 @@ class Engine:
             )[0]
         )
         self._step_logits_paged = jax.jit(
-            lambda params, extra, token, pk, pv, tables, lengths, active:
+            lambda params, extra, token, pk, pv, tables, lengths, active, state:
             self._decode_shard_paged(
-                params, extra, token, pk, pv, tables, lengths, active
+                params, extra, token, pk, pv, tables, lengths, active, state
             )[0]
         )
 
@@ -495,24 +533,28 @@ class Engine:
         # speculation (rejected draft rows must never reach the pool).
         chunk_mode = CHUNK_MODE[backend]
 
-        def chunk_fn(params, toks, kb, vb, off, last_idx):
-            logits, (kb, vb), stats = model.prefill_chunk_shard(
-                params, toks, kb, vb, off, last_idx, chunk_mode
+        def chunk_shard(params, toks, kb, vb, off, last_idx, state):
+            logits, (kb, vb), state, stats = chunk_model(
+                params, toks, kb, vb, off, last_idx, chunk_mode, state
             )
-            return jax.lax.all_gather(logits, axis, axis=1, tiled=True), kb, vb, stats
+            logits = jax.lax.all_gather(logits, axis, axis=1, tiled=True)
+            return logits, kb, vb, stats, state
 
         # One jitted object; jit's shape cache keys each (chunk_len, P)
-        # combination. kbuf/vbuf are donated — the running context buffer
-        # threads through the chunk loop in place.
-        self._prefill_chunk_prog = jax.jit(
-            jax.shard_map(
-                chunk_fn, mesh=mesh,
-                in_specs=(p_specs, tok_spec, kv_spec, kv_spec, P(), P()),
-                out_specs=(tok_spec, kv_spec, kv_spec, stats_spec),
-                check_vma=False,
-            ),
-            donate_argnums=(2, 3),
+        # combination. kbuf/vbuf (and a prompt's carried state) are donated
+        # — the running context buffer threads through the chunk loop in
+        # place.
+        chunk_sm = jax.shard_map(
+            chunk_shard, mesh=mesh,
+            in_specs=(p_specs, tok_spec, kv_spec, kv_spec, P(), P(), state_spec),
+            out_specs=(tok_spec, kv_spec, kv_spec, stats_spec, state_spec),
+            check_vma=False,
         )
+
+        def chunk_fn(params, toks, kb, vb, off, last_idx, state=()):
+            return chunk_sm(params, toks, kb, vb, off, last_idx, state)
+
+        self._prefill_chunk_prog = jax.jit(chunk_fn, donate_argnums=(2, 3, 6))
 
         cfg = model.config
         rows = model.cache_rows()
@@ -558,9 +600,9 @@ class Engine:
             paged_gather, out_shardings=(self._kv_sharding, self._kv_sharding)
         )
 
-        @partial(jax.jit, static_argnums=(8,), donate_argnums=(0, 1, 2, 3))
+        @partial(jax.jit, static_argnums=(8,), donate_argnums=(0, 1, 2, 3, 9))
         def paged_scatter_prefill(pk, pv, ks, vs, kbuf, vbuf, table_row,
-                                  start_block, wire):
+                                  start_block, wire, state, prompt_state, slot):
             """Block-granular scatter of a COMPLETED prefill buffer into the
             pool: one advanced-index write per pool, not one per row.
             Blocks below ``start_block`` are prefix-shared (owned by the
@@ -592,7 +634,13 @@ class Engine:
             else:
                 pk = pk.at[:, phys].set(kb)
                 pv = pv.at[:, phys].set(vb)
-            return pk, pv, ks, vs
+            # The finished prompt's own state takes the slot's place whole:
+            # nothing of the slot's last tenant is left.
+            state = jax.tree.map(
+                lambda all_, one: jax.lax.dynamic_update_slice_in_dim(all_, one, slot, 0),
+                state, prompt_state,
+            )
+            return pk, pv, ks, vs, state
 
         self._paged_scatter_prefill = paged_scatter_prefill
 
@@ -718,12 +766,27 @@ class Engine:
         block (see ``BlockAllocator``); the pool is zeroed so null reads are
         finite. ``quant`` ("int8"/"fp8") stores the pool in the wire dtype
         with a parallel per-row scale pool (``models/quant.py``)."""
-        return PagedKVCache.create(
+        if self.stateful and quant is not None:
+            raise NotImplementedError("a model with per-slot state keeps an unquantized pool")
+        paged = PagedKVCache.create(
             self.model.cache_rows(), num_slots, block_size=block_size,
             num_blocks=num_blocks, max_len=self.max_len,
             dtype=jnp.dtype(self.model.config.dtype),
             sharding=self._pool_sharding, quant=quant,
         )
+        return dataclasses.replace(paged, state=self.slots_state(num_slots))
+
+    def slots_state(self, num_slots: int):
+        """Zeros of what ``num_slots`` slots keep whatever their length:
+        ``()`` for a model that keeps nothing."""
+        return self._state_zeros(int(num_slots))
+
+    def prompt_state(self):
+        """What a prompt's first prefill chunk starts from: one slot's state,
+        zeros, so that nothing of the slot's last tenant reaches the next.
+        Each chunk is given the state the chunk before returned; the last
+        goes to :meth:`complete_paged_prefill` with the slot it is for."""
+        return self.slots_state(1)
 
     @staticmethod
     def _pool_pair(paged: PagedKVCache):
@@ -770,49 +833,64 @@ class Engine:
                 jnp.int32(shared_rows), int(p_len),
             )
 
-    def prefill_chunk(self, kbuf, vbuf, chunk_ids: jax.Array, off: int,
-                      last_idx: int):
+    def prefill_chunk_state(self, kbuf, vbuf, chunk_ids: jax.Array, off: int,
+                            last_idx: int, state):
         """One chunk of an incremental prefill against the running context
         buffers. ``chunk_ids`` (1, C) — the final chunk arrives padded to C;
         ``off`` is the chunk's absolute start, ``last_idx`` the row whose
-        logits matter (the prompt's last token, on the final chunk). One
-        compiled program per (C, P) shape pair; kbuf/vbuf are donated.
-        Returns (logits (1, V), kbuf', vbuf')."""
+        logits matter (the prompt's last token, on the final chunk);
+        ``state`` is the prompt's carried state, from :meth:`prompt_state`
+        or the chunk before (``()`` for a model that keeps none). One
+        compiled program per (C, P) shape pair; kbuf/vbuf/state are donated.
+        Returns (logits (1, V), kbuf', vbuf', state')."""
         timed = telemetry.enabled()
         t = time.perf_counter() if timed else 0.0
         # The engine's side of the boundary. The call is one phase
         # (admission), so one span: spans say where the host was, the
         # ``_phase`` stamp below stays what ``tdt_engine_phase_seconds`` reads.
         with tracing.span_current("tdt_engine_prefill_chunk"):
-            logits, kb, vb, stats = self._prefill_chunk_prog(
+            logits, kb, vb, stats, state = self._prefill_chunk_prog(
                 self.model.params, chunk_ids, kbuf, vbuf,
-                jnp.int32(off), jnp.int32(last_idx),
+                jnp.int32(off), jnp.int32(last_idx), state,
             )
             if timed:
                 # Admission: each prefill chunk's compute, the cost of
                 # joining one request into the running batch.
                 self._phase("admission", t, logits)
                 self.model.publish_step_stats(stats)
-        return logits, kb, vb
+        return logits, kb, vb, state
+
+    def prefill_chunk(self, kbuf, vbuf, chunk_ids: jax.Array, off: int,
+                      last_idx: int):
+        """:meth:`prefill_chunk_state` for a model whose prompts carry no
+        state, as the callers from before per-slot state spell it (the
+        benchmark's ``tests/benchmark/test_reference.py`` among them):
+        (logits (1, V), kbuf', vbuf')."""
+        return self.prefill_chunk_state(kbuf, vbuf, chunk_ids, off, last_idx, ())[:3]
 
     def complete_paged_prefill(self, paged: PagedKVCache, kbuf, vbuf, table_row,
-                               start_block: int) -> PagedKVCache:
+                               start_block: int, slot: int = 0,
+                               state=()) -> PagedKVCache:
         """Scatter a finished prefill's context buffer into the pool along
         the slot's block chain (blocks below ``start_block`` are shared and
-        skipped). Pool buffers are donated; tables/lengths are the host's to
-        update (they travel as data with the next dispatch)."""
+        skipped), and the prompt's carried ``state`` (the last chunk's) into
+        ``slot`` of the slots' state; the defaults are a stateless model's.
+        Pool buffers are donated; tables/lengths are the host's to update
+        (they travel as data with the next dispatch)."""
         timed = telemetry.enabled()
         t = time.perf_counter() if timed else 0.0
         # One phase (cache_scatter), so one span, as in ``prefill_chunk``.
         with tracing.span_current("tdt_engine_complete_paged_prefill"):
-            pk, pv, ks, vs = self._paged_scatter_prefill(
+            pk, pv, ks, vs, slots_state = self._paged_scatter_prefill(
                 paged.k, paged.v, paged.k_scale, paged.v_scale, kbuf, vbuf,
                 jnp.asarray(table_row, jnp.int32), jnp.int32(start_block),
-                paged.quant,
+                paged.quant, paged.state, state, jnp.int32(slot),
             )
             if timed:
                 self._phase("cache_scatter", t, pk)
-        return dataclasses.replace(paged, k=pk, v=pv, k_scale=ks, v_scale=vs)
+        return dataclasses.replace(
+            paged, k=pk, v=pv, k_scale=ks, v_scale=vs, state=slots_state
+        )
 
     def decode_steps_paged(self, paged: PagedKVCache, tokens: jax.Array,
                            remaining: jax.Array, chunk: int,
@@ -848,10 +926,12 @@ class Engine:
             with tracing.span_current("tdt_engine_dispatch"):
                 if pool:
                     pk_in, pv_in = self._pool_pair(paged)
-                    out, tok, pk, pv, lengths, rem, stats = self._decode_chunk_paged(
-                        self.model.params, self._decode_extra, tokens, pk_in,
-                        pv_in, paged.tables, paged.lengths, remaining, int(chunk),
-                        key,
+                    out, tok, pk, pv, lengths, rem, stats, state = (
+                        self._decode_chunk_paged(
+                            self.model.params, self._decode_extra, tokens, pk_in,
+                            pv_in, paged.tables, paged.lengths, remaining,
+                            int(chunk), key, paged.state,
+                        )
                     )
                     if self.backend == "mega":
                         telemetry.set_gauge(
@@ -877,6 +957,7 @@ class Engine:
                     if pool:
                         self.model.publish_step_stats(stats)
             if pool:
+                paged = dataclasses.replace(paged, state=state)
                 return out, tok, self._pool_update(paged, pk, pv, lengths), rem
             with tracing.span_current("tdt_engine_cache_scatter"):
                 pk, pv, ks, vs = self._paged_scatter_rows(
@@ -902,7 +983,7 @@ class Engine:
             return self._step_logits_paged(
                 self.model.params, self._decode_extra, tokens, pk, pv,
                 paged.tables, paged.lengths,
-                jnp.ones(tokens.shape, jnp.bool_),
+                jnp.ones(tokens.shape, jnp.bool_), paged.state,
             )
         kc, vc = self._paged_gather(
             paged.k, paged.v, paged.k_scale, paged.v_scale, paged.tables

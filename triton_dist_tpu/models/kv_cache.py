@@ -188,6 +188,12 @@ class PagedKVCache:
     #: What the two pools hold (``CacheRow.kind``): K and V rows, or the
     #: row kinds a model declared.
     kinds: tuple = ("k", "v")
+    #: What the slots keep whatever their length (a model's
+    #: ``slot_state(num_slots)``: recurrent state, a window's ring), the
+    #: slot's axis first on every array; ``()`` for a model that keeps none.
+    #: Not under the block table: a block shared with another slot shares
+    #: nothing of this.
+    state: object = ()
 
     @staticmethod
     def create(rows, num_slots, *, block_size, num_blocks, max_len,
@@ -266,10 +272,15 @@ class PagedKVCache:
             out[kind] = nl * hkv * bs * hd * pool.dtype.itemsize
         return out
 
+    @property
+    def slot_state_bytes(self) -> int:
+        """Bytes of the slots' state, all slots together."""
+        return sum(x.nbytes for x in jax.tree.leaves(self.state))
+
 
 jax.tree_util.register_dataclass(
     PagedKVCache,
-    data_fields=["k", "v", "tables", "lengths", "k_scale", "v_scale"],
+    data_fields=["k", "v", "tables", "lengths", "k_scale", "v_scale", "state"],
     meta_fields=["block_size", "quant", "kinds"],
 )
 

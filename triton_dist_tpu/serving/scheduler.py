@@ -483,6 +483,8 @@ class KVLedger:
         self.allocator = BlockAllocator(num_blocks)
         self.block_size = int(block_size)
         self.prefix_reuse = bool(prefix_reuse)
+        #: Bytes the slots' state takes beside the pool, whatever the load.
+        self.slot_state_bytes = 0
         #: Real HBM bytes one pool block costs (payloads + scale pools,
         #: ``PagedKVCache.bytes_per_block``). The server teaches the ledger
         #: this after allocating the device pool — budget math and the
@@ -494,6 +496,9 @@ class KVLedger:
 
     def set_bytes_per_block(self, nbytes: int) -> None:
         self.bytes_per_block = int(nbytes)
+
+    def set_slot_state_bytes(self, nbytes: int) -> None:
+        self.slot_state_bytes = int(nbytes)
 
     def blocks_needed(self, prompt_len: int, max_new: int) -> int:
         return -(-(int(prompt_len) + int(max_new)) // self.block_size)
@@ -579,6 +584,8 @@ class KVLedger:
             out["bytes_per_block"] = self.bytes_per_block
             out["bytes_used"] = a.num_used * self.bytes_per_block
             out["bytes_free"] = a.num_free * self.bytes_per_block
+        if self.slot_state_bytes:
+            out["bytes_slot_state"] = self.slot_state_bytes
         return out
 
     def reset(self) -> None:

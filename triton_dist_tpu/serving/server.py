@@ -201,9 +201,21 @@ class InferenceServer:
         #: wire-dtype blocks + per-row scale pools; greedy streams stay
         #: byte-identical across prefix sharing/CoW (quantize-once).
         self.kv_quant = kv_quant_from_env()
+        #: A model that keeps per-slot state beside the pool (recurrent
+        #: state, a window's ring: ``Engine.stateful``). The pool's blocks
+        #: do not hold it, so what rests on the pool alone is refused, not
+        #: served wrong: no prefix hit (skipped and counted), no
+        #: speculation (a rejected draft cannot be rewound), no KV handoff
+        #: (``export_kv`` / ``import_kv``); a preempted or recovered request
+        #: re-prefills from its token history, which rebuilds the state.
+        self.stateful = engine.stateful
+        want_prefix = get_int_env("TDT_PREFIX_REUSE", 1) != 0
+        #: The trie lookup an operator asked for and the model's state
+        #: forbids: skipped at every join, and counted there.
+        self._prefix_skipped = want_prefix and self.stateful
         self.kv_ledger = KVLedger(
             self.num_blocks, self.block_size,
-            prefix_reuse=get_int_env("TDT_PREFIX_REUSE", 1) != 0,
+            prefix_reuse=want_prefix and not self.stateful,
         )
         #: Disaggregated-pool role (``TDT_POOL_ROLE``, docs/disagg.md): a
         #: "prefill" replica parks finished prefills for handoff instead of
@@ -229,6 +241,11 @@ class InferenceServer:
         self.spec_k = (
             get_int_env("TDT_SPEC_K", 0) if spec_k is None else int(spec_k)
         )
+        if self.stateful and (self.spec_k >= 2 or drafter is not None):
+            raise ValueError(
+                "speculative decoding rewinds a rejected draft by its length "
+                "alone; this model's per-slot state cannot be rewound"
+            )
         self.spec_min_accept = get_float_env("TDT_SPEC_MIN_ACCEPT", 0.5)
         self._drafter = drafter
         self._dstate = None
@@ -633,10 +650,18 @@ class InferenceServer:
         ``KeyError`` when nothing is parked under ``req_id`` (the request
         never parked, or a recovery rebuild dropped the chain) — the
         caller's cue to re-derive from the journaled history."""
+        self._refuse_pool_alone("export_kv")
         st = self._handoffs.get(int(req_id))
         if st is None:
             raise KeyError(f"no parked handoff for request {int(req_id)}")
         return pack_kv_blocks(self.cache, st["blocks"], length=st["length"])
+
+    def _refuse_pool_alone(self, what: str) -> None:
+        if self.stateful:
+            raise ValueError(
+                f"{what}: the pool's blocks alone do not restore a request of "
+                "a model with per-slot state; resume it from its token history"
+            )
 
     def release_handoff(self, req_id: int) -> bool:
         """Drop a parked handoff's extra block refs (the transfer landed,
@@ -663,6 +688,7 @@ class InferenceServer:
         is consumed on first application, so a crash after admission falls
         back to re-deriving the same KV from the journaled token history —
         the stream stays byte-identical either way."""
+        self._refuse_pool_alone("import_kv")
         payload = unpack_kv_blocks(kv_blob)
         toks = [int(t) for t in tokens][: int(max_new)]
         if not toks:
@@ -801,6 +827,12 @@ class InferenceServer:
             telemetry.set_gauge(
                 "tdt_kv_pool_bytes", float(nbytes * self.num_blocks), kind=kind
             )
+        if self.stateful:
+            led.set_slot_state_bytes(self.cache.slot_state_bytes)
+            telemetry.set_gauge(
+                "tdt_kv_pool_bytes", float(self.cache.slot_state_bytes),
+                kind="slot_state",
+            )
         self._push_tables()
         self._publish_kv_gauges()
         return self.cache
@@ -864,6 +896,9 @@ class InferenceServer:
                               what=f"join of request {slot.request.req_id}")
         if joined:
             telemetry.inc("tdt_serving_joins_total", float(len(joined)))
+            if self._prefix_skipped:
+                telemetry.inc("tdt_serving_prefix_lookups_skipped_total",
+                              float(len(joined)))
         return bool(joined)
 
     def _prefill_to_completion(self, slot: Slot) -> None:
@@ -917,6 +952,7 @@ class InferenceServer:
             self._prefilling[slot.idx] = {
                 "req": req, "ids": ids, "off": shared_rows,
                 "kbuf": kbuf, "vbuf": vbuf, "key": sub, "n_chunks": 0,
+                "state": self.engine.prompt_state(),
             }
 
     def _advance_prefills(self) -> bool:
@@ -954,8 +990,9 @@ class InferenceServer:
             "tdt_serving_prefill", slot=slot.idx, hist_len=p_len,
             off=off, chunk_len=len(take), recovery=bool(req.tokens),
         ):
-            logits, st["kbuf"], st["vbuf"] = self.engine.prefill_chunk(
+            logits, st["kbuf"], st["vbuf"], st["state"] = self.engine.prefill_chunk_state(
                 st["kbuf"], st["vbuf"], jnp.asarray(chunk_ids), off, last_idx,
+                st["state"],
             )
         st["off"] = off + len(take)
         st["n_chunks"] += 1
@@ -976,7 +1013,7 @@ class InferenceServer:
         p_len = len(st["ids"])
         self.cache = self.engine.complete_paged_prefill(
             self.cache, st["kbuf"], st["vbuf"], self._table_row(req),
-            req.kv_shared,
+            req.kv_shared, slot.idx, st["state"],
         )
         self._lengths[slot.idx] = p_len
         self.kv_ledger.register_prefix(req)
@@ -1123,6 +1160,12 @@ class InferenceServer:
             self._last = np.asarray(tok, dtype=np.int32).copy()
         wall = time.perf_counter() - t0
         telemetry.inc("tdt_serving_decode_chunks_total")
+        # Rows the chunk's steps really advanced (a slot that runs out
+        # inside the chunk idles for the rest of it), of slots x chunk.
+        telemetry.inc(
+            "tdt_serving_decode_rows_total",
+            float(sum(min(n, self.chunk) for n in pre.values())),
+        )
         with self._trace.span("tdt_serving_emit", ring=False):
             n_streamed = 0
             for slot in decoding:
@@ -1543,10 +1586,10 @@ class InferenceServer:
         row = np.zeros((sandbox.max_blocks,), np.int32)
         row[:chain] = np.arange(1, chain + 1)
         kbuf, vbuf = eng.paged_kbuf_zeros(p_len)
-        logits, kbuf, vbuf = eng.prefill_chunk(
-            kbuf, vbuf, jnp.asarray(ids), 0, p_len - 1
+        logits, kbuf, vbuf, state = eng.prefill_chunk_state(
+            kbuf, vbuf, jnp.asarray(ids), 0, p_len - 1, eng.prompt_state()
         )
-        sandbox = eng.complete_paged_prefill(sandbox, kbuf, vbuf, row, 0)
+        sandbox = eng.complete_paged_prefill(sandbox, kbuf, vbuf, row, 0, 0, state)
         sandbox = dataclasses.replace(
             sandbox, tables=jnp.asarray(row[None]),
             lengths=jnp.asarray([p_len], jnp.int32),

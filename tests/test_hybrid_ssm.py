@@ -134,42 +134,44 @@ def test_ring_past_its_first_wrap_from_a_prompt_shorter_than_the_window(
 
 def test_a_slot_reused_by_a_shorter_prompt_keeps_nothing_of_the_longer(
         model, engine, ref_forward):
-    """Slot 0 serves 33 tokens, then a prompt of 6: the second tenant's
-    state starts from zeros, its ring holds nothing of the first's."""
-    first, second = _ids(30, seed=3), _ids(12, seed=4)
+    """Slot 0 serves 32 tokens, then a prompt of 5: the second tenant's
+    state starts from zeros, its ring holds nothing of the first's. (The
+    joins are of shapes other tests here compile: a program a (chunk,
+    prompt) pair is this file's cost.)"""
+    first, second = _ids(29, seed=3), _ids(11, seed=4)
     paged = paged_drive.alloc_chains(engine, 2)
     _, _, paged = _join(engine, paged, 0, first, chunk=12)
     for t in range(3):
         _, paged = _decode(engine, paged, [first[t], 0], [1, 0])
     want = ref_forward(model.params, second)
-    last, _, paged = _join(engine, paged, 0, second[:6], chunk=4)
-    assert np.abs(last - want[5]).max() <= TOL
-    for t in range(6, 12):
+    last, _, paged = _join(engine, paged, 0, second[:5], chunk=T_REF)
+    assert np.abs(last - want[4]).max() <= TOL
+    for t in range(5, 11):
         logits, paged = _decode(engine, paged, [second[t], 0], [1, 0])
         assert np.abs(logits[0] - want[t]).max() <= TOL, t
 
 
 def test_two_lengths_and_an_inactive_slot_in_one_decode_chunk(model, engine, ref_forward):
-    """Slots 0 and 2 decode at lengths 7 and 21 in one chunk of 4 steps in
+    """Slots 0 and 2 decode at lengths 5 and 29 in one chunk of 4 steps in
     which slot 2 runs out after 2, while slot 1 (a finished tenant, its
     state left where it stopped) sits idle: the idle slot's state, length
     and ring do not move, and the others' next logits are the reference's."""
-    a, b, idle = _ids(12, seed=5), _ids(24, seed=6), _ids(10, seed=7)
+    a, b, idle = _ids(10, seed=5), _ids(32, seed=6), _ids(W, seed=7)
     paged = paged_drive.alloc_chains(engine, 3)
-    _, _, paged = _join(engine, paged, 0, a[:7], chunk=4)
-    _, _, paged = _join(engine, paged, 1, idle, chunk=4)
-    _, _, paged = _join(engine, paged, 2, b[:21], chunk=8)
+    _, _, paged = _join(engine, paged, 0, a[:5], chunk=T_REF)
+    _, _, paged = _join(engine, paged, 1, idle, chunk=T_REF)
+    _, _, paged = _join(engine, paged, 2, b[:29], chunk=12)
     before = jax.tree.map(lambda x: np.asarray(x[1]), paged.state)
     # greedy continuations are the program's own: teacher-force through the reference
     out, tok, paged, rem = engine.decode_steps_paged(
-        paged, jnp.asarray([a[7], 3, b[21]], jnp.int32), jnp.asarray([4, 0, 2], jnp.int32), 4)
+        paged, jnp.asarray([a[5], 3, b[29]], jnp.int32), jnp.asarray([4, 0, 2], jnp.int32), 4)
     out = np.asarray(out)
     assert out[1].tolist() == [-1] * 4 and out[2, 2:].tolist() == [-1, -1]
-    assert np.asarray(paged.lengths).tolist() == [11, 10, 23]
+    assert np.asarray(paged.lengths).tolist() == [9, W, 31]
     for x, y in zip(jax.tree.leaves(before),
                     jax.tree.leaves(jax.tree.map(lambda x: np.asarray(x[1]), paged.state))):
         np.testing.assert_array_equal(x, y)
-    for slot, seq, n in ((0, a[:8], 4), (2, b[:22], 2)):
+    for slot, seq, n in ((0, a[:6], 4), (2, b[:30], 2)):
         seq = seq + out[slot, :n].tolist()
         want = ref_forward(model.params, seq)
         for i in range(n):  # each emitted token is the reference's first choice
@@ -184,23 +186,25 @@ def test_mamba_in_its_three_forms(model):
     steps give the same rows, the same scan output and the same state."""
     lp = model.params["layers"][0]
     u = jax.random.normal(jax.random.PRNGKey(1), (13, CFG.hidden_size))
-    want, want_m = hs.mamba_sequence(lp, u)
-    ref_out, ref_m = ref.mamba(jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), lp), u)
+    want, want_m = jax.jit(hs.mamba_sequence)(lp, u)
+    ref_out, ref_m = jax.jit(ref.mamba)(jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), lp), u)
     np.testing.assert_allclose(np.asarray(want), np.asarray(ref_out), atol=2e-5)
     np.testing.assert_allclose(np.asarray(want_m), np.asarray(ref_m), atol=2e-5)
     tail = jnp.zeros((CFG.d_conv - 1, CFG.d_inner))
     s = jnp.zeros((CFG.d_state, CFG.d_inner))
-    o1, m1, tail, s = hs.mamba_chunk(lp, u[:8], tail, s, 8)
+    chunk = jax.jit(hs.mamba_chunk)
+    o1, m1, tail, s = chunk(lp, u[:8], tail, s, 8)
     padded = jnp.concatenate([u[8:], jnp.ones((3, CFG.hidden_size))])
-    o2, m2, tail, s = hs.mamba_chunk(lp, padded, tail, s, 5)
+    o2, m2, tail, s = chunk(lp, padded, tail, s, 5)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([o1, o2[:5]])), np.asarray(want),
                                atol=2e-5)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([m1, m2[:5]])), np.asarray(want_m),
                                atol=2e-5)
     st, ss = jnp.zeros((2,) + tail.shape), jnp.zeros((2,) + s.shape)
     active = jnp.asarray([True, False])
+    step = jax.jit(hs.mamba_step)
     for t in range(13):
-        o, m, st, ss = hs.mamba_step(lp, jnp.stack([u[t], u[0]]), st, ss, active)
+        o, m, st, ss = step(lp, jnp.stack([u[t], u[0]]), st, ss, active)
         np.testing.assert_allclose(np.asarray(o[0]), np.asarray(want[t]), atol=2e-5)
     np.testing.assert_allclose(np.asarray(st[0]), np.asarray(tail), atol=1e-6)
     np.testing.assert_allclose(np.asarray(ss[0]), np.asarray(s), atol=2e-5)
@@ -215,20 +219,23 @@ def test_differential_attention_matches_the_pairwise_form(model):
     k = jax.random.normal(ks[1], (9, CFG.num_kv_heads, CFG.head_dim))
     v = jax.random.normal(ks[2], (9, CFG.num_kv_heads, CFG.head_dim))
     mask = jnp.tril(jnp.ones((9, 9), bool))
-    got = hs.mm(hs.diff_attend(q, k, v, mask, hs.diff_lambda(lp, layer), layer, lp["subln"],
-                               CFG.layer_norm_eps), lp["w_o"]) + lp["b_o"]
-    want = ref.diff_attention(CFG, lp, layer, q, k, v, mask)
+    attend = jax.jit(lambda q, k, v, mask: hs.diff_attend(
+        q, k, v, mask, hs.diff_lambda(lp, layer), layer, lp["subln"], CFG.layer_norm_eps))
+    got = hs.mm(attend(q, k, v, mask), lp["w_o"]) + lp["b_o"]
+    want = jax.jit(lambda *a: ref.diff_attention(CFG, lp, layer, *a))(q, k, v, mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     # one query a slot over whole rows: the block-diagonal form's numbers
     rows = lambda x: jnp.stack([x.reshape(9, -1), x[::-1].reshape(9, -1)])
     seen = jnp.stack([mask[5], mask[3]])
-    by_rows = hs.diff_attend_rows(jnp.stack([q[5], q[3]]), rows(k), rows(v), seen,
-                                  hs.diff_lambda(lp, layer), layer, lp["subln"],
-                                  CFG.layer_norm_eps)
+    by_rows = jax.jit(lambda q, k, v, mask: hs.diff_attend_rows(
+        q, k, v, mask, hs.diff_lambda(lp, layer), layer, lp["subln"], CFG.layer_norm_eps))(
+            jnp.stack([q[5], q[3]]), rows(k), rows(v), seen)
+    one = jax.jit(lambda q, k, v, mask: hs.diff_attend(
+        q, k, v, mask, hs.diff_lambda(lp, layer), layer, lp["subln"], CFG.layer_norm_eps))
     for i, (row, keys, vals) in enumerate(((5, k, v), (3, k[::-1], v[::-1]))):
-        one = hs.diff_attend(q[row][None], keys, vals, seen[i][None],
-                             hs.diff_lambda(lp, layer), layer, lp["subln"], CFG.layer_norm_eps)
-        np.testing.assert_allclose(np.asarray(by_rows[i]), np.asarray(one[0]), atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(by_rows[i]), np.asarray(one(q[row][None], keys, vals, seen[i][None])[0]),
+            atol=2e-5)
 
 
 def test_bfloat16_program_fails_the_tolerance(ctx, model, ref_forward):
@@ -360,20 +367,21 @@ def test_the_server_refuses_what_rests_on_the_pool_alone(model, engine):
 
 
 @pytest.mark.chaos
-def test_a_recovered_request_is_prefilled_again_from_its_history(model, monkeypatch):
+def test_a_recovered_request_is_prefilled_again_from_its_history(engine, monkeypatch):
     """An abort in the second decode chunk: the rebuild re-prefills every
     in-flight request from its tokens, which rebuilds its state, and the
-    streams are those of the same requests served undisturbed."""
+    streams are those of the same requests served undisturbed (first, on
+    the module's engine at the served test's shapes, whose programs are
+    there: what this test has to compile is the recovery's rebuild)."""
     from triton_dist_tpu.runtime import resilience
 
     monkeypatch.setenv("TDT_DEGRADE_PROBE_S", "0.01")
     telemetry.reset()
     resilience.reset_degradation()
-    eng = Engine(model, backend="dist", max_len=T_REF)
-    prompts = [_ids(20, seed=30), _ids(11, seed=31)]
+    prompts = [_ids(30, seed=20), _ids(17, seed=21)]
 
     def serve(schedule):
-        srv = InferenceServer(eng, num_slots=2, chunk=2, prefill_chunk=8)
+        srv = InferenceServer(engine, num_slots=2, chunk=4, prefill_chunk=12)
         reqs = [srv.submit(p, 7) for p in prompts]
         with resilience.chaos_schedule(schedule):
             srv.run()
@@ -382,9 +390,10 @@ def test_a_recovered_request_is_prefilled_again_from_its_history(model, monkeypa
         return [list(r.tokens) for r in reqs]
 
     try:
+        want = serve("heal")
         got = serve("abort@decode:1,heal")
         assert telemetry.counter_value("tdt_serving_recoveries_total", from_backend="dist") == 1.0
-        assert got == serve("heal") and all(len(t) == 7 for t in got)
+        assert got == want and all(len(t) == 7 for t in got)
     finally:
         resilience.reset_degradation()
-        eng.rebuild("dist")
+        engine.rebuild("dist")

@@ -692,9 +692,20 @@ def _ssm_scan(sds):
                       f32(512, 16), f32(5120), f32(16, 5120))
 
 
+def _shared_kv_decode(sds):
+    """One reading layer's decode step at the published widths: 32 slots of
+    40 block-diagonal query rows over the pool's rows of 1280, a table of
+    168 blocks of 16 a slot."""
+    from triton_dist_tpu.kernels.shared_kv_decode import shared_kv_decode
+
+    pool = sds((1, 32 * 168 + 1, 1, 16, 1280))
+    return (lambda *a: shared_kv_decode(*a, scale=0.125)), (
+        sds((32, 40, 1280)), pool, pool, sds((32, 168), jnp.int32), sds((32,), jnp.int32))
+
+
 @pytest.mark.parametrize(
     "case", [_flash_decode, _paged_flash_decode, _flash_attention, _dsa_kth_value,
-             _dsa_flash_prefill, _ssm_scan],
+             _dsa_flash_prefill, _ssm_scan, _shared_kv_decode],
     ids=lambda f: f.__name__.lstrip("_"))
 def test_named_kernel_compiles_under_its_name(topo_2x2, case):
     """At Qwen3-8B head shapes, for one v5e chip: the kernel compiles and
@@ -901,7 +912,8 @@ def test_hybrid_ssm_programs_fit_whole(topo_2x2):
     buffers, with one slot's state carried in and out and the scan in its
     kernel once a Mamba layer, and the decode chunk at 32 slots, which
     carries the pool pair AND the slots' state in place (both aliased to
-    its outputs) and holds no copy of a ring's shape beside them."""
+    its outputs), holds no copy of a ring's or the pool's shape beside them
+    and reads the pool through ``shared_kv_decode`` once a reading layer."""
     from triton_dist_tpu.models.engine import Engine
     from triton_dist_tpu.runtime.platform import force_mosaic
 
@@ -934,10 +946,21 @@ def test_hybrid_ssm_programs_fit_whole(topo_2x2):
         key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
         chunk, held = _compile(eng._decode_chunk_paged.lower(
             params, (), i32(slots), pool, pool, i32(slots, max_blocks), i32(slots), i32(slots),
-            int(sv["chunk"]), key, state), mosaic=False)  # decode attends in plain XLA
+            int(sv["chunk"]), key, state), kernels=("shared_kv_decode",))
     assert held < HBM_BYTES, held
     assert chunk.memory_analysis().alias_size_in_bytes >= resident
     ring = state["ring_k"][0]
-    assert _pool_sized_copies(chunk.as_text(), jax.ShapeDtypeStruct(
-        (1,) + ring.shape, ring.dtype)) == []
+    hlo = chunk.as_text()
+    assert _pool_sized_copies(hlo, jax.ShapeDtypeStruct((1,) + ring.shape, ring.dtype)) == []
+    # the full layer's K/V is read where it lies, by the eight layers that
+    # read it: no copy of the pool beside the kernel's operand, no gather of
+    # the table's whole extent, none of the dense passes over it
+    calls = [l for l in hlo.splitlines() if "tpu_custom_call" in l]
+    assert sum("%shared_kv_decode" in l.split("=")[0] for l in calls) == 1 + len(
+        c.layers_of("cross")), calls
+    assert _pool_sized_copies(hlo, pool) == []
+    extent = slots * max_blocks
+    for gone in (f"bf16[{extent},{bs},", f"f32[{slots},{c.num_q_heads},{max_blocks * bs}]",
+                 f"bf16[{slots},{max_blocks * bs},"):
+        assert gone not in hlo, gone
 

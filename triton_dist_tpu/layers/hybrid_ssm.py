@@ -1,6 +1,7 @@
 """The shard-local math of ``models/hybrid_ssm.py``: a Mamba-1 mixer,
 differential attention, a gated memory unit, the fused SwiGLU. Plain XLA but
-for the selective scan (``kernels/ssm_scan.py``).
+for the selective scan (``kernels/ssm_scan.py``) and the pool's read in
+place (``kernels/shared_kv_decode.py``).
 
 * **Mamba**, in three forms over the same weights. :func:`mamba_chunk`: a
   chunk of rows with the conv's tail and the state carried in and out, and
@@ -19,7 +20,9 @@ for the selective scan (``kernels/ssm_scan.py``).
   for one query a slot over keys and values kept as whole rows (all heads
   side by side, as the rings and the pool keep them): the heads' structure
   goes into a block-diagonal query, so both products are dense matrix
-  products over the rows as they lie, and no row is laid out again.
+  products over the rows as they lie, and no row is laid out again;
+  :func:`diff_attend_pool` is that over a block pool's rows read in place
+  (``kernels/shared_kv_decode.py``).
 * **Gated memory unit** (:func:`gmu`): ``W_out (m * silu(W_in u))``.
 """
 
@@ -30,6 +33,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from triton_dist_tpu.kernels.shared_kv_decode import shared_kv_decode
 from triton_dist_tpu.kernels.ssm_scan import ssm_scan, ssm_scan_xla
 from triton_dist_tpu.layers.latent_sparse import layer_norm, mm  # noqa: F401
 
@@ -147,28 +151,57 @@ def diff_attend(q, k, v, mask, lam, layer: int, subln, eps: float):
     return o.reshape(t, hq * dh).astype(q.dtype)
 
 
+def rows_query(q, hkv: int):
+    """q (B, Hq, D) as block-diagonal rows (B, Hq, Hkv * D): query head ``h``'s
+    values in the columns of the key head it reads, zeros elsewhere, so that
+    its score over a whole K row is one dense product."""
+    hq = q.shape[1]
+    heads = jnp.arange(hq)
+    key_head = 2 * (heads // (2 * (hq // hkv))) + heads % 2
+    reads = (key_head[:, None] == jnp.arange(hkv)[None, :]).astype(q.dtype)  # (Hq, Hkv)
+    return jnp.einsum("bhd,hj->bhjd", q, reads).reshape(q.shape[0], hq, -1)
+
+
+def diff_rows_finish(o, dtype, rep: int, dh: int, layer: int, subln, eps: float):
+    """o (B, pairs, Hkv * D) float32: what a pair's difference of softmaxes
+    gives over whole V rows. Keeps a pair's own group of ``2 D`` values,
+    norms and scales them. Returns (B, Hq * D)."""
+    b, pairs, width = o.shape
+    groups = width // (2 * dh)
+    o = o.reshape(b, pairs, groups, 2 * dh)
+    own = (jnp.arange(pairs)[:, None] // rep == jnp.arange(groups)[None, :])
+    o = jnp.sum(jnp.where(own[None, :, :, None], o, 0.0), axis=2)  # a pair's own group
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    o = o * subln.astype(F32) * (1.0 - lambda_init(layer))
+    return o.reshape(b, 2 * pairs * dh).astype(dtype)
+
+
 def diff_attend_rows(q, k_rows, v_rows, mask, lam, layer: int, subln, eps: float):
     """One query a slot: q (B, Hq, D); ``k_rows``, ``v_rows`` (B, S, Hkv * D)
     the keys and values of S positions, a row a position; mask (B, S) bool.
     Returns (B, Hq * D): :func:`diff_attend`'s numbers."""
     b, hq, dh = q.shape
     hkv = k_rows.shape[-1] // dh
-    groups, rep = hkv // 2, hq // hkv
-    heads = jnp.arange(hq)
-    key_head = 2 * (heads // (2 * rep)) + heads % 2  # the key head a query head reads
-    reads = (jnp.arange(hkv)[:, None] == key_head[None, :]).astype(q.dtype)  # (Hkv, Hq)
-    q_diag = jnp.einsum("bhd,jh->bjdh", q, reads).reshape(b, hkv * dh, hq)
-    sc = jnp.einsum("bsk,bkh->bhs", k_rows, q_diag, preferred_element_type=F32)
+    sc = jnp.einsum("bsk,bhk->bhs", k_rows, rows_query(q, hkv), preferred_element_type=F32)
     sc = jnp.where(mask[:, None, :], sc / math.sqrt(dh), NEG)
     pr = jax.nn.softmax(sc, axis=-1).reshape(b, hq // 2, 2, -1)
     a = (pr[:, :, 0] - lam * pr[:, :, 1]).astype(v_rows.dtype)  # (B, pairs, S)
     o = jnp.einsum("bps,bsk->bpk", a, v_rows, preferred_element_type=F32)
-    o = o.reshape(b, hq // 2, groups, 2 * dh)
-    own = (jnp.arange(hq // 2)[:, None] // rep == jnp.arange(groups)[None, :])
-    o = jnp.sum(jnp.where(own[None, :, :, None], o, 0.0), axis=2)  # a pair's own group
-    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
-    o = o * subln.astype(F32) * (1.0 - lambda_init(layer))
-    return o.reshape(b, hq * dh).astype(q.dtype)
+    return diff_rows_finish(o, q.dtype, hq // hkv, dh, layer, subln, eps)
+
+
+def diff_attend_pool(q, k_pool, v_pool, tables, lengths, lam, layer: int, subln, eps: float):
+    """:func:`diff_attend_rows` over the rows of a block pool (1, blocks, 1,
+    bs, Hkv * D) through a slot's table row, positions ``[0, length)``, read
+    in place by ``kernels/shared_kv_decode.py``: a head's softmax over the
+    whole V rows comes back float32, and the pair's difference is taken of
+    those, not of the probabilities."""
+    b, hq, dh = q.shape
+    hkv = k_pool.shape[-1] // dh
+    o = shared_kv_decode(rows_query(q, hkv), k_pool, v_pool, tables, lengths,
+                         scale=1.0 / math.sqrt(dh)).reshape(b, hq // 2, 2, -1)
+    return diff_rows_finish(o[:, :, 0] - lam * o[:, :, 1], q.dtype, hq // hkv, dh,
+                            layer, subln, eps)
 
 
 def gmu(lp, u, m):

@@ -26,8 +26,9 @@ tables as the other models; what differs is declared:
   prompt starting from zeros, and writes it into the slot when the prompt
   is done. Nothing of it is in the pool, so the server shares no prefix and
   rewinds no draft for such a model (``docs/serving.md``).
-* ``step_stats``: the window layers' attended and visible positions and the
-  rows scanned, [in prefill chunks, in decode steps].
+* ``step_stats``: the window layers' attended and visible positions, the
+  rows scanned, and the positions the layers that read the pool fetched
+  from it and could see, [in prefill chunks, in decode steps].
 
 A prompt row that is not its last needs only the first half, the last Mamba
 layer and the full layer's K/V: the layers above run for a prompt's last
@@ -44,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from triton_dist_tpu.kernels import shared_kv_decode
 from triton_dist_tpu.layers import hybrid_ssm as hs
 from triton_dist_tpu.models.config import HybridSSMConfig
 from triton_dist_tpu.models.kv_cache import kv_rows
@@ -193,10 +195,15 @@ class HybridSSMLLM:
         prefill chunks, in decode steps]: over the window layers and the
         rows somebody sent, the positions a row attended to and those it
         could have seen (its position + 1); the rows the Mamba layers
-        scanned (a row once, not once a layer)."""
+        scanned (a row once, not once a layer); over the layers that read
+        the pool and the decode rows somebody sent, the positions a step
+        fetched from the pool (whole tiles up to the length in place, the
+        table's extent gathered) and those its rows could see."""
         return {"swa_attended": jnp.zeros((2,), jnp.int32),
                 "swa_visible": jnp.zeros((2,), jnp.int32),
-                "ssm_tokens": jnp.zeros((2,), jnp.int32)}
+                "ssm_tokens": jnp.zeros((2,), jnp.int32),
+                "shared_kv_read": jnp.zeros((2,), jnp.int32),
+                "shared_kv_visible": jnp.zeros((2,), jnp.int32)}
 
     def publish_step_stats(self, stats) -> None:
         """Host side: feed the counters from a finished program's stats."""
@@ -207,6 +214,10 @@ class HybridSSMLLM:
             telemetry.inc("tdt_swa_positions_visible_total",
                           float(stats["swa_visible"][i]), phase=phase)
             telemetry.inc("tdt_ssm_tokens_total", float(stats["ssm_tokens"][i]), phase=phase)
+            telemetry.inc("tdt_shared_kv_positions_read_total",
+                          float(stats["shared_kv_read"][i]), phase=phase)
+            telemetry.inc("tdt_shared_kv_positions_visible_total",
+                          float(stats["shared_kv_visible"][i]), phase=phase)
 
     @staticmethod
     def _windowed(stats, phase: int, rows, mask, pos):
@@ -228,6 +239,9 @@ class HybridSSMLLM:
         return (self._heads(qkv[..., :qw], c.num_q_heads),
                 qkv[..., qw:qw + kvw], qkv[..., qw + kvw:])
 
+    def _out(self, lp, a):
+        return hs.mm(a, lp["w_o"]) + lp["b_o"]
+
     def _attend(self, lp, layer: int, q, k_rows, v_rows, mask):
         """A chunk's queries q (T, Hq, D), or one a slot (B, Hq, D) over the
         slot's own rows (B, S, Hkv * D)."""
@@ -238,7 +252,7 @@ class HybridSSMLLM:
         else:
             a = hs.diff_attend(q, self._heads(k_rows, c.num_kv_heads),
                                self._heads(v_rows, c.num_kv_heads), *args)
-        return hs.mm(a, lp["w_o"]) + lp["b_o"]
+        return self._out(lp, a)
 
     def _mlp(self, lp, x):
         c = self.config
@@ -345,9 +359,11 @@ class HybridSSMLLM:
         its window layers write position ``i`` at ``i % window`` of their
         rings, the full layer writes its one K/V row through the table (an
         inactive slot's to the NULL block, its state left as it was); the
-        pool's rows are gathered through the table once and read by the
-        full layer and every cross layer. Returns (logits (B, V), pk, pv,
-        state, stats)."""
+        full layer and every cross layer read the pool's rows through the
+        table: in place, a slot's live tiles only, where the shapes let
+        ``kernels/shared_kv_decode.py`` take them, else gathered once at
+        the table's whole extent. Returns (logits (B, V), pk, pv, state,
+        stats)."""
         del mode
         c = self.config
         w = c.sliding_window
@@ -361,10 +377,24 @@ class HybridSSMLLM:
         ring_row = jnp.where(active, pos % w, w)  # an inactive slot's write is dropped
         ring_at = pos[:, None] - jnp.mod(pos[:, None] - jnp.arange(w, dtype=jnp.int32)[None], w)
         in_window = ring_at >= 0  # (B, w)
-        visible = jnp.arange(max_blocks * bs, dtype=jnp.int32)[None] <= pos[:, None]
+        seen = jnp.where(active, pos + 1, 0)  # the positions a row somebody sent may see
+        # by shape alone: the kernel that reads the pool where it lies, or
+        # the pool gathered through the table at its whole extent
+        in_place = shared_kv_decode.takes(c.num_q_heads, pk.shape, max_blocks, pk.dtype.itemsize)
+        if in_place:
+            tile = bs * shared_kv_decode.tile_pages(pk.shape, max_blocks, pk.dtype.itemsize)
+            fetched = -(-seen // tile) * tile
+        else:
+            visible = jnp.arange(max_blocks * bs, dtype=jnp.int32)[None] <= pos[:, None]
+            fetched = jnp.where(active, max_blocks * bs, 0)
         state = {k: list(v) for k, v in state.items()}
         stats = self.step_stats()
         stats["ssm_tokens"] = stats["ssm_tokens"].at[1].add(active.sum(dtype=jnp.int32))
+        readers = 1 + len(c.layers_of("cross"))
+        stats["shared_kv_read"] = stats["shared_kv_read"].at[1].add(
+            readers * fetched.sum(dtype=jnp.int32))
+        stats["shared_kv_visible"] = stats["shared_kv_visible"].at[1].add(
+            readers * seen.sum(dtype=jnp.int32))
         x = p["embed"][token]
         i_mamba = i_win = 0
         m = k_all = v_all = None
@@ -390,14 +420,20 @@ class HybridSSMLLM:
                     q, k, v = self._qkv(lp, u)
                     pk = pk.at[0, phys, 0, sub].set(k)
                     pv = pv.at[0, phys, 0, sub].set(v)
-                    # a table holds block numbers of the pool: nothing to fill in
-                    through = lambda pool: jnp.take(
-                        pool[0, :, 0], tables, axis=0, mode="clip").reshape(
-                            B, max_blocks * bs, -1)
-                    k_all, v_all = through(pk), through(pv)
+                    if not in_place:
+                        # a table holds block numbers of the pool: nothing to fill in
+                        through = lambda pool: jnp.take(
+                            pool[0, :, 0], tables, axis=0, mode="clip").reshape(
+                                B, max_blocks * bs, -1)
+                        k_all, v_all = through(pk), through(pv)
                 else:
                     q = self._heads(hs.mm(u, lp["w_q"]) + lp["b_q"], c.num_q_heads)
-                mix = self._attend(lp, layer, q, k_all, v_all, visible)
+                if in_place:
+                    mix = self._out(lp, hs.diff_attend_pool(
+                        q, pk, pv, tables, seen, hs.diff_lambda(lp, layer), layer, lp["subln"],
+                        c.layer_norm_eps))
+                else:
+                    mix = self._attend(lp, layer, q, k_all, v_all, visible)
             x = self._mlp(lp, x + mix)
         return self._logits(p, x), pk, pv, state, stats
 

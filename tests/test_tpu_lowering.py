@@ -703,9 +703,50 @@ def _shared_kv_decode(sds):
         sds((32, 40, 1280)), pool, pool, sds((32, 168), jnp.int32), sds((32,), jnp.int32))
 
 
+def _lightning_chunk(sds):
+    """One lightning layer's prefill chunk at the published widths: 2048
+    rows of 32 heads of 128, the float32 state in and out."""
+    from triton_dist_tpu.kernels.lightning_attn import lightning_chunk
+
+    f32 = lambda *shape: sds(shape, jnp.float32)
+    return lightning_chunk, (sds((2048, 4096)), sds((2048, 4096)), sds((2048, 4096)),
+                             f32(32, 128, 128), f32(32), sds((), jnp.int32))
+
+
+def _bsa_select(sds):
+    """A sparse layer's selection scores for a chunk deep in the longest
+    prompt: 2048 rows of 2 x 16 heads over 1024 pooled keys."""
+    from triton_dist_tpu.kernels.block_sparse_attn import bsa_group_scores
+
+    return (lambda *a: bsa_group_scores(*a, kernel=32, stride=16)), (
+        sds((2048, 2, 16, 128)), sds((1024, 256)), sds((), jnp.int32))
+
+
+def _bsa_prefill(sds):
+    """A sparse layer's attend for that chunk: the prompt's K and V rows
+    of 256 as they lie, a selection of the 256 blocks a (head, row)."""
+    from triton_dist_tpu.kernels.block_sparse_attn import bsa_prefill
+
+    return (lambda *a: bsa_prefill(*a, block=64, scale=128 ** -0.5)), (
+        sds((2048, 2, 16, 128)), sds((16384, 256)), sds((16384, 256)),
+        sds((2, 2048, 256), jnp.bool_), sds((), jnp.int32))
+
+
+def _bsa_decode(sds):
+    """A sparse layer's decode step: 8 slots, 64 selected pages of 64 rows
+    a (slot, K/V head) of a three-layer pool, a table of 260 pages a slot."""
+    from triton_dist_tpu.kernels.block_sparse_attn import bsa_decode
+
+    pool = sds((3, 8 * 260 + 1, 1, 64, 256))
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    return (lambda q, pk, pv, *a: bsa_decode(q, pk, pv, 1, *a, scale=128 ** -0.5)), (
+        sds((8, 2, 16, 128)), pool, pool, i32(8, 260), i32(8, 2, 64), i32(8, 2), i32(8))
+
+
 @pytest.mark.parametrize(
     "case", [_flash_decode, _paged_flash_decode, _flash_attention, _dsa_kth_value,
-             _dsa_flash_prefill, _ssm_scan, _shared_kv_decode],
+             _dsa_flash_prefill, _ssm_scan, _shared_kv_decode, _lightning_chunk,
+             _bsa_select, _bsa_prefill, _bsa_decode],
     ids=lambda f: f.__name__.lstrip("_"))
 def test_named_kernel_compiles_under_its_name(topo_2x2, case):
     """At Qwen3-8B head shapes, for one v5e chip: the kernel compiles and
@@ -964,3 +1005,85 @@ def test_hybrid_ssm_programs_fit_whole(topo_2x2):
                  f"bf16[{slots},{max_blocks * bs},"):
         assert gone not in hlo, gone
 
+
+# ---------------------------------------------------------------------------
+# The fourth configuration's programs, at its published widths and its cut
+# ---------------------------------------------------------------------------
+
+
+def test_sparse_linear_programs_fit(topo_2x2):
+    """``longqa``'s programs for one v5e chip at layers 9-20 (7.86 GB of
+    weights): the prefill chunk over the longest prompt buffer, with one
+    slot's state carried in and out, the lightning kernel once a lightning
+    layer and the selection and the attend once a sparse layer; the decode
+    chunk at 8 slots, which carries the pool pair AND the slots' state in
+    place (both aliased to its outputs) and reads the pool through
+    ``bsa_decode`` once a sparse layer, never gathered at the table's
+    extent."""
+    import json
+    import sys
+
+    from triton_dist_tpu.models import Engine, SparseLinearLLM
+    from triton_dist_tpu.models.sparse_linear import layer_ones, layer_tensors
+    from triton_dist_tpu.runtime.mesh import initialize_distributed
+    from triton_dist_tpu.runtime.platform import force_mosaic
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.build.minicpm_sala import model_config
+
+    with open(os.path.join(root, "benchmark/configs/minicpm-sala-d12.json")) as f:
+        cfg = json.load(f)
+    c, sv = model_config(cfg), cfg["serving"]
+    ctx = initialize_distributed(devices=list(topo_2x2.devices[:1]), axis_names=("tp",),
+                                 set_default=False)
+    rep = ctx.replicated()
+    dt = jnp.dtype(c.dtype)
+    sds = lambda shape, dtype=dt: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=rep)
+    params = {"embed": sds((c.vocab_size, c.hidden_size)),
+              "head": sds((c.hidden_size, c.vocab_size)),
+              "final_norm": sds((c.hidden_size,)), "layers": []}
+    for layer in range(c.num_layers):
+        lp = {n: sds(shape) for n, shape in layer_tensors(c, layer)}
+        lp.update({n: sds((k,)) for n, k in layer_ones(c, layer)})
+        params["layers"].append(lp)
+    model = SparseLinearLLM(c, ctx, params=params)
+    slots, rows, bs = int(sv["slots"]), int(sv["prefill_chunk"]), int(sv["block_size"])
+    max_blocks = -(-int(sv["max_len"]) // bs)
+    n_sparse, n_lin = len(c.layers_of("sparse")), len(c.layers_of("lightning"))
+    width = c.num_kv_heads * c.head_dim
+    with force_mosaic():
+        eng = Engine(model, backend=sv["backend"], max_len=int(sv["max_len"]))
+        shaped = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+        i32 = lambda *shape: sds(shape, jnp.int32)
+        pool = jax.ShapeDtypeStruct((n_sparse, slots * max_blocks + 1, 1, bs, width), dt,
+                                    sharding=eng._pool_sharding)
+        state = shaped(jax.eval_shape(lambda: model.slot_state(slots)))
+        resident = 2 * _nbytes(pool) + sum(_nbytes(x) for x in jax.tree.leaves(state))
+        assert resident == cfg["bytes"]["pool"] + cfg["bytes"]["slot_state"]
+        buf = jax.ShapeDtypeStruct((n_sparse, 1, 1, 16384, width), dt, sharding=eng._kv_sharding)
+        one = shaped(jax.eval_shape(lambda: model.slot_state(1)))
+        compiled, held = _compile(eng._prefill_chunk_prog.lower(
+            params, i32(1, rows), buf, buf, i32(), i32(), one),
+            kernels=("lightning_chunk", "bsa_select", "bsa_prefill"))
+        assert held + resident < HBM_BYTES, held
+        calls = [l.split("=")[0] for l in compiled.as_text().splitlines()
+                 if "tpu_custom_call" in l]
+        count = lambda name: sum(f"%{name}." in l or l.strip().endswith(f"%{name} ")
+                                 for l in calls)
+        assert (count("lightning_chunk"), count("bsa_select"), count("bsa_prefill")) == (
+            n_lin, n_sparse, n_sparse), calls
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+        chunk, held = _compile(eng._decode_chunk_paged.lower(
+            params, (), i32(slots), pool, pool, i32(slots, max_blocks), i32(slots), i32(slots),
+            int(sv["chunk"]), key, state), kernels=("bsa_decode",))
+    assert held < HBM_BYTES, held
+    assert chunk.memory_analysis().alias_size_in_bytes >= resident
+    hlo = chunk.as_text()
+    calls = [l.split("=")[0] for l in hlo.splitlines() if "tpu_custom_call" in l]
+    assert sum("%bsa_decode" in l for l in calls) == n_sparse, calls
+    assert _pool_sized_copies(hlo, pool) == []
+    # no gather of a slot's whole table: nothing of (slots, extent, row) or (slots x pages, page, row)
+    for gone in (f"bf16[{slots},{max_blocks * bs},", f"bf16[{slots * max_blocks},{bs},"):
+        assert gone not in hlo, gone

@@ -91,6 +91,8 @@ from triton_dist_tpu.kernels.ep_fused import (
 from triton_dist_tpu.kernels.flash_attn import flash_attention, flash_attention_varlen
 from triton_dist_tpu.kernels.flash_decode import flash_decode
 from triton_dist_tpu.kernels.gdn import gdn_fwd
+from triton_dist_tpu.kernels.lightning_attn import lightning_chunk, lightning_step
+from triton_dist_tpu.kernels.block_sparse_attn import bsa_decode, bsa_prefill
 from triton_dist_tpu.kernels.memory_ops import copy_tensor, fill
 from triton_dist_tpu.kernels.low_latency_a2a import (
     dequantize_fp8,
@@ -172,6 +174,10 @@ __all__ = [
     "flash_attention_varlen",
     "flash_decode",
     "gdn_fwd",
+    "lightning_chunk",
+    "lightning_step",
+    "bsa_prefill",
+    "bsa_decode",
     "copy_tensor",
     "fill",
     "quantize_fp8",

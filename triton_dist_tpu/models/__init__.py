@@ -9,12 +9,14 @@ from triton_dist_tpu.models.config import (
     HybridSSMConfig,
     ModelConfig,
     PRESETS,
+    SparseLinearConfig,
 )
 from triton_dist_tpu.models.kv_cache import CacheRow, KVCache, PagedKVCache, kv_rows
 from triton_dist_tpu.models.dense import DenseLLM, Qwen3MoE, DenseParams, init_params
 from triton_dist_tpu.models.moe import EPMoELLM, ep_specs
 from triton_dist_tpu.models.latent_sparse import LatentSparseConfig, LatentSparseLLM
 from triton_dist_tpu.models.hybrid_ssm import HybridSSMLLM
+from triton_dist_tpu.models.sparse_linear import SparseLinearLLM
 from triton_dist_tpu.models.engine import Engine
 from triton_dist_tpu.models.drafter import (
     Drafter,
@@ -40,6 +42,8 @@ __all__ = [
     "HybridSSMConfig",
     "HYBRID_SSM_PRESETS",
     "HybridSSMLLM",
+    "SparseLinearConfig",
+    "SparseLinearLLM",
     "ep_specs",
     "DenseParams",
     "init_params",

@@ -89,6 +89,78 @@ class HybridSSMConfig:
         return tuple(l for l in range(self.num_layers) if self.layer_kind(l) == kind)
 
 
+@dataclasses.dataclass(frozen=True)
+class SparseLinearConfig:
+    """A decoder that mixes block-sparse attention layers with lightning
+    linear-attention layers (``models/sparse_linear.py``), in muP form. The
+    defaults are a toy whose contexts of 80-200 tokens select; the published
+    shapes are ``benchmark/configs/minicpm-sala-d12.json``'s.
+
+    ``mixer_types``: a layer is ``"sparse"`` or ``"lightning"``.
+    ``published_layers`` is the depth under the residual's root
+    (``scale_depth / sqrt(published_layers)``) whatever depth is held.
+    Sparse layers: ``num_q_heads`` query heads over ``num_kv_heads`` K/V
+    heads of ``head_dim``, no rotation; a pooled key is the mean of
+    ``kernel_size`` keys every ``kernel_stride``; a query group attends
+    ``topk`` blocks of ``block_size`` positions: the first ``init_blocks``,
+    the ``window_size // block_size`` ending at its own, the rest by score.
+    Lightning layers: ``lightning_heads`` heads of ``lightning_head_dim``,
+    rotated. ``max_len`` is the longest sequence a slot holds: the extent
+    of a slot's pooled keys (``max_len // kernel_stride`` of them)."""
+
+    vocab_size: int = 256
+    hidden_size: int = 64
+    intermediate_size: int = 128
+    mixer_types: tuple = ("sparse", "lightning", "lightning", "sparse")
+    published_layers: int = 32
+    num_q_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 16
+    lightning_heads: int = 4
+    lightning_head_dim: int = 16
+    kernel_size: int = 4
+    kernel_stride: int = 2
+    block_size: int = 8
+    topk: int = 4
+    init_blocks: int = 1
+    window_size: int = 16
+    scale_emb: float = 12.0
+    scale_depth: float = 1.4
+    dim_model_base: int = 4
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-6
+    max_len: int = 256
+    dtype: str = "float32"
+
+    def __post_init__(self):
+        assert set(self.mixer_types) <= {"sparse", "lightning"} and self.mixer_types
+        assert self.num_q_heads % self.num_kv_heads == 0
+        assert self.kernel_size % self.kernel_stride == 0
+        assert self.block_size % self.kernel_stride == 0
+        assert self.window_size % self.block_size == 0
+        assert self.init_blocks + self.window_size // self.block_size <= self.topk
+        assert self.lightning_head_dim % 2 == 0
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.mixer_types)
+
+    @property
+    def residual_scale(self) -> float:
+        return self.scale_depth / self.published_layers ** 0.5
+
+    @property
+    def logit_scale(self) -> float:
+        return self.dim_model_base / self.hidden_size
+
+    @property
+    def pooled_extent(self) -> int:
+        return self.max_len // self.kernel_stride
+
+    def layers_of(self, kind: str) -> tuple:
+        return tuple(l for l, k in enumerate(self.mixer_types) if k == kind)
+
+
 PRESETS: dict[str, ModelConfig] = {
     # Qwen3-8B/32B-style dense shapes (reference e2e targets, e2e_dense.md)
     "qwen3-8b": ModelConfig(

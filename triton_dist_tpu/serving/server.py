@@ -162,7 +162,8 @@ class InferenceServer:
                  shed_wait_s: float | None = None,
                  shed_priority: int | None = None,
                  journal=None, spec_k: int | None = None, drafter=None,
-                 prefill_chunk: int | None = None):
+                 prefill_chunk: int | None = None,
+                 block_size: int | None = None):
         self.engine = engine
         self.num_slots = (
             get_int_env("TDT_SERVE_SLOTS", 4) if num_slots is None else int(num_slots)
@@ -180,7 +181,12 @@ class InferenceServer:
         self._preferred_backend = getattr(
             engine, "preferred_backend", engine.backend
         )
-        self.block_size = get_int_env("TDT_KV_BLOCK_SIZE", 16)
+        #: Positions a page of the pool: the argument (a model whose
+        #: selection reads whole blocks states its block), else
+        #: TDT_KV_BLOCK_SIZE, else 16.
+        self.block_size = (
+            get_int_env("TDT_KV_BLOCK_SIZE", 16) if block_size is None else int(block_size)
+        )
         assert self.block_size >= 1
         max_blocks = -(-engine.max_len // self.block_size)
         # Default pool: every slot can hold a FULL max_len chain at once

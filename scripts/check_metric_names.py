@@ -198,6 +198,11 @@ REQUIRED_NAMES = {
     "tdt_serving_joins_total",
     "tdt_serving_decode_chunks_total",
     "tdt_jit_lowerings_total",
+    # how a decode chunk was landed: behind the next one's issue, or first
+    # and why (serving/server.py) — docs/serving.md "One chunk in flight";
+    # the two sum to tdt_serving_decode_chunks_total
+    "tdt_serving_decode_chunks_ahead_total",
+    "tdt_serving_decode_sync_boundaries_total",
     # which way a paged decode chunk ran: against the pool in place, or
     # bounced through the contiguous layout (models/engine.py)
     "tdt_engine_decode_chunks_total",

@@ -77,6 +77,7 @@ def _serve(eng, prefill_chunk, n_requests=3):
     seen = {id(r): {} for r in reqs}
     for _ in range(60):
         srv.step()
+        srv._land_in_flight()  # the cache and the last tokens of the same chunk
         decoding = srv.scheduler.decoding_slots()
         if decoding:
             logits = np.asarray(eng.decode_logits_paged(srv.cache, jnp.asarray(srv._last)))

@@ -574,3 +574,268 @@ def test_traced_serve_streams_the_same_tokens(traced_serve):
     assert traced_serve["tokens"] == traced_serve["warm_tokens"]
     for got, ref in zip(traced_serve["tokens"], traced_serve["refs"]):
         np.testing.assert_array_equal(np.asarray(got, np.int32), ref)
+
+
+def test_a_chunks_wait_is_the_engines_span_under_the_fetch(traced_serve):
+    """Every decode chunk's wait for the device lies under
+    ``tdt_engine_host_sync``, the fetch's child, the iteration's
+    grandchild: the loop's own spans hold no device time, whichever chunk
+    the iteration landed."""
+    events = traced_serve["events"]
+    waits = [e for e in events if e[0] == "tdt_engine_host_sync"]
+    assert len(waits) == traced_serve["counters"]["tdt_serving_decode_chunks_total"]
+    for _, a, b in waits:
+        over = [e[0] for e in events if e[1] <= a and b <= e[2]
+                and e[0] != "tdt_engine_host_sync"]
+        assert over == ["tdt_serving_step", "tdt_serving_fetch"]
+
+
+# ============================================= one decode chunk in flight
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    """The tiny ``test-hybrid-ssm`` preset: per-slot state (Mamba state,
+    window rings) rides each chunk beside the pool."""
+    from triton_dist_tpu.models import HYBRID_SSM_PRESETS, Engine, HybridSSMLLM
+    from triton_dist_tpu.runtime.mesh import initialize_distributed
+
+    ctx = initialize_distributed(
+        devices=jax.devices()[:1], axis_names=("tp",), set_default=False)
+    model = HybridSSMLLM(HYBRID_SSM_PRESETS["test-hybrid-ssm"], ctx,
+                         key=jax.random.PRNGKey(7))
+    return Engine(model, backend="dist", max_len=MAX_LEN)
+
+
+@pytest.fixture(scope="module")
+def engine1(model1):
+    """One engine for the tests below that need no fresh one: its programs
+    compile once."""
+    return make_engine(model1)
+
+
+def _landings(srv):
+    """Record every landing of ``srv`` as (why, slots dropped)."""
+    seen, inner = [], srv._land
+
+    def land(chunk, why, drop=()):
+        seen.append((why, sorted(drop)))
+        return inner(chunk, why, drop)
+
+    srv._land = land
+    return seen
+
+
+# (prompt, max_new, what its own token stream does to it): three slots at
+# chunk 2, more clients than slots, so that requests join mid-decode. The
+# cancel and the deadline are set off by a token of their own request (the
+# deadline through the server's clock, which only that token moves), so
+# both loops meet them at the same token; each falls while the loop runs
+# ahead, with the next chunk already issued.
+SCRIPT = [
+    ([3, 17, 42, 7, 99], 17, ("cancel", 6)),
+    ([8, 1, 13], 11, None),
+    ([5, 5, 5, 5, 5], 21, ("deadline", 12)),
+    ([100, 200, 30], 6, None),
+    ([7, 7, 7, 7, 7], 9, None),
+    ([91, 12, 55], 14, None),
+    ([3, 3, 9], 1, None),
+]
+
+
+def _serve_script(eng, journal_path, ahead: bool):
+    srv = InferenceServer(eng, num_slots=3, chunk=2, journal=str(journal_path))
+    if not ahead:
+        srv._sync_reason = lambda chunk: "other"  # the seam: never in flight
+    clock = [0.0]
+    srv._now = lambda: clock[0]
+    landings = _landings(srv)
+    streams: dict[int, list[int]] = {}
+    reqs = []
+
+    def on_token(req, token, index):
+        streams.setdefault(req.req_id, []).append(token)
+        what = SCRIPT[reqs.index(req)][2]
+        if what == ("cancel", index):
+            srv.cancel(req.req_id)
+        elif what == ("deadline", index):
+            clock[0] = 100.0
+
+    for prompt, max_new, what in SCRIPT:
+        reqs.append(srv.submit(
+            prompt, max_new, on_token=on_token,
+            deadline_s=50.0 if what and what[0] == "deadline" else None))
+    srv.run()
+    assert srv._in_flight is None and srv.scheduler.occupancy() == 0
+    by_req: dict = {}
+    for rec in srv.journal_records():
+        by_req.setdefault(rec["req_id"] - reqs[0].req_id, []).append(
+            {k: v for k, v in rec.items() if k != "req_id"})
+    alloc = srv.kv_ledger.allocator
+    left = {
+        "tokens": [list(r.tokens) for r in reqs],
+        "streamed": [streams.get(r.req_id, []) for r in reqs],
+        "reasons": [r.finish_reason for r in reqs],
+        "journal": by_req,
+        "blocks": (sorted(alloc._free), dict(alloc._ref), srv.kv_ledger.stats()),
+    }
+    srv.shutdown(drain=False)
+    return left, landings
+
+
+@pytest.mark.parametrize("kind", ["dense", "hybrid-ssm"])
+def test_in_flight_streams_are_the_landed_loops_byte_for_byte(
+        kind, engine1, hybrid_engine, tmp_path):
+    """The script above through the loop as it is and through the loop that
+    lands every chunk before ``step()`` returns: the same tokens to the
+    same callbacks, the same finish reasons, the same journal a request and
+    the same blocks left in the allocator."""
+    eng = engine1 if kind == "dense" else hybrid_engine
+    got, landings = _serve_script(eng, tmp_path / "ahead.jsonl", ahead=True)
+    want, landed = _serve_script(eng, tmp_path / "landed.jsonl", ahead=False)
+    assert got == want
+    assert got["tokens"] == got["streamed"]
+    assert got["reasons"] == ["cancelled", "ok", "deadline", "ok", "ok", "ok", "ok"]
+    assert [len(t) for t in got["tokens"]] == [7, 11, 13, 6, 9, 14, 1]
+    # the loop did run ahead, and both reaps found a chunk in flight and
+    # kept its tokens from the slot they freed
+    assert sum(1 for why, _ in landings if why is None) >= 4
+    assert [drop for _, drop in landings if drop] == [[0], [2]]
+    assert len(landings) == len(landed) and all(why == "other" for why, _ in landed)
+
+
+def test_in_flight_counters_are_the_hosts_count_of_the_boundaries(model1):
+    """Two slots at chunk 2, 8 and 12 tokens after the first: chunks 1-3
+    are landed behind the next one's issue; chunk 4 finishes a slot; chunk
+    5 runs beside a free slot; chunk 6 finishes the other. The two
+    counters sum to the chunks."""
+    from jax._src import monitoring
+
+    from triton_dist_tpu.runtime import tracing
+
+    lowered = []
+
+    def on_duration(event, duration, fun_name=None, **_):
+        if event == tracing.LOWERING_EVENT:
+            lowered.append(fun_name)
+
+    srv = InferenceServer(make_engine(model1), num_slots=2, chunk=2)
+    landings = _landings(srv)
+    reqs = [srv.submit([3, 17, 42], 9), srv.submit([8, 1, 13], 13)]
+    steps = 0
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        while srv.step():
+            steps += 1
+            # in flight exactly where the next boundary changes nothing
+            assert (srv._in_flight is not None) == (steps <= 3)
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+    assert [len(r.tokens) for r in reqs] == [9, 13] and steps == 6
+    assert [why for why, _ in landings] == [
+        None, None, None, "finish", "free_slot", "finish"]
+    sync = lambda why: telemetry.counter_value(
+        "tdt_serving_decode_sync_boundaries_total", why=why)
+    assert telemetry.counter_value("tdt_serving_decode_chunks_ahead_total") == 3.0
+    assert (sync("finish"), sync("free_slot")) == (2.0, 1.0)
+    assert telemetry.counter_total("tdt_serving_decode_sync_boundaries_total") == 3.0
+    assert telemetry.counter_value("tdt_serving_decode_chunks_total") == 6.0
+    # one executable for both routes: this engine's chunk program was
+    # lowered once, fed from the host (chunks 1, 5, 6) and from the device's
+    # own last tokens (chunks 2-4)
+    assert lowered.count("jit(decode_chunk_paged)") == 1
+    srv.shutdown(drain=False)
+
+
+def test_a_cancel_between_steps_drops_the_chunk_in_flight(engine1):
+    """A cancel that arrives from outside while a chunk is in flight: the
+    next boundary lands the chunk, the cancelled request gets nothing of it
+    and nothing after, and the other streams are untouched. A server told
+    to drain lands the chunk it had in flight and leaves none after it."""
+    eng = engine1
+    want = np.asarray(eng.serve(jnp.asarray([[8, 1, 13]], jnp.int32), gen_len=15))[0]
+    srv = InferenceServer(eng, num_slots=2, chunk=2)
+    streamed: list[int] = []
+    gone = srv.submit([3, 17, 42], 13, on_token=lambda r, t, i: streamed.append(t))
+    kept = srv.submit([8, 1, 13], 15)
+    later = srv.submit([8, 1, 13], 9)
+    srv.step()
+    srv.step()
+    assert srv._in_flight is not None and len(gone.tokens) == 3
+    srv.cancel(gone.req_id)
+    srv.step()
+    assert gone.finish_reason == "cancelled" and streamed == list(gone.tokens)
+    assert len(gone.tokens) == 3 and len(kept.tokens) == 7
+    while srv._in_flight is None:  # ``later`` joins the slot; then ahead again
+        assert srv.step()
+    srv.drain_begin()
+    srv.step()
+    assert srv._in_flight is None
+    assert telemetry.counter_value(
+        "tdt_serving_decode_sync_boundaries_total", why="drain") == 1.0
+    while not srv.drained:
+        srv.step()
+        assert srv._in_flight is None
+    np.testing.assert_array_equal(np.asarray(kept.tokens, np.int32), want)
+    np.testing.assert_array_equal(np.asarray(later.tokens, np.int32), want[:9])
+    srv.shutdown()
+
+
+@pytest.mark.chaos
+def test_chaos_fault_at_the_landing_of_a_chunk_in_flight(engine1):
+    """The scripted decode fault fires where the host comes for chunk 2,
+    with chunk 3 already issued from its tokens: neither streams, recovery
+    re-prefills both requests from the history chunk 1 left, and the
+    streams are those of the undisturbed serve, nothing twice."""
+    eng = engine1
+    sizes = [([3, 17, 42], 9), ([8, 1, 13], 13)]
+
+    def serve(schedule):
+        srv = InferenceServer(eng, num_slots=2, chunk=2)
+        landings = _landings(srv)
+        streams: dict[int, list[int]] = {}
+        reqs = [srv.submit(p, n, on_token=lambda r, t, i: streams.setdefault(
+            r.req_id, []).append((i, t))) for p, n in sizes]
+        with resilience.chaos_schedule(schedule):
+            srv.run()
+        srv.shutdown(drain=False)
+        for r in reqs:  # every position once, in order
+            assert streams[r.req_id] == list(enumerate(r.tokens))
+        return [list(r.tokens) for r in reqs], landings
+
+    want, _ = serve("heal")
+    chunks = lambda: telemetry.counter_value("tdt_serving_decode_chunks_total")
+    before = chunks()
+    got, landings = serve("abort@decode:1,heal")
+    assert got == want and [len(t) for t in got] == [9, 13]
+    assert telemetry.counter_value("tdt_serving_recoveries_total", from_backend="xla") == 1.0
+    # chunk 1 landed behind chunk 2's issue; chunk 2's landing raised with
+    # chunk 3 in flight, and neither of them was counted as a chunk
+    assert landings[:2] == [(None, []), (None, [])]
+    assert chunks() - before == len(landings) - 1
+
+
+def test_phases_are_stamped_and_the_fence_is_the_landings(engine1, monkeypatch):
+    """``host_sync`` is the wait at the landing, one a chunk and under the
+    engine's span; ``dispatch`` and ``admission`` are observed as before."""
+    from triton_dist_tpu.runtime import tracing
+
+    fences = []
+    inner = jax.block_until_ready
+
+    def fence(x):
+        cur = tracing.current_span()
+        fences.append(cur and cur["name"])
+        return inner(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", fence)
+    srv = InferenceServer(engine1, num_slots=2, chunk=2)
+    reqs = [srv.submit([3, 17, 42], 9), srv.submit([8, 1, 13], 13)]
+    srv.run()
+    assert all(r.done for r in reqs)
+    chunks = telemetry.counter_value("tdt_serving_decode_chunks_total")
+    assert fences.count("tdt_engine_host_sync") == chunks == 6.0
+    n = {e["labels"]["phase"]: e["n"] for e in
+         telemetry.snapshot()["digests"]["tdt_engine_phase_seconds"]}
+    assert n["host_sync"] == n["dispatch"] == chunks and n["admission"] == 2
+    srv.shutdown(drain=False)

@@ -111,6 +111,7 @@ def _serve(eng):
     seen = {}
     for _ in range(80):
         srv.step()
+        srv._land_in_flight()  # the cache and the last tokens of the same chunk
         decoding = srv.scheduler.decoding_slots()
         if decoding:
             logits = np.asarray(eng.decode_logits_paged(srv.cache, jnp.asarray(srv._last)))

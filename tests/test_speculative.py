@@ -351,6 +351,7 @@ def test_spec_rollback_pool_invariants(model1, monkeypatch, schedule):
         assert len(stream) == expect
         while len(base_stream) < len(stream):
             assert base.step()
+            base._land_in_flight()  # a token a step, and the pool as of it
         state, base_state = _pool_state(srv), _pool_state(base)
         assert state == base_state, (
             f"pool state diverged at stream position {len(stream)}"

@@ -77,6 +77,18 @@ def sample_token(
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
+@dataclasses.dataclass
+class DecodeChunk:
+    """One issued decode chunk (``Engine.issue_decode_chunk``): what the
+    device holds, or will, of it."""
+
+    out: jax.Array  # (B, chunk) sampled tokens, -1 where a slot was inactive
+    tok: jax.Array  # (B,) each slot's last token: the next chunk's ``tokens``
+    rem: jax.Array  # (B,) what is left of each slot: the next ``remaining``
+    stats: object  # the step counters the landing publishes (None: a bounce)
+    landed: bool = False
+
+
 class Engine:
     """Reference ``Engine`` (``models/engine.py:37``). ``model`` is any
     class that brings what docs/serving.md lists under "What a model brings
@@ -894,7 +906,8 @@ class Engine:
 
     def decode_steps_paged(self, paged: PagedKVCache, tokens: jax.Array,
                            remaining: jax.Array, chunk: int,
-                           key: jax.Array | None = None):
+                           key: jax.Array | None = None, *,
+                           in_flight: list | None = None):
         """Run ``chunk`` decode steps over the slot batch with a per-slot
         active mask (``remaining > 0``): finished/free slots neither advance
         their lengths nor contribute sampled tokens (their output cells hold
@@ -909,7 +922,29 @@ class Engine:
         into the contiguous layout, runs ``self._decode_chunk`` and scatters
         the chunk's written rows back with the null-block mask.
         ``tdt_engine_decode_chunks_total{path}`` says which ran.
+
+        The call is :meth:`issue_decode_chunk` followed by
+        :meth:`land_decode_chunk`. A caller that keeps the chunk in flight
+        (the server's loop) passes a list as ``in_flight``: the chunk's
+        handle is appended to it unlanded, and landing it is the caller's.
         Returns ``(out, last_tokens, paged', remaining')``."""
+        handle, paged = self.issue_decode_chunk(paged, tokens, remaining, chunk, key)
+        if in_flight is None:
+            self.land_decode_chunk(handle)
+        else:
+            in_flight.append(handle)
+        return handle.out, handle.tok, paged, handle.rem
+
+    def issue_decode_chunk(self, paged: PagedKVCache, tokens: jax.Array,
+                           remaining: jax.Array, chunk: int,
+                           key: jax.Array | None = None):
+        """Issue one decode chunk (see :meth:`decode_steps_paged`) without
+        waiting for it: ``(handle, paged')``, both of them the device's
+        promises. ``handle.tok`` and ``handle.rem`` are what the next chunk
+        takes where the slot set does not change, so the next issue needs
+        nothing from the host; ``handle.out`` is already on its way there.
+        A pp mesh's bounce is not split: its chunk is whole, scatter-back
+        included, when this returns, and the handle says so."""
         if key is None:
             key = jax.random.PRNGKey(0)
         with tracing.span_current(
@@ -937,6 +972,10 @@ class Engine:
                         telemetry.set_gauge(
                             "tdt_mega_steps_per_launch", float(chunk), path="paged"
                         )
+                    # What the landing fetches leaves for the host as soon
+                    # as the chunk is done, whenever the landing comes.
+                    for fetched in jax.tree.leaves((out, tok, stats)):
+                        fetched.copy_to_host_async()
                 else:
                     kc, vc = self._paged_gather(
                         paged.k, paged.v, paged.k_scale, paged.v_scale, paged.tables
@@ -948,17 +987,16 @@ class Engine:
                 if timed:
                     # dispatch = host wall to ISSUE the chunk program
                     # (async); host_sync = the wait for the device to finish
-                    # it. In place there is nothing to scatter and no
-                    # cache_scatter phase.
+                    # it, at the landing. In place there is nothing to
+                    # scatter and no cache_scatter phase.
                     t = self._phase("dispatch", t)
-            with tracing.span_current("tdt_engine_host_sync"):
-                if timed:
-                    t = self._phase("host_sync", t, tok)
-                    if pool:
-                        self.model.publish_step_stats(stats)
             if pool:
                 paged = dataclasses.replace(paged, state=state)
-                return out, tok, self._pool_update(paged, pk, pv, lengths), rem
+                return (DecodeChunk(out, tok, rem, stats),
+                        self._pool_update(paged, pk, pv, lengths))
+            handle = DecodeChunk(out, tok, rem, None)
+            self.land_decode_chunk(handle)
+            t = time.perf_counter() if timed else 0.0
             with tracing.span_current("tdt_engine_cache_scatter"):
                 pk, pv, ks, vs = self._paged_scatter_rows(
                     paged.k, paged.v, paged.k_scale, paged.v_scale, k2, v2,
@@ -967,9 +1005,25 @@ class Engine:
                 )
                 if timed:
                     self._phase("cache_scatter", t, pk)
-            return out, tok, dataclasses.replace(
+            return handle, dataclasses.replace(
                 paged, k=pk, v=pv, k_scale=ks, v_scale=vs, lengths=lengths
-            ), rem
+            )
+
+    def land_decode_chunk(self, handle: DecodeChunk):
+        """Land an issued chunk: wait for the device to finish it (phase
+        ``host_sync``: the wait alone, from the landing's start) and publish
+        its step counters. The wait lies under ``tdt_engine_host_sync``
+        whoever the caller is. Landing a landed chunk does nothing. Returns
+        ``(out, last_tokens)``, so that a watchdog round the call bounds the
+        wait with telemetry off too."""
+        if not handle.landed:
+            with tracing.span_current("tdt_engine_host_sync"):
+                if telemetry.enabled():
+                    self._phase("host_sync", time.perf_counter(), handle.tok)
+                    if handle.stats is not None:
+                        self.model.publish_step_stats(handle.stats)
+            handle.landed = True
+        return handle.out, handle.tok
 
     def decode_logits_paged(self, paged: PagedKVCache, tokens: jax.Array):
         """(B, V) float32 logits of ONE decode step over the paged cache,

@@ -17,6 +17,11 @@ cache: a global block pool + per-slot block tables
   streams each slot's newly valid tokens to its ``on_token`` callback.
   Chunking is the host/device trade: larger chunks amortize dispatch,
   smaller chunks tighten join latency for requests arriving mid-decode.
+  Where nothing changes the slot set (every slot decoding, none finishing
+  inside the chunk) the chunk is left **in flight** when :meth:`step`
+  returns, and the next one is issued from its device-resident last tokens
+  before it is landed (waited for, fetched, streamed): the device never
+  waits for the host between the two. See :meth:`_decode_once`.
 
 Everything the device sees is fixed-shape (one compile per chunk size, one
 prefill compile per distinct (chunk, prompt) length pair, one scatter
@@ -153,6 +158,22 @@ from triton_dist_tpu.serving.scheduler import (
 REPREFILL_RETRIES = 3
 
 
+@dataclasses.dataclass
+class _Chunk:
+    """One decode chunk between its issue and its landing, as the loop
+    keeps it."""
+
+    handle: object  # the engine's ``DecodeChunk``: what the landing waits on
+    out: object  # (B, chunk) tokens, as the engine's call returned them
+    tok: object  # (B,) last tokens and
+    rem: object  # (B,) what is left, on the device: the next chunk's operands
+    decoding: list  # the slots that decode in it
+    pre: dict  # slot idx -> tokens the slot still owed before it
+    t_issue: float  # ``time.perf_counter()`` before the issue
+    d_start: float  # the same moment on the tracing clock
+    dispatch_id: int | None  # the issue's span, for the tenants' traces
+
+
 class InferenceServer:
     """Continuous-batching server over one engine (host-side loop)."""
 
@@ -287,12 +308,20 @@ class InferenceServer:
         )
         self.cache = self._fresh_cache()
         # Host-authoritative per-slot decode state (tiny, synced per chunk).
+        # ``_remaining`` and ``_lengths`` advance when a chunk is ISSUED, by
+        # the counts the host knows beforehand; ``_last`` is set when it lands.
         self._last = np.zeros((self.num_slots,), np.int32)
         self._remaining = np.zeros((self.num_slots,), np.int32)
         self._key = jax.random.PRNGKey(0) if key is None else key
-        # retries=0: decode_steps_paged donates the pool, so a timed-out
-        # attempt must NOT be re-dispatched on the same (now consumed)
-        # buffers — recovery reallocates instead.
+        #: The decode chunk issued and not yet landed (at most one when
+        #: :meth:`step` returns), see :meth:`_decode_once`.
+        self._in_flight: _Chunk | None = None
+        #: ``time.perf_counter()`` of the last landing: a chunk's wall is
+        #: the time between two landings.
+        self._landed_at = 0.0
+        # The watchdog bounds a chunk's LANDING, which is where a hung
+        # device shows. retries=0: the chunk donated the pool, so a
+        # timed-out one is never dispatched again — recovery reallocates.
         self._watchdog = watchdog if watchdog is not None else (
             resilience.CollectiveWatchdog(
                 feature="collectives", name="serving.decode", retries=0
@@ -616,7 +645,10 @@ class InferenceServer:
         process (journal, endpoint) stays up — :meth:`drained` flips once
         the queue and every slot are empty. Unlike :meth:`shutdown` this is
         NOT terminal: the replica can still export its journal and serve
-        its in-flight streams while the router migrates them away."""
+        its in-flight streams while the router migrates them away. Only
+        flags are set here (the fleet replica calls this from its endpoint's
+        thread): the loop sees them at its next chunk, which it lands
+        before :meth:`step` returns, as every chunk of a draining server."""
         if self._draining:
             return
         self._draining = True
@@ -729,7 +761,9 @@ class InferenceServer:
         one masked decode chunk over the slot batch. Returns True when any
         work was done. A health sweep runs first: an expired heartbeat
         lease (or a chaos ``die@<rank>``) triggers ONE proactive rebuild at
-        the new epoch instead of a timeout per collective.
+        the new epoch instead of a timeout per collective. A decode chunk
+        may still be in flight when this returns (:meth:`_decode_once`
+        says when): the next call lands it.
 
         The iteration is one ``tdt_serving_step`` span of the server's own
         trace and everything it does that is not free a span beneath it
@@ -1135,51 +1169,154 @@ class InferenceServer:
 
     # ----------------------------------------------------------------- decode
     def _decode_once(self) -> None:
+        """Issue one decode chunk and land what is due.
+
+        The loop keeps at most one chunk **in flight** (issued, not landed)
+        across :meth:`step`. With chunk k in flight, chunk k+1 is issued from
+        k's last tokens and counts as they lie on the device, and only then
+        is k landed: waited for, fetched, streamed, journaled. So the device
+        goes from k to k+1 while the host still works on k. Chunk k+1 in its
+        turn stays in flight only if nothing about it changes the slot set
+        (:meth:`_sync_reason`); otherwise it is landed here too, before
+        :meth:`step` returns, which is the order the loop always had.
+
+        The host's mirrors follow the issue, not the landing: ``_remaining``
+        and ``_lengths`` advance by what the chunk will do (``min(remaining,
+        chunk)`` a slot, known beforehand: there is no stop token), so a
+        table push between the two never rolls a slot back. Whatever reads
+        or rewrites the slots, the tables or the cache from outside this
+        method lands the chunk in flight first (:meth:`_land_in_flight`)."""
         if self.spec_k >= 2:
             self._spec_decode_once()
             return
+        ahead = self._in_flight
         with self._trace.span("tdt_serving_decode_prep", ring=False):
-            resilience.chaos_check("decode")
             decoding = self.scheduler.decoding_slots()
             pre = {s.idx: int(self._remaining[s.idx]) for s in decoding}
             self._key, sub = jax.random.split(self._key)
         t0 = time.perf_counter()
         # One decode chunk is ONE shared device dispatch over the whole slot
-        # batch: it gets a single span in the SERVER trace (and is the
-        # ambient span while the chunk compiles, for KernelTrace
-        # correlation); each tenant then gets a per-slot chunk span in its
-        # own trace referencing the shared span's id.
+        # batch: its issue gets a single span in the SERVER trace (and is
+        # the ambient span while the chunk compiles, for KernelTrace
+        # correlation); at the landing each tenant gets a per-slot chunk
+        # span in its own trace, from the issue to the landing, referencing
+        # the shared span's id.
         d_start = tracing.now_s()
+        issued: list = []
         with self._trace.span(
             "tdt_serving_dispatch", n_active=len(decoding), chunk=self.chunk
         ) as dsp:
-            out, tok, cache, _ = self._watchdog.call(
-                self.engine.decode_steps_paged, self.cache,
-                jnp.asarray(self._last), jnp.asarray(self._remaining),
-                self.chunk, sub,
+            if ahead is None:
+                # Placed as the chunk program places its own outputs, so
+                # that both routes run one executable. Copies: the mirrors
+                # change below, before the device need have read them.
+                tok, rem = jax.device_put(
+                    (self._last.copy(), self._remaining.copy()),
+                    self.engine.model.ctx.replicated(),
+                )
+            else:
+                tok, rem = ahead.tok, ahead.rem
+            # Through ``decode_steps_paged`` by that name, and with what it
+            # returns: whoever wraps the engine's decode call (the
+            # benchmark's planted faults do) wraps the loop's chunks.
+            out, tok, self.cache, rem = self.engine.decode_steps_paged(
+                self.cache, tok, rem, self.chunk, sub, in_flight=issued,
             )
-        d_end = tracing.now_s()
-        dispatch_id = dsp["span_id"] if dsp is not None else None
-        self.cache = cache
+        chunk = _Chunk(
+            issued[0], out, tok, rem, decoding, pre, t0, d_start,
+            dsp["span_id"] if dsp is not None else None,
+        )
+        for slot in decoding:
+            n = min(pre[slot.idx], self.chunk)
+            self._remaining[slot.idx] -= n
+            self._lengths[slot.idx] += n  # the device's advance in-chunk
+        # From here a landing that fails drops all that is in flight: none
+        # of it was streamed or journaled, and recovery re-prefills from
+        # the history that was.
+        self._in_flight = None
+        if ahead is not None:
+            self._land(ahead, None)
+        self._in_flight = chunk
+        if ahead is not None:
+            # What just streamed may have cancelled a request or run its
+            # deadline out (a callback, the clock): the boundary's sweep,
+            # as it ran between the two chunks when k was landed before
+            # k+1 was issued. A slot it reaps gets nothing of k+1.
+            with self._trace.span("tdt_serving_reap", ring=False):
+                self._reap_slots()
+        if self._in_flight is chunk and (why := self._sync_reason(chunk)) is not None:
+            self._land_in_flight(why)
+
+    def _sync_reason(self, chunk: _Chunk) -> str | None:
+        """Why the chunk just issued is landed before :meth:`step` returns
+        (the label of ``tdt_serving_decode_sync_boundaries_total``), or None:
+        it stays in flight, because the next chunk will run over the same
+        slots whatever this one streams. The first that holds, in this
+        order: a slot finishes inside it; a slot is free (a request may join
+        it at the next boundary); a slot is prefilling; the chunk bounced
+        through the contiguous layout (a pp mesh: landed as issued); the
+        server is draining. The loop can see all of it before the chunk
+        runs; nothing here is a setting."""
+        if any(n <= self.chunk for n in chunk.pre.values()):
+            return "finish"
+        states = {s.state for s in self.scheduler.slots}
+        if states - {SlotState.DECODE, SlotState.PREFILL}:
+            return "free_slot"
+        if SlotState.PREFILL in states:
+            return "prefill"
+        if chunk.handle.landed:
+            return "bounce"
+        if self._draining or self._shutdown:
+            return "drain"
+        return None
+
+    def _land_in_flight(self, why: str = "other", drop=()) -> None:
+        """Land the chunk in flight, if any: what everything outside
+        :meth:`_decode_once` does before it touches the slots, the tables,
+        the cache or the mirrors. A landing that fails leaves nothing in
+        flight; the caller's ``_guarded`` recovers."""
+        chunk, self._in_flight = self._in_flight, None
+        if chunk is not None:
+            self._land(chunk, why, drop)
+
+    def _land(self, chunk: _Chunk, why: str | None, drop=()) -> None:
+        """Land one issued chunk: wait for it (in the engine, under
+        ``tdt_engine_host_sync``; the watchdog bounds the wait), fetch its
+        tokens, stream and journal them, finish the slots it finished.
+        ``why`` is None for a chunk landed behind the next one's issue, else
+        why it was not. The slots in ``drop`` get nothing of it: they are
+        being reaped, and the chunk was issued before the reap could know."""
         with self._trace.span("tdt_serving_fetch", ring=False, what="chunk"):
-            out_np = np.asarray(out)
-            self._last = np.asarray(tok, dtype=np.int32).copy()
-        wall = time.perf_counter() - t0
+            # The scripted fault of a decode chunk shows where a real one
+            # does: when the host comes for the chunk.
+            resilience.chaos_check("decode")
+            self._watchdog.call(self.engine.land_decode_chunk, chunk.handle)
+            out_np = np.asarray(chunk.out)
+            self._last = np.asarray(chunk.tok, dtype=np.int32).copy()
+        d_end = tracing.now_s()
+        now = time.perf_counter()
+        wall = now - max(chunk.t_issue, self._landed_at)
+        self._landed_at = now
         telemetry.inc("tdt_serving_decode_chunks_total")
+        if why is None:
+            telemetry.inc("tdt_serving_decode_chunks_ahead_total")
+        else:
+            telemetry.inc("tdt_serving_decode_sync_boundaries_total", why=why)
         # Rows the chunk's steps really advanced (a slot that runs out
         # inside the chunk idles for the rest of it), of slots x chunk.
         telemetry.inc(
             "tdt_serving_decode_rows_total",
-            float(sum(min(n, self.chunk) for n in pre.values())),
+            float(sum(min(n, self.chunk) for n in chunk.pre.values())),
         )
+        decoding = [s for s in chunk.decoding if s.idx not in drop]
         with self._trace.span("tdt_serving_emit", ring=False):
             n_streamed = 0
             for slot in decoding:
                 req = slot.request
-                n_valid = min(pre[slot.idx], self.chunk)
+                n_valid = min(chunk.pre[slot.idx], self.chunk)
                 req.trace.record(
-                    "tdt_serving_decode_chunk", d_start, d_end,
-                    slot=slot.idx, n_tokens=n_valid, dispatch=dispatch_id,
+                    "tdt_serving_decode_chunk", chunk.d_start, d_end,
+                    slot=slot.idx, n_tokens=n_valid, dispatch=chunk.dispatch_id,
                 )
                 s_start = tracing.now_s()
                 toks = [int(out_np[slot.idx, j]) for j in range(n_valid)]
@@ -1195,15 +1332,11 @@ class InferenceServer:
                             "chunk", req_id=req.req_id,
                             start=len(req.tokens) - n_valid, tokens=toks,
                         )
-                self._remaining[slot.idx] -= n_valid
-                self._lengths[slot.idx] += n_valid  # device updated in-chunk
                 n_streamed += n_valid
-        # Finishes run AFTER every slot's host length mirror is advanced:
-        # _finish pushes the mirror over the device lengths (wiping the
-        # in-chunk update), so a finisher processed before a still-active
-        # slot would otherwise roll that slot's KV length back by a chunk.
+        # Finishes run after every slot's tokens are out: _finish pushes the
+        # tables, and frees a slot that a request may join next.
         for slot in decoding:
-            if slot.request is not None and self._remaining[slot.idx] == 0:
+            if slot.request is not None and chunk.pre[slot.idx] <= self.chunk:
                 self._finish(slot)
         if n_streamed:
             telemetry.inc("tdt_serving_tokens_total", float(n_streamed))
@@ -1272,6 +1405,9 @@ class InferenceServer:
         self._last = np.asarray(tok, dtype=np.int32).copy()
         wall = time.perf_counter() - t0
         telemetry.inc("tdt_serving_decode_chunks_total")
+        # The accepted counts are the host's to read before the next round:
+        # a speculative chunk never stays in flight.
+        telemetry.inc("tdt_serving_decode_sync_boundaries_total", why="spec")
         n_streamed = 0
         n_proposed = 0
         n_accepted = 0
@@ -1401,19 +1537,34 @@ class InferenceServer:
     def _reap_slots(self) -> None:
         """Chunk-boundary lifecycle sweep: free cancelled slots and truncate
         streams whose TOTAL deadline passed mid-decode. Runs between chunk
-        dispatches, so both free their slot within one chunk of the event."""
+        dispatches, so both free their slot within one chunk of the event.
+        A chunk in flight was issued before the sweep could know: it is
+        landed first, and a reaped slot gets nothing of it (the chunk would
+        not have run for that slot had the loop landed before it issued)."""
         now = self._now()
+        hits = []
         for slot in self.scheduler.occupied_slots():
             req = slot.request
             if slot.state not in (SlotState.PREFILL, SlotState.DECODE):
                 continue
             if req.cancel_requested:
-                telemetry.inc("tdt_serving_cancelled_total", where="running")
-                self._finish(slot, reason="cancelled")
+                hits.append((slot, req, "cancelled"))
             elif (
                 req.deadline_s is not None
                 and now - req.arrived_at > req.deadline_s
             ):
+                hits.append((slot, req, "deadline"))
+        if hits and self._in_flight is not None:
+            self._guarded(
+                lambda: self._land_in_flight(drop={slot.idx for slot, _, _ in hits}),
+                what="decode chunk",
+            )
+        for slot, req, reason in hits:
+            if slot.request is not req:
+                continue  # a recovery at the landing sent it back to the queue
+            if reason == "cancelled":
+                telemetry.inc("tdt_serving_cancelled_total", where="running")
+            else:
                 telemetry.inc(
                     "tdt_serving_deadline_expiries_total", where="decode"
                 )
@@ -1421,7 +1572,7 @@ class InferenceServer:
                     "tdt_serving_deadline_overrun_seconds",
                     now - req.arrived_at - req.deadline_s,
                 )
-                self._finish(slot, reason="deadline")
+            self._finish(slot, reason=reason)
 
     # ----------------------------------------------------------- rank health
     def _health_sweep(self) -> bool:
@@ -1499,6 +1650,15 @@ class InferenceServer:
                 self.cache = self._fresh_cache()
 
     def _recover(self, why: str) -> None:
+        # A chunk still in flight lands first: what it streams is history
+        # the re-prefill starts from. One that cannot land is dropped
+        # unstreamed, as the chunk that failed was.
+        try:
+            self._land_in_flight()
+        except Exception as e:
+            telemetry.emit(
+                "serving_in_flight_dropped", error=f"{type(e).__name__}: {e}"
+            )
         eng = self.engine
         from_backend = eng.backend
         occupied = self.scheduler.occupied_slots()
@@ -1551,6 +1711,7 @@ class InferenceServer:
         due = resilience.probe_due()
         if not due:
             return False
+        self._guarded(self._land_in_flight, what="decode chunk")
         resilience.begin_probe(due)
         ok, err = True, ""
         with self._trace.span(
@@ -1611,6 +1772,7 @@ class InferenceServer:
         LIVE traffic without dropping a stream: fresh pool +
         re-prefill from history — the recovery machinery pointed back at
         the fused path."""
+        self._land_in_flight()
         occupied = self.scheduler.occupied_slots()
         to_backend = self.engine.backend
         telemetry.inc("tdt_serving_restores_total", to_backend=to_backend)
@@ -1652,6 +1814,7 @@ class InferenceServer:
         restored request handles in ``req_id`` (original FCFS) order."""
         from triton_dist_tpu.serving.journal import RequestJournal
 
+        self._guarded(self._land_in_flight, what="decode chunk")
         if journal is None:
             journal = self._journal
         if journal is None:
@@ -1736,6 +1899,9 @@ class InferenceServer:
             return
         self._shutdown = True
         self.scheduler.shutting_down = True
+        # What is in flight is streamed and journaled whether or not the
+        # rest drains.
+        self._guarded(lambda: self._land_in_flight("drain"), what="decode chunk")
         t0 = time.monotonic()
         if timeout_s is None:
             timeout_s = get_float_env("TDT_DRAIN_TIMEOUT_S", 0.0)

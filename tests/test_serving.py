@@ -839,3 +839,139 @@ def test_phases_are_stamped_and_the_fence_is_the_landings(engine1, monkeypatch):
          telemetry.snapshot()["digests"]["tdt_engine_phase_seconds"]}
     assert n["host_sync"] == n["dispatch"] == chunks and n["admission"] == 2
     srv.shutdown(drain=False)
+
+
+# ========================= a request's timeline and the device's ledger
+#
+# The served script again (three slots at chunk 2, more clients than slots,
+# a request of one token), with nothing cancelled: what the program says of
+# its own time, against the requests' fields, the wall clock and the
+# ledger's own running total taken at every ``step()``'s edges.
+
+
+@pytest.fixture(scope="module")
+def timeline(engine1):
+    import time
+
+    from triton_dist_tpu.runtime import tracing
+
+    srv = InferenceServer(engine1, num_slots=3, chunk=2)
+    for prompt, max_new, _ in SCRIPT[:3]:  # every program compiled and run once
+        srv.submit(prompt, max_new)
+    srv.run()
+    telemetry.reset()
+    tracing.reset()
+    srv = InferenceServer(engine1, num_slots=3, chunk=2)
+    observed: list = []
+    inner = telemetry.observe
+
+    def observe(name, value, /, **labels):
+        observed.append((name, value))
+        inner(name, value, **labels)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(telemetry, "observe", observe)
+        reqs = [srv.submit(prompt, max_new) for prompt, max_new, _ in SCRIPT]
+        t0 = time.perf_counter()
+        in_steps = 0.0
+        while True:
+            at = tracing.device_starved_s()
+            worked = srv.step()
+            in_steps += tracing.device_starved_s() - at
+            if not worked:
+                break
+        wall = time.perf_counter() - t0
+        idle_at = tracing.device_starved_s()
+        for _ in range(3):  # a server with nothing to serve
+            time.sleep(0.01)
+            srv.step()
+    left = {
+        "reqs": reqs, "wall": wall, "observed": observed, "in_steps": in_steps,
+        "total": idle_at, "after_idle": tracing.device_starved_s(),
+        "snap": telemetry.snapshot(),
+    }
+    srv.shutdown(drain=False)
+    return left
+
+
+def _hist(snap, name):
+    (e,) = snap["histograms"][name]
+    return e["sum"], e["count"]
+
+
+def test_queue_wait_and_residence_are_the_time_to_the_first_token(timeline):
+    reqs, snap = timeline["reqs"], timeline["snap"]
+    assert all(r.done and r.finish_reason == "ok" for r in reqs)
+    for r in reqs:
+        wait, residence = r.admitted_at - r.arrived_at, r.first_token_at - r.admitted_at
+        assert wait >= 0.0 and residence > 0.0
+        assert wait + residence == pytest.approx(r.ttft_s, abs=1e-3)
+    wait_s, n_wait = _hist(snap, "tdt_serving_queue_wait_seconds")
+    res_s, n_res = _hist(snap, "tdt_serving_prefill_residence_seconds")
+    ttft_s, n_ttft = _hist(snap, "tdt_serving_ttft_seconds")
+    assert n_wait == n_res == n_ttft == len(reqs)
+    # the histograms are the requests' own fields, and they add up
+    assert wait_s == pytest.approx(sum(r.admitted_at - r.arrived_at for r in reqs), abs=1e-6)
+    assert res_s == pytest.approx(sum(r.first_token_at - r.admitted_at for r in reqs), abs=1e-6)
+    assert wait_s + res_s == pytest.approx(ttft_s, rel=1e-2, abs=1e-6)
+    assert wait_s > 0.0  # four of the seven waited for a slot
+
+
+def test_own_prefill_time_is_within_the_residence_request_by_request(timeline):
+    seen = [(n, v) for n, v in timeline["observed"] if n.startswith("tdt_serving_prefill_")
+            and n.endswith(("_residence_seconds", "_own_seconds"))]
+    pairs = list(zip(seen[::2], seen[1::2]))
+    assert len(pairs) == len(timeline["reqs"])
+    for (res_name, residence), (own_name, own) in pairs:
+        assert res_name == "tdt_serving_prefill_residence_seconds"
+        assert own_name == "tdt_serving_prefill_own_seconds"
+        assert 0.0 < own <= residence + 1e-9
+    # requests that joined together took turns: someone's residence holds
+    # another's prefill, so the sums differ
+    own_s, _ = _hist(timeline["snap"], "tdt_serving_prefill_own_seconds")
+    res_s, _ = _hist(timeline["snap"], "tdt_serving_prefill_residence_seconds")
+    assert own_s < res_s
+
+
+def test_chunk_walls_lie_within_the_served_time_and_boundaries_sum(timeline):
+    snap = timeline["snap"]
+    count = lambda name: sum(e["value"] for e in snap["counters"].get(name, []))
+    chunks = count("tdt_serving_decode_chunks_total")
+    assert chunks > 0 and chunks == (
+        count("tdt_serving_decode_chunks_ahead_total")
+        + count("tdt_serving_decode_sync_boundaries_total"))
+    assert count("tdt_serving_decode_chunks_ahead_total") > 0
+    wall_s, n = _hist(snap, "tdt_serving_decode_chunk_seconds")
+    # one observation a chunk, disjoint stretches of the loop's time
+    assert n == chunks and 0.0 < wall_s <= timeline["wall"]
+    assert "tdt_serving_chunk_token_seconds" not in snap["histograms"]
+    # the doc's batch-level TPOT: the histogram's sum over the tokens
+    tokens = count("tdt_serving_tokens_total")
+    assert tokens == sum(len(r.tokens) for r in timeline["reqs"]) - len(timeline["reqs"])
+
+
+def test_starved_phases_and_the_time_outside_spans_are_the_counter(timeline):
+    """Over the served window: the digest's sum over phases is what the
+    ledger's total moved inside ``step()`` (every second of a step lies in a
+    span), that plus the move outside is the total, and the counter less
+    ``no_work`` is the total less the interval still open at the end."""
+    snap = timeline["snap"]
+    after = {e["labels"]["after"]: e["value"]
+             for e in snap["counters"]["tdt_engine_device_starved_seconds_total"]}
+    phases = {e["labels"]["phase"]: e["sum"]
+              for e in snap["digests"]["tdt_span_starved_seconds"]}
+    total, in_steps = timeline["total"], timeline["in_steps"]
+    assert 0.0 < in_steps <= total <= timeline["wall"]
+    assert sum(phases.values()) == pytest.approx(in_steps, rel=1e-2)
+    counted = sum(v for k, v in after.items() if k != "no_work")
+    # the idle steps closed the last interval, so nothing is open
+    assert counted == pytest.approx(total, rel=1e-2)
+    assert counted == pytest.approx(sum(phases.values()) + (total - in_steps), rel=1e-2)
+    # the intervals are named after the waits that began them
+    assert set(after) <= {"prefill_chunk", "cache_scatter", "no_work"} | {
+        f"decode_land:{why}" for why in ("finish", "free_slot", "prefill")}
+    assert {"prefill_chunk", "cache_scatter", "decode_land:finish"} <= set(after)
+    # the device starved under the engine's calls and the loop's own spans alike
+    assert {"tdt_engine_prefill_chunk", "tdt_serving_fetch"} <= set(phases)
+    # a server with nothing to serve is not starving its device
+    assert timeline["after_idle"] == pytest.approx(total, abs=1e-4)

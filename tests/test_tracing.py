@@ -699,3 +699,157 @@ def test_jit_cache_misses_are_counted_and_pointed():
     f(vec)  # a miss outside any span: counted, no point
     assert telemetry.counter_total("tdt_jit_lowerings_total") == n0 + 2
     assert len([s for s in tracing.spans() if s["name"] == "tdt_jit_lowering"]) == 1
+
+
+# ============================================================ device ledger
+
+
+class _ScriptedClock:
+    """``tracing.now_s`` by hand: the test sets ``t``; every read counts."""
+
+    def __init__(self, t=100.0):
+        self.t, self.reads = t, 0
+
+    def __call__(self):
+        self.reads += 1
+        return self.t
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = _ScriptedClock()
+    monkeypatch.setattr(tracing, "now_s", c)
+    return c
+
+
+def _starved_by(kind: str, name: str, label: str) -> dict[str, float]:
+    field = "value" if kind == "counters" else "sum"
+    return {e["labels"][label]: e[field]
+            for e in telemetry.snapshot()[kind].get(name, [])}
+
+
+def _starved_counter():
+    return _starved_by("counters", "tdt_engine_device_starved_seconds_total", "after")
+
+
+def _starved_phases():
+    return _starved_by("digests", "tdt_span_starved_seconds", "phase")
+
+
+def test_ledger_on_a_scripted_clock_through_a_nest_of_spans(clock):
+    """Issue, wait, issue inside three nested spans (a ring span, a
+    ring-less one, a lower layer's ``span_current``): each span's starved
+    self time is its share of the ledger's total with its children's taken
+    out, and the phases' sum plus the starved time outside any span is the
+    counter."""
+    t = tracing.start_trace("tdt_test_trace")
+    first = tracing.device_issued()  # nothing was known: no interval ends
+    assert first == 1 and tracing.device_starved_s() == 0.0
+    clock.t = 101.0
+    with t.span("tdt_test_a") as a:  # opens while the device is busy
+        clock.t = 102.0
+        tracing.device_waited(first, "prefill_chunk")  # starved from 102
+        clock.t = 103.0
+        with t.span("tdt_test_b", ring=False) as b:
+            clock.t = 104.0
+            with tracing.span_current("tdt_test_c") as c:
+                clock.t = 105.0
+                second = tracing.device_issued()  # 102-105 after the chunk's fence
+                clock.t = 106.0
+            clock.t = 107.0
+            tracing.device_waited(second, "decode_land:finish")
+            clock.t = 108.0
+        clock.t = 109.0
+    assert (a["starved_s"], b["starved_s"], c["starved_s"]) == (2.0, 2.0, 1.0)
+    assert (a["self_s"], b["self_s"], c["self_s"]) == (3.0, 3.0, 2.0)
+    clock.t = 110.0  # a second outside any span, the device still starved
+    assert tracing.device_starved_s() == 6.0  # the open interval is in the total
+    third = tracing.device_issued()
+    assert _starved_counter() == {"prefill_chunk": 3.0, "decode_land:finish": 3.0}
+    phases = _starved_phases()
+    assert phases == {"tdt_test_a": 2.0, "tdt_test_b": 2.0, "tdt_test_c": 1.0}
+    outside = 1.0  # 109-110
+    assert sum(phases.values()) + outside == sum(_starved_counter().values())
+    assert tracing.device_starved_s() == 6.0 and third == 3
+    # the ring keeps the span's share beside its self time
+    ring = {s["name"]: s for s in tracing.spans(t.trace_id)}
+    assert ring["tdt_test_a"]["starved_s"] == 2.0 and "tdt_test_b" not in ring
+
+
+def test_ledger_counts_no_work_apart_and_waits_for_the_newest_program(clock):
+    """``no_work`` is no span's starved time and not in the total; a wait
+    for a program that is not the newest issued changes nothing (the chunk
+    landed behind the next one's issue), and costs no clock read; nor does
+    a span's open or close beyond the two it always made."""
+    t = tracing.start_trace("tdt_test_trace")
+    one = tracing.device_issued()
+    clock.t = 111.0
+    tracing.device_waited(one, "cache_scatter")
+    clock.t = 112.0
+    tracing.device_no_work()  # 111-112 stays the scatter's; no_work from here
+    reads = clock.reads
+    tracing.device_no_work()  # nothing new to say, no clock read
+    assert clock.reads == reads and tracing.device_starved_s() == 1.0
+    reads = clock.reads
+    clock.t = 113.0
+    with t.span("tdt_test_idle", ring=False) as idle:
+        clock.t = 115.0
+    assert idle["starved_s"] == 0.0 and clock.reads == reads + 2  # open, close
+    clock.t = 116.0
+    two = tracing.device_issued()
+    assert _starved_counter() == {"cache_scatter": 1.0, "no_work": 4.0}
+    assert _starved_phases() == {}  # observed only where it is over zero
+    clock.t = 117.0
+    three = tracing.device_issued()  # issued behind ``two``, which is in flight
+    clock.t = 118.0
+    reads = clock.reads
+    tracing.device_waited(two, "decode_land")  # not the newest: still busy
+    assert clock.reads == reads and tracing.device_starved_s() == 1.0
+    with t.span("tdt_test_busy", ring=False) as busy:
+        clock.t = 119.0
+    assert busy["starved_s"] == 0.0 and busy["self_s"] == 1.0
+    clock.t = 120.0
+    tracing.device_waited(three, "decode_land:drain")
+    tracing.device_waited(three, "decode_land:drain")  # a second wait moves nothing
+    clock.t = 122.0
+    assert tracing.device_starved_s() == 3.0
+    tracing.device_issued()
+    assert _starved_counter()["decode_land:drain"] == 2.0
+    tracing.reset()
+    assert tracing.device_starved_s() == 0.0 and tracing.device_issued() == 1
+
+
+def test_ledger_is_off_with_telemetry_off(clock):
+    telemetry.reset(enabled_override=False)
+    reads = clock.reads
+    ticket = tracing.device_issued()
+    tracing.device_waited(ticket, "prefill_chunk")
+    tracing.device_no_work()
+    clock.t = 200.0
+    assert ticket == 0 and tracing.device_issued() == 0
+    assert clock.reads == reads and tracing.device_starved_s() == 0.0
+    snap = telemetry.snapshot()
+    assert "tdt_engine_device_starved_seconds_total" not in snap["counters"]
+    assert "tdt_span_starved_seconds" not in snap["digests"]
+
+
+def test_engine_tells_the_ledger_each_step_program_and_each_fence(model1):
+    """A join and a decode chunk through the engine's own calls: each step
+    program takes a ticket, each fence that leaves nothing in flight opens
+    an interval named after it, and the next issue books it."""
+    from paged_drive import alloc_chains, join
+
+    eng = make_engine(model1)
+    paged = alloc_chains(eng, 1)
+    t = tracing.start_trace("tdt_test_trace")
+    with t.span("tdt_test_step", ring=False):
+        tok, paged = join(eng, paged, 0, [3, 17, 42])
+        eng.decode_steps_paged(
+            paged, jax.numpy.asarray([tok], jax.numpy.int32),
+            jax.numpy.asarray([2], jax.numpy.int32), 2)
+    after = _starved_counter()
+    # the chunk's fence, then the scatter's; the landing's interval is open
+    assert set(after) == {"prefill_chunk", "cache_scatter"}
+    assert tracing.device_starved_s() > sum(after.values()) > 0.0
+    phases = _starved_phases()
+    assert {"tdt_engine_complete_paged_prefill", "tdt_engine_dispatch"} <= set(phases)

@@ -86,6 +86,7 @@ class DecodeChunk:
     tok: jax.Array  # (B,) each slot's last token: the next chunk's ``tokens``
     rem: jax.Array  # (B,) what is left of each slot: the next ``remaining``
     stats: object  # the step counters the landing publishes (None: a bounce)
+    ticket: int = 0  # ``tracing.device_issued``'s, for the wait at the landing
     landed: bool = False
 
 
@@ -865,10 +866,12 @@ class Engine:
                 self.model.params, chunk_ids, kbuf, vbuf,
                 jnp.int32(off), jnp.int32(last_idx), state,
             )
+            ticket = tracing.device_issued()
             if timed:
                 # Admission: each prefill chunk's compute, the cost of
                 # joining one request into the running batch.
                 self._phase("admission", t, logits)
+                tracing.device_waited(ticket, "prefill_chunk")
                 self.model.publish_step_stats(stats)
         return logits, kb, vb, state
 
@@ -898,8 +901,10 @@ class Engine:
                 jnp.asarray(table_row, jnp.int32), jnp.int32(start_block),
                 paged.quant, paged.state, state, jnp.int32(slot),
             )
+            ticket = tracing.device_issued()
             if timed:
                 self._phase("cache_scatter", t, pk)
+                tracing.device_waited(ticket, "cache_scatter")
         return dataclasses.replace(
             paged, k=pk, v=pv, k_scale=ks, v_scale=vs, state=slots_state
         )
@@ -984,6 +989,7 @@ class Engine:
                         self.model.params, self._decode_extra, tokens, kc, vc,
                         paged.lengths, remaining, int(chunk), key,
                     )
+                ticket = tracing.device_issued()
                 if timed:
                     # dispatch = host wall to ISSUE the chunk program
                     # (async); host_sync = the wait for the device to finish
@@ -992,10 +998,10 @@ class Engine:
                     t = self._phase("dispatch", t)
             if pool:
                 paged = dataclasses.replace(paged, state=state)
-                return (DecodeChunk(out, tok, rem, stats),
+                return (DecodeChunk(out, tok, rem, stats, ticket),
                         self._pool_update(paged, pk, pv, lengths))
-            handle = DecodeChunk(out, tok, rem, None)
-            self.land_decode_chunk(handle)
+            handle = DecodeChunk(out, tok, rem, None, ticket)
+            self.land_decode_chunk(handle, "bounce")
             t = time.perf_counter() if timed else 0.0
             with tracing.span_current("tdt_engine_cache_scatter"):
                 pk, pv, ks, vs = self._paged_scatter_rows(
@@ -1003,23 +1009,30 @@ class Engine:
                     paged.tables, paged.lengths, jnp.clip(remaining, 0, chunk),
                     int(chunk), paged.quant,
                 )
+                ticket = tracing.device_issued()
                 if timed:
                     self._phase("cache_scatter", t, pk)
+                    tracing.device_waited(ticket, "cache_scatter")
             return handle, dataclasses.replace(
                 paged, k=pk, v=pv, k_scale=ks, v_scale=vs, lengths=lengths
             )
 
-    def land_decode_chunk(self, handle: DecodeChunk):
+    def land_decode_chunk(self, handle: DecodeChunk, why: str | None = None):
         """Land an issued chunk: wait for the device to finish it (phase
         ``host_sync``: the wait alone, from the landing's start) and publish
         its step counters. The wait lies under ``tdt_engine_host_sync``
-        whoever the caller is. Landing a landed chunk does nothing. Returns
+        whoever the caller is. ``why`` is the caller's reason for landing it
+        before the next chunk is issued, where it has one: the device's
+        ledger names the starved interval that begins here after it
+        (``decode_land:<why>``). Landing a landed chunk does nothing. Returns
         ``(out, last_tokens)``, so that a watchdog round the call bounds the
         wait with telemetry off too."""
         if not handle.landed:
             with tracing.span_current("tdt_engine_host_sync"):
                 if telemetry.enabled():
                     self._phase("host_sync", time.perf_counter(), handle.tok)
+                    tracing.device_waited(
+                        handle.ticket, f"decode_land:{why}" if why else "decode_land")
                     if handle.stats is not None:
                         self.model.publish_step_stats(handle.stats)
             handle.landed = True
@@ -1194,11 +1207,13 @@ class Engine:
                 tokens, pk_in, pv_in, paged.tables, paged.lengths,
                 remaining, kcap, int(chunk), int(k), dstate,
             )
+            ticket = tracing.device_issued()
             telemetry.set_gauge(
                 "tdt_mega_steps_per_launch", float(chunk * k), path="spec_paged"
             )
             if timed:
                 self._phase("spec_propose", t, tok)
+                tracing.device_waited(ticket, "spec")
             return out, tok, self._pool_update(
                 paged, pk, pv, lengths
             ), rem, dstate, stats
@@ -1210,16 +1225,20 @@ class Engine:
             tokens, kc, vc, paged.lengths, remaining, kcap,
             int(chunk), int(k), dstate,
         )
+        ticket = tracing.device_issued()
         if timed:
             t = self._phase("spec_propose", t, tok)
+            tracing.device_waited(ticket, "spec")
         nv = lengths - paged.lengths
         pk, pv, ks, vs = self._paged_scatter_rows(
             paged.k, paged.v, paged.k_scale, paged.v_scale, k2, v2,
             paged.tables, paged.lengths, nv, int(chunk) * int(k), paged.quant,
         )
+        ticket = tracing.device_issued()
         if timed:
             # Commit: only the ACCEPTED rows scatter back into the pool.
             self._phase("spec_commit", t, pk)
+            tracing.device_waited(ticket, "spec")
         return out, tok, dataclasses.replace(
             paged, k=pk, v=pv, k_scale=ks, v_scale=vs, lengths=lengths
         ), rem, dstate, stats

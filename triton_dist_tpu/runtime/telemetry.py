@@ -137,6 +137,9 @@ def _ring() -> collections.deque:
 
 
 def _key(name: str, labels: Mapping[str, Any]) -> tuple[str, tuple]:
+    if len(labels) == 1:  # most series, a span's digests among them: nothing to sort
+        ((k, v),) = labels.items()
+        return name, ((k, str(v)),)
     return name, tuple(sorted((k, str(v)) for k, v in labels.items()))
 
 

@@ -54,6 +54,17 @@ Orca's iteration timeline). This module is that answer:
   ``ring=False`` (the serving loop's per-iteration phases) go to the
   profiler and the digest only: they would otherwise halve the seconds of
   serving the ring holds.
+* **The device ledger** — the engine says when it *issued* a step program
+  (:func:`device_issued`) and when a *wait* for one returned
+  (:func:`device_waited`). From a wait that leaves nothing issued unwaited
+  to the next issue the device is **starved**: it has no step program to
+  run, whatever the host is busy with. The ledger keeps the running total
+  of those seconds on this module's clock; a live span takes it as it
+  opens and closes, as it takes the time, and what the spans inside it did
+  not cover is its starved self time, ``tdt_span_starved_seconds`` under
+  ``phase=<span name>``. So "where did the device starve, by span" reads
+  from two snapshots, with no profiler (``docs/observability.md``, "The
+  loop's spans").
 
 Clocks: spans stamp raw ``time.monotonic()`` seconds. Callers whose
 bookkeeping lives in another monotonic-derived clock (the serving loop's
@@ -107,6 +118,16 @@ _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "tdt_current_span", default=None
 )
 
+#: The ``after`` of an interval in which there was no work for the device:
+#: counted apart, and no span's starved time.
+NO_WORK = "no_work"
+# The device ledger (one a process, as the digest is; written by the one
+# thread that drives the engine, read by whoever closes a span).
+_ISSUED = 0  # ticket of the newest step program issued
+_STARVED_S = 0.0  # starved seconds of the closed intervals, no_work's left out
+_STARVED_SINCE: float | None = None  # the open interval's start; None: a program may be running
+_STARVED_AFTER = ""  # the wait that began the open interval
+
 
 def _ring() -> collections.deque:
     global _SPANS
@@ -138,11 +159,13 @@ def reset() -> None:
     """Drop every span (finished and open) and restart ids + the sampling
     accumulator. Tests and operator resets only."""
     global _SPANS, _IDS, _SAMPLE_ACC
+    global _ISSUED, _STARVED_S, _STARVED_SINCE, _STARVED_AFTER
     with _LOCK:
         _SPANS = None
         _OPEN.clear()
         _IDS = itertools.count(1)
         _SAMPLE_ACC = 0.0
+        _ISSUED, _STARVED_S, _STARVED_SINCE, _STARVED_AFTER = 0, 0.0, None, ""
 
 
 def _clean_attrs(attrs: Mapping[str, Any]) -> dict:
@@ -400,7 +423,8 @@ def _live_span(trace_id: int, name: str, parent_id: int | None, root_id: int,
     sp = _start_span(trace_id, name, parent_id, attrs, ring=ring)
     if not ring:
         sp["anchor_id"] = parent_id
-    sp["child_s"] = 0.0
+    sp["child_s"] = sp["child_starved_s"] = 0.0
+    starved0 = _starved_at(sp["start_s"])
     outer = _CURRENT.get()
     tok = _CURRENT.set(sp)
     try:
@@ -410,14 +434,20 @@ def _live_span(trace_id: int, name: str, parent_id: int | None, root_id: int,
         _CURRENT.reset(tok)
         end = now_s()
         dur = end - sp["start_s"]
+        starved = _starved_at(end) - starved0
         sp["self_s"] = max(dur - sp.pop("child_s"), 0.0)
+        sp["starved_s"] = max(starved - sp.pop("child_starved_s"), 0.0)
         if outer is not None and "child_s" in outer:
             outer["child_s"] += dur
+            outer["child_starved_s"] += starved
         if ring:
             _finish_span(sp, end_s=end)
         else:
             sp["end_s"] = end
         telemetry.observe_digest("tdt_span_self_seconds", sp["self_s"], phase=name)
+        if sp["starved_s"] > 0.0:
+            telemetry.observe_digest(
+                "tdt_span_starved_seconds", sp["starved_s"], phase=name)
 
 
 def span_current(name: str, /, **attrs):
@@ -501,6 +531,79 @@ def point_current(name: str, /, **attrs) -> None:
     t = now_s()
     sp = _start_span(cur["trace_id"], name, _anchor(cur), attrs, start_s=t)
     _finish_span(sp, end_s=t)
+
+
+# ---------------------------------------------------------- the device ledger
+
+
+def device_issued() -> int:
+    """The engine has just issued a step program (a prefill chunk, the pool
+    scatter, a decode chunk, a speculative chunk): the device has work, and
+    the starved interval that was open ends here, counted into
+    ``tdt_engine_device_starved_seconds_total`` under ``after=<the wait that
+    began it>``. Returns the program's ticket, which the wait for it hands
+    to :func:`device_waited`. The helper programs a step is surrounded by (a
+    key split, a buffer of zeros, sampling one row) are not told: the device
+    runs each in microseconds, and the host's time in them is what the
+    ledger measures. With telemetry off: ticket 0, nothing kept, no clock
+    read."""
+    global _ISSUED, _STARVED_SINCE
+    if not telemetry.enabled():
+        return 0
+    _ISSUED += 1
+    if _STARVED_SINCE is not None:
+        _close_starved(now_s())
+        _STARVED_SINCE = None
+    return _ISSUED
+
+
+def device_waited(ticket: int, after: str) -> None:
+    """A wait for the step program ``ticket`` has returned. The device runs
+    its programs in the order of their issue, so if that program is the
+    newest issued nothing is left for the device to run, and a starved
+    interval opens, named ``after`` the wait. If a later program was issued
+    meanwhile (a decode chunk landed behind the next one's issue, a program
+    nobody waits for) the device is not known starved and nothing changes:
+    where programs go unwaited the total is a lower bound."""
+    global _STARVED_SINCE, _STARVED_AFTER
+    if ticket and ticket == _ISSUED and _STARVED_SINCE is None:
+        _STARVED_SINCE, _STARVED_AFTER = now_s(), after
+
+
+def device_no_work() -> None:
+    """The serving loop has found nothing to do (no tenant, no request
+    due): the open interval closes where it stands, under the wait that
+    began it (the host was finishing that work), and what follows, up to
+    the next issue, is counted under ``after="no_work"`` and is no span's
+    starved time: a server with nothing to serve is not starving its
+    device."""
+    global _STARVED_SINCE, _STARVED_AFTER
+    if _STARVED_SINCE is None or _STARVED_AFTER == NO_WORK:
+        return
+    now = now_s()
+    _close_starved(now)
+    _STARVED_SINCE, _STARVED_AFTER = now, NO_WORK
+
+
+def device_starved_s() -> float:
+    """The ledger's running total now: starved seconds since the process
+    began, the open interval included, ``no_work`` left out."""
+    return _starved_at(now_s())
+
+
+def _starved_at(t: float) -> float:
+    """The running total at ``t``, a time not before the last call."""
+    if _STARVED_SINCE is None or _STARVED_AFTER == NO_WORK:
+        return _STARVED_S
+    return _STARVED_S + (t - _STARVED_SINCE)
+
+
+def _close_starved(now: float) -> None:
+    global _STARVED_S
+    dt = now - _STARVED_SINCE
+    telemetry.inc("tdt_engine_device_starved_seconds_total", dt, after=_STARVED_AFTER)
+    if _STARVED_AFTER != NO_WORK:
+        _STARVED_S += dt
 
 
 # ------------------------------------------------------------- jit cache misses

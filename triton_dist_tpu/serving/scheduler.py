@@ -176,6 +176,7 @@ class Request:
     )
     submitted_at: float = 0.0
     arrived_at: float = 0.0
+    admitted_at: float | None = None  # its newest join into a slot
     first_token_at: float | None = None
     finished_at: float | None = None
 
@@ -947,6 +948,7 @@ class Scheduler:
                     slot = free.pop(0)
                     req.state = RequestState.RUNNING
                     req.arrived_at = max(req.submitted_at, req.arrival_time_s)
+                    req.admitted_at = now_s
                     slot.state = SlotState.PREFILL
                     slot.request = req
                     self._wfq_clock = max(self._wfq_clock, req.wfq_tag)

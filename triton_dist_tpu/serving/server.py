@@ -769,8 +769,12 @@ class InferenceServer:
         trace and everything it does that is not free a span beneath it
         (``docs/observability.md``, "The loop's spans"): none enters the
         span ring; they reach the profiler, on the device trace's clock, and
-        the ``tdt_span_self_seconds`` digest."""
+        the ``tdt_span_self_seconds`` digest. An iteration that finds no
+        tenant and no request due says so to the device's ledger first: what
+        follows is ``no_work``, not the device starved by the host."""
         with self._trace.span("tdt_serving_step", ring=False):
+            if self._nothing_to_serve():
+                tracing.device_no_work()
             with self._trace.span("tdt_serving_health", ring=False):
                 worked = self._health_sweep()
                 worked = self._maybe_probe() or worked
@@ -782,6 +786,12 @@ class InferenceServer:
                 return worked
             self._guarded(self._decode_once, what="decode chunk")
             return True
+
+    def _nothing_to_serve(self) -> bool:
+        if self._in_flight is not None or self.scheduler.occupancy():
+            return False
+        nxt = self.scheduler.next_arrival_s()
+        return nxt is None or nxt > self._now()
 
     def run(self, poll_s: float = 0.05) -> None:
         """Serve until the queue is drained and every slot is free.
@@ -993,6 +1003,9 @@ class InferenceServer:
                 "req": req, "ids": ids, "off": shared_rows,
                 "kbuf": kbuf, "vbuf": vbuf, "key": sub, "n_chunks": 0,
                 "state": self.engine.prompt_state(),
+                # Seconds of this request's own prefill calls so far
+                # (``tdt_serving_prefill_own_seconds``).
+                "own_s": 0.0,
             }
 
     def _advance_prefills(self) -> bool:
@@ -1026,6 +1039,7 @@ class InferenceServer:
         chunk_ids[0, : len(take)] = take
         final = off + len(take) >= p_len
         last_idx = (p_len - 1 - off) if final else (c - 1)
+        t_own = self._now()
         with req.trace.span(
             "tdt_serving_prefill", slot=slot.idx, hist_len=p_len,
             off=off, chunk_len=len(take), recovery=bool(req.tokens),
@@ -1036,6 +1050,7 @@ class InferenceServer:
             )
         st["off"] = off + len(take)
         st["n_chunks"] += 1
+        st["own_s"] += self._now() - t_own
         if final:
             with self._trace.span(
                 "tdt_serving_prefill_complete", ring=False, slot=slot.idx
@@ -1047,7 +1062,16 @@ class InferenceServer:
         pool along the slot's chain (shared prefix blocks stay the donor's),
         publish the table row, then sample/stream token0. A request that
         arrives WITH tokens (recovery, restore, journal replay, migration)
-        arms decode from its history instead."""
+        arms decode from its history instead.
+
+        As token0 streams, the request's time in its slot is known:
+        ``tdt_serving_prefill_residence_seconds`` from its admission (where
+        ``tdt_serving_queue_wait_seconds`` ends) to that token (where
+        ``tdt_serving_ttft_seconds`` ends, on the same clock), and of it
+        ``tdt_serving_prefill_own_seconds``, its own chunk calls and this
+        completion up to the token. The rest it sat in the slot while the
+        loop served the others."""
+        t_own = self._now()
         req = st["req"]
         del self._prefilling[slot.idx]
         p_len = len(st["ids"])
@@ -1094,6 +1118,15 @@ class InferenceServer:
         self.scheduler.start_decode(slot)
         with self._trace.span("tdt_serving_emit", ring=False, n_tokens=1):
             self._stream(req, tok)
+        if req.admitted_at is not None:
+            telemetry.observe(
+                "tdt_serving_prefill_residence_seconds",
+                max(req.first_token_at - req.admitted_at, 0.0),
+            )
+            telemetry.observe(
+                "tdt_serving_prefill_own_seconds",
+                st["own_s"] + max(req.first_token_at - t_own, 0.0),
+            )
         if self._journal is not None:
             self._journal.append(
                 "prefill", req_id=req.req_id, start=0, tokens=[tok]
@@ -1290,7 +1323,7 @@ class InferenceServer:
             # The scripted fault of a decode chunk shows where a real one
             # does: when the host comes for the chunk.
             resilience.chaos_check("decode")
-            self._watchdog.call(self.engine.land_decode_chunk, chunk.handle)
+            self._watchdog.call(self.engine.land_decode_chunk, chunk.handle, why)
             out_np = np.asarray(chunk.out)
             self._last = np.asarray(chunk.tok, dtype=np.int32).copy()
         d_end = tracing.now_s()
@@ -1298,6 +1331,7 @@ class InferenceServer:
         wall = now - max(chunk.t_issue, self._landed_at)
         self._landed_at = now
         telemetry.inc("tdt_serving_decode_chunks_total")
+        telemetry.observe("tdt_serving_decode_chunk_seconds", wall)
         if why is None:
             telemetry.inc("tdt_serving_decode_chunks_ahead_total")
         else:
@@ -1340,7 +1374,6 @@ class InferenceServer:
                 self._finish(slot)
         if n_streamed:
             telemetry.inc("tdt_serving_tokens_total", float(n_streamed))
-            telemetry.observe("tdt_serving_chunk_token_seconds", wall / n_streamed)
             # Feed the admission-time overload projection.
             self.scheduler.note_decode_rate(n_streamed, wall)
 
@@ -1405,6 +1438,7 @@ class InferenceServer:
         self._last = np.asarray(tok, dtype=np.int32).copy()
         wall = time.perf_counter() - t0
         telemetry.inc("tdt_serving_decode_chunks_total")
+        telemetry.observe("tdt_serving_decode_chunk_seconds", wall)
         # The accepted counts are the host's to read before the next round:
         # a speculative chunk never stays in flight.
         telemetry.inc("tdt_serving_decode_sync_boundaries_total", why="spec")
@@ -1469,7 +1503,6 @@ class InferenceServer:
                 self._finish(slot)
         if n_streamed:
             telemetry.inc("tdt_serving_tokens_total", float(n_streamed))
-            telemetry.observe("tdt_serving_chunk_token_seconds", wall / n_streamed)
             self.scheduler.note_decode_rate(n_streamed, wall)
 
     # -------------------------------------------------------------- streaming

@@ -203,6 +203,9 @@ REQUIRED_NAMES = {
     # the two sum to tdt_serving_decode_chunks_total
     "tdt_serving_decode_chunks_ahead_total",
     "tdt_serving_decode_sync_boundaries_total",
+    # prefill chunks issued and not waited for (every chunk of a prompt
+    # but its last), of the tdt_serving_prefill_chunks histogram's sum
+    "tdt_serving_prefill_chunks_unfenced_total",
     # which way a paged decode chunk ran: against the pool in place, or
     # bounced through the contiguous layout (models/engine.py)
     "tdt_engine_decode_chunks_total",

@@ -15,6 +15,7 @@ WITHOUT dropping the queue, and every stream completes with zero dropped
 and zero duplicated tokens.
 """
 
+import contextlib
 import os
 
 import jax
@@ -614,6 +615,34 @@ def engine1(model1):
     return make_engine(model1)
 
 
+@contextlib.contextmanager
+def _lowerings():
+    """The names of the programs jax lowers inside the block, as they come."""
+    from jax._src import monitoring
+
+    from triton_dist_tpu.runtime import tracing
+
+    lowered = []
+
+    def on_duration(event, duration, fun_name=None, **_):
+        if event == tracing.LOWERING_EVENT:
+            lowered.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        yield lowered
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+
+
+def _fence_every_chunk(mp, eng):
+    """The seam: every prefill chunk waited for as it is issued, as the
+    engine did before a chunk could be left to the device."""
+    inner = eng.prefill_chunk_state
+    mp.setattr(eng, "prefill_chunk_state", lambda *args, wait=True: inner(*args))
+
+
+
 def _landings(srv):
     """Record every landing of ``srv`` as (why, slots dropped)."""
     seen, inner = [], srv._land
@@ -643,8 +672,12 @@ SCRIPT = [
 ]
 
 
-def _serve_script(eng, journal_path, ahead: bool):
-    srv = InferenceServer(eng, num_slots=3, chunk=2, journal=str(journal_path))
+def _serve_script(eng, journal_path, ahead: bool, script=None, **server_args):
+    """``ahead`` false: the loop that lands every decode chunk before
+    ``step()`` returns and waits for every prefill chunk as it is issued."""
+    script = SCRIPT if script is None else script
+    srv = InferenceServer(
+        eng, num_slots=3, chunk=2, journal=str(journal_path), **server_args)
     if not ahead:
         srv._sync_reason = lambda chunk: "other"  # the seam: never in flight
     clock = [0.0]
@@ -655,17 +688,20 @@ def _serve_script(eng, journal_path, ahead: bool):
 
     def on_token(req, token, index):
         streams.setdefault(req.req_id, []).append(token)
-        what = SCRIPT[reqs.index(req)][2]
+        what = script[reqs.index(req)][2]
         if what == ("cancel", index):
             srv.cancel(req.req_id)
         elif what == ("deadline", index):
             clock[0] = 100.0
 
-    for prompt, max_new, what in SCRIPT:
+    for prompt, max_new, what in script:
         reqs.append(srv.submit(
             prompt, max_new, on_token=on_token,
             deadline_s=50.0 if what and what[0] == "deadline" else None))
-    srv.run()
+    with pytest.MonkeyPatch.context() as mp:
+        if not ahead:
+            _fence_every_chunk(mp, eng)  # ... and no chunk left to the device
+        srv.run()
     assert srv._in_flight is None and srv.scheduler.occupancy() == 0
     by_req: dict = {}
     for rec in srv.journal_records():
@@ -709,28 +745,15 @@ def test_in_flight_counters_are_the_hosts_count_of_the_boundaries(model1):
     are landed behind the next one's issue; chunk 4 finishes a slot; chunk
     5 runs beside a free slot; chunk 6 finishes the other. The two
     counters sum to the chunks."""
-    from jax._src import monitoring
-
-    from triton_dist_tpu.runtime import tracing
-
-    lowered = []
-
-    def on_duration(event, duration, fun_name=None, **_):
-        if event == tracing.LOWERING_EVENT:
-            lowered.append(fun_name)
-
     srv = InferenceServer(make_engine(model1), num_slots=2, chunk=2)
     landings = _landings(srv)
     reqs = [srv.submit([3, 17, 42], 9), srv.submit([8, 1, 13], 13)]
     steps = 0
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    try:
+    with _lowerings() as lowered:
         while srv.step():
             steps += 1
             # in flight exactly where the next boundary changes nothing
             assert (srv._in_flight is not None) == (steps <= 3)
-    finally:
-        monitoring.unregister_event_duration_listener(on_duration)
     assert [len(r.tokens) for r in reqs] == [9, 13] and steps == 6
     assert [why for why, _ in landings] == [
         None, None, None, "finish", "free_slot", "finish"]
@@ -815,9 +838,14 @@ def test_chaos_fault_at_the_landing_of_a_chunk_in_flight(engine1):
     assert chunks() - before == len(landings) - 1
 
 
-def test_phases_are_stamped_and_the_fence_is_the_landings(engine1, monkeypatch):
+@pytest.mark.parametrize("chunked", [False, True], ids=["one-chunk", "chunked"])
+def test_phases_are_stamped_and_the_fence_is_the_landings(engine1, monkeypatch, chunked):
     """``host_sync`` is the wait at the landing, one a chunk and under the
-    engine's span; ``dispatch`` and ``admission`` are observed as before."""
+    engine's span; ``dispatch`` is observed as before. ``admission`` and the
+    fence under ``tdt_engine_prefill_chunk`` are one a PROMPT: its last
+    chunk's, from that chunk's issue to the end of its compute (with what
+    was queued on the device before it). Where every prompt is one chunk
+    that is one a chunk, as it was; a prompt of three chunks is fenced once."""
     from triton_dist_tpu.runtime import tracing
 
     fences = []
@@ -829,16 +857,282 @@ def test_phases_are_stamped_and_the_fence_is_the_landings(engine1, monkeypatch):
         return inner(x)
 
     monkeypatch.setattr(jax, "block_until_ready", fence)
-    srv = InferenceServer(engine1, num_slots=2, chunk=2)
-    reqs = [srv.submit([3, 17, 42], 9), srv.submit([8, 1, 13], 13)]
+    srv = InferenceServer(engine1, num_slots=2, chunk=2, prefill_chunk=4 if chunked else 16)
+    reqs = [srv.submit([3, 17, 42], 9), srv.submit(LONG12 if chunked else [8, 1, 13], 13)]
     srv.run()
     assert all(r.done for r in reqs)
     chunks = telemetry.counter_value("tdt_serving_decode_chunks_total")
-    assert fences.count("tdt_engine_host_sync") == chunks == 6.0
+    assert fences.count("tdt_engine_host_sync") == chunks == (8.0 if chunked else 6.0)
     n = {e["labels"]["phase"]: e["n"] for e in
          telemetry.snapshot()["digests"]["tdt_engine_phase_seconds"]}
     assert n["host_sync"] == n["dispatch"] == chunks and n["admission"] == 2
+    assert fences.count("tdt_engine_prefill_chunk") == 2
+    (hist,) = telemetry.snapshot()["histograms"]["tdt_serving_prefill_chunks"]
+    assert hist["sum"] == (1.0 + 3.0 if chunked else 2.0)
+    assert telemetry.counter_value(
+        "tdt_serving_prefill_chunks_unfenced_total") == hist["sum"] - 2.0
     srv.shutdown(drain=False)
+
+
+# ===================== prefill chunks behind work in flight (chunked prompts)
+#
+# ``prefill_chunk=4``: a prompt of 9 or 12 takes three chunks, one of 14
+# four (the last one padded). A chunk that is not its prompt's last is issued
+# and not waited for, and a slot that prefills does not land the decode chunk
+# beside it; the turn of a prompt's last chunk waits for that chunk and lands
+# what is in flight (``why="prefill"``) before it touches the pool.
+
+LONG9 = [61, 62, 63, 64, 65, 66, 67, 68, 69]
+LONG12 = [21, 22, 23, 24, 25, 26, 27, 28, 29, 30, 31, 32]
+LONG14 = [41, 42, 43, 44, 45, 46, 47, 48, 49, 50, 51, 52, 53, 54]
+
+# Three slots at chunk 2, more clients than slots: chunked prompts join
+# beside decoding slots at the opening and after every finish. The cancel
+# and the deadline are set off by a token of their own request while other
+# slots are mid-prompt.
+CHUNKED_SCRIPT = [
+    ([3, 17, 42], 15, None),
+    (LONG12, 7, None),
+    (LONG14, 9, ("cancel", 4)),
+    (LONG9, 6, None),
+    ([8, 1, 13], 12, ("deadline", 7)),
+    (LONG14, 3, None),
+    (LONG12, 1, None),
+]
+
+
+@pytest.mark.parametrize("kind", ["dense", "hybrid-ssm"])
+def test_chunked_prompts_beside_decoding_slots_stream_the_fenced_loops_bytes(
+        kind, engine1, hybrid_engine, tmp_path):
+    """Prompts of three and four chunks among decoding slots, through the
+    loop as it is and through the loop that waits for every prefill chunk
+    and lands every decode chunk before ``step()`` returns: the same tokens
+    to the same callbacks, finish reasons, journal and allocator, for a
+    stateless model and for one whose prompt state rides (donated) from
+    chunk to chunk."""
+    eng = engine1 if kind == "dense" else hybrid_engine
+    unfenced = lambda: telemetry.counter_value("tdt_serving_prefill_chunks_unfenced_total")
+    got, landings = _serve_script(
+        eng, tmp_path / "ahead.jsonl", True, CHUNKED_SCRIPT, prefill_chunk=4)
+    n_unfenced = unfenced()
+    want, landed = _serve_script(
+        eng, tmp_path / "landed.jsonl", False, CHUNKED_SCRIPT, prefill_chunk=4)
+    assert got == want
+    assert got["tokens"] == got["streamed"]
+    assert got["reasons"] == ["ok", "ok", "cancelled", "ok", "deadline", "ok", "ok"]
+    assert [len(t) for t in got["tokens"]] == [15, 7, 5, 6, 9, 3, 1]
+    whys = [why for why, _ in landings]
+    # the loop ran ahead beside prefilling slots and last chunks landed what
+    # was in flight; both loops issued the same chunks, one of them unwaited
+    assert whys.count(None) >= 3 and whys.count("prefill") >= 2
+    assert len(landings) == len(landed) and all(why == "other" for why, _ in landed)
+    assert n_unfenced == 2 * (2 + 3) + 2 == unfenced() - n_unfenced
+    assert not eng._unwaited_stats
+
+
+def test_a_chunk_stays_in_flight_across_a_step_that_prefills(model1):
+    """Three slots at chunk 2: a short prompt decodes 20 tokens while a
+    prompt of 12 (three chunks) and one of 14 (four) prefill beside it. The
+    host's count of every boundary: which steps leave a chunk in flight, why
+    each chunk was landed, the chunks nobody waited for."""
+    srv = InferenceServer(make_engine(model1), num_slots=3, chunk=2, prefill_chunk=4)
+    landings = _landings(srv)
+    reqs = [srv.submit([3, 17, 42], 21), srv.submit(LONG12, 9), srv.submit(LONG14, 7)]
+    unfenced = lambda: telemetry.counter_value("tdt_serving_prefill_chunks_unfenced_total")
+    in_flight, prefilling, counted = [], [], []
+    while True:
+        before = sorted(srv._prefilling)
+        if not srv.step():
+            break
+        in_flight.append(srv._in_flight is not None)
+        prefilling.append(before + sorted(srv._prefilling))
+        counted.append(unfenced())
+    assert [len(r.tokens) for r in reqs] == [21, 9, 7]
+    # step 1: three joins, the short prompt's one chunk (its last, waited
+    # for) and the first chunk of the other two; decode chunk 1 over slot 0
+    # stays in flight though slots 1 and 2 prefill. Step 2: their second
+    # chunks behind it, chunk 2 issued ahead. Step 3: slot 1's last chunk is
+    # waited for and lands chunk 2 (``prefill``); chunk 3, over slots 0 and
+    # 1, in flight beside slot 2's third. Step 4: slot 2's last lands chunk
+    # 3. Steps 5-6: all three decode, chunks 4 and 5 landed behind the next
+    # one's issue; slots 1 and 2 run out in chunk 6, landed ``finish``; from
+    # there a slot is free.
+    assert in_flight[:7] == [True, True, True, True, True, False, False]
+    assert prefilling[0] == [1, 2] and prefilling[1] == [1, 2, 1, 2]
+    assert prefilling[2] == [1, 2, 2] and prefilling[3] == [2]
+    assert counted[:5] == [2.0, 4.0, 5.0, 5.0, 5.0] and counted[-1] == 5.0
+    whys = [why for why, _ in landings]
+    assert whys[:6] == [None, "prefill", "prefill", None, None, "finish"]
+    assert set(whys[6:]) <= {"finish", "free_slot"}
+    sync = lambda why: telemetry.counter_value(
+        "tdt_serving_decode_sync_boundaries_total", why=why)
+    chunks = telemetry.counter_value("tdt_serving_decode_chunks_total")
+    assert chunks == len(landings)
+    assert telemetry.counter_value("tdt_serving_decode_chunks_ahead_total") == 3.0
+    # ``prefill`` is the prompts' last chunks that found a chunk in flight
+    assert sync("prefill") == 2.0
+    assert telemetry.counter_total(
+        "tdt_serving_decode_sync_boundaries_total") == chunks - 3.0
+    (hist,) = telemetry.snapshot()["histograms"]["tdt_serving_prefill_chunks"]
+    assert (hist["sum"], hist["count"]) == (1.0 + 3.0 + 4.0, 3)
+    srv.shutdown(drain=False)
+
+
+def test_no_program_is_lowered_that_the_fenced_loop_does_not_lower(model1):
+    """The same requests through a fresh engine a loop: the programs jax
+    lowers are, name for name and count for count, those of the loop that
+    waits for every chunk. The decode chunk is one executable fed from the
+    host and from the device, and each (chunk, prompt) shape of the prefill
+    one program, waited for or not."""
+    def serve(fenced: bool):
+        eng = make_engine(model1)
+        with pytest.MonkeyPatch.context() as mp, _lowerings() as lowered:
+            if fenced:
+                _fence_every_chunk(mp, eng)
+            srv = InferenceServer(eng, num_slots=3, chunk=2, prefill_chunk=4)
+            if fenced:
+                srv._sync_reason = lambda chunk: "other"
+            reqs = [srv.submit(p, n) for p, n in
+                    [([3, 17, 42], 21), (LONG12, 9), (LONG14, 7), (LONG9, 5)]]
+            srv.run()
+            srv.shutdown(drain=False)
+        return [list(r.tokens) for r in reqs], sorted(lowered)
+
+    tokens, lowered = serve(fenced=False)
+    want_tokens, want = serve(fenced=True)
+    assert tokens == want_tokens and lowered == want
+    assert lowered.count("jit(decode_chunk_paged)") == 1
+    assert lowered.count("jit(chunk_fn)") == 4  # prompts of 3, 9, 12 and 14
+
+
+def test_unwaited_chunks_step_counters_sum_to_the_fenced_loops(hybrid_engine):
+    """The step counters of a chunk nobody waits for are published at the
+    next wait the loop makes: over a served script every model counter
+    reads what it reads when each chunk is waited for as it is issued."""
+    eng = hybrid_engine
+
+    def serve(fenced: bool):
+        telemetry.reset()
+        with pytest.MonkeyPatch.context() as mp:
+            if fenced:
+                _fence_every_chunk(mp, eng)
+            srv = InferenceServer(eng, num_slots=3, chunk=2, prefill_chunk=4)
+            reqs = [srv.submit(p, n) for p, n, _ in CHUNKED_SCRIPT]
+            srv.run()
+            srv.shutdown(drain=False)
+        assert not eng._unwaited_stats
+        counters = {
+            (name, tuple(sorted(e["labels"].items()))): e["value"]
+            for name, es in telemetry.snapshot()["counters"].items()
+            if name.startswith(("tdt_swa_", "tdt_ssm_", "tdt_shared_kv_"))
+            for e in es
+        }
+        unfenced = telemetry.counter_value("tdt_serving_prefill_chunks_unfenced_total")
+        return [list(r.tokens) for r in reqs], counters, unfenced
+
+    tokens, counters, unfenced = serve(fenced=False)
+    want_tokens, want, also_unfenced = serve(fenced=True)
+    assert tokens == want_tokens
+    # the loop counts the chunks it asked no wait for; the seam waited anyway
+    assert unfenced == also_unfenced == 2 * (2 + 3) + 2
+    assert counters == want and len(counters) == 10
+    prefill_rows = counters[("tdt_ssm_tokens_total", (("phase", "prefill"),))]
+    assert prefill_rows >= sum(len(p) for p, _, _ in CHUNKED_SCRIPT)
+
+
+@pytest.mark.chaos
+@pytest.mark.parametrize("surfaces_at", ["last_chunk", "landing"])
+def test_chaos_fault_in_an_unwaited_chunk_surfaces_at_the_next_wait(
+        engine1, surfaces_at):
+    """A fault the device keeps for whoever waits next: the first chunk
+    nobody waits for is poisoned, and the next fence the host enters raises.
+    With no slot decoding yet that is the fence of a prompt's last chunk
+    (under ``tdt_engine_prefill_chunk``); beside a decoding slot it is the
+    landing of the chunk in flight (``tdt_engine_host_sync``). Either way
+    recovery re-prefills from the history and every stream is the
+    undisturbed serve's, each position once."""
+    from triton_dist_tpu.runtime import tracing
+
+    eng = engine1
+    sizes = [(LONG12, 5), (LONG14, 6)]
+    if surfaces_at == "landing":
+        sizes.insert(0, ([3, 17, 42], 13))
+
+    def serve(poison: bool):
+        fired = []
+        with pytest.MonkeyPatch.context() as mp:
+            inner_chunk, inner_fence = eng.prefill_chunk_state, jax.block_until_ready
+            armed = [False]
+
+            def chunk(*args, wait=True):
+                out = inner_chunk(*args, wait=wait)
+                armed[0] = armed[0] or (poison and not wait and not fired)
+                return out
+
+            def fence(x):
+                if armed[0]:
+                    armed[0] = False
+                    fired.append(tracing.current_span()["name"])
+                    raise resilience.CollectiveAbortError("poisoned promise")
+                return inner_fence(x)
+
+            mp.setattr(eng, "prefill_chunk_state", chunk)
+            mp.setattr(jax, "block_until_ready", fence)
+            srv = InferenceServer(eng, num_slots=3, chunk=2, prefill_chunk=4)
+            streams: dict[int, list] = {}
+            reqs = [srv.submit(p, n, on_token=lambda r, t, i: streams.setdefault(
+                r.req_id, []).append((i, t))) for p, n in sizes]
+            srv.run()
+            assert srv._in_flight is None and not srv._prefilling
+            srv.shutdown(drain=False)
+        for r in reqs:  # every position once, in order
+            assert streams[r.req_id] == list(enumerate(r.tokens))
+        return [list(r.tokens) for r in reqs], fired
+
+    want, _ = serve(poison=False)
+    recoveries = lambda: telemetry.counter_total("tdt_serving_recoveries_total")
+    assert recoveries() == 0.0
+    got, fired = serve(poison=True)
+    assert got == want and [len(t) for t in got] == [n for _, n in sizes]
+    assert fired == ["tdt_engine_prefill_chunk" if surfaces_at == "last_chunk"
+                     else "tdt_engine_host_sync"]
+    assert recoveries() == 1.0
+
+
+def test_a_cancel_of_a_prefilling_slot_with_chunks_unwaited(engine1):
+    """Four slots full, one decoding, three mid-prompt with a chunk each
+    issued and not waited for, a decode chunk in flight: the cancel of a
+    prefilling slot lands the chunk in flight (nothing of it is the
+    cancelled slot's: it never decoded), drops the slot's buffers and frees
+    its chain; the other streams are the undisturbed serve's."""
+    eng = engine1
+    sizes = [([3, 17, 42], 13), (LONG14, 6), (LONG12, 5), (LONG12, 9)]
+
+    def serve(cancel: bool):
+        srv = InferenceServer(eng, num_slots=4, chunk=2, prefill_chunk=4)
+        landings = _landings(srv)
+        reqs = [srv.submit(p, n) for p, n in sizes]
+        assert srv.step()
+        assert srv._in_flight is not None and sorted(srv._prefilling) == [1, 2, 3]
+        if cancel:
+            srv.cancel(reqs[1].req_id)
+            free = srv.kv_ledger.stats()["blocks_free"]
+            assert srv.step()
+            assert 1 not in srv._prefilling and srv.scheduler.slots[1].request is None
+            assert srv.kv_ledger.stats()["blocks_free"] > free
+        srv.run()
+        assert srv._in_flight is None and srv.scheduler.occupancy() == 0
+        srv.shutdown(drain=False)
+        return reqs, landings
+
+    want, _ = serve(cancel=False)
+    got, landings = serve(cancel=True)
+    assert got[1].finish_reason == "cancelled" and got[1].tokens == []
+    for i in (0, 2, 3):
+        assert list(got[i].tokens) == list(want[i].tokens) and got[i].finish_reason == "ok"
+    # the reap landed the chunk in flight and kept it from the slot it freed
+    assert ("other", [1]) in landings
+    assert telemetry.counter_value("tdt_serving_cancelled_total", where="running") == 1.0
 
 
 # ========================= a request's timeline and the device's ledger

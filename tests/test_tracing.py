@@ -819,6 +819,41 @@ def test_ledger_counts_no_work_apart_and_waits_for_the_newest_program(clock):
     assert tracing.device_starved_s() == 0.0 and tracing.device_issued() == 1
 
 
+def test_ledger_programs_nobody_waits_for_open_no_interval(clock):
+    """A decode chunk in flight, two prefill chunks issued behind it and
+    never waited for, the next decode chunk behind them: the landing of the
+    first chunk, a wait for an older program, opens nothing; the device is
+    known starved only from the wait for the newest program issued, and the
+    interval is that wait's. The unwaited programs cost the ledger nothing
+    but their tickets: its total stays a lower bound."""
+    t = tracing.start_trace("tdt_test_trace")
+    chunk = tracing.device_issued()
+    clock.t = 101.0
+    unwaited = [tracing.device_issued(), tracing.device_issued()]
+    clock.t = 102.0
+    nxt = tracing.device_issued()
+    clock.t = 103.0
+    reads = clock.reads
+    with t.span("tdt_test_landing", ring=False) as landing:
+        tracing.device_waited(chunk, "decode_land")  # three programs behind it
+        clock.t = 104.0
+    assert landing["starved_s"] == 0.0 and clock.reads == reads + 2  # open, close
+    assert tracing.device_starved_s() == 0.0 and _starved_counter() == {}
+    assert unwaited == [chunk + 1, chunk + 2] and nxt == chunk + 3
+    # a prompt's last chunk, issued behind all of it and waited for: the
+    # newest, so the device is starved from the fence's return; the landing
+    # of the chunk in flight after it finds an interval open and moves nothing
+    last = tracing.device_issued()
+    clock.t = 107.0
+    tracing.device_waited(last, "prefill_chunk")
+    clock.t = 108.0
+    tracing.device_waited(nxt, "decode_land:prefill")
+    clock.t = 109.5
+    tracing.device_issued()  # the pool scatter
+    assert _starved_counter() == {"prefill_chunk": 2.5}
+    assert tracing.device_starved_s() == 2.5
+
+
 def test_ledger_is_off_with_telemetry_off(clock):
     telemetry.reset(enabled_override=False)
     reads = clock.reads
@@ -853,3 +888,30 @@ def test_engine_tells_the_ledger_each_step_program_and_each_fence(model1):
     assert tracing.device_starved_s() > sum(after.values()) > 0.0
     phases = _starved_phases()
     assert {"tdt_engine_complete_paged_prefill", "tdt_engine_dispatch"} <= set(phases)
+
+
+
+def test_engine_leaves_a_chunk_that_is_not_the_last_to_the_device(model1, monkeypatch):
+    """``prefill_chunk_state(..., wait=False)`` takes a ticket, enters no
+    fence, stamps no ``admission`` and opens no starved interval; the call
+    for the prompt's last chunk is the fence it always was, and the two
+    routes run one program on the same operands: the logits are the fenced
+    calls' to the bit."""
+    eng = make_engine(model1)
+    ids = jax.numpy.asarray([[3, 17, 42, 7]], jax.numpy.int32)
+    fences = []
+    inner = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: fences.append(1) or inner(x))
+    admissions = lambda: sum(
+        e["n"] for e in telemetry.snapshot()["digests"].get("tdt_engine_phase_seconds", [])
+        if e["labels"]["phase"] == "admission")
+    _, kbuf, vbuf, state = eng.prefill_chunk_state(
+        *eng.paged_kbuf_zeros(8), ids, 0, 3, (), wait=False)
+    assert not fences and admissions() == 0 and _starved_counter() == {}
+    assert tracing.device_starved_s() == 0.0 and tracing.device_issued() == 2
+    assert eng._unwaited_stats == []  # a dense model's chunk has no counters
+    logits, *_ = eng.prefill_chunk_state(kbuf, vbuf, ids, 4, 3, state)
+    assert len(fences) == 1 and admissions() == 1 and tracing.device_starved_s() > 0.0
+    _, kbuf, vbuf, state = eng.prefill_chunk_state(*eng.paged_kbuf_zeros(8), ids, 0, 3, ())
+    want, *_ = eng.prefill_chunk_state(kbuf, vbuf, ids, 4, 3, state)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))

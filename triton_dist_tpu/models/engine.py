@@ -109,6 +109,11 @@ class Engine:
         # round-trips (self.backend tracks what is currently built).
         self.preferred_backend = backend
         self._drafter = None
+        # (ticket, step stats) of the prefill chunks issued and not waited
+        # for, oldest first: the device's promises until a wait for a later
+        # program has returned (``_publish_unwaited``). Empty with telemetry
+        # off, and for a model without stats.
+        self._unwaited_stats: list = []
         tracing.watch_lowerings()
         self._build(backend)
 
@@ -847,7 +852,7 @@ class Engine:
             )
 
     def prefill_chunk_state(self, kbuf, vbuf, chunk_ids: jax.Array, off: int,
-                            last_idx: int, state):
+                            last_idx: int, state, *, wait: bool = True):
         """One chunk of an incremental prefill against the running context
         buffers. ``chunk_ids`` (1, C) — the final chunk arrives padded to C;
         ``off`` is the chunk's absolute start, ``last_idx`` the row whose
@@ -855,7 +860,15 @@ class Engine:
         ``state`` is the prompt's carried state, from :meth:`prompt_state`
         or the chunk before (``()`` for a model that keeps none). One
         compiled program per (C, P) shape pair; kbuf/vbuf/state are donated.
-        Returns (logits (1, V), kbuf', vbuf', state')."""
+        Returns (logits (1, V), kbuf', vbuf', state').
+
+        ``wait`` is whether the caller needs this chunk's result: it does of
+        a prompt's last (its logits are sampled, its buffers scattered into
+        the pool), and that chunk is fenced as every chunk was. A chunk that
+        is not the last (``wait=False``) is issued and left to the device:
+        what it returns are promises that the prompt's next chunk takes as
+        they are, behind whatever else is in flight, and its step counters
+        are kept (:attr:`_unwaited_stats`) for the next wait to publish."""
         timed = telemetry.enabled()
         t = time.perf_counter() if timed else 0.0
         # The engine's side of the boundary. The call is one phase
@@ -867,13 +880,32 @@ class Engine:
                 jnp.int32(off), jnp.int32(last_idx), state,
             )
             ticket = tracing.device_issued()
-            if timed:
-                # Admission: each prefill chunk's compute, the cost of
-                # joining one request into the running batch.
+            if timed and wait:
+                # Admission: the cost of joining one request into the
+                # running batch, stamped once a prompt: its last chunk from
+                # the issue to the end of its compute, what was queued on
+                # the device before it included.
                 self._phase("admission", t, logits)
                 tracing.device_waited(ticket, "prefill_chunk")
+                self._publish_unwaited(ticket)
                 self.model.publish_step_stats(stats)
+            elif timed and (counters := jax.tree.leaves(stats)):
+                # On their way to the host as soon as the chunk is done, as
+                # a decode chunk's are.
+                for fetched in counters:
+                    fetched.copy_to_host_async()
+                self._unwaited_stats.append((ticket, stats))
         return logits, kb, vb, state
+
+    def _publish_unwaited(self, ticket: int) -> None:
+        """Feed the counters from the unwaited chunks issued before the
+        program ``ticket``, which a wait has just returned for: the device
+        runs its programs in the order of their issue, so those are done and
+        nothing here waits. A chunk issued behind that program stays for the
+        next wait. Taken off the list before it is fetched: a fault the
+        device kept for the fetch surfaces here once."""
+        while self._unwaited_stats and self._unwaited_stats[0][0] < ticket:
+            self.model.publish_step_stats(self._unwaited_stats.pop(0)[1])
 
     def prefill_chunk(self, kbuf, vbuf, chunk_ids: jax.Array, off: int,
                       last_idx: int):
@@ -1033,6 +1065,7 @@ class Engine:
                     self._phase("host_sync", time.perf_counter(), handle.tok)
                     tracing.device_waited(
                         handle.ticket, f"decode_land:{why}" if why else "decode_land")
+                    self._publish_unwaited(handle.ticket)
                     if handle.stats is not None:
                         self.model.publish_step_stats(handle.stats)
             handle.landed = True

@@ -1011,7 +1011,9 @@ class InferenceServer:
     def _advance_prefills(self) -> bool:
         """Advance every in-flight chunked prefill by ONE chunk (the decode
         stall bound: a long prompt joining mid-decode delays the next decode
-        dispatch by at most one chunk's work)."""
+        dispatch by at most one chunk's work). Only a prompt's last chunk
+        is waited for (:meth:`_advance_prefill`): the others queue on the
+        device behind whatever is in flight."""
         if not self._prefilling:
             return False
         for idx in list(self._prefilling):
@@ -1044,18 +1046,28 @@ class InferenceServer:
             "tdt_serving_prefill", slot=slot.idx, hist_len=p_len,
             off=off, chunk_len=len(take), recovery=bool(req.tokens),
         ):
+            # Waited for only where its result is needed, at the prompt's
+            # last chunk: until then the buffers are the prompt's own and the
+            # logits are nobody's, so the chunk is left to the device.
             logits, st["kbuf"], st["vbuf"], st["state"] = self.engine.prefill_chunk_state(
                 st["kbuf"], st["vbuf"], jnp.asarray(chunk_ids), off, last_idx,
-                st["state"],
+                st["state"], wait=final,
             )
         st["off"] = off + len(take)
         st["n_chunks"] += 1
         st["own_s"] += self._now() - t_own
-        if final:
-            with self._trace.span(
-                "tdt_serving_prefill_complete", ring=False, slot=slot.idx
-            ):
-                self._complete_prefill(slot, st, logits)
+        if not final:
+            telemetry.inc("tdt_serving_prefill_chunks_unfenced_total")
+            return
+        # The completion writes the pool, the tables and the mirrors, and
+        # the slot decodes from the host's ``_last`` / ``_remaining``: a
+        # decode chunk kept in flight beside this prefill lands first (the
+        # fence above has waited for it too: it was issued before).
+        self._land_in_flight("prefill")
+        with self._trace.span(
+            "tdt_serving_prefill_complete", ring=False, slot=slot.idx
+        ):
+            self._complete_prefill(slot, st, logits)
 
     def _complete_prefill(self, slot: Slot, st: dict, logits) -> None:
         """Finish a chunked prefill: scatter the context buffer into the
@@ -1211,7 +1223,10 @@ class InferenceServer:
         goes from k to k+1 while the host still works on k. Chunk k+1 in its
         turn stays in flight only if nothing about it changes the slot set
         (:meth:`_sync_reason`); otherwise it is landed here too, before
-        :meth:`step` returns, which is the order the loop always had.
+        :meth:`step` returns, which is the order the loop always had. The
+        prefill chunks a step issues lie between k and k+1 on the device, as
+        they always did; those that are not their prompt's last are waited
+        for by nobody.
 
         The host's mirrors follow the issue, not the landing: ``_remaining``
         and ``_lengths`` advance by what the chunk will do (``min(remaining,
@@ -1286,17 +1301,17 @@ class InferenceServer:
         it stays in flight, because the next chunk will run over the same
         slots whatever this one streams. The first that holds, in this
         order: a slot finishes inside it; a slot is free (a request may join
-        it at the next boundary); a slot is prefilling; the chunk bounced
-        through the contiguous layout (a pp mesh: landed as issued); the
-        server is draining. The loop can see all of it before the chunk
-        runs; nothing here is a setting."""
+        it at the next boundary); the chunk bounced through the contiguous
+        layout (a pp mesh: landed as issued); the server is draining. A slot
+        that prefills is no reason: its chunks touch the prompt's own buffers
+        alone, and the one that completes it lands what is in flight itself
+        (:meth:`_advance_prefill`, counted as ``prefill``). The loop can see
+        all of it before the chunk runs; nothing here is a setting."""
         if any(n <= self.chunk for n in chunk.pre.values()):
             return "finish"
-        states = {s.state for s in self.scheduler.slots}
-        if states - {SlotState.DECODE, SlotState.PREFILL}:
+        if any(s.state not in (SlotState.DECODE, SlotState.PREFILL)
+               for s in self.scheduler.slots):
             return "free_slot"
-        if SlotState.PREFILL in states:
-            return "prefill"
         if chunk.handle.landed:
             return "bounce"
         if self._draining or self._shutdown:

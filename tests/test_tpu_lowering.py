@@ -682,6 +682,28 @@ def _dsa_flash_prefill(sds):
         sds((2048, 16384), jnp.bool_), sds((512, 64, 192)), sds((512, 64, 256)))
 
 
+def _latent_flash_prefill(sds):
+    """A prefill chunk's attention over everything before it at A.X-K1's
+    widths: 2048 query rows of 64 heads (128 + 64, padded to 256 inside, and
+    128 wide), no mask handed over, the last chunk of the longest prompt
+    buffer's latent rows (576 values in 640)."""
+    from triton_dist_tpu.kernels.latent_flash import latent_flash_prefill
+
+    return (lambda *a: latent_flash_prefill(*a, 0.13086)), (
+        sds((2048, 64, 128)), sds((2048, 64, 64)), sds((32768, 640)), sds((), jnp.int32),
+        sds((512, 64, 128)), sds((512, 64, 128)))
+
+
+def _latent_flash_decode(sds):
+    """One layer's decode step over the latent pool where it lies: 8 slots of
+    64 absorbed queries over rows of 640, a table of 2064 pages of 16."""
+    from triton_dist_tpu.kernels.latent_flash import latent_flash_decode
+
+    return (lambda q, pool, t, n: latent_flash_decode(q, pool, 3, t, n, rank=512, scale=0.13086)), (
+        sds((8, 64, 640)), sds((5, 8 * 2064 + 1, 1, 16, 640)), sds((8, 2064), jnp.int32),
+        sds((8,), jnp.int32))
+
+
 def _ssm_scan(sds):
     """One Mamba layer's scan over a prefill chunk at the published widths:
     512 rows of 5120 channels, a state of 16 a channel."""
@@ -746,7 +768,8 @@ def _bsa_decode(sds):
 @pytest.mark.parametrize(
     "case", [_flash_decode, _paged_flash_decode, _flash_attention, _dsa_kth_value,
              _dsa_flash_prefill, _ssm_scan, _shared_kv_decode, _lightning_chunk,
-             _bsa_select, _bsa_prefill, _bsa_decode],
+             _bsa_select, _bsa_prefill, _bsa_decode, _latent_flash_prefill,
+             _latent_flash_decode],
     ids=lambda f: f.__name__.lstrip("_"))
 def test_named_kernel_compiles_under_its_name(topo_2x2, case):
     """At Qwen3-8B head shapes, for one v5e chip: the kernel compiles and
@@ -803,9 +826,11 @@ def test_collective_kernel_is_named_after_its_function(tpu_mesh):
 # ---------------------------------------------------------------------------
 
 
-def _abstract_latent_sparse(devices):
-    """(model, params, configuration file) of ``glm-5.2-ep16-d5`` over one
-    described chip, the parameters as shapes."""
+def _abstract_latent_sparse(devices, config="glm-5.2-ep16-d5"):
+    """(model, params, configuration file) of a ``LatentSparseLLM``
+    configuration of the benchmark over one described chip, the parameters
+    as shapes."""
+    import importlib
     import json
     import sys
 
@@ -816,11 +841,9 @@ def _abstract_latent_sparse(devices):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
-    from benchmark.build.glm_moe_dsa import model_config
-
-    with open(os.path.join(root, "benchmark/configs/glm-5.2-ep16-d5.json")) as f:
+    with open(os.path.join(root, f"benchmark/configs/{config}.json")) as f:
         cfg = json.load(f)
-    c = model_config(cfg)
+    c = importlib.import_module(f"benchmark.build.{cfg['architecture']}").model_config(cfg)
     ctx = initialize_distributed(devices=list(devices), axis_names=("tp",), set_default=False)
     dt = jnp.dtype(c.dtype)
     sds = lambda shape, dtype=dt: jax.ShapeDtypeStruct(
@@ -908,6 +931,59 @@ def test_latent_sparse_chunk_attends_without_a_score_matrix(topo_2x2, p_len):
     # ... and the search does find the parent's, by its line in the ledger
     was = "%fusion.431 = f32[16,2048,2048]{2,1,0:T(8,128)} fusion(%bitcast.9, %p.1), kind=kLoop"
     assert "f32[16,2048,2048]" in was
+
+
+def test_latent_dense_programs_fit(topo_2x2):
+    """``longread``'s two step programs for one v5e chip at A.X-K1's widths
+    (11.1 GB of weights, 1.7 GB of pool): the prefill chunk over the longest
+    prompt buffer attends through ``latent_flash_prefill`` once a layer, is
+    handed no mask and leaves no score matrix in the program; the decode
+    chunk at 8 slots carries the pool pair in place (aliased to its
+    outputs), reads the latent pool through ``latent_flash_decode`` once a
+    layer, and holds no copy of the pool and no gather of the table's
+    extent. The index-key pool of a model that owns no indexer is empty."""
+    from triton_dist_tpu.models.engine import Engine
+    from triton_dist_tpu.runtime.platform import force_mosaic
+
+    model, params, cfg = _abstract_latent_sparse(topo_2x2.devices[:1], "a.x-k1-ep16-d5")
+    c, sv = model.config, cfg["serving"]
+    slots, rows, bs = int(sv["slots"]), int(sv["prefill_chunk"]), int(sv["block_size"])
+    max_blocks = -(-int(sv["max_len"]) // bs)
+    p_len = 32768
+    dt = jnp.dtype(c.dtype)
+    with force_mosaic():
+        eng = Engine(model, backend=sv["backend"], max_len=int(sv["max_len"]))
+        rep = model.ctx.replicated()
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+        buf = lambda layers, width: jax.ShapeDtypeStruct(
+            (layers, 1, 1, p_len, width), dt, sharding=eng._kv_sharding)
+        pool = lambda layers, width: jax.ShapeDtypeStruct(
+            (layers, slots * max_blocks + 1, 1, bs, width), dt, sharding=eng._pool_sharding)
+        pk, pv = pool(c.num_layers, c.cache_row), pool(0, c.index_head_dim)
+        assert _nbytes(pv) == 0 and abs(_nbytes(pk) - cfg["bytes"]["pool_bytes"]) < 2 * bs * 6400
+        weights = sum(_nbytes(x) for x in jax.tree.leaves(params))
+        assert abs(weights - cfg["bytes"]["weight_bytes"]) < 1e6, weights
+        chunk, held = _compile(eng._prefill_chunk_prog.lower(
+            params, i32(1, rows), buf(c.num_layers, c.cache_row), buf(0, c.index_head_dim),
+            i32(), i32()), kernels=("latent_flash_prefill",))
+        assert held + _nbytes(pk) < HBM_BYTES, held
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+        step, held = _compile(eng._decode_chunk_paged.lower(
+            params, (), i32(slots), pk, pv, i32(slots, max_blocks), i32(slots), i32(slots),
+            int(sv["chunk"]), key), kernels=("latent_flash_decode",))
+    assert held < HBM_BYTES, held
+    assert step.memory_analysis().alias_size_in_bytes >= _nbytes(pk)
+    for compiled, name in ((chunk, "latent_flash_prefill"), (step, "latent_flash_decode")):
+        calls = [l.split("=")[0] for l in compiled.as_text().splitlines()
+                 if "tpu_custom_call" in l]
+        assert len(calls) == c.num_layers and all(f"%{name}" in l for l in calls), calls
+    hlo = chunk.as_text()
+    assert "f32[16,2048,2048]" not in hlo and f"pred[{rows},{p_len}]" not in hlo
+    assert f"s8[{rows},{p_len}]" not in hlo
+    hlo = step.as_text()
+    assert _pool_sized_copies(hlo, pk) == []
+    for gone in (f"bf16[{slots},{max_blocks * bs},", f"f32[{slots},{c.num_heads},{max_blocks * bs}]"):
+        assert gone not in hlo, gone
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,14 @@ which part a later change should replace next.
   attends in the *expanded* form (per-head K and V made from the rows, a
   block of keys at a time, online softmax: at serving shapes one flash
   kernel, ``kernels/latent_flash.py``), decode in the *absorbed* form
-  (scores and the weighted sum taken in the latent space). The two give the
-  same numbers (``tests/test_latent_sparse.py``).
+  (scores and the weighted sum taken in the latent space: over gathered rows
+  where a selection names them, :func:`attend_absorbed`, and over the whole
+  visible extent where the layer has no indexer, read in the pool where it
+  lies, :func:`attend_absorbed_paged`). The two give the same numbers
+  (``tests/test_latent_sparse.py``, ``tests/test_latent_dense.py``). The
+  softmax scale and the rotary's frequency table are the configuration's
+  (``softmax_scale``, :func:`rope_freqs`): a plain table, or YaRN's blended
+  one with its ``mscale ** 2`` on the scale (:class:`Yarn`).
 * **Sparse selection.** Index scores ``I[t, s] = sum_h w[t, h] relu(q[t, h]
   . k[s])`` over the visible cache, and exactly the ``k`` largest a query,
   ties to the lower position: ``lax.top_k``'s set. Prefill finds a row's
@@ -28,8 +34,12 @@ which part a later change should replace next.
 
 from __future__ import annotations
 
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from triton_dist_tpu.kernels import latent_flash
 from triton_dist_tpu.kernels.kth_value import kth_value
@@ -58,15 +68,72 @@ def layer_norm(x, weight, bias, eps):
     return (xc * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight + bias
 
 
-def rope_interleaved(x, pos, theta: float):
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's scaling of a rotary table, as the DeepSeek-V3 family's
+    ``rope_scaling`` states it: positions stretched ``factor`` times past
+    ``original_max`` on the slow pairs, left alone on the fast ones, a ramp
+    between the pairs that turn ``beta_fast`` and ``beta_slow`` times over
+    the original context."""
+
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def _m(self, t: float) -> float:
+        return 0.1 * t * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
+
+    def ramp_ends(self, dim: int, theta: float) -> tuple[int, int]:
+        """(low, high): the pairs between which the table blends."""
+        corr = lambda n: dim * math.log(self.original_max / (n * 2 * math.pi)) / (
+            2 * math.log(theta))
+        return (max(math.floor(corr(self.beta_fast)), 0),
+                min(math.ceil(corr(self.beta_slow)), dim - 1))
+
+    def inv_freq(self, dim: int, theta: float):
+        """(dim // 2,) float64: ``f / factor`` where the ramp is 1, ``f``
+        where it is 0."""
+        extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        low, high = self.ramp_ends(dim, theta)
+        ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return extra / self.factor * ramp + extra * (1.0 - ramp)
+
+    @property
+    def rope_mscale(self) -> float:
+        """What cos and sin are multiplied by."""
+        return self._m(self.mscale) / self._m(self.mscale_all_dim)
+
+    @property
+    def softmax_mscale(self) -> float:
+        """What the softmax scale is multiplied by."""
+        return self._m(self.mscale_all_dim) ** 2 if self.mscale_all_dim else 1.0
+
+
+def rope_freqs(dim: int, c):
+    """(dim // 2,) float32 frequencies of a rotary over ``dim`` values under
+    configuration ``c`` (``rope_theta``, ``rope_scaling``), and what cos and
+    sin are multiplied by: the one place the table is made."""
+    half = dim // 2
+    if c.rope_scaling is None:
+        return c.rope_theta ** (-jnp.arange(half, dtype=F32) / half), 1.0
+    y = c.rope_scaling
+    return jnp.asarray(y.inv_freq(dim, c.rope_theta), F32), y.rope_mscale
+
+
+def rope_interleaved(x, pos, table):
     """Rotary embedding over interleaved pairs ``(2i, 2i+1)`` of the last
     axis, in place (no de-interleave: q and k turn alike, so their dot
     products are the published ones). ``pos`` broadcasts against
-    ``x.shape[:-1]``."""
+    ``x.shape[:-1]``; ``table`` is :func:`rope_freqs`' of the axis."""
+    freqs, mscale = table
     half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
     ang = jnp.asarray(pos, F32)[..., None] * freqs
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     xf = x.astype(F32).reshape(x.shape[:-1] + (half, 2))
     x1, x2 = xf[..., 0], xf[..., 1]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
@@ -84,10 +151,11 @@ def latent_project(lp, h, pos, c):
     c_q = rms_norm(mm(h, lp["w_dq"]), lp["q_norm"], c.rms_eps)
     q = mm(c_q, lp["w_uq"]).reshape(t, c.num_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
     q_nope, q_rope = q[..., : c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
-    q_rope = rope_interleaved(q_rope, pos[:, None], c.rope_theta)
+    table = rope_freqs(c.qk_rope_head_dim, c)
+    q_rope = rope_interleaved(q_rope, pos[:, None], table)
     ckv = mm(h, lp["w_dkv"])
     c_kv = rms_norm(ckv[:, : c.kv_lora_rank], lp["kv_norm"], c.rms_eps)
-    k_r = rope_interleaved(ckv[:, c.kv_lora_rank:], pos, c.rope_theta)
+    k_r = rope_interleaved(ckv[:, c.kv_lora_rank:], pos, table)
     return c_q, q_nope, q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
 
 
@@ -97,12 +165,13 @@ def index_project(lp, h, c_q, pos, c):
     in. RoPE turns the first ``index_rope_dim`` values of q and k."""
     t = h.shape[0]
     r = c.index_rope_dim
+    table = rope_freqs(r, c)
     q = mm(c_q, lp["w_iq"]).reshape(t, c.index_n_heads, c.index_head_dim)
     q = jnp.concatenate(
-        [rope_interleaved(q[..., :r], pos[:, None], c.rope_theta), q[..., r:]], axis=-1)
+        [rope_interleaved(q[..., :r], pos[:, None], table), q[..., r:]], axis=-1)
     k = layer_norm(mm(h, lp["w_ik"]), lp["ik_norm_w"], lp["ik_norm_b"], c.index_norm_eps)
     k = jnp.concatenate(
-        [rope_interleaved(k[..., :r], pos, c.rope_theta), k[..., r:]], axis=-1)
+        [rope_interleaved(k[..., :r], pos, table), k[..., r:]], axis=-1)
     w = mm(h, lp["w_iw"], F32) * (c.index_n_heads ** -0.5 * c.index_head_dim ** -0.5)
     return q, k, w
 
@@ -192,22 +261,49 @@ def attend_tiles(allowed, off):
     return table, jnp.stack([table.sum(dtype=jnp.int32), under.astype(jnp.int32)])
 
 
+def causal_tiles(C: int, P: int, off):
+    """:func:`attend_tiles` of a chunk whose mask is causality alone, from
+    its first position: no (C, P) array is made."""
+    tq, tk = latent_flash.tile_sizes(C, P)
+    table = latent_flash.causal_table(C, P, off, tq, tk)
+    nq, nk = table.shape
+    under = nq * jnp.clip((off + C + tk - 1) // tk, 1, nk)
+    return table, jnp.stack([table.sum(dtype=jnp.int32), under.astype(jnp.int32)])
+
+
+def tile_rows_read(table, sent, P: int):
+    """Keys the attention fetches for the rows somebody sent, in whole key
+    tiles: a query row reads every key tile its query tile visits. ``table``
+    (query tiles, key tiles) bool, ``sent`` (C,) bool. -> () int32."""
+    nq, _ = table.shape
+    _, tk = latent_flash.tile_sizes(sent.shape[0], P)
+    per_tile = sent.reshape(nq, -1).sum(axis=1, dtype=jnp.int32)
+    return (per_tile * table.sum(axis=1, dtype=jnp.int32)).sum() * tk
+
+
 def attend_expanded(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c, *, table=None):
     """Expanded-form attention of a prefill chunk. q_nope (C, H, N), q_rope
-    (C, H, R); ``rows`` (P, kv_rank + R) the prompt's latent buffer;
-    ``allowed`` (C, P) bool, selection and causality together; ``off`` the
+    (C, H, R); ``rows`` (P, kv_rank + R or wider) the prompt's latent buffer;
+    ``allowed`` (C, P) bool, selection and causality together, or None where
+    every earlier position is allowed (a layer with no indexer); ``off`` the
     chunk's first position; ``table`` :func:`attend_tiles`' of ``allowed``
-    where the caller has it. Where the shapes tile, one flash kernel
-    (``kernels/latent_flash.py``: K and V made from the rows in VMEM, scores
-    and ``p`` never in HBM, tiles the table leaves empty skipped); else
-    :func:`attend_expanded_xla`, the same mathematics in plain XLA.
-    -> (C, H * V) in q's type."""
+    (:func:`causal_tiles`' where it is None) where the caller has it. Where
+    the shapes tile, one flash kernel (``kernels/latent_flash.py``: K and V
+    made from the rows in VMEM, scores and ``p`` never in HBM, tiles the
+    table leaves empty skipped; without ``allowed`` the one that makes its
+    mask from the positions); else :func:`attend_expanded_xla`, the same
+    mathematics in plain XLA. -> (C, H * V) in q's type."""
     C, H, N = q_nope.shape
     if latent_flash.takes(C, H, c.kv_lora_rank, N + q_rope.shape[-1], w_uv.shape[-1],
                           q_nope.dtype.itemsize):
-        scale = float(c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+        if allowed is None:
+            return latent_flash.latent_flash_prefill(
+                q_nope, q_rope, rows, off, w_uk, w_uv, c.softmax_scale, table=table)
         return latent_flash.dsa_flash_prefill(
-            q_nope, q_rope, rows, allowed, w_uk, w_uv, scale, table=table)
+            q_nope, q_rope, rows, allowed, w_uk, w_uv, c.softmax_scale, table=table)
+    if allowed is None:
+        pos = off + jnp.arange(C, dtype=jnp.int32)
+        allowed = jnp.arange(rows.shape[0], dtype=jnp.int32)[None, :] <= pos[:, None]
     return attend_expanded_xla(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c)
 
 
@@ -226,8 +322,8 @@ def attend_expanded_xla(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c, *,
     n_blocks = jnp.clip((off + C + kb - 1) // kb, 1, -(-P // kb))
     g = min(head_group, H)
     assert H % g == 0, (H, g)
-    scale = float(c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
-    rank = c.kv_lora_rank
+    scale = c.softmax_scale
+    rank, R = c.kv_lora_rank, q_rope.shape[-1]
 
     def group(args):
         qn, qr, wuk, wuv = args  # (C, g, N), (C, g, R), (rank, g, N), (rank, g, V)
@@ -238,7 +334,7 @@ def attend_expanded_xla(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c, *,
             # dynamic_slice does anyway); its overlap is masked out below.
             start = jnp.minimum(j * kb, P - kb)
             blk = jax.lax.dynamic_slice(rows, (start, 0), (kb, rows.shape[1]))
-            ckv, kr = blk[:, :rank], blk[:, rank:]
+            ckv, kr = blk[:, :rank], blk[:, rank:rank + R]
             kn = jnp.einsum("sc,chn->shn", ckv, wuk, preferred_element_type=F32).astype(dt)
             v = jnp.einsum("sc,chv->shv", ckv, wuv, preferred_element_type=F32).astype(dt)
             s = jnp.einsum("thn,shn->hts", qn, kn, preferred_element_type=F32)
@@ -267,15 +363,17 @@ def attend_expanded_xla(q_nope, q_rope, rows, allowed, off, w_uk, w_uv, c, *,
 
 
 def attend_absorbed(q_nope, q_rope, rows, real, w_uk, w_uv, c):
-    """Absorbed-form attention of one decode step over the selected rows.
-    q_nope (B, H, N), q_rope (B, H, R); ``rows`` (B, K, kv_rank + R) the
-    gathered latent rows and ``real`` (B, K) which of them count. The query
-    is taken into the latent space, scores and the weighted sum stay there,
-    and the result comes back through W_uv. -> (B, H * V)."""
+    """Absorbed-form attention of one decode step over gathered rows (the
+    selected ones, or a whole extent: the form of shapes
+    :func:`attend_absorbed_paged`'s kernel does not take, and its oracle).
+    q_nope (B, H, N), q_rope (B, H, R); ``rows`` (B, K, kv_rank + R or wider)
+    the gathered latent rows and ``real`` (B, K) which of them count. The
+    query is taken into the latent space, scores and the weighted sum stay
+    there, and the result comes back through W_uv. -> (B, H * V)."""
     dt = q_nope.dtype
     rank = c.kv_lora_rank
-    scale = float(c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
-    ckv, kr = rows[..., :rank], rows[..., rank:]
+    scale = c.softmax_scale
+    ckv, kr = rows[..., :rank], rows[..., rank:rank + q_rope.shape[-1]]
     q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w_uk, preferred_element_type=F32).astype(dt)
     s = jnp.einsum("bhc,bkc->bhk", q_lat, ckv, preferred_element_type=F32)
     s = (s + jnp.einsum("bhr,bkr->bhk", q_rope, kr, preferred_element_type=F32)) * scale
@@ -284,6 +382,25 @@ def attend_absorbed(q_nope, q_rope, rows, real, w_uk, w_uv, c):
     ctx = jnp.einsum("bhk,bkc->bhc", p.astype(dt), ckv, preferred_element_type=F32).astype(dt)
     o = jnp.einsum("bhc,chv->bhv", ctx, w_uv, preferred_element_type=F32).astype(dt)
     return o.reshape(o.shape[0], -1)
+
+
+def attend_absorbed_paged(q_nope, q_rope, pool, layer: int, tables, lengths, w_uk, w_uv, c):
+    """:func:`attend_absorbed` over everything a slot's query may see, read
+    in the pool where it lies (``kernels/latent_flash.py:latent_flash_decode``:
+    the block table walked, a slot's live tiles alone fetched, once for all
+    heads). ``pool`` (L, blocks, 1, bs, W) whose rows are ``[c_kv | k_r | 0]``
+    in whole lanes; ``lengths`` (B,) the positions visible (0: none, zeros).
+    -> (B, H * V)."""
+    dt = q_nope.dtype
+    B, H, _ = q_nope.shape
+    rank, width = c.kv_lora_rank, pool.shape[-1]
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w_uk, preferred_element_type=F32).astype(dt)
+    pad = jnp.zeros((B, H, width - rank - q_rope.shape[-1]), dt)
+    ctx = latent_flash.latent_flash_decode(
+        jnp.concatenate([q_lat, q_rope, pad], axis=-1), pool, layer, tables, lengths,
+        rank=rank, scale=c.softmax_scale)
+    o = jnp.einsum("bhc,chv->bhv", ctx.astype(dt), w_uv, preferred_element_type=F32).astype(dt)
+    return o.reshape(B, -1)
 
 
 # ---------------------------------------------------------------- experts
@@ -295,12 +412,13 @@ def swiglu(x, w_gate, w_up, w_down):
 
 def route_sigmoid(x, w_router, bias, k: int, scaling: float, normalise: bool = True):
     """Sigmoid scores in float32 over every expert the router knows; the
-    ``k`` with the largest score plus bias; gates from the scores alone,
-    normalised over all ``k`` chosen (held here or not) and scaled.
-    -> (idx (T, k) int32, gates (T, k) float32)."""
+    ``k`` with the largest score plus bias (the score alone where ``bias``
+    is None: a router that has none), ties to the lower index; gates from
+    the scores alone, normalised over all ``k`` chosen (held here or not)
+    and scaled. -> (idx (T, k) int32, gates (T, k) float32)."""
     s = jax.nn.sigmoid(
         jnp.dot(x.astype(F32), w_router.astype(F32), precision=HIGHEST))
-    _, idx = jax.lax.top_k(s + bias.astype(F32), k)
+    _, idx = jax.lax.top_k(s if bias is None else s + bias.astype(F32), k)
     g = jnp.take_along_axis(s, idx, axis=-1)
     if normalise:
         g = g / (g.sum(axis=-1, keepdims=True) + 1e-20)

@@ -1,6 +1,7 @@
 """A decoder whose stack is a LIST of layer kinds: latent attention in every
-layer, a learned sparse selection that some layers compute (``full``) and
-the layers above them borrow (``shared``), and a feed-forward that is a
+layer, a learned sparse selection that some layers compute (``full``),
+the layers above them borrow (``shared``) and others do without (``none``:
+every earlier position is attended to), and a feed-forward that is a
 dense SwiGLU in the leading layers and a sigmoid-routed expert layer with a
 shared expert after them (``layers/latent_sparse.py`` holds the math).
 
@@ -12,7 +13,14 @@ tables as ``DenseLLM``; what differs is declared, not forked:
   ``index_head_dim`` values on the layers that own an indexer. The engine
   sizes its prompt buffers and the paged pools from this declaration; the
   pool pair of every program is (latent pool, index-key pool) where
-  ``DenseLLM``'s is (K pool, V pool).
+  ``DenseLLM``'s is (K pool, V pool). A model none of whose layers owns an
+  indexer declares the same pair with NO layers in its second kind: the
+  index-key pool and buffers are empty arrays that cost nothing, and the
+  pool manager, the ledger and the engine's programs are as they were. A
+  model with a ``none`` layer and a latent rank in whole lanes keeps its
+  latent row in whole lanes too (``cache_row``: 576 values in 640), which
+  is what the chip's tiled memory holds anyway, so that the decode kernel
+  can copy a page where it lies.
 * ``step_stats``: the per-expert row counts, and what the selecting layers
   saw and selected, leave the device as one more small output of the
   prefill chunk and of the decode chunk (summed over the chunk's steps on
@@ -39,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from triton_dist_tpu.kernels import latent_flash
 from triton_dist_tpu.layers import latent_sparse as ls
 from triton_dist_tpu.models.kv_cache import CacheRow
 from triton_dist_tpu.runtime import telemetry
@@ -74,15 +83,22 @@ class LatentSparseConfig:
     experts_held: tuple = (0, 16)
     routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
+    #: whether the router adds a correction bias to the scores it ranks
+    router_bias: bool = True
     rope_theta: float = 8e6
+    #: ``layers/latent_sparse.py:Yarn`` or None for the plain table
+    rope_scaling: object = None
     rms_eps: float = 1e-5
     dtype: str = "float32"
 
     def __post_init__(self):
         assert len(self.mlp_kinds) == len(self.index_kinds)
-        assert self.index_kinds[0] == "full", "a shared layer borrows from a full one below it"
         assert set(self.mlp_kinds) <= {"dense", "experts"}
-        assert set(self.index_kinds) <= {"full", "shared"}
+        assert set(self.index_kinds) <= {"full", "shared", "none"}
+        lent = False
+        for kind in self.index_kinds:
+            assert kind != "shared" or lent, "a shared layer borrows from a full one below it"
+            lent = lent or kind == "full"
         first, count = self.experts_held
         assert 0 <= first and first + count <= self.num_experts
 
@@ -93,6 +109,22 @@ class LatentSparseConfig:
     @property
     def latent_row(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_row(self) -> int:
+        """The latent row's width in the pool and the prompt buffers: the
+        row itself, or the whole lanes it lies in where a layer without an
+        indexer reads the pool in place (see the module docstring)."""
+        if "none" in self.index_kinds and self.kv_lora_rank % 128 == 0:
+            return -(-self.latent_row // 128) * 128
+        return self.latent_row
+
+    @property
+    def softmax_scale(self) -> float:
+        """The attention's scale: one over the root of the query/key head,
+        times YaRN's ``mscale ** 2`` where the table is scaled."""
+        scale = float(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        return scale if self.rope_scaling is None else scale * self.rope_scaling.softmax_mscale
 
     @property
     def index_layers(self) -> tuple:
@@ -128,7 +160,7 @@ def layer_tensors(c: LatentSparseConfig, layer: int) -> list:
         f, E = c.expert_intermediate_size, c.experts_held[1]
         out += [
             ("router", (d, c.num_experts), None),
-            ("router_bias", (c.num_experts,), 0.1),
+            *([("router_bias", (c.num_experts,), 0.1)] if c.router_bias else []),
             ("e_gate", (E, d, f), 1 / math.sqrt(d)),
             ("e_up", (E, d, f), 1 / math.sqrt(d)),
             ("e_down", (E, f, d), 1 / math.sqrt(f)),
@@ -222,7 +254,7 @@ class LatentSparseLLM:
 
     def cache_rows(self):
         c = self.config
-        return (CacheRow("latent", c.num_layers, 1, c.latent_row),
+        return (CacheRow("latent", c.num_layers, 1, c.cache_row),
                 CacheRow("index_key", len(c.index_layers), 1, c.index_head_dim))
 
     def step_stats(self):
@@ -237,13 +269,18 @@ class LatentSparseLLM:
         those. ``attend_tiles``: over the layers of the prefill chunks, the
         tiles of the attention's mask that allow anything, [which the flash
         kernel visits, and those at or before the chunk's last position]
-        (``layers/latent_sparse.py:attend_tiles``)."""
+        (``layers/latent_sparse.py:attend_tiles``). On the layers with no
+        indexer, [prefill, decode]: ``rows_visible`` the latent rows the
+        queries could see and ``rows_read`` those the attention fetched for
+        them (whole key tiles in prefill, whole tiles of pages in decode)."""
         return {"expert_rows": jnp.zeros((self.config.num_experts,), jnp.int32),
                 "dispatches": jnp.zeros((), jnp.int32),
                 "visible": jnp.zeros((2,), jnp.int32),
                 "selected": jnp.zeros((2,), jnp.int32),
                 "tie_rows": jnp.zeros((), jnp.int32),
-                "attend_tiles": jnp.zeros((2,), jnp.int32)}
+                "attend_tiles": jnp.zeros((2,), jnp.int32),
+                "rows_visible": jnp.zeros((2,), jnp.int32),
+                "rows_read": jnp.zeros((2,), jnp.int32)}
 
     def publish_step_stats(self, stats) -> None:
         """Host side: feed the counters from a finished program's stats."""
@@ -257,6 +294,10 @@ class LatentSparseLLM:
                           float(stats["visible"][i]), phase=phase)
             telemetry.inc("tdt_dsa_positions_selected_total",
                           float(stats["selected"][i]), phase=phase)
+            telemetry.inc("tdt_latent_rows_visible_total",
+                          float(stats["rows_visible"][i]), phase=phase)
+            telemetry.inc("tdt_latent_rows_read_total",
+                          float(stats["rows_read"][i]), phase=phase)
         telemetry.inc("tdt_dsa_select_tie_rows_total", float(stats["tie_rows"]),
                       phase="prefill")
         for i, kind in enumerate(("visited", "under_diagonal")):
@@ -273,7 +314,20 @@ class LatentSparseLLM:
         return {**stats, "visible": stats["visible"].at[phase].add(seen),
                 "selected": stats["selected"].at[phase].add(took)}
 
+    @staticmethod
+    def _read(stats, phase: int, rows):
+        """``stats`` with a layer without an indexer's counts added: ``rows``
+        (2,) the latent rows its real queries could see, and those fetched."""
+        return {**stats, "rows_visible": stats["rows_visible"].at[phase].add(rows[0]),
+                "rows_read": stats["rows_read"].at[phase].add(rows[1])}
+
     # -- layers ------------------------------------------------------------
+    def _cache_row(self, row):
+        """A token's latent row (T, latent_row) as the pool keeps it."""
+        c = self.config
+        return row if c.cache_row == c.latent_row else jnp.pad(
+            row, ((0, 0), (0, c.cache_row - c.latent_row)))
+
     def _mlp(self, lp, layer: int, h, stats, rows):
         """The layer's feed-forward over ``h`` (T, d); ``rows`` (T,) bool
         marks the rows somebody sent: the others reach no routed expert and
@@ -282,10 +336,11 @@ class LatentSparseLLM:
         if c.mlp_kinds[layer] == "dense":
             return ls.swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), stats
         idx, gates = ls.route_sigmoid(
-            h, lp["router"], lp["router_bias"], c.experts_per_token,
+            h, lp["router"], lp.get("router_bias"), c.experts_per_token,
             c.routed_scaling_factor, c.norm_topk_prob)
-        y = ls.held_experts(h, idx, gates, lp["e_gate"], lp["e_up"], lp["e_down"],
-                            c.experts_held[0], rows=rows)
+        with jax.named_scope("held_experts"):
+            y = ls.held_experts(h, idx, gates, lp["e_gate"], lp["e_up"], lp["e_down"],
+                                c.experts_held[0], rows=rows)
         y = y + ls.swiglu(h, lp["s_gate"], lp["s_up"], lp["s_down"]).astype(jnp.float32)
         stats = {**stats, "dispatches": stats["dispatches"] + 1,
                  "expert_rows": stats["expert_rows"]
@@ -294,7 +349,7 @@ class LatentSparseLLM:
 
     def prefill_chunk_shard(self, p, tokens, kbufs, vbufs, off, last_idx, mode: str):
         """One chunk of an incremental prefill. tokens (1, C); ``kbufs`` (L,
-        1, 1, P, latent_row) and ``vbufs`` (F, 1, 1, P, index_head_dim) the
+        1, 1, P, cache_row) and ``vbufs`` (F, 1, 1, P, index_head_dim) the
         prompt's running buffers of the two kinds of cache row; ``off`` the
         chunk's first position, ``last_idx`` the row whose logits matter.
         Rows past P (a padded final chunk) are dropped on insertion. Returns
@@ -308,14 +363,18 @@ class LatentSparseLLM:
         pos = off + jnp.arange(C, dtype=jnp.int32)
         x = p["embed"][tokens[0]]
         visible = jnp.arange(P_len, dtype=jnp.int32)[None, :] <= pos[:, None]
-        allowed = visible
         sent = pos < P_len  # a padded final chunk's rows past the prompt are nobody's
         stats = self.step_stats()
+        if "none" in c.index_kinds:  # causality alone: one table for all of them
+            causal_table, causal_tiles = ls.causal_tiles(C, P_len, off)
+            causal_rows = jnp.stack([jnp.where(sent, pos + 1, 0).sum(),
+                                     ls.tile_rows_read(causal_table, sent, P_len)])
         for layer, lp in enumerate(p["layers"]):
+            kind = c.index_kinds[layer]
             h = ls.rms_norm(x, lp["ln1"], c.rms_eps)
             c_q, q_nope, q_rope, row = ls.latent_project(lp, h, pos, c)
-            kbufs = kbufs.at[layer, 0, 0, pos].set(row, mode="drop")
-            if c.index_kinds[layer] == "full":
+            kbufs = kbufs.at[layer, 0, 0, pos].set(self._cache_row(row), mode="drop")
+            if kind == "full":
                 fi = c.index_layers.index(layer)
                 q_i, k_i, w_i = ls.index_project(lp, h, c_q, pos, c)
                 vbufs = vbufs.at[fi, 0, 0, pos].set(k_i, mode="drop")
@@ -325,9 +384,16 @@ class LatentSparseLLM:
                 stats = {**stats, "tie_rows": stats["tie_rows"]
                          + (walked & sent).sum(dtype=jnp.int32)}
                 table, tiles = ls.attend_tiles(allowed, off)  # a shared layer's too
-            a = ls.attend_expanded(q_nope, q_rope, kbufs[layer, 0, 0], allowed, off,
-                                   lp["w_uk"], lp["w_uv"], c, table=table)
-            stats = {**stats, "attend_tiles": stats["attend_tiles"] + tiles}
+            if kind == "none":
+                with jax.named_scope("latent_attend"):
+                    a = ls.attend_expanded(q_nope, q_rope, kbufs[layer, 0, 0], None, off,
+                                           lp["w_uk"], lp["w_uv"], c, table=causal_table)
+                stats = self._read(stats, 0, causal_rows)
+                stats = {**stats, "attend_tiles": stats["attend_tiles"] + causal_tiles}
+            else:
+                a = ls.attend_expanded(q_nope, q_rope, kbufs[layer, 0, 0], allowed, off,
+                                       lp["w_uk"], lp["w_uv"], c, table=table)
+                stats = {**stats, "attend_tiles": stats["attend_tiles"] + tiles}
             x = x + ls.mm(a, lp["w_o"])
             h = ls.rms_norm(x, lp["ln2"], c.rms_eps)
             m, stats = self._mlp(lp, layer, h, stats, sent)
@@ -340,13 +406,16 @@ class LatentSparseLLM:
 
     def decode_shard_paged(self, p, token, pk, pv, tables, lengths, active, mode: str):
         """One decode step against the pools where they lie. ``pk`` (L,
-        blocks, 1, bs, latent_row) the latent pool, ``pv`` (F, blocks, 1, bs,
+        blocks, 1, bs, cache_row) the latent pool, ``pv`` (F, blocks, 1, bs,
         index_head_dim) the index-key pool, both under the one block table.
         Each layer writes its one latent row a slot through the table (an
         inactive slot's to the NULL block), a ``full`` layer its index key
         too; a ``full`` layer reads the index keys of the table's whole
-        extent and selects, and every layer gathers the selected latent
-        rows alone. Returns (logits (B, V), pk, pv, stats)."""
+        extent and selects, and it and the layers that borrow from it gather
+        the selected latent rows alone; a layer with no indexer attends over
+        everything the slot has, read in the pool where it lies (one kernel,
+        a slot's live tiles alone; over the gathered extent where the shapes
+        do not tile). Returns (logits (B, V), pk, pv, stats)."""
         del mode
         c = self.config
         bs = pk.shape[3]
@@ -360,11 +429,19 @@ class LatentSparseLLM:
         visible = span[None, :] <= pos[:, None]
         sel = real = None
         stats = self.step_stats()
+        if "none" in c.index_kinds:
+            in_place = latent_flash.decode_takes(
+                c.num_heads, c.kv_lora_rank, pk.shape, max_blocks, pk.dtype.itemsize)
+            seen = jnp.where(active, pos + 1, 0)  # an inactive slot reads nothing
+            tile = (latent_flash.decode_tile_pages(pk.shape, max_blocks, pk.dtype.itemsize) * bs
+                    if in_place else max_blocks * bs)
+            dense_rows = jnp.stack([seen.sum(), (-(-seen // tile) * tile).sum()])
         for layer, lp in enumerate(p["layers"]):
+            kind = c.index_kinds[layer]
             h = ls.rms_norm(x, lp["ln1"], c.rms_eps)
             c_q, q_nope, q_rope, row = ls.latent_project(lp, h, pos, c)
-            pk = pk.at[layer, phys, 0, sub].set(row)
-            if c.index_kinds[layer] == "full":
+            pk = pk.at[layer, phys, 0, sub].set(self._cache_row(row))
+            if kind == "full":
                 fi = c.index_layers.index(layer)
                 q_i, k_i, w_i = ls.index_project(lp, h, c_q, pos, c)
                 pv = pv.at[fi, phys, 0, sub].set(k_i)
@@ -373,9 +450,21 @@ class LatentSparseLLM:
                 scores = ls.index_scores_batched(q_i, w_i, keys)
                 sel, real = ls.select_positions(scores, visible, c.index_topk)
                 stats = self._selected(stats, 1, active, pos + 1, real)
-            rows_blk = jnp.take_along_axis(tables, sel // bs, axis=1)
-            rows = pk[layer, rows_blk, 0, sel % bs]  # (B, K, latent_row)
-            a = ls.attend_absorbed(q_nope, q_rope, rows, real, lp["w_uk"], lp["w_uv"], c)
+            if kind == "none":
+                with jax.named_scope("latent_attend"):
+                    if in_place:
+                        a = ls.attend_absorbed_paged(q_nope, q_rope, pk, layer, tables, seen,
+                                                     lp["w_uk"], lp["w_uv"], c)
+                    else:
+                        rows = jnp.take(pk[layer, :, 0], tables, axis=0)  # (B, MB, bs, W)
+                        rows = rows.reshape(B, max_blocks * bs, -1)
+                        a = ls.attend_absorbed(q_nope, q_rope, rows, visible,
+                                               lp["w_uk"], lp["w_uv"], c)
+                stats = self._read(stats, 1, dense_rows)
+            else:
+                rows_blk = jnp.take_along_axis(tables, sel // bs, axis=1)
+                rows = pk[layer, rows_blk, 0, sel % bs]  # (B, K, latent_row)
+                a = ls.attend_absorbed(q_nope, q_rope, rows, real, lp["w_uk"], lp["w_uv"], c)
             x = x + ls.mm(a, lp["w_o"])
             h = ls.rms_norm(x, lp["ln2"], c.rms_eps)
             m, stats = self._mlp(lp, layer, h, stats, active)

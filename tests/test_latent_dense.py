@@ -42,6 +42,7 @@ TOY = json.loads((REPO / "tests/benchmark/toy/configs/toy-axk1.json").read_text(
 REAL = json.loads((REPO / "benchmark/configs/a.x-k1-ep16-d5.json").read_text())
 SEED = 2**31 + 4040
 T_REF = 128
+TILES = (8, 16)  # query rows, keys: a prefill chunk of 32 holds unmasked tiles
 
 
 @pytest.fixture(scope="module")
@@ -50,7 +51,8 @@ def served():
     ``LatentSparseLLM``: prompts of 70 and 96 in chunks of 32 (a padded last
     chunk, and none), a third request that joins when the first leaves; after
     every loop iteration the next-token logits of each decoding slot from the
-    engine's own paged step over the server's pool."""
+    engine's own paged step over the server's pool. The prefill's tiles are
+    ``TILES`` (the toy's heads do not tile, so they size the counters alone)."""
     telemetry.reset()
     key = harness.seed_key(SEED)
     model, eng, srv = build.build(TOY, key, jax.devices()[:1])
@@ -58,17 +60,20 @@ def served():
     sizes = [(70, 6), (96, 11), (70, 9)]
     reqs = [srv.submit(rng.integers(0, 256, size=n).tolist(), new) for n, new in sizes]
     seen = {id(r): {} for r in reqs}
-    for _ in range(40):
-        srv.step()
-        srv._land_in_flight()  # the cache and the last tokens of the same chunk
-        decoding = srv.scheduler.decoding_slots()
-        if decoding:
-            logits = np.asarray(eng.decode_logits_paged(srv.cache, jnp.asarray(srv._last)))
-            for slot in decoding:
-                r = slot.request
-                seen[id(r)][len(r.prompt) + len(r.tokens) - 1] = logits[slot.idx]
-        if all(r.finish_reason is not None for r in reqs):
-            break
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(latent_flash, "QUERY_TILE", TILES[0])
+        mp.setattr(latent_flash, "KEY_TILE", TILES[1])
+        for _ in range(40):
+            srv.step()
+            srv._land_in_flight()  # the cache and the last tokens of the same chunk
+            decoding = srv.scheduler.decoding_slots()
+            if decoding:
+                logits = np.asarray(eng.decode_logits_paged(srv.cache, jnp.asarray(srv._last)))
+                for slot in decoding:
+                    r = slot.request
+                    seen[id(r)][len(r.prompt) + len(r.tokens) - 1] = logits[slot.idx]
+            if all(r.finish_reason is not None for r in reqs):
+                break
     srv.shutdown(drain=False)
     assert all(len(r.tokens) == new for r, (_, new) in zip(reqs, sizes))
     weights = ref.make_weights(TOY, key, jax.devices()[:1])
@@ -124,6 +129,26 @@ def test_served_matches_reference(served):
     assert srv.cache.k.shape[-1] == 24
 
 
+def test_unmasked_tiles_counter_is_the_arithmetic(served):
+    """``tdt_dsa_attend_tiles_total{kind="unmasked"}``: every chunk of 32 (a
+    prompt's buffer is its length, the last chunk padded) on each of the 5
+    layers counts the (query tile, key tile) pairs whose last key is at or
+    before the query tile's first position; never more than ``visited``."""
+    _, _, _, reqs = served
+    tq, tk = TILES
+    want = 0
+    for r, _ in reqs:
+        P = len(r.prompt)
+        last_key = (np.arange(-(-P // tk)) + 1) * tk - 1
+        for off in range(0, P, 32):
+            first = off + np.arange(32 // tq) * tq
+            want += 5 * int((last_key[None, :] <= first[:, None]).sum())
+    got = {e["labels"]["kind"]: e["value"]
+           for e in telemetry.snapshot()["counters"]["tdt_dsa_attend_tiles_total"]}
+    assert got["unmasked"] == want > 0
+    assert got["unmasked"] <= got["visited"] <= got["under_diagonal"]
+
+
 def test_a_lower_precision_fails_the_tolerance(served):
     """The reference's own bfloat16 rounding of every linear layer moves the
     logits far outside ``TOL``: the comparison would catch a lower precision."""
@@ -158,15 +183,24 @@ def _qkv(key, C, P, c):
             bf(k[4], (rank, c.num_heads, c.v_head_dim), rank ** -0.5))
 
 
-@pytest.mark.parametrize("off", [0, 96], ids=["first_chunk", "deep_chunk"])
-def test_causal_prefill_kernel_matches_the_xla_body(monkeypatch, off):
+@pytest.mark.parametrize("C, P, off", [
+    (64, 200, 0),     # the diagonal tiles alone
+    (64, 200, 96),    # one unmasked tile, then the diagonal
+    (64, 600, 448),   # three unmasked tiles a query tile
+    (64, 330, 256),   # two unmasked, then a diagonal tile that straddles P's end
+    (128, 200, 160),  # a padded final chunk: its last query tile, all rows past P,
+                      # takes the tile that straddles P's end unmasked
+], ids=["first_chunk", "deep_chunk", "several_unmasked", "straddles_p", "padded_final"])
+def test_causal_prefill_kernel_matches_the_xla_body(monkeypatch, C, P, off):
     """``latent_flash_prefill`` (no mask handed over: the causal table and
     the positions) against ``attend_expanded_xla`` under the causal mask, at
-    a head of 192 padded to 256 inside, key tiles that straddle the diagonal
-    and a prompt that is not whole key tiles."""
+    a head of 192 padded to 256 inside, key tiles wholly under a query tile
+    (no mask) and across its diagonal (masked), and a prompt that is not
+    whole key tiles. Rows past P are nobody's (a chunk drops them), and are
+    not compared."""
     monkeypatch.setattr(latent_flash, "QUERY_TILE", 32)
     monkeypatch.setattr(latent_flash, "KEY_TILE", 128)
-    c, C, P = KCFG, 64, 200
+    c = KCFG
     assert c.cache_row == 256 and c.latent_row == 192
     q_nope, q_rope, rows, w_uk, w_uv = _qkv(jax.random.PRNGKey(off + 1), C, P, c)
     assert latent_flash.takes(C, c.num_heads, 128, 192, 128, 2)
@@ -178,14 +212,20 @@ def test_causal_prefill_kernel_matches_the_xla_body(monkeypatch, off):
     want = ls.attend_expanded_xla(q_nope, q_rope, rows, allowed, jnp.int32(off), w_uk, w_uv, c,
                                   head_group=4, key_block=64)
     assert got.shape == (C, c.num_heads * 128) and got.dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+    sent = pos < P
+    np.testing.assert_allclose(np.asarray(got, np.float32)[np.asarray(sent)],
+                               np.asarray(want, np.float32)[np.asarray(sent)],
                                atol=KTOL, rtol=KTOL)
     # the table is the mask's: the tiles that allow anything, no others
     np.testing.assert_array_equal(np.asarray(table),
                                   np.asarray(latent_flash.tile_table(allowed, 32, 128)))
-    assert int(counts[0]) == int(table.sum()) <= int(counts[1])
-    sent = pos < P
-    assert int(ls.tile_rows_read(table, sent, P)) == int(table.sum(axis=1).sum()) * 32 * 128
+    # unmasked: a key tile's last key at or before the query tile's first row
+    last_key = (np.arange(-(-P // 128)) + 1) * 128 - 1
+    unmasked = int((last_key[None, :] <= off + np.arange(C // 32)[:, None] * 32).sum())
+    assert int(counts[2]) == unmasked <= int(counts[0]) == int(table.sum()) <= int(counts[1])
+    per_tile = np.asarray(sent).reshape(C // 32, 32).sum(axis=1)
+    assert int(ls.tile_rows_read(table, sent, P)) == int(
+        (per_tile * np.asarray(table).sum(axis=1)).sum()) * 128
 
 
 def test_paged_decode_kernel_matches_the_gathered_form(monkeypatch):

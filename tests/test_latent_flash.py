@@ -181,7 +181,7 @@ def test_attend_expanded_takes_the_kernel_with_attend_tiles_table(monkeypatch):
     allowed = jnp.asarray(_selected(C, P, off, k=40))
     table, counts = ls.attend_tiles(allowed, jnp.int32(off))
     assert np.asarray(table).tolist() == [[True, True, False]] * 2
-    assert np.asarray(counts).tolist() == [4, 4]
+    assert np.asarray(counts).tolist() == [4, 4, 0]
     calls = _spy(monkeypatch)
     got = ls.attend_expanded(q_nope, q_rope, rows, allowed, jnp.int32(off), w_uk, w_uv, c,
                              table=table)
@@ -231,4 +231,4 @@ def test_chunk_program_hands_each_layer_the_table_of_its_mask(monkeypatch):
     np.testing.assert_allclose(got[0], want[0], atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(got[1], want[1], atol=2e-4, rtol=2e-4)
     # positions 64..127 lie in the first key tile of 128: 2 query tiles x 1 a layer
-    assert got[2] == want[2] == [2 * c.num_layers] * 2
+    assert got[2] == want[2] == [2 * c.num_layers] * 2 + [0]  # a selection's: none unmasked

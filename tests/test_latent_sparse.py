@@ -253,7 +253,8 @@ def test_attend_tiles_counter_counts_the_tiles_of_the_mask(model, monkeypatch):
     arithmetic: the key tiles that hold a position up to the chunk's last,
     times the query tiles, a layer. ``visited`` is what the masks hold: the
     tiles in which a row allows anything, which leaves out at least the
-    tile above the first query tile's diagonal."""
+    tile above the first query tile's diagonal. ``unmasked`` is a layer
+    with no indexer's: a selecting model reads 0."""
     from triton_dist_tpu.kernels import latent_flash
 
     monkeypatch.setattr(latent_flash, "QUERY_TILE", 8)
@@ -269,7 +270,8 @@ def test_attend_tiles_counter_counts_the_tiles_of_the_mask(model, monkeypatch):
     tokens = jnp.asarray([np.arange(C) % c.vocab_size], jnp.int32)
     _, _, stats = model.prefill_chunk_shard(  # op by op: the masks are values
         model.params, tokens, bufs[0], bufs[1], jnp.int32(off), jnp.int32(C - 1), "dist_ar")
-    visited, under = (int(x) for x in stats["attend_tiles"])
+    visited, under, unmasked = (int(x) for x in stats["attend_tiles"])
+    assert unmasked == 0
     assert under == c.num_layers * (C // t) * ((off + C) // t) == 5 * 2 * 6
     assert len(masks) == c.num_layers
     nonempty = [m.reshape(C // t, t, P // t, t).any(axis=(1, 3)) for m, _ in masks]
@@ -282,7 +284,7 @@ def test_attend_tiles_counter_counts_the_tiles_of_the_mask(model, monkeypatch):
     model.publish_step_stats(stats)
     got = {e["labels"]["kind"]: e["value"]
            for e in telemetry.snapshot()["counters"]["tdt_dsa_attend_tiles_total"]}
-    assert got == {"visited": visited, "under_diagonal": under}
+    assert got == {"visited": visited, "under_diagonal": under, "unmasked": 0}
 
 
 def _expert_layer(model, layer=1, rows=40, seed=0):

@@ -42,7 +42,13 @@ Where the layer has no indexer the mask says nothing the chunk's offset does
 not: :func:`latent_flash_prefill` is the same body handed ``off`` instead of
 ``allowed``. Its table is the causal one (:func:`causal_table`) and a tile's
 mask comes from the positions, so no ``(C, P)`` array is made, padded or
-fetched.
+fetched. A key tile wholly at or before a query tile's first position
+(:func:`interior_table`, a scalar test in the kernel) hides nothing from the
+tile's rows and is attended with no mask at all; only the tile across a
+query tile's diagonal makes one. The scale rides in K (in float32, before
+K's cast: once a key and head), so no score is scaled either. Under a
+selection's mask every visited tile is masked and the scores are scaled, as
+they always were.
 
 **Decode.** One query a slot, every earlier position visible. The query is
 taken into the latent space outside (``[q_nope W_uk | q_rope]``, 576 wide at
@@ -141,6 +147,14 @@ def causal_table(C: int, P: int, off, tq: int, tk: int):
     return jnp.arange(-(-P // tk), dtype=jnp.int32)[None, :] * tk <= last[:, None]
 
 
+def interior_table(C: int, P: int, off, tq: int, tk: int):
+    """The tiles of :func:`causal_table` that the kernel attends with no
+    mask: key tile ``j``'s last key is at or before query tile ``i``'s first
+    position, ``(j + 1) tk - 1 <= off + i tq``. (C / tq, ceil(P / tk)) bool."""
+    first = off + jnp.arange(C // tq, dtype=jnp.int32) * tq
+    return (jnp.arange(-(-P // tk), dtype=jnp.int32)[None, :] + 1) * tk - 1 <= first[:, None]
+
+
 def _kernel(*refs, causal: bool, scale: float, rank: int, tq: int, nq: int, nk: int,
             g: int, v_dim: int):
     if causal:  # the chunk's first position in the mask's place
@@ -167,15 +181,47 @@ def _kernel(*refs, causal: bool, scale: float, rank: int, tq: int, nq: int, nk: 
     @pl.when(seen != 0)
     def _():
         def expand(h, carry):
-            k_scr[h] = jnp.dot(rows_ref[...], wk_ref[h], preferred_element_type=F32).astype(dt)
+            k = jnp.dot(rows_ref[...], wk_ref[h], preferred_element_type=F32)
+            k_scr[h] = (k * scale if causal else k).astype(dt)  # causal: K carries the scale
             v_scr[h] = jnp.dot(rows_ref[:, :rank], wv_ref[h],
                                preferred_element_type=F32).astype(dt)
             return carry
 
         jax.lax.fori_loop(0, g, expand, 0)
 
+        def tile(r, masked: bool):
+            """The online softmax of query rows ``r`` over the key tile, a
+            head at a time; ``masked``: under ``bias_scr``, a row that sees
+            nothing yet kept finite."""
+            def head(h, carry):
+                s = jax.lax.dot_general(
+                    q_ref[h, r, :], k_scr[h], (((1,), (1,)), ((), ())),
+                    preferred_element_type=F32)  # (tq, tk)
+                if not causal:
+                    s = s * scale
+                if masked:
+                    s = s + bias_scr[...]
+                m_prev = m_scr[h, r, :]  # (tq, LANES), a row's value in every lane
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                m_safe = jnp.where(m_new == NEG, 0.0, m_new) if masked else m_new
+                alpha = jnp.exp(m_prev - m_safe)
+                p = jnp.exp(s - m_safe[:, :1])
+                l_scr[h, r, :] = l_scr[h, r, :] * alpha + jnp.sum(p, axis=1, keepdims=True)
+                m_scr[h, r, :] = m_new
+                acc_scr[h, r, :] = acc_scr[h, r, :] * alpha[:, :1] + jnp.dot(
+                    p.astype(dt), v_scr[h], preferred_element_type=F32)
+                return carry
+
+            jax.lax.fori_loop(0, g, head, 0)
+
         def query_tile(i, carry):
-            @pl.when(tab_ref[i * nk + j] != 0)
+            visit = tab_ref[i * nk + j] != 0
+            if causal:  # a key tile wholly at or before the query tile's first
+                # position hides nothing from any of its rows
+                interior = (j + 1) * tk - 1 <= off_ref[0] + i * tq
+                visit = visit & jnp.logical_not(interior)
+
+            @pl.when(visit)
             def _():
                 r = pl.ds(pl.multiple_of(i * tq, tq), tq)
                 if causal:
@@ -185,24 +231,12 @@ def _kernel(*refs, causal: bool, scale: float, rank: int, tq: int, nq: int, nk: 
                 else:
                     ok = mask_ref[r, :].astype(jnp.int32) != 0
                 bias_scr[...] = jnp.where(ok, 0.0, NEG)
+                tile(r, masked=True)
 
-                def head(h, carry):
-                    s = jax.lax.dot_general(
-                        q_ref[h, r, :], k_scr[h], (((1,), (1,)), ((), ())),
-                        preferred_element_type=F32)  # (tq, tk)
-                    s = s * scale + bias_scr[...]
-                    m_prev = m_scr[h, r, :]  # (tq, LANES), a row's value in every lane
-                    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-                    m_safe = jnp.where(m_new == NEG, 0.0, m_new)
-                    alpha = jnp.exp(m_prev - m_safe)
-                    p = jnp.exp(s - m_safe[:, :1])
-                    l_scr[h, r, :] = l_scr[h, r, :] * alpha + jnp.sum(p, axis=1, keepdims=True)
-                    m_scr[h, r, :] = m_new
-                    acc_scr[h, r, :] = acc_scr[h, r, :] * alpha[:, :1] + jnp.dot(
-                        p.astype(dt), v_scr[h], preferred_element_type=F32)
-                    return carry
-
-                jax.lax.fori_loop(0, g, head, 0)
+            if causal:
+                @pl.when(interior)
+                def _():
+                    tile(pl.ds(pl.multiple_of(i * tq, tq), tq), masked=False)
 
             return carry
 

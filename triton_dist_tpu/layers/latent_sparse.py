@@ -250,25 +250,31 @@ def attend_tiles(allowed, off):
     """What :func:`attend_expanded` computes of a chunk's attention, in tiles
     of ``kernels/latent_flash.py``'s sizes: ``table`` (query tiles, key
     tiles) bool, the tiles where ``allowed`` (C, P) allows anything, and
-    ``counts`` (2,) int32: how many those are, and how many tiles hold a key
+    ``counts`` (3,) int32: how many those are, how many tiles hold a key
     at or before the chunk's last position ``off + C - 1`` (what a loop over
-    the key blocks under the chunk's diagonal computes)."""
+    the key blocks under the chunk's diagonal computes), and how many are
+    attended with no mask (none under a selection's mask)."""
     C, P = allowed.shape
     tq, tk = latent_flash.tile_sizes(C, P)
     table = latent_flash.tile_table(allowed, tq, tk)
-    nq, nk = table.shape
-    under = nq * jnp.clip((off + C + tk - 1) // tk, 1, nk)
-    return table, jnp.stack([table.sum(dtype=jnp.int32), under.astype(jnp.int32)])
+    return table, _counts(table, C, off, tk, jnp.int32(0))
 
 
 def causal_tiles(C: int, P: int, off):
     """:func:`attend_tiles` of a chunk whose mask is causality alone, from
-    its first position: no (C, P) array is made."""
+    its first position: no (C, P) array is made; the tiles wholly at or
+    before a query tile's first position are the unmasked ones
+    (``latent_flash.interior_table``)."""
     tq, tk = latent_flash.tile_sizes(C, P)
     table = latent_flash.causal_table(C, P, off, tq, tk)
+    unmasked = latent_flash.interior_table(C, P, off, tq, tk).sum(dtype=jnp.int32)
+    return table, _counts(table, C, off, tk, unmasked)
+
+
+def _counts(table, C: int, off, tk: int, unmasked):
     nq, nk = table.shape
     under = nq * jnp.clip((off + C + tk - 1) // tk, 1, nk)
-    return table, jnp.stack([table.sum(dtype=jnp.int32), under.astype(jnp.int32)])
+    return jnp.stack([table.sum(dtype=jnp.int32), under.astype(jnp.int32), unmasked])
 
 
 def tile_rows_read(table, sent, P: int):

@@ -268,17 +268,19 @@ class LatentSparseLLM:
         nobody sent (a chunk's padding, an inactive slot) are in none of
         those. ``attend_tiles``: over the layers of the prefill chunks, the
         tiles of the attention's mask that allow anything, [which the flash
-        kernel visits, and those at or before the chunk's last position]
-        (``layers/latent_sparse.py:attend_tiles``). On the layers with no
-        indexer, [prefill, decode]: ``rows_visible`` the latent rows the
-        queries could see and ``rows_read`` those the attention fetched for
-        them (whole key tiles in prefill, whole tiles of pages in decode)."""
+        kernel visits, those at or before the chunk's last position, and
+        those of a layer with no indexer that the kernel attends with no
+        mask] (``layers/latent_sparse.py:attend_tiles``, ``causal_tiles``).
+        On the layers with no indexer, [prefill, decode]: ``rows_visible``
+        the latent rows the queries could see and ``rows_read`` those the
+        attention fetched for them (whole key tiles in prefill, whole tiles
+        of pages in decode)."""
         return {"expert_rows": jnp.zeros((self.config.num_experts,), jnp.int32),
                 "dispatches": jnp.zeros((), jnp.int32),
                 "visible": jnp.zeros((2,), jnp.int32),
                 "selected": jnp.zeros((2,), jnp.int32),
                 "tie_rows": jnp.zeros((), jnp.int32),
-                "attend_tiles": jnp.zeros((2,), jnp.int32),
+                "attend_tiles": jnp.zeros((3,), jnp.int32),
                 "rows_visible": jnp.zeros((2,), jnp.int32),
                 "rows_read": jnp.zeros((2,), jnp.int32)}
 
@@ -300,7 +302,7 @@ class LatentSparseLLM:
                           float(stats["rows_read"][i]), phase=phase)
         telemetry.inc("tdt_dsa_select_tie_rows_total", float(stats["tie_rows"]),
                       phase="prefill")
-        for i, kind in enumerate(("visited", "under_diagonal")):
+        for i, kind in enumerate(("visited", "under_diagonal", "unmasked")):
             telemetry.inc("tdt_dsa_attend_tiles_total", float(stats["attend_tiles"][i]),
                           kind=kind)
 

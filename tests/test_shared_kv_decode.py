@@ -73,9 +73,10 @@ def test_a_slot_reads_its_live_tiles_through_the_table(both_forms, case):
 
 
 def test_the_shape_rule():
-    """Whole lanes, whole sublane tiles of the pool's type, one layer and
-    one "head" of whole rows, the buffers inside the VMEM rule; the tile is
-    the most pages that divide the table under ``TILE_BYTES``."""
+    """Whole lanes, whole sublane tiles of the pool's type, one "head" of
+    whole rows (of any layer of the pool: the layer is the kernel's static
+    argument), the buffers inside the VMEM rule; the tile is the most pages
+    that divide the table under ``TILE_BYTES``."""
     pool = lambda bs, w: (1, 33, 1, bs, w)
     assert skd.takes(40, (1, 5377, 1, 16, 1280), 168, 2)  # phi-4-mini-flash, bfloat16
     assert skd.tile_pages((1, 5377, 1, 16, 1280), 168, 2) == 14
@@ -83,8 +84,8 @@ def test_the_shape_rule():
     assert not skd.takes(8, pool(8, 32), 8, 4)  # the CPU tests' default: 32 lanes
     assert not skd.takes(8, pool(4, 128), 8, 4)  # half a float32 sublane tile
     assert not skd.takes(8, pool(8, 128), 8, 2)  # half a bfloat16 one
-    assert not skd.takes(8, (2, 33, 1, 8, 128), 8, 4)  # a stacked pool: the dense models'
-    assert not skd.takes(8, (1, 33, 4, 8, 128), 8, 4)
+    assert skd.takes(32, (4, 17665, 1, 16, 512), 552, 2)  # granite-4.0-h-micro's four layers
+    assert not skd.takes(8, (1, 33, 4, 8, 128), 8, 4)  # heads apart: the dense models'
     assert not skd.takes(4096, (1, 33, 1, 16, 1280), 7, 2)  # 7 pages are 0.3 MB; 4096 query rows are not
 
 

@@ -765,11 +765,44 @@ def _bsa_decode(sds):
         sds((8, 2, 16, 128)), pool, pool, i32(8, 260), i32(8, 2, 64), i32(8, 2), i32(8))
 
 
+def _ssd_chunk(sds):
+    """One Mamba-2 layer's prefill chunk at Granite-4.0-H-Micro's widths:
+    2048 rows of 64 heads of 64, B and C of 128, the float32 state in and
+    out."""
+    from triton_dist_tpu.kernels.lightning_attn import ssd_chunk
+
+    f32 = lambda *shape: sds(shape, jnp.float32)
+    return ssd_chunk, (sds((2048, 64, 64)), f32(2048, 64), f32(64), sds((2048, 128)),
+                       sds((2048, 128)), f32(64), f32(128, 4096), sds((), jnp.int32))
+
+
+def _ssd_step(sds):
+    """That layer's decode step: 32 slots, each one's state of 128 x 4096
+    float32 read and written where it lies."""
+    from triton_dist_tpu.kernels.lightning_attn import ssd_step
+
+    f32 = lambda *shape: sds(shape, jnp.float32)
+    return (lambda *a: ssd_step(*a)[:2]), (
+        sds((32, 64, 64)), f32(32, 64), f32(64), sds((32, 128)), sds((32, 128)), f32(64),
+        f32(32, 128, 4096), sds((32,), jnp.bool_))
+
+
+def _shared_kv_decode_gqa(sds):
+    """A Granite attention layer's decode step: 32 slots of 32 query rows
+    over the third of four layers' K/V rows of 512 (8 heads of 64), a table
+    of 552 blocks of 16 a slot. Named ``shared_kv_decode`` in the trace."""
+    from triton_dist_tpu.kernels.shared_kv_decode import shared_kv_decode
+
+    pool = sds((4, 32 * 552 + 1, 1, 16, 512))
+    return (lambda *a: shared_kv_decode(*a, scale=0.015625, layer=2)), (
+        sds((32, 32, 512)), pool, pool, sds((32, 552), jnp.int32), sds((32,), jnp.int32))
+
+
 @pytest.mark.parametrize(
     "case", [_flash_decode, _paged_flash_decode, _flash_attention, _dsa_kth_value,
              _dsa_flash_prefill, _ssm_scan, _shared_kv_decode, _lightning_chunk,
              _bsa_select, _bsa_prefill, _bsa_decode, _latent_flash_prefill,
-             _latent_flash_decode],
+             _latent_flash_decode, _ssd_chunk, _ssd_step, _shared_kv_decode_gqa],
     ids=lambda f: f.__name__.lstrip("_"))
 def test_named_kernel_compiles_under_its_name(topo_2x2, case):
     """At Qwen3-8B head shapes, for one v5e chip: the kernel compiles and
@@ -783,7 +816,7 @@ def test_named_kernel_compiles_under_its_name(topo_2x2, case):
         lowered = jax.jit(fn).lower(*args)
         assert "tpu_custom_call" in lowered.as_text()
         compiled = lowered.compile()
-    name = case.__name__.lstrip("_")
+    name = case.__name__.lstrip("_").removesuffix("_gqa")
     calls = [l.strip().removeprefix("ROOT ") for l in compiled.as_text().splitlines()
              if "tpu_custom_call" in l]
     assert calls and all(l.startswith(f"%{name}") for l in calls), calls
@@ -1163,3 +1196,126 @@ def test_sparse_linear_programs_fit(topo_2x2):
     # no gather of a slot's whole table: nothing of (slots, extent, row) or (slots x pages, page, row)
     for gone in (f"bf16[{slots},{max_blocks * bs},", f"bf16[{slots * max_blocks},{bs},"):
         assert gone not in hlo, gone
+
+
+# ---------------------------------------------------------------------------
+# The sixth configuration's programs, whole and at its published widths
+# ---------------------------------------------------------------------------
+
+
+def test_granite_hybrid_programs_fit_whole(topo_2x2):
+    """``rag``'s programs for one v5e chip, Granite-4.0-H-Micro whole (40
+    layers, 6.4 GB of weights): the prefill chunk over each of the mix's
+    prompt buffers, with one slot's state carried in and out, ``ssd_chunk``
+    once a Mamba-2 layer and the flash kernel once an attention layer, no
+    score matrix of a chunk against its buffer; and the decode chunk at 32
+    slots, which carries the pool pair AND the slots' state in place (both
+    aliased to its outputs), moves the Mamba-2 state through ``ssd_step``
+    once a layer with no copy of a slot's or a layer's state beside it, and
+    reads the pool through ``shared_kv_decode`` once an attention layer."""
+    import json
+    import sys
+
+    from triton_dist_tpu.models import Engine, SparseLinearLLM
+    from triton_dist_tpu.models.sparse_linear import SSD_F32, layer_ones, layer_tensors
+    from triton_dist_tpu.runtime.mesh import initialize_distributed
+    from triton_dist_tpu.runtime.platform import force_mosaic
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.build.granite_hybrid import model_config
+
+    with open(os.path.join(root, "benchmark/configs/granite-4.0-h-micro.json")) as f:
+        cfg = json.load(f)
+    c, sv = model_config(cfg), cfg["serving"]
+    ctx = initialize_distributed(devices=list(topo_2x2.devices[:1]), axis_names=("tp",),
+                                 set_default=False)
+    rep = ctx.replicated()
+    dt = jnp.dtype(c.dtype)
+    sds = lambda shape, dtype=dt: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=rep)
+    kind = lambda n: jnp.float32 if n in SSD_F32 else dt
+    params = {"embed": sds((c.vocab_size, c.hidden_size)), "final_norm": sds((c.hidden_size,)),
+              "layers": []}
+    for layer in range(c.num_layers):
+        lp = {n: sds(shape, kind(n)) for n, shape in layer_tensors(c, layer)}
+        lp.update({n: sds((k,), kind(n)) for n, k in layer_ones(c, layer)})
+        params["layers"].append(lp)
+    assert sum(_nbytes(x) for x in jax.tree.leaves(params)) == cfg["bytes"]["weights"]
+    model = SparseLinearLLM(c, ctx, params=params)
+    slots, rows, bs = int(sv["slots"]), int(sv["prefill_chunk"]), int(sv["block_size"])
+    max_blocks = -(-int(sv["max_len"]) // bs)
+    n_ssd, n_full = len(c.layers_of("mamba2")), len(c.layers_of("full"))
+    width = c.num_kv_heads * c.head_dim
+    with force_mosaic():
+        eng = Engine(model, backend=sv["backend"], max_len=int(sv["max_len"]))
+        shaped = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+        i32 = lambda *shape: sds(shape, jnp.int32)
+        pool = jax.ShapeDtypeStruct((n_full, slots * max_blocks + 1, 1, bs, width), dt,
+                                    sharding=eng._pool_sharding)
+        state = shaped(jax.eval_shape(lambda: model.slot_state(slots)))
+        resident = 2 * _nbytes(pool) + sum(_nbytes(x) for x in jax.tree.leaves(state))
+        assert resident == cfg["bytes"]["pool"] + cfg["bytes"]["slot_state"]
+        one = shaped(jax.eval_shape(lambda: model.slot_state(1)))
+        for p_len in (2048, 4096, 8192):
+            buf = jax.ShapeDtypeStruct((n_full, 1, 1, p_len, width), dt, sharding=eng._kv_sharding)
+            compiled, held = _compile(eng._prefill_chunk_prog.lower(
+                params, i32(1, rows), buf, buf, i32(), i32(), one),
+                kernels=("ssd_chunk", "flash_attention"))
+            assert held + resident < HBM_BYTES, (p_len, held)
+            calls = [l.split("=")[0] for l in compiled.as_text().splitlines()
+                     if "tpu_custom_call" in l]
+            count = lambda name: sum(f"%{name}" in l for l in calls)
+            assert (count("ssd_chunk"), count("flash_attention")) == (n_ssd, n_full), calls
+            hlo = compiled.as_text()
+            assert f"f32[{c.num_q_heads},{rows},{p_len}]" not in hlo
+            assert f"f32[1,{c.num_q_heads},{rows},{p_len}]" not in hlo
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+        chunk, held = _compile(eng._decode_chunk_paged.lower(
+            params, (), i32(slots), pool, pool, i32(slots, max_blocks), i32(slots), i32(slots),
+            int(sv["chunk"]), key, state), kernels=("ssd_step", "shared_kv_decode"))
+    assert held < HBM_BYTES, held
+    assert chunk.memory_analysis().alias_size_in_bytes >= resident
+    hlo = chunk.as_text()
+    calls = [l.split("=")[0] for l in hlo.splitlines() if "tpu_custom_call" in l]
+    assert sum("%ssd_step" in l for l in calls) == n_ssd, calls
+    assert sum("%shared_kv_decode" in l for l in calls) == n_full, calls
+    assert _pool_sized_copies(hlo, pool) == []
+    ssm = state["ssm"][0]
+    assert _pool_sized_copies(hlo, jax.ShapeDtypeStruct((1,) + ssm.shape, ssm.dtype)) == []
+    for gone in (f"bf16[{slots},{max_blocks * bs},", f"bf16[{slots * max_blocks},{bs},"):
+        assert gone not in hlo, gone
+
+
+#: sha256 of ``lightning_chunk``'s Mosaic module at the published widths,
+#: printed without debug locations, as the parent of the change that put the
+#: Mamba-2 kernels beside it compiled it: ``longqa``'s kernel is that module.
+LIGHTNING_CHUNK_MODULE = "0bd2754404fcf604ef88cf48076c933c935ec33ba90b3df951d537c2a0e0626b"
+
+
+def test_lightning_chunk_is_the_module_it_was(topo_2x2):
+    """The chunked linear kernel's Mosaic module, with the state-space
+    kernels beside it in ``kernels/lightning_attn.py``, is the one
+    ``minicpm-sala-d12.longqa`` was measured with: the same text once the
+    source locations (file paths, line numbers) are left out."""
+    import base64
+    import hashlib
+    import json
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    from triton_dist_tpu.runtime.platform import force_mosaic
+
+    one = SingleDeviceSharding(topo_2x2.devices[0])
+    fn, args = _lightning_chunk(lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one))
+    with force_mosaic():
+        text = jax.jit(fn).lower(*args).as_text()
+    [call] = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    config = re.search(r'backend_config = "([^"]*)"', call).group(1).replace("\\22", '"')
+    body = base64.b64decode(json.loads(config)["custom_call_config"]["body"])
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    module = ir.Module.parse(body, context=ctx).operation.get_asm(enable_debug_info=False)
+    assert hashlib.sha256(module.encode()).hexdigest() == LIGHTNING_CHUNK_MODULE

@@ -2,7 +2,9 @@
 
 ``models/hybrid_ssm.py`` keeps one layer's K and V in the paged pool, a row a
 position with all heads side by side (``(1, blocks, 1, bs, W)``), and eight
-layers read it every decode step. This kernel is that read: for each slot it
+layers read it every decode step; ``models/sparse_linear.py``'s ``full``
+layers keep theirs so too, a pool layer each (``(L, blocks, 1, bs, W)``,
+the layer a static argument). This kernel is that read: for each slot it
 walks the slot's row of the block table over its LIVE length and gives, a
 query row, ``softmax(q K^T) V`` over the whole rows, float32. The heads'
 structure is the caller's (a block-diagonal query,
@@ -51,21 +53,21 @@ def tile_pages(pool_shape: tuple, max_blocks: int, itemsize: int) -> int:
 
 
 def takes(rows: int, pool_shape: tuple, max_blocks: int, itemsize: int) -> bool:
-    """Whether the kernel takes ``rows`` query rows a slot over this pool: one
-    layer and one "head" of whole rows, the row in whole lanes, a page in
+    """Whether the kernel takes ``rows`` query rows a slot over this pool:
+    one "head" of whole rows, the row in whole lanes, a page in
     whole sublane tiles of its type, and the two double-buffered tiles, the
     query's and the result's blocks and the accumulator within ``VMEM_BYTES``."""
-    layers, _, heads, bs, width = pool_shape
+    _, _, heads, bs, width = pool_shape
     tile = tile_pages(pool_shape, max_blocks, itemsize) * bs
     vmem = (2 * 2 * tile * width * itemsize + 2 * rows * width * (itemsize + 4)
             + rows * (width + 2 * LANES) * 4)
-    return (layers == 1 and heads == 1 and width % LANES == 0
+    return (heads == 1 and width % LANES == 0
             and bs % (32 // itemsize) == 0 and vmem <= VMEM_BYTES)
 
 
 def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, sems, turn, acc_scr, m_scr, l_scr, *,
-            scale: float, block_size: int, pages: int, slots: int):
+            scale: float, block_size: int, pages: int, slots: int, layer: int):
     b = pl.program_id(0)
     tile = pages * block_size
     tiles_of = lambda row: (lengths_ref[row] + tile - 1) // tile
@@ -80,7 +82,7 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
             phys = tables_ref[row, t * pages + p]
             for i, (pool, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
                 pltpu.make_async_copy(
-                    pool.at[0, phys, 0],
+                    pool.at[layer, phys, 0],
                     dst.at[buf, pl.ds(p * block_size, block_size), :],
                     sems.at[buf, i],
                 ).start()
@@ -125,9 +127,10 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
 
 
-def shared_kv_decode(q_rows, k_pool, v_pool, tables, lengths, *, scale: float):
+def shared_kv_decode(q_rows, k_pool, v_pool, tables, lengths, *, scale: float, layer: int = 0):
     """``q_rows`` (B, G, W): G query rows a slot, each over whole rows;
-    ``k_pool``, ``v_pool`` (1, blocks, 1, bs, W); ``tables`` (B, max_blocks)
+    ``k_pool``, ``v_pool`` (L, blocks, 1, bs, W), of which layer ``layer``'s
+    rows are read; ``tables`` (B, max_blocks)
     int32 block numbers; ``lengths`` (B,) int32, the positions a slot's rows
     may see (0: none, the result zeros). Returns (B, G, W) float32:
     ``softmax(scale * q K^T) V`` a query row over positions ``[0, length)``.
@@ -135,14 +138,15 @@ def shared_kv_decode(q_rows, k_pool, v_pool, tables, lengths, *, scale: float):
     past it."""
     pages = tile_pages(k_pool.shape, tables.shape[1], k_pool.dtype.itemsize)
     return _call(q_rows, k_pool, v_pool, tables.astype(jnp.int32), lengths.astype(jnp.int32),
-                 scale=scale, pages=pages, interpret=interpret_mode_default())
+                 scale=scale, pages=pages, layer=layer, interpret=interpret_mode_default())
 
 
 # A program calls this once a reading layer at the same shapes: under its own
 # jit the kernel is traced and lowered once a program, not once a layer
 # (0.16 s each in every process that builds the decode chunk).
-@functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
-def _call(q_rows, k_pool, v_pool, tables, lengths, *, scale: float, pages: int, interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "pages", "layer", "interpret"))
+def _call(q_rows, k_pool, v_pool, tables, lengths, *, scale: float, pages: int, layer: int,
+          interpret):
     b, g, w = q_rows.shape
     bs = k_pool.shape[3]
     tile = pages * bs
@@ -163,7 +167,8 @@ def _call(q_rows, k_pool, v_pool, tables, lengths, *, scale: float, pages: int, 
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, block_size=bs, pages=pages, slots=b),
+        functools.partial(_kernel, scale=scale, block_size=bs, pages=pages, slots=b,
+                          layer=layer),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, w), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),

@@ -151,13 +151,15 @@ def diff_attend(q, k, v, mask, lam, layer: int, subln, eps: float):
     return o.reshape(t, hq * dh).astype(q.dtype)
 
 
-def rows_query(q, hkv: int):
+def rows_query(q, hkv: int, key_head=None):
     """q (B, Hq, D) as block-diagonal rows (B, Hq, Hkv * D): query head ``h``'s
-    values in the columns of the key head it reads, zeros elsewhere, so that
-    its score over a whole K row is one dense product."""
+    values in the columns of the key head it reads (``key_head[h]``; by
+    default differential attention's pairs), zeros elsewhere, so that its
+    score over a whole K row is one dense product."""
     hq = q.shape[1]
     heads = jnp.arange(hq)
-    key_head = 2 * (heads // (2 * (hq // hkv))) + heads % 2
+    if key_head is None:
+        key_head = 2 * (heads // (2 * (hq // hkv))) + heads % 2
     reads = (key_head[:, None] == jnp.arange(hkv)[None, :]).astype(q.dtype)  # (Hq, Hkv)
     return jnp.einsum("bhd,hj->bhjd", q, reads).reshape(q.shape[0], hq, -1)
 

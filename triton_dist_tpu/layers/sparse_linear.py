@@ -1,9 +1,13 @@
 """The shard-local math of ``models/sparse_linear.py``: a lightning
-(decayed linear attention) mixer and a block-sparse attention mixer whose
-selection reads pooled keys alone. Plain XLA but for the lightning chunk
-(``kernels/lightning_attn.py``), a chunk's selection scores and the two
-attends (``kernels/block_sparse_attn.py``), which run where the shapes let them
-(heads in whole lanes) and have a ``jax.numpy`` form here for the rest.
+(decayed linear attention) mixer, a block-sparse attention mixer whose
+selection reads pooled keys alone, a Mamba-2 mixer and full (causal,
+grouped-query) attention. Plain XLA but for the lightning chunk and the
+Mamba-2 chunk and step (``kernels/lightning_attn.py``), a chunk's selection
+scores and the two attends (``kernels/block_sparse_attn.py``), which run
+where the shapes let them (heads in whole lanes) and have a ``jax.numpy``
+form here for the rest, and full attention (``kernels/flash_attn.py`` over a
+chunk, ``kernels/shared_kv_decode.py`` over the pool in place, a gather of
+the table's extent where the row is not whole lanes).
 
 * **Pooled keys**: ``c_j = mean(k[s j : s j + K])``, a K/V head's, kept a
   slot beside the pool. :func:`pool_chunk` makes the ones a prefill chunk
@@ -21,6 +25,15 @@ attends (``kernels/block_sparse_attn.py``), which run where the shapes let them
 * **Attend**: :func:`attend_chunk` (a chunk's queries over the prompt's
   buffers) and :func:`attend_step` (a slot's query over its pages), each the
   kernel or the ``jax.numpy`` form by shape.
+* **Mamba-2** (:func:`mamba2_chunk`, :func:`mamba2_step`): ``[z | xBC | dt]
+  = W_in u``; a depthwise causal conv of ``K`` taps over ``xBC`` (its last
+  ``K - 1`` inputs are the slot's conv tail), silu; ``[x | B | C] = xBC'``;
+  ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the state-space
+  recurrence (``kernels/lightning_attn.py``); ``W_out RMS(y * silu(z))``,
+  the norm over the whole inner width (one group).
+* **Full attention** (:func:`attend_full_chunk`, :func:`attend_full_step`):
+  softmax at the configuration's scale over every position up to a
+  query's own, query head ``h`` reading K/V head ``h // (Hq / Hkv)``.
 """
 
 from __future__ import annotations
@@ -32,7 +45,9 @@ import jax.numpy as jnp
 
 from triton_dist_tpu.kernels import block_sparse_attn as bsa
 from triton_dist_tpu.kernels import lightning_attn as la
-from triton_dist_tpu.layers.hybrid_ssm import swiglu  # noqa: F401
+from triton_dist_tpu.kernels import shared_kv_decode as skv
+from triton_dist_tpu.kernels.flash_attn import flash_attention
+from triton_dist_tpu.layers.hybrid_ssm import rows_query, swiglu  # noqa: F401
 from triton_dist_tpu.layers.latent_sparse import mm  # noqa: F401
 
 F32 = jnp.float32
@@ -246,3 +261,109 @@ def attend_step(q, k_pool, v_pool, layer: int, tables, sel, seen, topk: int):
         q1[None], k1, v1, s1[:, None], (n1 - 1)[None], bs)[0]
     out = jax.vmap(one)(q, through(k_pool), through(v_pool), sel, seen)
     return out, hkv * tables.shape[1] * jnp.sum(seen > 0, dtype=jnp.int32)
+
+
+# ----------------------------------------------------------------- Mamba-2
+
+
+def _mamba2_in(lp, u):
+    """(z, xBC, dt (..., H) float32 after its softplus) of rows u."""
+    din = lp["w_out"].shape[0]
+    heads = lp["a_log"].shape[0]
+    zxd = mm(u, lp["w_in"])
+    dt = jax.nn.softplus(zxd[..., -heads:].astype(F32) + lp["dt_bias"].astype(F32))
+    return zxd[..., :din], zxd[..., din:-heads], dt
+
+
+def _mamba2_out(lp, y, z, eps: float):
+    """``W_out RMS(y * silu(z))``: y (..., Din) float32, gated before the
+    norm, the norm over all ``Din`` at once."""
+    g = y * jax.nn.silu(z.astype(F32))
+    return mm(rms(g, lp["norm"], eps), lp["w_out"])
+
+
+def _split_xbc(xc, din: int, n: int):
+    return xc[..., :din], xc[..., din:din + n], xc[..., din + n:]
+
+
+def mamba2_chunk(lp, u, tail, s, n_real, heads: int, state: int, sub: int, eps: float):
+    """u (T, d) normed rows, of which the first ``n_real`` are somebody's;
+    ``tail`` (K-1, Din + 2N) the conv's last inputs before the chunk, ``s``
+    (N, Din) float32 the state. Returns (Mix (T, d), tail', s')."""
+    t = u.shape[0]
+    din = lp["w_out"].shape[0]
+    k = lp["conv_w"].shape[0]
+    z, xbc, dt = _mamba2_in(lp, u)
+    xp = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=0)  # (K-1+T, Din+2N)
+    conv = sum(xp[i:i + t].astype(F32) * lp["conv_w"][i].astype(F32) for i in range(k))
+    xc = jax.nn.silu(conv + lp["conv_b"].astype(F32)).astype(xbc.dtype)
+    x, b, c = _split_xbc(xc, din, state)
+    A = -jnp.exp(lp["a_log"].astype(F32))
+    y, s = la.ssd_chunk(x.reshape(t, heads, din // heads), dt, A, b, c, lp["d"], s, n_real,
+                        sub=sub)
+    tail = jax.lax.dynamic_slice_in_dim(xp, jnp.clip(n_real, 0, t), k - 1, axis=0)
+    return _mamba2_out(lp, y.reshape(t, din), z, eps), tail, s
+
+
+def mamba2_step(lp, u, tail, s, active, heads: int, state: int, eps: float):
+    """One row a slot: u (B, d), tail (B, K-1, Din + 2N), s (slots, N, Din)
+    float32, ``active`` (B,) bool: an inactive slot's tail and state stay as
+    they were, and its state is not touched. Returns (Mix (B, d), tail', s',
+    the slots whose state was moved)."""
+    b_ = u.shape[0]
+    din = lp["w_out"].shape[0]
+    z, xbc, dt = _mamba2_in(lp, u)
+    xp = jnp.concatenate([tail.astype(xbc.dtype), xbc[:, None]], axis=1)  # (B, K, Din+2N)
+    conv = jnp.sum(xp.astype(F32) * lp["conv_w"].astype(F32)[None], axis=1)
+    xc = jax.nn.silu(conv + lp["conv_b"].astype(F32)).astype(xbc.dtype)
+    x, b, c = _split_xbc(xc, din, state)
+    A = -jnp.exp(lp["a_log"].astype(F32))
+    y, s, moved = la.ssd_step(x.reshape(b_, heads, din // heads), dt, A, b, c, lp["d"], s,
+                              active)
+    tail = jnp.where(active[:, None, None], xp[:, 1:], tail).astype(tail.dtype)
+    return _mamba2_out(lp, y.reshape(b_, din), z, eps), tail, s, moved
+
+
+# ---------------------------------------------------------- full attention
+
+
+def attend_full_chunk(q, k_rows, v_rows, off, scale: float):
+    """A prefill chunk's causal attention: q (C, Hq, D) at positions ``off
+    ...``; ``k_rows``, ``v_rows`` (P, Hkv * D) the prompt's rows. Returns
+    (C, Hq, D) in q's type, through the flash kernel: no score matrix
+    leaves the chip."""
+    C, hq, D = q.shape
+    P = k_rows.shape[0]
+    heads = lambda z: z.reshape(P, -1, D).transpose(1, 0, 2)[None]
+    o = flash_attention(q.transpose(1, 0, 2)[None], heads(k_rows), heads(v_rows),
+                        causal=True, scale=scale, q_offset=off)
+    return o[0].transpose(1, 0, 2)
+
+
+def attend_full_step(q, k_pool, v_pool, layer: int, tables, seen, scale: float):
+    """A decode step's attention: q (B, Hq, D); the pools (L, pages, 1, bs,
+    Hkv * D); ``seen`` (B,) the positions a slot's row may see (0: none).
+    Returns (B, Hq, D) float32. The pool is read where it lies through
+    ``kernels/shared_kv_decode.py`` with a block-diagonal query (whole tiles
+    up to the length), else gathered through the table at its whole
+    extent."""
+    B, hq, D = q.shape
+    hkv = k_pool.shape[-1] // D
+    key_head = jnp.arange(hq) // (hq // hkv)
+    if skv.takes(hq, k_pool.shape, tables.shape[1], k_pool.dtype.itemsize):
+        o = skv.shared_kv_decode(rows_query(q, hkv, key_head), k_pool, v_pool, tables, seen,
+                                 scale=scale, layer=layer).reshape(B, hq, hkv, D)
+        own = key_head[:, None] == jnp.arange(hkv)[None]
+        return jnp.sum(jnp.where(own[None, :, :, None], o, 0.0), axis=2)
+    through = lambda pool: jnp.take(pool[layer, :, 0], tables, axis=0, mode="clip").reshape(
+        B, -1, hkv, D)
+    k, v = through(k_pool), through(v_pool)
+    qg = q.reshape(B, hkv, hq // hkv, D)
+    sc = jnp.einsum("bgrd,bsgd->bgrs", qg, k, preferred_element_type=F32) * scale
+    visible = (jnp.arange(k.shape[1])[None] < seen[:, None])[:, None, None]
+    sc = jnp.where(visible, sc, NEG)
+    m = jnp.max(sc, axis=-1, keepdims=True)
+    e = jnp.where(visible, jnp.exp(sc - jnp.where(m == NEG, 0.0, m)), 0.0)
+    p = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    o = jnp.einsum("bgrs,bsgd->bgrd", p.astype(v.dtype), v, preferred_element_type=F32)
+    return o.reshape(B, hq, D)

@@ -91,12 +91,15 @@ class HybridSSMConfig:
 
 @dataclasses.dataclass(frozen=True)
 class SparseLinearConfig:
-    """A decoder that mixes block-sparse attention layers with lightning
-    linear-attention layers (``models/sparse_linear.py``), in muP form. The
-    defaults are a toy whose contexts of 80-200 tokens select; the published
-    shapes are ``benchmark/configs/minicpm-sala-d12.json``'s.
+    """A decoder that mixes attention layers with linear-recurrence layers
+    (``models/sparse_linear.py``), in muP form. The defaults are a toy whose
+    contexts of 80-200 tokens select; the published shapes are
+    ``benchmark/configs/minicpm-sala-d12.json``'s (block-sparse and
+    lightning layers) and ``benchmark/configs/granite-4.0-h-micro.json``'s
+    (Mamba-2 and full attention layers).
 
-    ``mixer_types``: a layer is ``"sparse"`` or ``"lightning"``.
+    ``mixer_types``: a layer is ``"sparse"``, ``"lightning"``, ``"mamba2"``
+    or ``"full"``.
     ``published_layers`` is the depth under the residual's root
     (``scale_depth / sqrt(published_layers)``) whatever depth is held.
     Sparse layers: ``num_q_heads`` query heads over ``num_kv_heads`` K/V
@@ -105,8 +108,21 @@ class SparseLinearConfig:
     ``topk`` blocks of ``block_size`` positions: the first ``init_blocks``,
     the ``window_size // block_size`` ending at its own, the rest by score.
     Lightning layers: ``lightning_heads`` heads of ``lightning_head_dim``,
-    rotated. ``max_len`` is the longest sequence a slot holds: the extent
-    of a slot's pooled keys (``max_len // kernel_stride`` of them)."""
+    rotated. Full layers: grouped-query attention over every position before
+    a query, the same heads as the sparse layers', no rotation, no norm on q
+    or k. Mamba-2 layers: ``mamba_heads`` heads of ``mamba_head_dim``
+    channels, one group of ``B`` and ``C`` of ``mamba_state``, a causal conv
+    of ``mamba_conv`` taps, the chunked form over sub-chunks of
+    ``mamba_chunk`` rows. ``max_len`` is the longest sequence a slot holds:
+    the extent of a slot's pooled keys (``max_len // kernel_stride`` of
+    them).
+
+    The muP numbers: ``h0 = scale_emb E[token]``; a residual branch times
+    ``residual_multiplier``, the logits times ``logit_multiplier``, a full
+    layer's scores times ``attention_multiplier``; each left None is
+    MiniCPM's (``scale_depth / sqrt(published_layers)``, ``dim_model_base /
+    hidden_size``, ``head_dim ** -0.5``). ``tie_embeddings``: the head is
+    the embedding's transpose. ``embed_std``: the seeded embedding's scale."""
 
     vocab_size: int = 256
     hidden_size: int = 64
@@ -131,9 +147,22 @@ class SparseLinearConfig:
     rms_eps: float = 1e-6
     max_len: int = 256
     dtype: str = "float32"
+    mamba_heads: int = 4
+    mamba_head_dim: int = 32
+    mamba_state: int = 16
+    mamba_conv: int = 4
+    mamba_chunk: int = 16
+    residual_multiplier: float | None = None
+    logit_multiplier: float | None = None
+    attention_multiplier: float | None = None
+    tie_embeddings: bool = False
+    embed_std: float = 0.02
 
     def __post_init__(self):
-        assert set(self.mixer_types) <= {"sparse", "lightning"} and self.mixer_types
+        assert set(self.mixer_types) <= {"sparse", "lightning", "mamba2", "full"}
+        assert self.mixer_types
+        # one pool of K/V rows: a model's attention layers are of one kind
+        assert not {"sparse", "full"} <= set(self.mixer_types)
         assert self.num_q_heads % self.num_kv_heads == 0
         assert self.kernel_size % self.kernel_stride == 0
         assert self.block_size % self.kernel_stride == 0
@@ -147,11 +176,25 @@ class SparseLinearConfig:
 
     @property
     def residual_scale(self) -> float:
+        if self.residual_multiplier is not None:
+            return self.residual_multiplier
         return self.scale_depth / self.published_layers ** 0.5
 
     @property
     def logit_scale(self) -> float:
+        if self.logit_multiplier is not None:
+            return self.logit_multiplier
         return self.dim_model_base / self.hidden_size
+
+    @property
+    def attention_scale(self) -> float:
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return self.head_dim ** -0.5
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
 
     @property
     def pooled_extent(self) -> int:

@@ -71,6 +71,14 @@ def _references(eng):
     ]
 
 
+@pytest.fixture(scope="module")
+def refs(model1):
+    """The xla engine's one-shot greedy streams of ``REQUESTS``, which every
+    served stream below must equal. Greedy serve is deterministic, so the
+    module computes them once."""
+    return _references(make_engine(model1, backend="xla"))
+
+
 # ================================================= ChaosSchedule (host)
 
 
@@ -148,16 +156,13 @@ def test_chaos_check_stall_wedges_caller_then_runs_clean(monkeypatch):
 
 
 @pytest.mark.chaos
-def test_chaos_probe_arc_restores_fused_backend(model1, monkeypatch):
+def test_chaos_probe_arc_restores_fused_backend(model1, refs, monkeypatch):
     """The ISSUE acceptance arc: fused serving → chaos abort on the second
     decode chunk → degraded-XLA recovery (zero loss/dup) → first half-open
     probe FAILS (scripted) and doubles the backoff → second probe succeeds
     → fused routing restored in-process, breaker CLOSED, all transitions
     visible in telemetry."""
     monkeypatch.setenv("TDT_DEGRADE_PROBE_S", "0.01")
-    ref_eng = make_engine(model1, backend="xla")
-    refs = _references(ref_eng)
-
     eng = make_engine(model1, backend="dist_ar")
     srv = InferenceServer(eng, num_slots=2, chunk=2)
     streams: dict[int, list[int]] = {}
@@ -248,11 +253,10 @@ def _assert_pool_untouched(before, after):
     assert after["ledger"] == before["ledger"]
 
 
-def _degraded_server(model1, monkeypatch):
+def _degraded_server(model1, monkeypatch, refs):
     """A dist_ar server whose second decode chunk aborts, its requests
     submitted: (engine, server, handles, streams, xla references)."""
     monkeypatch.setenv("TDT_DEGRADE_PROBE_S", "0.01")
-    refs = _references(make_engine(model1, backend="xla"))
     eng = make_engine(model1, backend="dist_ar")
     srv = InferenceServer(eng, num_slots=2, chunk=2)
     streams: dict[int, list[int]] = {}
@@ -265,13 +269,13 @@ def _degraded_server(model1, monkeypatch):
 
 
 @pytest.mark.chaos
-def test_chaos_probe_runs_the_programs_that_serve(model1, monkeypatch):
+def test_chaos_probe_runs_the_programs_that_serve(model1, refs, monkeypatch):
     """The probe's sandbox is a pool of its own driven through the join's and
     the chunk's programs: a passing probe counts one in-place decode chunk
     (``tdt_engine_decode_chunks_total{path="pool"}``) and, up to the moment
     the restore begins, leaves the serving pool's bytes, its tables and the
     ledger's books as they were — with tenants in their slots."""
-    eng, srv, handles, streams, refs = _degraded_server(model1, monkeypatch)
+    eng, srv, handles, streams, refs = _degraded_server(model1, monkeypatch, refs)
     before, after = {}, {}
     dispatch, restore = srv._probe_dispatch, srv._restore_streams
 
@@ -298,12 +302,12 @@ def test_chaos_probe_runs_the_programs_that_serve(model1, monkeypatch):
 
 
 @pytest.mark.chaos
-def test_chaos_probe_fails_on_a_fault_in_the_paged_step(model1, monkeypatch):
+def test_chaos_probe_fails_on_a_fault_in_the_paged_step(model1, refs, monkeypatch):
     """A fault in the decode chunk that serves, and in nothing else, fails
     the probe: the breaker re-opens, the engine goes back to xla, and the
     live streams end there byte for byte, their pool and its gauge as the
     probe found them."""
-    eng, srv, handles, streams, refs = _degraded_server(model1, monkeypatch)
+    eng, srv, handles, streams, refs = _degraded_server(model1, monkeypatch, refs)
     before, after = [], []
     dispatch = srv._probe_dispatch
 
@@ -357,15 +361,12 @@ def test_chaos_probe_fails_on_a_fault_in_the_paged_step(model1, monkeypatch):
 
 
 @pytest.mark.chaos
-def test_chaos_double_fault_recovery_stays_degraded(model1, monkeypatch):
+def test_chaos_double_fault_recovery_stays_degraded(model1, refs, monkeypatch):
     """Double fault: the chunk abort's recovery re-prefill is ITSELF
     aborted (site 'recovery'). The bounded retry loop absorbs it on a
     fresh cache and — with probing disabled — the engine stays pinned on
     xla, still with zero token loss or duplication."""
     monkeypatch.setenv("TDT_DEGRADE_PROBE_S", "0")  # sticky: no un-degrade
-    ref_eng = make_engine(model1, backend="xla")
-    refs = _references(ref_eng)
-
     eng = make_engine(model1, backend="dist_ar")
     srv = InferenceServer(eng, num_slots=2, chunk=2)
     streams: dict[int, list[int]] = {}
@@ -402,7 +403,7 @@ def test_chaos_double_fault_recovery_stays_degraded(model1, monkeypatch):
 
 
 @pytest.mark.chaos
-def test_chaos_rank_death_arc_fails_fast_and_recovers(model1, monkeypatch):
+def test_chaos_rank_death_arc_fails_fast_and_recovers(model1, refs, monkeypatch):
     """The rank-loss acceptance arc: scripted ``die@1`` mid-decode kills a
     peer on the health board → the in-flight collective fails fast with
     ``dead_peer`` (NO bounded-wait timeout storm: zero aborts on the
@@ -414,9 +415,6 @@ def test_chaos_rank_death_arc_fails_fast_and_recovers(model1, monkeypatch):
     from triton_dist_tpu.runtime import mesh
 
     monkeypatch.setenv("TDT_DEGRADE_PROBE_S", "0.01")
-    ref_eng = make_engine(model1, backend="xla")
-    refs = _references(ref_eng)
-
     eng = make_engine(model1, backend="dist_ar")
     srv = InferenceServer(eng, num_slots=2, chunk=2)
     # Huge heartbeat so only the scripted die — never a wall-clock lease

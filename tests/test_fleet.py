@@ -25,6 +25,7 @@ import os
 import time
 import urllib.error
 import urllib.request
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -86,11 +87,20 @@ def engine(model1):
     return Engine(model1, backend="xla", max_len=MAX_LEN)
 
 
+#: Engine -> {(prompt, gen_len): its one-shot stream}.
+_ONE_SHOT = weakref.WeakKeyDictionary()
+
+
 def _references(eng, requests):
-    return [
-        list(np.asarray(eng.serve(jnp.asarray([p], jnp.int32), gen_len=g))[0])
-        for p, g in requests
-    ]
+    """The one-shot ``serve`` stream of each request, which its served
+    stream must equal. Greedy serve is deterministic, so each (prompt,
+    length) is served once an engine."""
+    done = _ONE_SHOT.setdefault(eng, {})
+    for p, g in requests:
+        if (tuple(p), g) not in done:
+            done[tuple(p), g] = list(
+                np.asarray(eng.serve(jnp.asarray([p], jnp.int32), gen_len=g))[0])
+    return [list(done[tuple(p), g]) for p, g in requests]
 
 
 def _post(url, body):

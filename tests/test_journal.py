@@ -88,6 +88,14 @@ def _references(eng):
     ]
 
 
+@pytest.fixture(scope="module")
+def refs(engine):
+    """The one-shot streams of ``REQUESTS``, which every served or recovered
+    stream below must equal. Greedy serve is deterministic, so the module
+    computes them once."""
+    return _references(engine)
+
+
 # =========================================================== file mechanics
 
 
@@ -252,8 +260,7 @@ def _serve_journaled(engine, path, *, partial=False):
     return srv, handles, streams
 
 
-def test_server_journals_full_lifecycle(engine, tmp_path):
-    refs = _references(engine)
+def test_server_journals_full_lifecycle(engine, refs, tmp_path):
     path = tmp_path / "journal.jsonl"
     srv, handles, streams = _serve_journaled(engine, path)
     assert all(h.done for h in handles)
@@ -287,12 +294,11 @@ def test_server_journals_full_lifecycle(engine, tmp_path):
 
 
 @pytest.mark.chaos
-def test_kill_and_recover_zero_drop_zero_dup(engine, tmp_path):
+def test_kill_and_recover_zero_drop_zero_dup(engine, refs, tmp_path):
     """Acceptance: abandon a journaled server mid-serve (process "crash" —
     no shutdown, no flush beyond the per-record fsync), point a fresh
     server at the journal, and every surviving stream completes
     byte-identically with zero dropped and zero duplicated tokens."""
-    refs = _references(engine)
     path = tmp_path / "journal.jsonl"
     srv1, handles1, streams1 = _serve_journaled(engine, path, partial=True)
     # The crash must land mid-serve: some requests done, some in flight.
@@ -334,12 +340,12 @@ def test_kill_and_recover_zero_drop_zero_dup(engine, tmp_path):
     assert any(e["kind"] == "serving_journal_replay" for e in telemetry.events())
 
 
-def test_crash_at_every_record_boundary(engine, tmp_path):
+@pytest.mark.timeout(360)
+def test_crash_at_every_record_boundary(engine, refs, tmp_path):
     """The sweep: truncate the full journal at EVERY record boundary and
     recover from the prefix. Whatever the crash point, every request whose
     submit survived must finish with byte-identical tokens — zero drops,
     zero dups, no fabricated suffixes."""
-    refs = _references(engine)
     path = tmp_path / "journal.jsonl"
     srv, handles, _ = _serve_journaled(engine, path)
     assert all(h.done for h in handles)
@@ -372,7 +378,7 @@ def test_crash_at_every_record_boundary(engine, tmp_path):
 
 
 @pytest.mark.chaos
-def test_journal_portability_across_server_shapes(engine, tmp_path, monkeypatch):
+def test_journal_portability_across_server_shapes(engine, refs, tmp_path, monkeypatch):
     """A journal is a portable request ledger, not a dump of one server's
     internals: records written by a 3-slot server over the default paged
     pool replay into a fresh server with a different slot count AND a
@@ -380,7 +386,6 @@ def test_journal_portability_across_server_shapes(engine, tmp_path, monkeypatch)
     byte-identically with zero dropped / duplicated tokens. This is the
     invariant the fleet router leans on when it migrates work between
     replicas that need not share serving-shape knobs."""
-    refs = _references(engine)
     path = tmp_path / "journal.jsonl"
     srv1, handles1, _ = _serve_journaled(engine, path, partial=True)
     pre = RequestJournal.replay(RequestJournal.read(path))
@@ -428,8 +433,7 @@ def test_recover_drops_oversized_requests(engine, tmp_path):
 # ======================================================= graceful shutdown
 
 
-def test_shutdown_drains_then_rejects(engine, tmp_path):
-    refs = _references(engine)
+def test_shutdown_drains_then_rejects(engine, refs, tmp_path):
     journal = RequestJournal(tmp_path / "j.jsonl", fsync_every=1)
     srv = InferenceServer(engine, num_slots=3, chunk=2, journal=journal)
     handles = [srv.submit(p, g) for p, g in REQUESTS[:3]]
@@ -452,8 +456,7 @@ def test_shutdown_drains_then_rejects(engine, tmp_path):
     srv.shutdown()                          # idempotent
 
 
-def test_shutdown_without_drain_leaves_recoverable_journal(engine, tmp_path):
-    refs = _references(engine)
+def test_shutdown_without_drain_leaves_recoverable_journal(engine, refs, tmp_path):
     path = tmp_path / "j.jsonl"
     journal = RequestJournal(path, fsync_every=1)
     srv = InferenceServer(engine, num_slots=2, chunk=2, journal=journal)

@@ -705,6 +705,7 @@ def _skip_if_cpu_cant_interpret_collectives(exc: Exception):
     raise exc
 
 
+@pytest.mark.timeout(360)
 def test_mega_masked_paged_parity_world4(dense_model, monkeypatch):
     """World-4 ragged-mask byte parity of the paged chunk vs the op-by-op
     dist_ar path, chunk against chunk and against dist_ar's one-shot serve.

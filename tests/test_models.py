@@ -63,6 +63,7 @@ def test_moe_model_runs(dense_model):
     np.testing.assert_array_equal(out_m, out_x)
 
 
+@pytest.mark.timeout(360)
 def test_engine_sampling(dense_model):
     """Temperature/top-p sampling: deterministic under a fixed key, varies
     across keys, and top-p=tiny degenerates to (near-)greedy."""
@@ -72,10 +73,11 @@ def test_engine_sampling(dense_model):
     a = np.asarray(eng.serve(ids, gen_len=4, key=jax.random.PRNGKey(7)))
     b = np.asarray(eng.serve(ids, gen_len=4, key=jax.random.PRNGKey(7)))
     np.testing.assert_array_equal(a, b)
-    outs = {
-        tuple(np.asarray(eng.serve(ids, gen_len=4, key=jax.random.PRNGKey(s)))[0])
-        for s in range(8)
-    }
+    outs = set()
+    for s in range(8):  # until two keys draw different tokens
+        outs.add(tuple(np.asarray(eng.serve(ids, gen_len=4, key=jax.random.PRNGKey(s)))[0]))
+        if len(outs) > 1:
+            break
     assert len(outs) > 1, "sampling should vary across keys"
 
     # top_p → 0 keeps only the argmax bucket: must equal greedy.

@@ -273,9 +273,16 @@ def _references(eng):
     ]
 
 
-def test_server_parity_staggered(model1):
+@pytest.fixture(scope="module")
+def refs(model1):
+    """The one-shot streams of ``REQUESTS``, which every served stream below
+    must equal. Greedy serve is deterministic, so the module computes them
+    once, on an engine of their own."""
+    return _references(make_engine(model1))
+
+
+def test_server_parity_staggered(model1, refs):
     eng = make_engine(model1)
-    refs = _references(eng)
 
     srv = InferenceServer(eng, num_slots=3, chunk=2)
     streams: dict[int, list[int]] = {}
@@ -334,11 +341,10 @@ def test_server_parity_staggered(model1):
     assert "tdt_serving_tpot_seconds" in hist_names
 
 
-def test_server_synthetic_arrivals(model1):
+def test_server_synthetic_arrivals(model1, refs):
     """Offered-load staggering: future arrival_time_s defers joins but the
     run loop drains everything, and TTFT is measured from effective arrival."""
     eng = make_engine(model1)
-    refs = _references(eng)
     srv = InferenceServer(eng, num_slots=2, chunk=3)
     handles = [
         srv.submit(p, g, arrival_time_s=i * 0.02)
@@ -370,14 +376,11 @@ def test_serve_profile_dir_counts_once(model1, tmp_path):
 
 
 @pytest.mark.chaos
-def test_chaos_abort_midserving_no_token_loss(model1):
+def test_chaos_abort_midserving_no_token_loss(model1, refs):
     """A collective abort mid-serving degrades the engine to xla WITHOUT
     dropping the queue: every in-flight slot re-prefills from its token
     history and every stream completes with zero dropped or duplicated
     tokens (byte-identical to the greedy one-shot reference)."""
-    ref_eng = make_engine(model1, backend="xla")
-    refs = _references(ref_eng)
-
     eng = make_engine(model1, backend="dist_ar")
     srv = InferenceServer(eng, num_slots=2, chunk=2)
 
@@ -421,7 +424,7 @@ def test_chaos_abort_midserving_no_token_loss(model1):
 
 
 @pytest.fixture(scope="module")
-def traced_serve(model1, tmp_path_factory):
+def traced_serve(model1, refs, tmp_path_factory):
     """ONE toy serve under a profiler session, after a warm-up serve of the
     same requests: the tokens, the program's counters and self-time digest
     as the difference of two snapshots round it, and the ``tdt_*`` events
@@ -433,7 +436,6 @@ def traced_serve(model1, tmp_path_factory):
     telemetry.reset()
     tracing.reset()
     eng = make_engine(model1)
-    refs = _references(eng)
     warm = InferenceServer(eng, num_slots=3, chunk=2)
     warm_handles = [warm.submit(p, g) for p, g in REQUESTS]
     warm.run()
@@ -672,14 +674,20 @@ SCRIPT = [
 ]
 
 
-def _serve_script(eng, journal_path, ahead: bool, script=None, **server_args):
+def _serve_script(eng, journal_path, ahead: bool, script=None, seam=None,
+                  num_slots=3, **server_args):
     """``ahead`` false: the loop that lands every decode chunk before
-    ``step()`` returns and waits for every prefill chunk as it is issued."""
+    ``step()`` returns and waits for every prefill chunk as it is issued, so
+    no request joins before the chunk that frees its slot has landed.
+    ``seam`` is called with the server before it runs."""
     script = SCRIPT if script is None else script
     srv = InferenceServer(
-        eng, num_slots=3, chunk=2, journal=str(journal_path), **server_args)
+        eng, num_slots=num_slots, chunk=2, journal=str(journal_path), **server_args)
     if not ahead:
-        srv._sync_reason = lambda chunk: "other"  # the seam: never in flight
+        srv._sync_reason = lambda chunk: "other"  # the seams: never in flight,
+        srv._join_ahead = lambda chunk: None  # and no join ahead of a landing
+    if seam is not None:
+        seam(srv)
     clock = [0.0]
     srv._now = lambda: clock[0]
     landings = _landings(srv)
@@ -719,16 +727,52 @@ def _serve_script(eng, journal_path, ahead: bool, script=None, **server_args):
     return left, landings
 
 
+def _count_joins_ahead(srv):
+    """The host's own count of the joins that can go ahead: at each chunk
+    issued, the slots that run out in it, in index order, each to the next
+    waiting request in the queue's order while the free pool holds its chain
+    beside the finishing tenants' (no prompt of these scripts fills a block, so
+    no chain is shared)."""
+    counted, inner = [0], srv._join_ahead
+
+    def join_ahead(chunk):
+        free = srv.kv_ledger.allocator.num_free
+        finishing = [i for i, n in sorted(chunk.pre.items()) if n <= srv.chunk
+                     and srv.scheduler.slots[i].request is chunk.reqs[i]]
+        for req in sorted(srv.scheduler._pending, key=lambda r: r.wfq_tag):
+            need = srv.kv_ledger.blocks_needed(len(req.prompt), req.max_new)
+            if not finishing or need > free:
+                break
+            free -= need
+            finishing.pop(0)
+            counted[0] += 1
+        return inner(chunk)
+
+    srv._join_ahead = join_ahead
+    return counted
+
+
 @pytest.mark.parametrize("kind", ["dense", "hybrid-ssm"])
 def test_in_flight_streams_are_the_landed_loops_byte_for_byte(
         kind, engine1, hybrid_engine, tmp_path):
     """The script above through the loop as it is and through the loop that
-    lands every chunk before ``step()`` returns: the same tokens to the
-    same callbacks, the same finish reasons, the same journal a request and
-    the same blocks left in the allocator."""
+    lands every chunk before ``step()`` returns (so no join goes ahead):
+    the same tokens to the same callbacks, the same finish reasons, the
+    same journal a request and the same blocks left in the allocator. The
+    prompt that joins ahead of a landing is the host's count of them, and
+    the loop lowers nothing the landed loop did not: the chunk program and
+    the row that puts token 0 into it once."""
     eng = engine1 if kind == "dense" else hybrid_engine
-    got, landings = _serve_script(eng, tmp_path / "ahead.jsonl", ahead=True)
     want, landed = _serve_script(eng, tmp_path / "landed.jsonl", ahead=False)
+    telemetry.reset()
+    counted = []
+    with _lowerings() as lowered:
+        got, landings = _serve_script(
+            eng, tmp_path / "ahead.jsonl", ahead=True,
+            seam=lambda srv: counted.append(_count_joins_ahead(srv)))
+    assert telemetry.counter_value("tdt_serving_joins_ahead_total") == counted[0][0] == 1.0
+    # (the pool's zeros are a program of each server's own)
+    assert [name for name in lowered if name != "jit(<lambda>)"] == []
     assert got == want
     assert got["tokens"] == got["streamed"]
     assert got["reasons"] == ["cancelled", "ok", "deadline", "ok", "ok", "ok", "ok"]
@@ -912,9 +956,12 @@ def test_chunked_prompts_beside_decoding_slots_stream_the_fenced_loops_bytes(
     chunk to chunk."""
     eng = engine1 if kind == "dense" else hybrid_engine
     unfenced = lambda: telemetry.counter_value("tdt_serving_prefill_chunks_unfenced_total")
+    counted = []
     got, landings = _serve_script(
-        eng, tmp_path / "ahead.jsonl", True, CHUNKED_SCRIPT, prefill_chunk=4)
+        eng, tmp_path / "ahead.jsonl", True, CHUNKED_SCRIPT, prefill_chunk=4,
+        seam=lambda srv: counted.append(_count_joins_ahead(srv)))
     n_unfenced = unfenced()
+    assert telemetry.counter_value("tdt_serving_joins_ahead_total") == counted[0][0] == 2.0
     want, landed = _serve_script(
         eng, tmp_path / "landed.jsonl", False, CHUNKED_SCRIPT, prefill_chunk=4)
     assert got == want
@@ -924,6 +971,8 @@ def test_chunked_prompts_beside_decoding_slots_stream_the_fenced_loops_bytes(
     whys = [why for why, _ in landings]
     # the loop ran ahead beside prefilling slots and last chunks landed what
     # was in flight; both loops issued the same chunks, one of them unwaited
+    # (a prompt that joins ahead has its first chunk behind the decode chunk
+    # that frees its slot, and it counts as its chunk for that boundary)
     assert whys.count(None) >= 3 and whys.count("prefill") >= 2
     assert len(landings) == len(landed) and all(why == "other" for why, _ in landed)
     assert n_unfenced == 2 * (2 + 3) + 2 == unfenced() - n_unfenced
@@ -983,7 +1032,11 @@ def test_no_program_is_lowered_that_the_fenced_loop_does_not_lower(model1):
     lowers are, name for name and count for count, those of the loop that
     waits for every chunk. The decode chunk is one executable fed from the
     host and from the device, and each (chunk, prompt) shape of the prefill
-    one program, waited for or not."""
+    one program, waited for or not. The three tenants' chains fill the
+    pool, so the prompt that waits finds no room to join ahead of a landing
+    (the host's count says none can) and joins after it, the same bytes."""
+    counted = []
+
     def serve(fenced: bool):
         eng = make_engine(model1)
         with pytest.MonkeyPatch.context() as mp, _lowerings() as lowered:
@@ -992,6 +1045,9 @@ def test_no_program_is_lowered_that_the_fenced_loop_does_not_lower(model1):
             srv = InferenceServer(eng, num_slots=3, chunk=2, prefill_chunk=4)
             if fenced:
                 srv._sync_reason = lambda chunk: "other"
+                srv._join_ahead = lambda chunk: None
+            else:
+                counted.append(_count_joins_ahead(srv))
             reqs = [srv.submit(p, n) for p, n in
                     [([3, 17, 42], 21), (LONG12, 9), (LONG14, 7), (LONG9, 5)]]
             srv.run()
@@ -999,10 +1055,14 @@ def test_no_program_is_lowered_that_the_fenced_loop_does_not_lower(model1):
         return [list(r.tokens) for r in reqs], sorted(lowered)
 
     tokens, lowered = serve(fenced=False)
+    assert telemetry.counter_value("tdt_serving_joins_ahead_total") == counted[0][0] == 0.0
+    assert telemetry.counter_value("tdt_serving_kv_budget_wait_total") == 0.0
     want_tokens, want = serve(fenced=True)
     assert tokens == want_tokens and lowered == want
     assert lowered.count("jit(decode_chunk_paged)") == 1
     assert lowered.count("jit(chunk_fn)") == 4  # prompts of 3, 9, 12 and 14
+    # the join ahead's row program, run once as the server is built
+    assert lowered.count("jit(put_decode_rows)") == 1
 
 
 def test_unwaited_chunks_step_counters_sum_to_the_fenced_loops(hybrid_engine):
@@ -1135,6 +1195,71 @@ def test_a_cancel_of_a_prefilling_slot_with_chunks_unwaited(engine1):
     assert telemetry.counter_value("tdt_serving_cancelled_total", where="running") == 1.0
 
 
+# ============================ a join behind the chunk that frees its slot
+#
+# The scripts above join ahead too (``SCRIPT`` a prompt of one chunk that
+# decodes, ``CHUNKED_SCRIPT`` a prompt of four chunks and one of three that
+# wants one token): their tests hold those loops to the landed loop's bytes.
+
+
+@pytest.mark.chaos
+def test_chaos_fault_at_the_landing_of_a_chunk_with_a_join_ahead_behind_it(engine1):
+    """Two slots at chunk 2: the first request runs out in its first decode
+    chunk, the third (a prompt of one chunk that wants one token) joins
+    ahead of that chunk's landing, and the scripted decode fault fires at
+    that landing. The newcomer goes back to the queue with nothing
+    streamed, recovery re-prefills the first two from the history they had,
+    and every stream is the undisturbed serve's, each position once."""
+    eng = engine1
+    sizes = [([3, 17, 42], 3), ([8, 1, 13], 9), ([5, 5, 5], 1)]
+
+    def serve(schedule):
+        srv = InferenceServer(eng, num_slots=2, chunk=2)
+        landings = _landings(srv)
+        streams: dict[int, list] = {}
+        reqs = [srv.submit(p, n, on_token=lambda r, t, i: streams.setdefault(
+            r.req_id, []).append((i, t))) for p, n in sizes]
+        with resilience.chaos_schedule(schedule):
+            srv.run()
+        assert srv._in_flight is None and not srv._handovers
+        srv.shutdown(drain=False)
+        for r in reqs:  # every position once, in order
+            assert streams[r.req_id] == list(enumerate(r.tokens))
+        return [list(r.tokens) for r in reqs], landings
+
+    want, _ = serve("heal")
+    assert telemetry.counter_value("tdt_serving_joins_ahead_total") == 1.0
+    telemetry.reset()
+    got, landings = serve("abort@decode:0,heal")
+    assert got == want and [len(t) for t in got] == [3, 9, 1]
+    assert telemetry.counter_total("tdt_serving_recoveries_total") == 1.0
+    # the first landing raised with the join ahead behind it; the newcomer
+    # joined ahead again after the recovery, so it joined twice
+    assert landings[0] == (None, [])
+    assert telemetry.counter_value("tdt_serving_joins_ahead_total") == 2.0
+    assert telemetry.counter_value("tdt_serving_joins_total") == 4.0
+
+
+def test_join_ahead_rows_take_a_copy_of_the_counts(engine1, monkeypatch):
+    """The counts ``put_decode_rows`` hands the device are a copy of the
+    caller's: the server's ``_remaining`` mirror advances right after the
+    call, before the device need have read it, and a zero-copy alias of it
+    let a chunk see counts already advanced and stream -1 past them."""
+    seen = []
+    inner = engine1._put_decode_rows
+    monkeypatch.setattr(engine1, "_put_decode_rows",
+                        lambda *a: seen.append(a[-1]) or inner(*a))
+    rep = engine1.model.ctx.replicated()
+    tok, rem = jax.device_put((np.zeros(3, np.int32), np.full(3, 4, np.int32)), rep)
+    counts = np.array([9, 5, 7], np.int32)
+    out_tok, out_rem = engine1.put_decode_rows(
+        tok, rem, {1: jax.device_put(np.array([11], np.int32), rep)}, counts)
+    counts -= 2  # as the server's mirror moves on at once
+    assert not np.shares_memory(np.asarray(seen[0]), counts)
+    assert np.asarray(out_tok).tolist() == [0, 11, 0]
+    assert np.asarray(out_rem).tolist() == [4, 5, 4]
+
+
 # ========================= a request's timeline and the device's ledger
 #
 # The served script again (three slots at chunk 2, more clients than slots,
@@ -1248,8 +1373,11 @@ def test_starved_phases_and_the_time_outside_spans_are_the_counter(timeline):
     """Over the served window: the digest's sum over phases is what the
     ledger's total moved inside ``step()`` (every second of a step lies in a
     span), that plus the move outside is the total, and the counter less
-    ``no_work`` is the total less the interval still open at the end."""
+    ``no_work`` is the total less the interval still open at the end. The
+    script's waiting requests join ahead of the landings that free their
+    slots, so the identity holds with the waits a join ahead makes."""
     snap = timeline["snap"]
+    assert snap["counters"]["tdt_serving_joins_ahead_total"][0]["value"] >= 3
     after = {e["labels"]["after"]: e["value"]
              for e in snap["counters"]["tdt_engine_device_starved_seconds_total"]}
     phases = {e["labels"]["phase"]: e["sum"]
@@ -1261,8 +1389,9 @@ def test_starved_phases_and_the_time_outside_spans_are_the_counter(timeline):
     # the idle steps closed the last interval, so nothing is open
     assert counted == pytest.approx(total, rel=1e-2)
     assert counted == pytest.approx(sum(phases.values()) + (total - in_steps), rel=1e-2)
-    # the intervals are named after the waits that began them
-    assert set(after) <= {"prefill_chunk", "cache_scatter", "no_work"} | {
+    # the intervals are named after the waits that began them (``token0``:
+    # a join ahead's token 0 fetched at a landing with nothing queued behind)
+    assert set(after) <= {"prefill_chunk", "cache_scatter", "token0", "no_work"} | {
         f"decode_land:{why}" for why in ("finish", "free_slot", "prefill")}
     assert {"prefill_chunk", "cache_scatter", "decode_land:finish"} <= set(after)
     # the device starved under the engine's calls and the loop's own spans alike

@@ -121,6 +121,7 @@ def _engine_spec_run(eng, drafter, prompts, gens, token0s, kcaps):
     return spec, stats_tot, sizes
 
 
+@pytest.mark.timeout(360)
 def test_spec_engine_parity_paged(model1):
     """Byte parity of the k-wide verify against plain greedy decode on the
     block pool — truncated AND GDN drafters, with kcap moving mid-run and a
@@ -180,6 +181,7 @@ def _one_shot_refs(eng):
     ]
 
 
+@pytest.mark.timeout(360)
 @pytest.mark.parametrize("backend", ["xla", "mega"])
 def test_spec_serving_parity_staggered(model1, backend):
     """The acceptance bar: a spec-enabled InferenceServer streams
@@ -382,6 +384,7 @@ def test_spec_rollback_pool_invariants(model1, monkeypatch, schedule):
 
 
 @pytest.mark.chaos
+@pytest.mark.timeout(360)
 def test_spec_chaos_abort_mid_verify_restores_mega(model1, monkeypatch):
     """Chaos abort lands INSIDE the spec decode dispatch: the breaker
     degrades mega -> xla with zero dropped/duplicated tokens (speculative

@@ -930,7 +930,7 @@ def _longdoc_chunk_program(topo, p_len: int):
     return c, hlo, calls, held + pools
 
 
-@pytest.mark.parametrize("p_len", [4096, 8192, 16384])
+@pytest.mark.parametrize("p_len", [4096, 8192, 16384], scope="module")
 def test_latent_sparse_chunk_selects_without_a_sort(topo_2x2, p_len):
     """``longdoc``'s chunk program at each of its prompt lengths, for one
     v5e chip: it fits beside the pools, each of its two selecting layers
@@ -948,7 +948,7 @@ def test_latent_sparse_chunk_selects_without_a_sort(topo_2x2, p_len):
     assert _sorted_shapes(was) == ["f32[2048,16384]"]
 
 
-@pytest.mark.parametrize("p_len", [4096, 8192, 16384])
+@pytest.mark.parametrize("p_len", [4096, 8192, 16384], scope="module")
 def test_latent_sparse_chunk_attends_without_a_score_matrix(topo_2x2, p_len):
     """The same programs: every layer's attention under the mask is one call
     of the ``dsa_flash_prefill`` kernel, the selection's kernel is the only

@@ -24,6 +24,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.models.kv_cache import KVCache, PagedKVCache
@@ -529,6 +530,18 @@ class Engine:
 
         self._decode_chunk_paged = decode_chunk_paged
 
+        # The joins ahead's rows in the next chunk's operands
+        # (``put_decode_rows``): one program whatever the slots, its outputs
+        # placed as the server places the host's mirrors for a chunk it
+        # feeds itself.
+        def put_decode_rows(tok, rem, tokens, take, remaining):
+            return (jnp.where(take, jnp.concatenate(tokens), tok),
+                    jnp.where(take, remaining, rem))
+
+        self._put_decode_rows = jax.jit(
+            put_decode_rows, out_shardings=(ctx.replicated(), ctx.replicated()))
+        self._row_pad = None  # placed at the first call: a build places nothing
+
         # One decode step's LOGITS (see decode_logits_paged): the same step
         # programs the two chunk loops above iterate, nothing donated.
         self._step_logits = jax.jit(
@@ -917,13 +930,20 @@ class Engine:
 
     def complete_paged_prefill(self, paged: PagedKVCache, kbuf, vbuf, table_row,
                                start_block: int, slot: int = 0,
-                               state=()) -> PagedKVCache:
+                               state=(), *, wait: bool = True) -> PagedKVCache:
         """Scatter a finished prefill's context buffer into the pool along
         the slot's block chain (blocks below ``start_block`` are shared and
         skipped), and the prompt's carried ``state`` (the last chunk's) into
         ``slot`` of the slots' state; the defaults are a stateless model's.
         Pool buffers are donated; tables/lengths are the host's to update
-        (they travel as data with the next dispatch)."""
+        (they travel as data with the next dispatch).
+
+        With telemetry on the scatter is fenced, and the phase
+        (``cache_scatter``) runs from its issue to the end of its compute.
+        ``wait=False`` is a scatter issued behind a decode chunk still in
+        flight (the server's join ahead): nobody waits for it, the device
+        runs it before whatever is issued next, and the phase is its issue
+        alone."""
         timed = telemetry.enabled()
         t = time.perf_counter() if timed else 0.0
         # One phase (cache_scatter), so one span, as in ``prefill_chunk``.
@@ -934,9 +954,11 @@ class Engine:
                 paged.quant, paged.state, state, jnp.int32(slot),
             )
             ticket = tracing.device_issued()
-            if timed:
+            if timed and wait:
                 self._phase("cache_scatter", t, pk)
                 tracing.device_waited(ticket, "cache_scatter")
+            elif timed:
+                self._phase("cache_scatter", t)
         return dataclasses.replace(
             paged, k=pk, v=pv, k_scale=ks, v_scale=vs, state=slots_state
         )
@@ -1092,6 +1114,31 @@ class Engine:
             self.model.params, self._decode_extra, tokens, kc, vc,
             paged.lengths,
         )
+
+    def put_decode_rows(self, tokens: jax.Array, remaining: jax.Array,
+                        rows: dict, counts: np.ndarray):
+        """``tokens`` and ``remaining`` (a chunk's device-resident last tokens
+        and counts: the next chunk's operands) with each row ``slot`` of
+        ``rows`` set to its token (token 0 of a join, (1,), as
+        :meth:`sample_logits` returns it) and to ``counts[slot]``. The
+        server's join ahead puts its requests into the chunk issued after
+        the one that freed their slots this way, with nothing fetched. The
+        slots and the counts are data, and a slot with no row takes a
+        placeholder: one program, one call."""
+        n = tokens.shape[0]
+        if self._row_pad is None:
+            self._row_pad = jax.device_put(
+                np.zeros((1,), np.int32), self.model.ctx.replicated())
+        take = np.zeros((n,), bool)
+        take[list(rows)] = True
+        row_tokens = jax.device_put(
+            tuple(rows.get(i, self._row_pad) for i in range(n)),
+            self.model.ctx.replicated(),
+        )
+        # A copy of the counts: the caller's mirror changes before the device
+        # need have read it, and a zero-copy alias would let it see the change.
+        return self._put_decode_rows(
+            tokens, remaining, row_tokens, take, np.array(counts, np.int32))
 
     def sample_logits(self, logits: jax.Array, key: jax.Array) -> jax.Array:
         """Sample with the engine's configured method: a join's token 0

@@ -570,6 +570,13 @@ def device_waited(ticket: int, after: str) -> None:
         _STARVED_SINCE, _STARVED_AFTER = now_s(), after
 
 
+def device_ticket() -> int:
+    """The ticket of the newest step program issued (0 with telemetry off):
+    what a wait for a result the device computes after everything issued so
+    far (a sample made behind it) hands to :func:`device_waited`."""
+    return _ISSUED if telemetry.enabled() else 0
+
+
 def device_no_work() -> None:
     """The serving loop has found nothing to do (no tenant, no request
     due): the open interval closes where it stands, under the wait that

@@ -363,6 +363,17 @@ class PrefixIndex:
                 return False
         return True
 
+    def freeable_blocks(self) -> int:
+        """Blocks that evicting the whole index would return to the pool:
+        the indexed ones no running slot holds a ref on."""
+        n = 0
+        stack = list(self._roots.values())
+        while stack:
+            for child in stack.pop().children.values():
+                stack.append(child)
+                n += self.allocator.refcount(child.block) == 1
+        return n
+
     def evict(self, need_free: int, tenant: str | None = None) -> int:
         """Drop LRU leaves until the allocator has ``need_free`` free blocks
         or the index is empty, in isolation-preserving preference order:
@@ -513,7 +524,9 @@ class KVLedger:
         """Reserve ``req``'s full block chain (shared prefix + fresh tail).
         On success ``req.kv_blocks``/``req.kv_shared`` are set and True is
         returned; on False nothing is held (park the request, do not
-        reject — in-flight frees will eventually satisfy it)."""
+        reject — in-flight frees will eventually satisfy it). The prefix
+        index is evicted only where that makes the room: one stripped for
+        nothing would cost the prefix hits that come after."""
         bs = self.block_size
         need_total = self.blocks_needed(len(req.prompt), req.max_new)
         shared: list[int] = []
@@ -526,6 +539,10 @@ class KVLedger:
             self.allocator.incref(shared)
         fresh_need = need_total - len(shared)
         if self.allocator.num_free < fresh_need:
+            if self.allocator.num_free + self.prefix.freeable_blocks() < fresh_need:
+                if shared:
+                    self.allocator.free(shared)
+                return False
             dropped = self.prefix.evict(fresh_need, tenant=req.tenant)
             if dropped:
                 telemetry.inc("tdt_kv_evictions_total", float(dropped))
@@ -764,6 +781,17 @@ class Scheduler:
         telemetry.set_gauge("tdt_serving_queue_depth", float(depth))
         return req
 
+    def requeue(self, req: Request) -> None:
+        """Put an admitted request back where its tag places it in the
+        queue, ahead of everything submitted after it: a join ahead undone
+        because the landing it waited behind failed. Its chain is the
+        caller's to release first."""
+        req.state = RequestState.QUEUED
+        with self._lock:
+            self._pending.append(req)
+            depth = len(self._pending)
+        telemetry.set_gauge("tdt_serving_queue_depth", float(depth))
+
     def _assign_wfq_tag_locked(self, req: Request) -> None:
         """Stamp ``req``'s WFQ virtual finish tag: start at the later of
         the queue clock and the tenant's previous tag (serializing a
@@ -899,7 +927,7 @@ class Scheduler:
         return False
 
     # ------------------------------------------------------------------ joins
-    def join_free_slots(self, now_s: float) -> list[Slot]:
+    def join_free_slots(self, now_s: float, slots=None, fits=None) -> list[Slot]:
         """Admit arrived requests into free slots in WFQ-tag order
         (weighted-fair across tenants, FCFS within one — a single-tenant
         queue's tags are monotone in submission order, so the walk is
@@ -910,12 +938,22 @@ class Scheduler:
         TTFT/total budget lapsed while queued are rejected here (with
         ``shed_deadline``) and requests cancelled while queued are dropped
         — both run even when no slot is free, so a hopeless request never
-        waits for capacity it can no longer use."""
+        waits for capacity it can no longer use.
+
+        ``slots`` are the slots the walk fills, in that order (the free
+        ones by default). With ``fits`` given the walk admits in strict
+        order: the first arrived request that ``fits`` refuses or the ledger
+        cannot hold ends the admissions, keeps its place and is not parked
+        for blocks. That is the server's join ahead, offered the slots a
+        decode chunk in flight finishes (their tenants still hold their
+        chains): it must not admit past a request that the walk after the
+        landing would have admitted."""
         # The scheduler's side of the boundary the server's join crosses.
         with tracing.span_current("tdt_scheduler_join_free_slots"):
             joined: list[Slot] = []
             expired: list[Request] = []
-            free = [s for s in self.slots if s.state is SlotState.FREE]
+            free = ([s for s in self.slots if s.state is SlotState.FREE]
+                    if slots is None else list(slots))
             with self._lock:
                 deferred: collections.deque[Request] = collections.deque()
                 # Stable sort: ties (same tag — impossible within a tenant,
@@ -933,7 +971,13 @@ class Scheduler:
                     if req.arrival_time_s > now_s or not free:
                         deferred.append(req)  # not offered yet / no capacity —
                         continue              # keep its order
-                    if self.kv_ledger is not None and not self.kv_ledger.reserve(req):
+                    if fits is not None:
+                        if not (fits(req) and (self.kv_ledger is None
+                                               or self.kv_ledger.reserve(req))):
+                            free = []  # the strict walk admits no one past it
+                            deferred.append(req)
+                            continue
+                    elif self.kv_ledger is not None and not self.kv_ledger.reserve(req):
                         # Pool dry even after prefix-index eviction. Blocks WILL
                         # free as running slots finish, so this is a deferral
                         # (kv_budget_wait), not a reject; the walk keeps going —

@@ -22,6 +22,11 @@ cache: a global block pool + per-slot block tables
   returns, and the next one is issued from its device-resident last tokens
   before it is landed (waited for, fetched, streamed): the device never
   waits for the host between the two. See :meth:`_decode_once`.
+* **join ahead** — where a slot finishes inside the chunk just issued and a
+  request waits, the request is admitted into that slot at once and its
+  prefill, token 0 and pool scatter are issued behind the chunk; the chunk
+  stays in flight, the next one takes the newcomer's row from the device,
+  and the landing hands the slot over. See :meth:`_join_ahead`.
 
 Everything the device sees is fixed-shape (one compile per chunk size, one
 prefill compile per distinct (chunk, prompt) length pair, one scatter
@@ -172,6 +177,20 @@ class _Chunk:
     t_issue: float  # ``time.perf_counter()`` before the issue
     d_start: float  # the same moment on the tracing clock
     dispatch_id: int | None  # the issue's span, for the tenants' traces
+    reqs: dict  # slot idx -> its tenant at the issue (a join ahead may take the slot)
+
+
+@dataclasses.dataclass
+class _Handover:
+    """A slot taken by a join ahead (:meth:`InferenceServer._join_ahead`):
+    its tenant until ``chunk`` lands, and, once the newcomer's one-chunk
+    prompt is issued, what its completion at that landing fetches."""
+
+    chunk: _Chunk  # the chunk in flight that frees the slot
+    old: Request  # the tenant that finishes in it
+    st: dict | None = None  # the newcomer's prefill cursor, its last chunk issued
+    token0: object = None  # (1,) token 0 on the device
+    ticket: int = 0  # the newest step program when token 0 was sampled
 
 
 class InferenceServer:
@@ -316,6 +335,18 @@ class InferenceServer:
         #: The decode chunk issued and not yet landed (at most one when
         #: :meth:`step` returns), see :meth:`_decode_once`.
         self._in_flight: _Chunk | None = None
+        #: Slot idx -> the join ahead that took it before the chunk in
+        #: flight landed (:meth:`_join_ahead`); settled at that landing.
+        self._handovers: dict[int, _Handover] = {}
+        # Counted from 0, so that a server where none went ahead says so.
+        telemetry.inc("tdt_serving_joins_ahead_total", 0.0)
+        # The program the joins ahead put their rows into the next chunk's
+        # operands with, run once here in set-up: never lowered in the
+        # window, whether or not a join goes ahead before then.
+        self.engine.put_decode_rows(*jax.device_put(
+            (self._last.copy(), self._remaining.copy()),
+            self.engine.model.ctx.replicated(),
+        ), {}, self._remaining)
         #: ``time.perf_counter()`` of the last landing: a chunk's wall is
         #: the time between two landings.
         self._landed_at = 0.0
@@ -951,6 +982,65 @@ class InferenceServer:
                               float(len(joined)))
         return bool(joined)
 
+    def _join_ahead(self, chunk: _Chunk) -> None:
+        """Admit waiting requests into the slots that ``chunk``, just issued
+        and in flight, finishes, before it lands: the host knows at the issue
+        which slots run out in it (there is no stop token), so the join need
+        not wait for the landing, and its device work lies behind the chunk.
+
+        The scheduler's walk (:meth:`Scheduler.join_free_slots`) is offered
+        those slots in index order, as the free list would hold them after
+        the landing, and admits in strict order: it stops at the first
+        request that cannot go ahead (:meth:`_fits_ahead`) or whose chain the
+        pool cannot hold beside the finishing tenants' (still held), and
+        that slot joins after the landing as it always did. Each newcomer's
+        prefill is armed and its first chunk issued (:meth:`_issue_ahead`);
+        the finishing tenant stays the chunk's until the landing hands the
+        slot over (:meth:`_hand_over`). Where every finishing slot has a
+        successor the chunk stays in flight (:meth:`_sync_reason`). Nothing
+        but the finishing slot, the waiting request and the pool's room
+        decides it."""
+        if not self.scheduler.queue_depth():
+            return
+        finishing = [s for s in chunk.decoding if chunk.pre[s.idx] <= self.chunk
+                     and s.request is chunk.reqs[s.idx]]
+        if not finishing:
+            return
+        with self._trace.span("tdt_serving_join", ring=False):
+            joined = self.scheduler.join_free_slots(
+                self._now(), slots=finishing, fits=self._fits_ahead)
+            for slot in joined:
+                self._handovers[slot.idx] = _Handover(chunk, chunk.reqs[slot.idx])
+                self._lengths[slot.idx] = 0  # the newcomer's, until its prompt is in
+            for slot in joined:
+                # As in ``_join_ready``: a recovery set off by an earlier
+                # slot's issue has prefilled this one (or sent it back).
+                if slot.request is None or slot.state is not SlotState.PREFILL:
+                    continue
+                self._guarded(lambda s=slot: self._issue_ahead(s),
+                              what=f"join ahead of request {slot.request.req_id}")
+            if joined and self._in_flight is chunk:
+                self._push_tables()
+                self._publish_kv_gauges()
+        if joined:
+            telemetry.inc("tdt_serving_joins_total", float(len(joined)))
+            telemetry.inc("tdt_serving_joins_ahead_total", float(len(joined)))
+            if self._prefix_skipped:
+                telemetry.inc("tdt_serving_prefix_lookups_skipped_total",
+                              float(len(joined)))
+
+    def _fits_ahead(self, req: Request) -> bool:
+        """A request that joins ahead: a fresh prompt prefilled here. One
+        that resumes a history, imports its KV or parks it for a handoff
+        joins after the landing."""
+        return not req.tokens and req.kv_import is None and not req.prefill_only
+
+    def _issue_ahead(self, slot: Slot) -> None:
+        """Arm a join ahead's prefill and issue its first chunk behind the
+        chunk in flight (the key split in join order, as every join's)."""
+        self._begin_prefill(slot)
+        self._advance_prefill(slot, ahead=self._handovers[slot.idx])
+
     def _prefill_to_completion(self, slot: Slot) -> None:
         """The chunked prefill of ``slot``'s tenant run to its end before
         the next slot's turn: what recovery and restore re-prefill with.
@@ -1019,12 +1109,22 @@ class InferenceServer:
         for idx in list(self._prefilling):
             if idx not in self._prefilling:
                 continue  # a recovery mid-sweep rebuilt the cursor map
+            if self._prefilling[idx].pop("ahead", False):
+                # Its chunk for this boundary went ahead, behind the decode
+                # chunk that freed its slot: one chunk a boundary, as ever.
+                continue
             slot = self.scheduler.slots[idx]
             self._guarded(lambda s=slot: self._advance_prefill(s),
                           what=f"prefill chunk for slot {idx}")
         return True
 
-    def _advance_prefill(self, slot: Slot) -> None:
+    def _advance_prefill(self, slot: Slot, ahead: _Handover | None = None) -> None:
+        """One chunk of ``slot``'s prefill. ``ahead`` is the join ahead whose
+        first chunk this is: a prompt's last chunk is then left to the
+        device too, and so are the sample and the scatter behind it
+        (:meth:`_issue_ahead_completion`); any other is the prompt's chunk
+        for the boundary it was issued before, so the sweep of the next
+        iteration passes the slot by (:meth:`_advance_prefills`)."""
         st = self._prefilling.get(slot.idx)
         if st is None:
             return
@@ -1051,13 +1151,17 @@ class InferenceServer:
             # logits are nobody's, so the chunk is left to the device.
             logits, st["kbuf"], st["vbuf"], st["state"] = self.engine.prefill_chunk_state(
                 st["kbuf"], st["vbuf"], jnp.asarray(chunk_ids), off, last_idx,
-                st["state"], wait=final,
+                st["state"], wait=final and ahead is None,
             )
         st["off"] = off + len(take)
         st["n_chunks"] += 1
         st["own_s"] += self._now() - t_own
         if not final:
             telemetry.inc("tdt_serving_prefill_chunks_unfenced_total")
+            st["ahead"] = ahead is not None
+            return
+        if ahead is not None:
+            self._issue_ahead_completion(slot, st, logits, ahead)
             return
         # The completion writes the pool, the tables and the mirrors, and
         # the slot decodes from the host's ``_last`` / ``_remaining``: a
@@ -1091,6 +1195,7 @@ class InferenceServer:
             self.cache, st["kbuf"], st["vbuf"], self._table_row(req),
             req.kv_shared, slot.idx, st["state"],
         )
+        ticket = tracing.device_ticket()
         self._lengths[slot.idx] = p_len
         self.kv_ledger.register_prefix(req)
         # CoW safety net over decode's write range. Structurally dead (the
@@ -1122,12 +1227,26 @@ class InferenceServer:
                 self._park_handoff(slot, p_len)
             return
         _, sub = jax.random.split(st["key"])
-        tok = self.engine.sample_logits(logits, sub)
-        with self._trace.span("tdt_serving_fetch", ring=False, what="token0"):
-            tok = int(tok[0])
+        tok = self._fetch_token0(self.engine.sample_logits(logits, sub), ticket)
         self._last[slot.idx] = tok
         self._remaining[slot.idx] = req.max_new - 1
         self.scheduler.start_decode(slot)
+        self._stream_token0(slot, st, tok, t_own)
+
+    def _fetch_token0(self, token0, ticket: int) -> int:
+        """Wait for token 0 and fetch it. The device samples it after the
+        step program ``ticket`` (the scatter), so where nothing was issued
+        since, the device's ledger opens its starved interval here."""
+        with self._trace.span("tdt_serving_fetch", ring=False, what="token0"):
+            tok = int(token0[0])
+        tracing.device_waited(ticket, "token0")
+        return tok
+
+    def _stream_token0(self, slot: Slot, st: dict, tok: int, t_own: float) -> None:
+        """Stream a fresh prefill's token 0 (TTFT ends here), observe the
+        request's time in its slot, journal it, and finish or park the slot
+        where the request wants nothing more of it."""
+        req = slot.request
         with self._trace.span("tdt_serving_emit", ring=False, n_tokens=1):
             self._stream(req, tok)
         if req.admitted_at is not None:
@@ -1143,10 +1262,55 @@ class InferenceServer:
             self._journal.append(
                 "prefill", req_id=req.req_id, start=0, tokens=[tok]
             )
-        if self._remaining[slot.idx] == 0:
+        if len(req.tokens) >= req.max_new:
             self._finish(slot)
         elif req.prefill_only:
-            self._park_handoff(slot, p_len)
+            self._park_handoff(slot, len(st["ids"]))
+
+    def _issue_ahead_completion(self, slot: Slot, st: dict, logits,
+                                h: _Handover) -> None:
+        """A join ahead's one-chunk prompt, its chunk issued unwaited: the
+        scatter into the pool along the new chain and into the slot's state,
+        then token 0's sample, both behind it and nothing waited for. The
+        mirrors and the slot turn to the newcomer, so the next chunk decodes
+        it (``_decode_once`` puts token 0 into its operands on the device);
+        the rest of the completion, token 0 fetched and streamed, is the
+        landing's (:meth:`_land_successor`)."""
+        t_own = self._now()
+        req = st["req"]
+        del self._prefilling[slot.idx]
+        self.cache = self.engine.complete_paged_prefill(
+            self.cache, st["kbuf"], st["vbuf"], self._table_row(req),
+            req.kv_shared, slot.idx, st["state"], wait=False,
+        )
+        h.ticket = tracing.device_ticket()
+        _, sub = jax.random.split(st["key"])
+        token0 = self.engine.sample_logits(logits, sub)
+        token0.copy_to_host_async()
+        self._lengths[slot.idx] = len(st["ids"])
+        self._remaining[slot.idx] = req.max_new - 1
+        for j in range(len(st["ids"]) // self.block_size, len(req.kv_blocks)):
+            self.kv_ledger.make_writable(req, j)
+        self.scheduler.start_decode(slot)
+        st["own_s"] += self._now() - t_own
+        h.st, h.token0 = st, token0
+
+    def _land_successor(self, slot: Slot, h: _Handover) -> None:
+        """The rest of a join ahead's completion, at the landing of the
+        chunk it was issued behind, once that chunk's tenant has handed the
+        slot over: the prefix registered, token 0 fetched (the device has
+        long sampled it) and streamed."""
+        t_own = self._now()
+        req, st = slot.request, h.st
+        with self._trace.span(
+            "tdt_serving_prefill_complete", ring=False, slot=slot.idx
+        ):
+            self.kv_ledger.register_prefix(req)
+            self._publish_kv_gauges()
+            telemetry.observe("tdt_serving_prefill_chunks", float(st["n_chunks"]))
+            tok = self._fetch_token0(h.token0, h.ticket)
+            self._last[slot.idx] = tok
+            self._stream_token0(slot, st, tok, t_own)
 
     def _park_handoff(self, slot: Slot, p_len: int) -> None:
         """Prefill-pool half of a disaggregated handoff: keep the prefilled
@@ -1226,7 +1390,10 @@ class InferenceServer:
         :meth:`step` returns, which is the order the loop always had. The
         prefill chunks a step issues lie between k and k+1 on the device, as
         they always did; those that are not their prompt's last are waited
-        for by nobody.
+        for by nobody. A slot that finishes in k+1 may be taken at once by a
+        waiting request (:meth:`_join_ahead`): its prefill, token 0 and pool
+        scatter are issued behind k+1, and k+2 takes token 0 into its
+        operands on the device.
 
         The host's mirrors follow the issue, not the landing: ``_remaining``
         and ``_lengths`` advance by what the chunk will do (``min(remaining,
@@ -1264,6 +1431,13 @@ class InferenceServer:
                 )
             else:
                 tok, rem = ahead.tok, ahead.rem
+                # The slots joins ahead took behind ``ahead`` decode their
+                # newcomers from token 0, which the device sampled.
+                rows = {idx: h.token0 for idx, h in self._handovers.items()
+                        if h.chunk is ahead and h.token0 is not None}
+                if rows:
+                    tok, rem = self.engine.put_decode_rows(
+                        tok, rem, rows, self._remaining)
             # Through ``decode_steps_paged`` by that name, and with what it
             # returns: whoever wraps the engine's decode call (the
             # benchmark's planted faults do) wraps the loop's chunks.
@@ -1273,6 +1447,7 @@ class InferenceServer:
         chunk = _Chunk(
             issued[0], out, tok, rem, decoding, pre, t0, d_start,
             dsp["span_id"] if dsp is not None else None,
+            {s.idx: s.request for s in decoding},
         )
         for slot in decoding:
             n = min(pre[slot.idx], self.chunk)
@@ -1292,6 +1467,8 @@ class InferenceServer:
             # k+1 was issued. A slot it reaps gets nothing of k+1.
             with self._trace.span("tdt_serving_reap", ring=False):
                 self._reap_slots()
+        if self._in_flight is chunk:
+            self._join_ahead(chunk)
         if self._in_flight is chunk and (why := self._sync_reason(chunk)) is not None:
             self._land_in_flight(why)
 
@@ -1300,14 +1477,16 @@ class InferenceServer:
         (the label of ``tdt_serving_decode_sync_boundaries_total``), or None:
         it stays in flight, because the next chunk will run over the same
         slots whatever this one streams. The first that holds, in this
-        order: a slot finishes inside it; a slot is free (a request may join
-        it at the next boundary); the chunk bounced through the contiguous
-        layout (a pp mesh: landed as issued); the server is draining. A slot
-        that prefills is no reason: its chunks touch the prompt's own buffers
+        order: a slot finishes inside it and no join ahead took it
+        (:meth:`_join_ahead`); a slot is free (a request may join it at the
+        next boundary); the chunk bounced through the contiguous layout (a
+        pp mesh: landed as issued); the server is draining. A slot that
+        prefills is no reason: its chunks touch the prompt's own buffers
         alone, and the one that completes it lands what is in flight itself
         (:meth:`_advance_prefill`, counted as ``prefill``). The loop can see
         all of it before the chunk runs; nothing here is a setting."""
-        if any(n <= self.chunk for n in chunk.pre.values()):
+        if any(n <= self.chunk and self.scheduler.slots[idx].request is chunk.reqs[idx]
+               for idx, n in chunk.pre.items()):
             return "finish"
         if any(s.state not in (SlotState.DECODE, SlotState.PREFILL)
                for s in self.scheduler.slots):
@@ -1333,7 +1512,11 @@ class InferenceServer:
         tokens, stream and journal them, finish the slots it finished.
         ``why`` is None for a chunk landed behind the next one's issue, else
         why it was not. The slots in ``drop`` get nothing of it: they are
-        being reaped, and the chunk was issued before the reap could know."""
+        being reaped, and the chunk was issued before the reap could know
+        (a slot a join ahead took is reaped for its newcomer: its tenant in
+        the chunk streams). A slot taken by a join ahead is handed over: its
+        tenant here finishes without freeing it, and the newcomer's
+        completion follows (:meth:`_land_successor`)."""
         with self._trace.span("tdt_serving_fetch", ring=False, what="chunk"):
             # The scripted fault of a decode chunk shows where a real one
             # does: when the host comes for the chunk.
@@ -1357,11 +1540,12 @@ class InferenceServer:
             "tdt_serving_decode_rows_total",
             float(sum(min(n, self.chunk) for n in chunk.pre.values())),
         )
-        decoding = [s for s in chunk.decoding if s.idx not in drop]
+        decoding = [s for s in chunk.decoding
+                    if s.idx not in drop or chunk.reqs[s.idx] is not s.request]
         with self._trace.span("tdt_serving_emit", ring=False):
             n_streamed = 0
             for slot in decoding:
-                req = slot.request
+                req = chunk.reqs[slot.idx]
                 n_valid = min(chunk.pre[slot.idx], self.chunk)
                 req.trace.record(
                     "tdt_serving_decode_chunk", chunk.d_start, d_end,
@@ -1385,8 +1569,18 @@ class InferenceServer:
         # Finishes run after every slot's tokens are out: _finish pushes the
         # tables, and frees a slot that a request may join next.
         for slot in decoding:
-            if slot.request is not None and chunk.pre[slot.idx] <= self.chunk:
+            req = chunk.reqs[slot.idx]
+            if chunk.pre[slot.idx] > self.chunk or req.state is not RequestState.RUNNING:
+                continue
+            h = self._handovers.get(slot.idx)
+            if h is not None and h.chunk is chunk:
+                del self._handovers[slot.idx]
+            if slot.request is req:
                 self._finish(slot)
+                continue
+            self._hand_over(slot, req)
+            if h is not None and h.token0 is not None and slot.request is not None:
+                self._land_successor(slot, h)
         if n_streamed:
             telemetry.inc("tdt_serving_tokens_total", float(n_streamed))
             # Feed the admission-time overload projection.
@@ -1544,19 +1738,6 @@ class InferenceServer:
             "tdt_serving_finish_slot", ring=False, slot=slot.idx, reason=reason
         ):
             req = slot.request
-            req.finish_reason = reason
-            req.state = (
-                RequestState.CANCELLED if reason == "cancelled" else RequestState.DONE
-            )
-            req.finished_at = self._now()
-            if reason == "ok":
-                tpot = req.tpot_s
-                if tpot is not None:
-                    telemetry.observe("tdt_serving_tpot_seconds", tpot)
-                telemetry.inc("tdt_serving_requests_completed_total")
-            # Per-(tenant, tier) SLO ledger: digests + goodput/violation
-            # counters, classified against the request's own deadline fields.
-            slo.record_finish(req, reason)
             self.scheduler.finish(slot)
             self.scheduler.release(slot)
             self._remaining[slot.idx] = 0
@@ -1567,20 +1748,51 @@ class InferenceServer:
             self.kv_ledger.release(req)
             self._push_tables()
             self._publish_kv_gauges()
-            if self._journal is not None:
-                # "finish" always forces the fsync: a completed stream must be
-                # durable so recovery can skip it idempotently.
-                self._journal.append(
-                    "finish", req_id=req.req_id, reason=reason,
-                    n_tokens=len(req.tokens),
-                )
-            if req.on_finish is not None:
-                try:
-                    req.on_finish(req)
-                except Exception:
-                    telemetry.inc("tdt_serving_callback_errors_total", kind="finish")
-            req.trace.point("tdt_serving_finish", slot=slot.idx, reason=reason)
-            req.trace.finish(status=reason, n_tokens=len(req.tokens))
+            self._end_stream(req, slot.idx, reason)
+
+    def _hand_over(self, slot: Slot, req: Request) -> None:
+        """End the stream of ``req``, the tenant of ``slot`` in the chunk
+        just landed, where a join ahead took the slot before that landing
+        (:meth:`_join_ahead`): its chain goes back to the pool, and the
+        slot, its mirrors and its table row are the newcomer's and stay so."""
+        with self._trace.span(
+            "tdt_serving_finish_slot", ring=False, slot=slot.idx, reason="ok"
+        ):
+            self.kv_ledger.release(req)
+            self._publish_kv_gauges()
+            self._end_stream(req, slot.idx, "ok")
+
+    def _end_stream(self, req: Request, idx: int, reason: str) -> None:
+        """A finished request's own end, its slot's left behind: its state
+        and the counters, its SLO record, the journal's ``finish`` record,
+        its callback and its trace."""
+        req.finish_reason = reason
+        req.state = (
+            RequestState.CANCELLED if reason == "cancelled" else RequestState.DONE
+        )
+        req.finished_at = self._now()
+        if reason == "ok":
+            tpot = req.tpot_s
+            if tpot is not None:
+                telemetry.observe("tdt_serving_tpot_seconds", tpot)
+            telemetry.inc("tdt_serving_requests_completed_total")
+        # Per-(tenant, tier) SLO ledger: digests + goodput/violation
+        # counters, classified against the request's own deadline fields.
+        slo.record_finish(req, reason)
+        if self._journal is not None:
+            # "finish" always forces the fsync: a completed stream must be
+            # durable so recovery can skip it idempotently.
+            self._journal.append(
+                "finish", req_id=req.req_id, reason=reason,
+                n_tokens=len(req.tokens),
+            )
+        if req.on_finish is not None:
+            try:
+                req.on_finish(req)
+            except Exception:
+                telemetry.inc("tdt_serving_callback_errors_total", kind="finish")
+        req.trace.point("tdt_serving_finish", slot=idx, reason=reason)
+        req.trace.finish(status=reason, n_tokens=len(req.tokens))
 
     def _reap_slots(self) -> None:
         """Chunk-boundary lifecycle sweep: free cancelled slots and truncate
@@ -1697,6 +1909,22 @@ class InferenceServer:
                     )
                 self.cache = self._fresh_cache()
 
+    def _undo_joins_ahead(self) -> None:
+        """The joins ahead whose chunk did not land (its landing failed, or
+        it was dropped unlanded): each slot goes back to the tenant that
+        decoded in that chunk, which recovery re-prefills from the history
+        it had streamed, and each newcomer back to its place in the queue,
+        its chain released and nothing of it streamed."""
+        for idx, h in sorted(self._handovers.items()):
+            slot = self.scheduler.slots[idx]
+            new = slot.request
+            if new is not None and new is not h.old:
+                self._prefilling.pop(idx, None)
+                self.kv_ledger.release(new)
+                self.scheduler.requeue(new)
+            slot.request, slot.state = h.old, SlotState.DECODE
+        self._handovers.clear()
+
     def _recover(self, why: str) -> None:
         # A chunk still in flight lands first: what it streams is history
         # the re-prefill starts from. One that cannot land is dropped
@@ -1707,6 +1935,7 @@ class InferenceServer:
             telemetry.emit(
                 "serving_in_flight_dropped", error=f"{type(e).__name__}: {e}"
             )
+        self._undo_joins_ahead()
         eng = self.engine
         from_backend = eng.backend
         occupied = self.scheduler.occupied_slots()
